@@ -219,17 +219,9 @@ def _wait_healthy(addr: str, timeout: float = 120.0):
     raise RuntimeError(f"replica {addr} never became healthy")
 
 
-_FORCE_CPU = (
-    "import jax; jax.config.update('jax_platforms', 'cpu'); "
-    "import sys; from elasticdl_tpu.serving.server import main; "
-    "sys.exit(main(sys.argv[1:]))"
-)
-
-_FORCE_CPU_ROWSVC = (
-    "import jax; jax.config.update('jax_platforms', 'cpu'); "
-    "import sys; from elasticdl_tpu.embedding.row_service import main; "
-    "sys.exit(main(sys.argv[1:]))"
-)
+# This bench measures the host-side serving plane; its replica
+# processes stay off any chip.
+_CPU_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
 
 def _spawn_row_service():
@@ -242,12 +234,12 @@ def _spawn_row_service():
     port = _free_port()
     proc = subprocess.Popen(
         [
-            sys.executable, "-c", _FORCE_CPU_ROWSVC,
+            sys.executable, "-m", "elasticdl_tpu.embedding.row_service",
             "--model_zoo", model_zoo_dir(),
             "--model_def", "deepfm.deepfm_host.custom_model",
             "--addr", f"localhost:{port}",
         ],
-        cwd=_ROOT, stdout=subprocess.DEVNULL,
+        cwd=_ROOT, env=_CPU_ENV, stdout=subprocess.DEVNULL,
         stderr=subprocess.DEVNULL,
     )
     deadline = time.monotonic() + 120
@@ -280,7 +272,7 @@ def _spawn_replicas(bundle: str, row_addr: str, n: int,
     for i in range(n):
         port = _free_port()
         cmd = [
-            sys.executable, "-c", _FORCE_CPU,
+            sys.executable, "-m", "elasticdl_tpu.serving.server",
             "--model_dir", bundle,
             "--row_service_addr", row_addr,
             "--port", str(port),
@@ -293,7 +285,7 @@ def _spawn_replicas(bundle: str, row_addr: str, n: int,
         if pin:
             cmd = ["taskset", "-c", str(i % cores)] + cmd
         proc = subprocess.Popen(
-            cmd, cwd=_ROOT, stdout=subprocess.DEVNULL,
+            cmd, cwd=_ROOT, env=_CPU_ENV, stdout=subprocess.DEVNULL,
             stderr=subprocess.DEVNULL,
         )
         replicas.append((proc, f"localhost:{port}"))

@@ -116,7 +116,9 @@ def _submit_job(args, mode: str) -> int:
 
 def _run_local(args, mode: str) -> int:
     from elasticdl_tpu.api.local_executor import LocalExecutor
+    from elasticdl_tpu.common.jax_env import enable_compile_cache
 
+    logger.info("XLA compilation cache at %s", enable_compile_cache())
     if mode == "train":
         result = LocalExecutor(args).run()
         logger.info("Job finished: %s", result)

@@ -440,16 +440,12 @@ def main(argv=None) -> int:
                              "removed afterwards)")
     args = parser.parse_args(argv)
 
-    # Virtual multi-device CPU mesh, same forcing as the chaos CLI.
+    # Virtual multi-device CPU mesh (run with JAX_PLATFORMS=cpu).
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8"
         ).strip()
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
 
     workdir = args.workdir
     cleanup = False

@@ -81,13 +81,6 @@ def make_row_service():
 '''
 
 
-def _force_cpu_if_requested():
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
-
 def _free_port() -> int:
     import socket
 
@@ -416,7 +409,6 @@ def run_two_process(workdir: str, timeout_secs: float = 120.0) -> dict:
 
 
 def main(argv=None) -> int:
-    _force_cpu_if_requested()
     parser = argparse.ArgumentParser("elasticdl_tpu-profile-drill")
     parser.add_argument("--workdir", default="",
                         help="Scratch dir (default: a tempdir)")

@@ -330,7 +330,12 @@ class CheckpointHook:
                 remark_dirty(self._host_tables, dirty_ids)
                 self._planner.reset()
                 raise
-            self._last_saved = version
 
         self._writer.submit(write, label=f"v{version}-{plan}")
+        # The save interval counts from the last version HANDED to the
+        # writer, not the last one landed: a multi-GB write takes many
+        # steps' time, and counting from a stale baseline saved on every
+        # task and wrote the final version twice (seen on the chip at
+        # 2.6 GB a version). A failed write surfaces at flush().
+        self._last_saved = version
         self._m_stall.observe(_time.monotonic() - t0)

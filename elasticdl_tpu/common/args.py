@@ -268,10 +268,6 @@ def add_train_params(parser):
     add_bool_param(parser, "--fuse_task_steps", False,
                    "Scan a whole task's minibatches in one XLA program "
                    "(removes per-step host dispatch)")
-    parser.add_argument("--compilation_cache_dir", default="",
-                        help="Persistent XLA compilation cache; elastic "
-                             "relaunches skip recompiling unchanged "
-                             "programs (point at a shared volume)")
     parser.add_argument("--profile_dir", default="",
                         help="Write a jax.profiler trace (TensorBoard/"
                              "Perfetto) for a step window")

@@ -92,8 +92,6 @@ MASTER_SERVICE_PORT = 50001
 WORKER_COORD_PORT = 50002
 
 MAX_TASK_RETRIES = 3
-MAX_MINIBATCH_RETRY_NUM = 64
-MAX_ALLREDUCE_RETRY_NUM = 5
 
 # Embedding tables larger than this are auto-sharded across the mesh
 # (reference model_handler.py:85-89).

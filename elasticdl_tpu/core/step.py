@@ -97,12 +97,12 @@ def build_multi_step(loss_fn: Callable, unroll: int = 4) -> Callable:
     work is already a task of ``num_minibatches_per_task`` minibatches
     (task_dispatcher.py records_per_task), and on TPU fusing those steps
     removes T-1 host dispatches per task — the dominant cost for small
-    models behind a device tunnel. ``metrics`` leaves come back stacked
-    (T,) so per-step losses stay observable.
+    models. ``metrics`` leaves come back stacked (T,) so per-step
+    losses stay observable.
 
     ``unroll`` partially unrolls the scan body (measured ~5% on the mnist
-    CNN at unroll=4 on v5e; full unroll inflates the program for no
-    further gain and can exceed remote-compile payload limits).
+    CNN at unroll=4 on v5e; full unroll inflates the program, and its
+    compile time, for no further gain).
     """
 
     def multi_step(state, batches):

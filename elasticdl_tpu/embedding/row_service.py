@@ -2671,7 +2671,12 @@ def main(argv=None):
     rows (see validate_shard_layout)."""
     import argparse
 
+    from elasticdl_tpu.common.jax_env import force_cpu
     from elasticdl_tpu.core.model_spec import load_model_zoo_module
+
+    # The zoo module imports jax; rows live on the host and this
+    # process must never take a chip from a worker.
+    force_cpu()
 
     parser = argparse.ArgumentParser("elasticdl_tpu-row-service")
     parser.add_argument("--model_zoo", required=True)

@@ -21,6 +21,7 @@ from elasticdl_tpu.common.args import (
     parse_envs,
     parse_master_args,
 )
+from elasticdl_tpu.common.jax_env import force_cpu
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.comm.rpc import RpcServer
 from elasticdl_tpu.core.model_spec import get_model_spec
@@ -1078,9 +1079,11 @@ class Master:
                 self.scheduler.cancel(PROBER_TENANT)
         return self.scheduler.idle()
 
-    def run(self, poll_secs: float = 5.0):
+    def run(self, poll_secs: float = 5.0) -> int:
         """Sleep until the dispatcher drains (reference master.py:218-238);
-        each tick, kill stragglers (3× mean task time, :487-509)."""
+        each tick, kill stragglers (3× mean task time, :487-509).
+        Returns the process exit code: non-zero when tasks failed
+        permanently (their records were never trained)."""
         try:
             while not self._job_finished():
                 if self._stop_requested:
@@ -1162,6 +1165,13 @@ class Master:
                 self.servicer.model_version
             )
             self.stop()
+        failed = self.task_dispatcher.counters.failed_records
+        if failed:
+            logger.error(
+                "job finished with permanently failed tasks "
+                "(records by task type: %s)", failed,
+            )
+            return 1
         return 0
 
     def stop(self):
@@ -1319,6 +1329,11 @@ def run_standby(args, k8s_client=None) -> int:
 
 
 def main(argv=None):
+    # The master (and the --standby role) imports the user's zoo module
+    # and with it jax, but owns no device: with libtpu the first process
+    # to initialise a backend holds every chip of the host, and that
+    # must be a worker.
+    force_cpu()
     args = parse_master_args(argv)
     k8s_client = None
     if getattr(args, "image_name", ""):

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.ops.flash_attention import (
     flash_attention,
+    log_traced as log_traced_attention,
     supports as flash_supports,
 )
 from elasticdl_tpu.ops.ring_attention import dense_attention, ring_attention
@@ -143,13 +144,23 @@ class SelfAttention(nn.Module):
         scale = cfg.head_dim ** -0.5
         if self.decode:
             return self._decode_step(q, k, v, scale)
+        backend = jax.default_backend()
         if self.mesh is not None:
             o = ring_attention(q, k, v, self.mesh, causal=True, scale=scale)
-        elif jax.default_backend() == "tpu" and flash_supports(q.shape):
+        elif backend == "tpu" and flash_supports(q.shape):
             # Single-chip TPU hot path: fused Pallas kernel (O(S) HBM,
             # causal block skipping) instead of the O(S^2) dense scores.
+            log_traced_attention(
+                "pallas flash kernel",
+                "tpu backend, shape tiles the kernel blocks", q.shape,
+            )
             o = flash_attention(q, k, v, causal=True, scale=scale)
         else:
+            log_traced_attention(
+                "dense reference",
+                f"backend is {backend}" if backend != "tpu"
+                else "shape does not tile the kernel blocks", q.shape,
+            )
             o = dense_attention(q, k, v, causal=True, scale=scale)
         o = nn.DenseGeneral(
             cfg.d_model, axis=(-2, -1), dtype=cfg.compute_dtype, name="out"

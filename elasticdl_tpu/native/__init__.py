@@ -1,33 +1,34 @@
 """Native (C++) components: build + ctypes loading.
 
 The reference builds its C++ kernels with ``g++ -O3`` into a static lib
-linked from Go (elasticdl/Makefile:22-24). Here the shared library builds
-lazily on first import (cached next to the source, keyed by source mtime)
-and binds via ctypes — pybind11 is not in the image.
+linked from Go (elasticdl/Makefile:22-24). Here the shared libraries
+build lazily on first import, next to their sources, under a file name
+that carries a digest of the sources' content: a copy of the tree that
+resets mtimes, or an edit that keeps them, can neither serve a stale
+binary nor rebuild a fresh one. They bind via ctypes / the extension
+loader — pybind11 is not in the image.
 
-``native_available()`` gates every caller; set ELASTICDL_TPU_NO_NATIVE=1
-to force the pure-Python fallbacks.
+A build or load that fails is an error. ELASTICDL_TPU_NO_NATIVE=1 is
+the one way onto the pure-Python versions, and it is the caller's
+choice, not a silent one.
 """
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import tempfile
-
-from elasticdl_tpu.common.log_utils import get_logger
-
-logger = get_logger("native")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = [
     os.path.join(_HERE, "row_store.cc"),
 ]
-_LIB = os.path.join(_HERE, "_librowstore.so")
 # The record reader is a CPython extension (record_ext.c): it returns
 # list[bytes] built in C, which a ctypes design cannot do without a
 # second Python-side pass (measured slower than the pure scanner).
 _EXT_SRC = os.path.join(_HERE, "record_ext.c")
-_EXT_LIB = os.path.join(_HERE, "_record_ext.so")
 
 _ext = None
 _ext_load_attempted = False
@@ -36,31 +37,41 @@ _lib = None
 _load_attempted = False
 
 
-def _build() -> bool:
+def _ensure_built(stem: str, sources, command) -> str:
+    """Path of ``<stem>-<digest of sources>.so``, compiled by
+    ``command(output_path)`` unless it already exists."""
+    digest = hashlib.sha256()
+    for src in sources:
+        with open(src, "rb") as f:
+            digest.update(f.read())
+    path = os.path.join(_HERE, f"{stem}-{digest.hexdigest()[:16]}.so")
+    if os.path.exists(path):
+        return path
     # Compile to a temp file, atomic-rename into place (concurrent
     # importers race benignly).
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
     os.close(fd)
-    cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-        "-o", tmp, *_SOURCES,
-    ]
     try:
         subprocess.run(
-            cmd, check=True, capture_output=True, timeout=120
+            command(tmp), check=True, capture_output=True, timeout=120
         )
-        os.replace(tmp, _LIB)
-        return True
+        os.replace(tmp, path)
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
             FileNotFoundError) as exc:
-        detail = getattr(exc, "stderr", b"")
-        logger.warning(
-            "native build failed (%s) %s — using pure-Python row store",
-            exc, detail.decode() if detail else "",
-        )
+        detail = getattr(exc, "stderr", b"") or b""
+        raise RuntimeError(
+            f"native build of {stem} failed ({exc}) {detail.decode()}"
+            " — set ELASTICDL_TPU_NO_NATIVE=1 to run on the "
+            "pure-Python versions"
+        ) from exc
+    finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
-        return False
+    for old in glob.glob(os.path.join(_HERE, f"{stem}-*.so")):
+        if old != path:
+            with contextlib.suppress(FileNotFoundError):
+                os.unlink(old)  # a concurrent importer got there first
+    return path
 
 
 def _bind(lib):
@@ -92,24 +103,19 @@ def _bind(lib):
 
 
 def get_lib():
-    """The loaded library, or None when unavailable."""
+    """The loaded row-store library; None only under
+    ELASTICDL_TPU_NO_NATIVE."""
     global _lib, _load_attempted
     if _load_attempted:
         return _lib
+    if not os.environ.get("ELASTICDL_TPU_NO_NATIVE"):
+        path = _ensure_built(
+            "_librowstore", _SOURCES,
+            lambda out: ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
+                         "-o", out, *_SOURCES],
+        )
+        _lib = _bind(ctypes.CDLL(path))
     _load_attempted = True
-    if os.environ.get("ELASTICDL_TPU_NO_NATIVE"):
-        return None
-    stale = not os.path.exists(_LIB) or any(
-        os.path.getmtime(_LIB) < os.path.getmtime(src)
-        for src in _SOURCES
-    )
-    if stale and not _build():
-        return None
-    try:
-        _lib = _bind(ctypes.CDLL(_LIB))
-    except OSError as exc:
-        logger.warning("could not load %s: %s", _LIB, exc)
-        _lib = None
     return _lib
 
 
@@ -117,59 +123,30 @@ def native_available() -> bool:
     return get_lib() is not None
 
 
-def _build_ext() -> bool:
-    import sysconfig
-
-    include = sysconfig.get_paths()["include"]
-    fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
-    os.close(fd)
-    cmd = [
-        "gcc", "-O3", "-shared", "-fPIC", f"-I{include}",
-        "-o", tmp, _EXT_SRC,
-    ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _EXT_LIB)
-        return True
-    except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
-            FileNotFoundError) as exc:
-        detail = getattr(exc, "stderr", b"")
-        logger.warning(
-            "record_ext build failed (%s) %s — using Python scanner",
-            exc, detail.decode() if detail else "",
-        )
-        if os.path.exists(tmp):
-            os.unlink(tmp)
-        return False
-
-
 def get_record_ext():
-    """The _record_ext extension module, or None when unavailable."""
+    """The _record_ext extension module; None only under
+    ELASTICDL_TPU_NO_NATIVE."""
     global _ext, _ext_load_attempted
     if _ext_load_attempted:
         return _ext
-    _ext_load_attempted = True
     if os.environ.get("ELASTICDL_TPU_NO_NATIVE"):
+        _ext_load_attempted = True
         return None
-    stale = (
-        not os.path.exists(_EXT_LIB)
-        or os.path.getmtime(_EXT_LIB) < os.path.getmtime(_EXT_SRC)
-    )
-    if stale and not _build_ext():
-        return None
-    try:
-        import importlib.machinery
-        import importlib.util
+    import importlib.machinery
+    import importlib.util
+    import sysconfig
 
-        # The name must match the C module's PyInit__record_ext.
-        loader = importlib.machinery.ExtensionFileLoader(
-            "_record_ext", _EXT_LIB
-        )
-        spec = importlib.util.spec_from_loader("_record_ext", loader)
-        module = importlib.util.module_from_spec(spec)
-        loader.exec_module(module)
-        _ext = module
-    except (ImportError, OSError) as exc:
-        logger.warning("could not load %s: %s", _EXT_LIB, exc)
-        _ext = None
+    include = sysconfig.get_paths()["include"]
+    path = _ensure_built(
+        "_record_ext", [_EXT_SRC],
+        lambda out: ["gcc", "-O3", "-shared", "-fPIC", f"-I{include}",
+                     "-o", out, _EXT_SRC],
+    )
+    # The name must match the C module's PyInit__record_ext.
+    loader = importlib.machinery.ExtensionFileLoader("_record_ext", path)
+    spec = importlib.util.spec_from_loader("_record_ext", loader)
+    module = importlib.util.module_from_spec(spec)
+    loader.exec_module(module)
+    _ext = module
+    _ext_load_attempted = True
     return _ext

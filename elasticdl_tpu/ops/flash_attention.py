@@ -29,6 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from elasticdl_tpu.common.log_utils import get_logger
+
+logger = get_logger("attention")
+
 _NEG_INF = -1e30
 # Measured on v5e (bf16, D=64): per-grid-step overhead dominates small
 # tiles on this backend — round-2 found 512-blocks 10-27x faster than
@@ -37,8 +41,14 @@ _NEG_INF = -1e30
 # 1.083 ms vs 1.244 ms at 512x512 (+13%) — fewer grid steps beat the
 # causal block-skipping the smaller tiles enable. 1024 is the default;
 # blocks clamp to S for shorter sequences (S=512 uses 512x512). VMEM
-# per step at 1024 blocks: the f32 score tile is 4 MB — comfortably
-# inside the 128 MB VMEM next to the K/V/Q tiles.
+# per step at 1024 blocks: each f32 (block_q, block_k) tile is 4 MB and
+# the backward keeps several live (s, p, dp, ds). No vmem_limit_bytes
+# is set: Mosaic (jax 0.9.0, libtpu 0.0.34, v5e) takes all three
+# kernels under its default scoped limit at the flagship geometry
+# (bf16, B*H=128, S=1024, D=128; tests/test_tpu_kernels.py). The
+# forward kernel's scoped allocation there is 65 MB of the v5e's
+# 128 MiB (the compiler's own figure, from its refusal when the limit
+# was lowered on purpose): a larger default block would not fit.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
@@ -570,6 +580,17 @@ def flash_chunk_grads(
         interpret=interpret,
     )(qoff, koff, q, k_chunk, v_chunk, do, lse, delta)
     return dq, dk, dv
+
+
+@functools.lru_cache(maxsize=None)
+def log_traced(implementation: str, why: str, q_shape: tuple):
+    """Say which attention implementation a trace took and why, once
+    per distinct choice in the process (every layer of every trace asks
+    again). The dense reference standing in for the kernel is a
+    slowdown nothing else reports."""
+    logger.info(
+        "attention: traced %s for q%s: %s", implementation, q_shape, why
+    )
 
 
 def _auto_block(s_len: int, requested: int) -> int:

@@ -358,45 +358,74 @@ def ring_attention(
     local block tiles by the kernel blocks) fuses each chunk update into
     one Pallas kernel call; backward is the fused reverse ring from the
     saved logsumexp (no forward recompute).
+
+    A mesh with no usable ``sp`` axis (dp-only, dp×tp) needs no ring:
+    each device then runs the single-chip flash kernel on its own
+    (batch, head) shard under ``shard_map``, by the same gate. The
+    dense reference remains for the CPU backend and for shapes the
+    kernel does not tile; which one was traced is logged.
     """
+    from elasticdl_tpu.ops import flash_attention as flash
+
     if scale is None:
         scale = q.shape[-1] ** -0.5
     axes = set(mesh.axis_names)
-    b, s, h, _ = q.shape
-    # The ring needs equal sequence blocks; other axes degrade to
-    # replicated when they don't divide (same policy as rules.fit_spec).
-    if (
-        sp_axis not in axes
-        or mesh.shape[sp_axis] == 1
-        or s % mesh.shape[sp_axis] != 0
-    ):
-        return dense_attention(q, k, v, causal=causal, scale=scale)
+    b, s, h, d = q.shape
 
     def usable(axis, dim):
+        # Axes the mesh lacks or that do not divide degrade to
+        # replicated (same policy as rules.fit_spec).
         return (
             axis if axis and axis in axes and dim % mesh.shape[axis] == 0
             else None
         )
 
-    s_loc = s // mesh.shape[sp_axis]
-    if use_pallas is None:
-        from elasticdl_tpu.ops.flash_attention import (
-            supports as flash_supports,
-        )
+    def size(axis):
+        return mesh.shape[axis] if axis else 1
 
+    dp, tp = usable(dp_axis, b), usable(tp_axis, h)
+    # The ring needs equal sequence blocks.
+    ring = (
+        sp_axis in axes
+        and mesh.shape[sp_axis] > 1
+        and s % mesh.shape[sp_axis] == 0
+    )
+    s_loc = s // mesh.shape[sp_axis] if ring else s
+    local = (b // size(dp), s_loc, h // size(tp), d)
+    if use_pallas is None:
         # Same tiling gate as single-chip flash: the local block must
-        # tile by the clamped kernel blocks, or fall back to jnp.
-        use_pallas = (
-            jax.default_backend() == "tpu"
-            and flash_supports((b, s_loc, h, q.shape[-1]))
+        # tile by the clamped kernel blocks.
+        backend = jax.default_backend()
+        use_pallas = backend == "tpu" and flash.supports(local)
+        why = (
+            f"{backend} backend, local block {local} tiles"
+            if use_pallas else
+            f"backend is {backend}" if backend != "tpu" else
+            f"local block {local} does not tile the kernel blocks"
         )
-    if use_pallas:
+    else:
+        why = f"use_pallas={use_pallas} passed by the caller"
+    implementation = (
+        ("pallas ring" if use_pallas else "jnp ring") if ring
+        else "pallas flash kernel per shard" if use_pallas
+        else f"dense reference (no usable {sp_axis} axis)"
+    )
+    mesh_str = "x".join(f"{a}{mesh.shape[a]}" for a in mesh.axis_names)
+    flash.log_traced(f"{implementation} on {mesh_str}", why, q.shape)
+    if not ring and not use_pallas:
+        return dense_attention(q, k, v, causal=causal, scale=scale)
+    if not ring:
+        def body(q, k, v):
+            return flash.flash_attention(
+                q, k, v, causal=causal, scale=scale, interpret=interpret
+            )
+    elif use_pallas:
         body = _make_ring_local_pallas(
             sp_axis, causal, float(scale), interpret
         )
     else:
         body = _make_ring_local_jnp(sp_axis, causal, float(scale))
-    spec = P(usable(dp_axis, b), sp_axis, usable(tp_axis, h), None)
+    spec = P(dp, sp_axis if ring else None, tp, None)
     return jax.shard_map(
         body,
         mesh=mesh,

@@ -53,6 +53,35 @@ def make_mesh(
     return Mesh(dev_array, tuple(axes))
 
 
+def placement_summary(tree) -> str:
+    """One log line on how a tree of device arrays is laid out: bytes
+    held per device, and the global and per-device shard shape of its
+    largest leaf. The check that state and batch are spread over the
+    mesh and not piled on its first device (``rules.fit_spec`` drops an
+    axis that does not divide without a word)."""
+    per_device = {}
+    largest_name, largest = "", None
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        if not isinstance(leaf, jax.Array):
+            continue
+        for shard in leaf.addressable_shards:
+            per_device[shard.device.id] = (
+                per_device.get(shard.device.id, 0) + shard.data.nbytes
+            )
+        if largest is None or leaf.size > largest.size:
+            largest_name, largest = jax.tree_util.keystr(path), leaf
+    if largest is None:
+        return "no device arrays"
+    shards = sorted({
+        tuple(s.data.shape) for s in largest.addressable_shards
+    })
+    return (
+        f"bytes per device {dict(sorted(per_device.items()))}; largest "
+        f"leaf {largest_name} {tuple(largest.shape)} held as shards "
+        f"{shards} on {len(largest.addressable_shards)} device(s)"
+    )
+
+
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 

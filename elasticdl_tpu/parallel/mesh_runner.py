@@ -27,11 +27,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.core import step as step_lib
 from elasticdl_tpu.core.train_state import TrainState, init_train_state
 from elasticdl_tpu.embedding import partition as partition_lib
 from elasticdl_tpu.parallel import mesh as mesh_lib
 from elasticdl_tpu.parallel import rules as rules_lib
+
+logger = get_logger("mesh_runner")
 
 
 class MeshRunner:
@@ -182,7 +185,17 @@ class MeshRunner:
         abstract = jax.eval_shape(make_state, example_batch)
         shardings = self.state_shardings(abstract)
         self._state_shardings = shardings
-        return jax.jit(make_state, out_shardings=shardings)(example_batch)
+        state = jax.jit(make_state, out_shardings=shardings)(example_batch)
+        for name, tree in (
+            ("params", state.params),
+            ("optimizer state", state.opt_state),
+            ("first batch", self.place_batch(example_batch)),
+        ):
+            logger.info(
+                "mesh %s: %s: %s", dict(self.mesh.shape), name,
+                mesh_lib.placement_summary(tree),
+            )
+        return state
 
     def place_batch(self, batch):
         """Shard a host batch onto the mesh (leading dim over dp by

@@ -1156,6 +1156,11 @@ def main(argv=None) -> int:
     import argparse
     import signal
 
+    from elasticdl_tpu.common.jax_env import force_cpu
+
+    # The router computes nothing on a device; the replicas own them.
+    force_cpu()
+
     parser = argparse.ArgumentParser("elasticdl_tpu-route")
     parser.add_argument(
         "--replicas", required=True,

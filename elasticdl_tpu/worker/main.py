@@ -8,10 +8,12 @@ it restores from the latest sharded checkpoint via
 ``--checkpoint_dir_for_init`` handed down by the master.
 """
 
+import json
 import sys
 
 from elasticdl_tpu.common.args import parse_worker_args
 from elasticdl_tpu.common.constants import DistributionStrategy
+from elasticdl_tpu.common.jax_env import enable_compile_cache
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.timing import Timing
 from elasticdl_tpu.core.model_spec import get_model_spec
@@ -26,27 +28,8 @@ from elasticdl_tpu.worker.worker import Worker
 logger = get_logger("worker_main")
 
 
-def _enable_compilation_cache(args):
-    """Persistent XLA compilation cache: an elastic relaunch (same
-    program shapes) restores compiled executables from disk instead of
-    paying full recompilation — recovery time becomes checkpoint-read
-    bound, not compile bound. Point --compilation_cache_dir at a volume
-    that survives the pod."""
-    cache_dir = getattr(args, "compilation_cache_dir", "")
-    if not cache_dir:
-        return
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # Cache every program, however small/fast-compiling.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    logger.info("XLA compilation cache at %s", cache_dir)
-
-
 def build_worker(args, master_client=None) -> Worker:
     """Assemble a Worker from parsed args (shared with tests)."""
-    _enable_compilation_cache(args)
     # Multi-host: wire jax.distributed BEFORE anything can touch the JAX
     # backend — including the user's model-zoo module imported below,
     # which may build arrays at import time. The process id must be
@@ -350,9 +333,36 @@ def resolve_init_checkpoint(args) -> dict:
     }
 
 
+def device_report() -> dict:
+    """What this process ran on, as JAX reports it — the closing line
+    carries it so a launcher can check the device without touching JAX
+    itself (a second process cannot share the chip)."""
+    import jax
+
+    devices = jax.local_devices()
+    stats = [d.memory_stats() or {} for d in devices]
+    peaks = [s.get("peak_bytes_in_use") for s in stats]
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": jax.device_count(),
+        # None where the backend keeps no memory statistics (the CPU).
+        "peak_bytes_in_use": (
+            max(peaks) if all(p is not None for p in peaks) else None
+        ),
+        # Per local device: a mesh job piled on its first device shows
+        # here.
+        "bytes_in_use": [s.get("bytes_in_use") for s in stats],
+    }
+
+
 def main(argv=None):
     args = parse_worker_args(argv)
+    logger.info("XLA compilation cache at %s", enable_compile_cache())
     worker = build_worker(args)
+    # The first device query: a worker whose platform is missing dies
+    # here, before it can pull (and fail) a single task.
+    logger.info("Worker %d runs on %s", args.worker_id, device_report())
     # k8s sends SIGTERM ahead of the KILL: stop at the next batch
     # boundary, checkpoint the freshest state, hand the task back.
     import signal
@@ -361,8 +371,11 @@ def main(argv=None):
         signal.SIGTERM, lambda signum, frame: worker.request_stop()
     )
     result = worker.run()
-    logger.info("Worker %d done: %s", args.worker_id, result)
-    return 0
+    result.update(device_report())
+    logger.info("Worker %d done: %s", args.worker_id, json.dumps(result))
+    # A task that failed for any reason but preemption is a failed
+    # worker, whether or not the master found it another home.
+    return 1 if result["failed_tasks"] else 0
 
 
 if __name__ == "__main__":

@@ -16,7 +16,11 @@ reference's protocol:
   _update_local_model): under SPMD every step already applies to the
   one true state, so the knob maps onto ``version_report_steps`` —
   the master only observes (and eval-triggers on) every N-th version,
-- minibatch retry with a cap (reference worker.py:49 MAX_MINIBATCH_RETRY_NUM).
+- no minibatch retry (the reference retried a rejected gradient,
+  worker.py:49): the step donates its state, so a failed step cannot
+  run again on it, and a compile or out-of-memory error fails the same
+  way every time. A failed task is reported to the master (which
+  re-queues it) and counted; a device error then ends the worker.
 
 Under MeshStrategy the same code runs SPMD over the device mesh: batches
 are globally sharded, the optimizer state is ZeRO-sharded (parallel/), and
@@ -31,7 +35,6 @@ import jax
 import numpy as np
 
 from elasticdl_tpu.common.constants import (
-    MAX_MINIBATCH_RETRY_NUM,
     Mode,
     TaskType,
 )
@@ -224,6 +227,12 @@ class Worker:
             "worker_tasks_total",
             "Tasks processed", ["type", "result"],
         )
+        # Tasks that failed for a reason other than preemption: the
+        # process exit code reports them (worker/main.py).
+        self._failed_tasks = 0
+        # Per-step losses of the training task in flight (device
+        # scalars; read back once per task for the task log line).
+        self._task_losses = []
         # Phase accumulators feed the registry too (publish enables
         # timing; DEBUG log output stays gated on a logger being set).
         self._timing.publish(self._metrics)
@@ -641,29 +650,13 @@ class Worker:
     def _process_train_batch(self, batch):
         if self._multihost_sync:
             # One barrier exchange per step; a failed collective step is
-            # fatal (restart-from-checkpoint), so no local retry loop.
+            # fatal (restart-from-checkpoint).
             from elasticdl_tpu.parallel import multihost
 
             self._await_turn(multihost.STEP_TRAIN)
-            self.state, metrics = self._train_step(self.state, batch)
-            self.last_metrics = metrics
-            return
-        for attempt in range(MAX_MINIBATCH_RETRY_NUM):
-            try:
-                self.state, metrics = self._train_step(self.state, batch)
-                self.last_metrics = metrics
-                return
-            except jax.errors.JaxRuntimeError:
-                # Transient device error (e.g. preempted donated buffer
-                # after a mesh rebuild): retry the minibatch like the
-                # reference's rejected-gradient retry (worker.py:880-908).
-                logger.warning(
-                    "train step failed (attempt %d):\n%s",
-                    attempt + 1, traceback.format_exc(),
-                )
-        raise RuntimeError(
-            f"Minibatch failed after {MAX_MINIBATCH_RETRY_NUM} retries"
-        )
+        self.state, metrics = self._train_step(self.state, batch)
+        self.last_metrics = metrics
+        self._task_losses.append(metrics["loss"])
 
     def request_stop(self):
         """Ask the worker to stop at the next TASK boundary, saving a
@@ -675,6 +668,7 @@ class Worker:
         self._stop_requested = True
 
     def _process_train_task(self, task, batches) -> int:
+        self._task_losses = []
         if self._fuse_task_steps:
             batch_list = list(batches)
             if not batch_list:
@@ -796,23 +790,11 @@ class Worker:
             "device_step", kind="train_fused", batches=len(batch_list)
         ):
             with self._timing.record("batch_process"):
-                for attempt in range(MAX_MINIBATCH_RETRY_NUM):
-                    try:
-                        self.state, metrics = self._multi_step(
-                            self.state, stacked
-                        )
-                        break
-                    except jax.errors.JaxRuntimeError:
-                        logger.warning(
-                            "fused task step failed (attempt %d):\n%s",
-                            attempt + 1, traceback.format_exc(),
-                        )
-                else:
-                    raise RuntimeError(
-                        f"Fused task failed after "
-                        f"{MAX_MINIBATCH_RETRY_NUM} retries"
-                    )
+                self.state, metrics = self._multi_step(
+                    self.state, stacked
+                )
         self.last_metrics = {"loss": metrics["loss"][-1]}
+        self._task_losses.append(metrics["loss"])
         self._observe_step("train_fused", time.monotonic() - step_t0)
         self._m_examples.labels(TaskType.TRAINING).inc(
             sum(self._batch_examples(b) for b in batch_list)
@@ -841,6 +823,20 @@ class Worker:
         with self._timing.record("checkpoint"):
             self._checkpoint.maybe_save(self.state)
         return len(batch_list)
+
+    def _log_trained_task(self, task, trained: int):
+        """One line per training task: the job's loss trajectory at
+        task granularity (one host readback per task)."""
+        if not self._task_losses:
+            return
+        losses = np.concatenate(
+            [np.ravel(np.asarray(x)) for x in self._task_losses]
+        )
+        logger.info(
+            "Task %d trained: batches=%d version=%d mean_loss=%.6f",
+            task.task_id, trained, int(self.state.step),
+            float(losses.mean()),
+        )
 
     def _drain_multihost(self):
         """Drain barrier: keep participating in other processes' steps
@@ -979,6 +975,7 @@ class Worker:
         return {
             "worker_id": self._id,
             "trained_batches": trained_batches,
+            "failed_tasks": self._failed_tasks,
             "final_version": (
                 int(self.state.step) if self.state is not None else 0
             ),
@@ -1008,6 +1005,7 @@ class Worker:
                 except Exception as exc:
                     if not callbacks_ok:
                         self._m_tasks.labels(task.type, "error").inc()
+                        self._failed_tasks += 1
                     self._report_task(
                         task.task_id,
                         err_reason=f"callback: {type(exc).__name__}: {exc}",
@@ -1055,9 +1053,9 @@ class Worker:
             try:
                 with self._timing.record("task_process"):
                     if task.type == TaskType.TRAINING:
-                        trained_batches += self._process_train_task(
-                            task, batches
-                        )
+                        trained = self._process_train_task(task, batches)
+                        trained_batches += trained
+                        self._log_trained_task(task, trained)
                     elif task.type == TaskType.EVALUATION:
                         self._process_eval_task(task, batches)
                     elif task.type == TaskType.PREDICTION:
@@ -1087,10 +1085,17 @@ class Worker:
                 # err_reason would read as success at the master).
                 if not processed_ok:
                     self._m_tasks.labels(task.type, "error").inc()
+                    self._failed_tasks += 1
                 self._report_task(
                     task.task_id,
                     err_reason=f"{type(exc).__name__}: {exc}",
                 )
+                if isinstance(exc, jax.errors.JaxRuntimeError):
+                    # A device or compiler error (a Mosaic refusal,
+                    # RESOURCE_EXHAUSTED): the step donated the state
+                    # it failed on, and the next task would fail the
+                    # same way. The master has the task back; die.
+                    raise
         if not self._stop_requested:
             # A stopping worker must not drain: the barrier drains only
             # when ALL processes are done, and peers aren't — its death

@@ -37,8 +37,45 @@ CONFIG = TransformerConfig(
 )
 
 
+# The widths the benchmark cells and chip_smoke.py run, as model
+# functions ``--model_def`` can name
+# (``transformer.transformer_lm.transformer_l``), so suite, worker and
+# smoke build one model from one definition. Sequence 1,024 engages the
+# Pallas flash kernels. head_dim 128 is the MXU/lane width: D=64 heads
+# measured half the attention-kernel throughput on v5e. remat=False:
+# activations at these sizes fit HBM and rematerialization costs ~10%.
+SEQ_LEN = 1024
+VOCAB = 32768
+WIDTHS = {
+    "transformer": dict(d_model=512, n_heads=4, n_layers=8, d_ff=2048),
+    "transformer_l": dict(d_model=1024, n_heads=8, n_layers=12,
+                          d_ff=4096),
+    "moe": dict(d_model=512, n_heads=4, n_layers=8, d_ff=2048,
+                moe_experts=8, moe_every=2, moe_top_k=1,
+                moe_dispatch="scatter"),
+}
+
+
+def width_config(name: str) -> TransformerConfig:
+    return TransformerConfig(
+        vocab_size=VOCAB, max_len=SEQ_LEN, remat=False, **WIDTHS[name]
+    )
+
+
 def custom_model(mesh=None, config: TransformerConfig = CONFIG):
     return TransformerLM(config, mesh=mesh)
+
+
+def transformer(mesh=None):
+    return TransformerLM(width_config("transformer"), mesh=mesh)
+
+
+def transformer_l(mesh=None):
+    return TransformerLM(width_config("transformer_l"), mesh=mesh)
+
+
+def moe(mesh=None):
+    return TransformerLM(width_config("moe"), mesh=mesh)
 
 
 def generate_text(params, prompt_tokens, max_new_tokens,

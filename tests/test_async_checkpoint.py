@@ -48,6 +48,28 @@ def test_save_final_flushes(tmp_path):
     assert CheckpointSaver(str(tmp_path)).get_valid_latest_version() == 3
 
 
+def test_interval_counts_from_versions_handed_to_a_slow_writer():
+    """A write that outlasts several tasks (a multi-GB state) must not
+    change which versions are saved: every 8 steps means 8, 16, 24 for
+    a job of fused 4-step tasks, and the final version once. Counting
+    from the last LANDED write saved 8, 12, 16, 20, 24, 24."""
+    _, state, _ = _state()
+    saved = []
+
+    class SlowSaver:
+        def save(self, version, leaves):
+            time.sleep(0.2)
+            saved.append(version)
+
+    hook = CheckpointHook(checkpoint_steps=8, saver=SlowSaver(),
+                          async_save=True)
+    for version in range(4, 25, 4):
+        state = state.replace(step=version)
+        hook.maybe_save(state)
+    hook.save_final(state)
+    assert saved == [8, 16, 24]
+
+
 def test_deferred_write_error_surfaces_on_flush(tmp_path):
     _, state, _ = _state()
 

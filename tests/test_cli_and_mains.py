@@ -268,31 +268,6 @@ def test_worker_fresh_start_on_empty_rolling_dir(tmp_path):
     assert worker.state is not None
 
 
-def test_compilation_cache_flag_plumb(tmp_path):
-    """--compilation_cache_dir configures the persistent XLA cache."""
-    import jax
-
-    from elasticdl_tpu.worker.main import _enable_compilation_cache
-
-    class Args:
-        compilation_cache_dir = str(tmp_path / "xla-cache")
-
-    try:
-        _enable_compilation_cache(Args())
-        assert (
-            jax.config.jax_compilation_cache_dir
-            == str(tmp_path / "xla-cache")
-        )
-    finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-
-    class Off:
-        compilation_cache_dir = ""
-
-    _enable_compilation_cache(Off())  # no-op, no error
-    assert jax.config.jax_compilation_cache_dir is None
-
-
 def test_cli_output_flag_exports_bundle(tmp_path):
     """--output auto-injects a SavedModelExporter (reference
     `elasticdl train --output`): the bundle appears without the zoo

@@ -64,6 +64,35 @@ def test_sp_absent_falls_back_to_dense():
                                rtol=1e-6, atol=1e-6)
 
 
+def test_no_sp_axis_runs_flash_per_shard():
+    """A dp x tp mesh needs no ring, and on the TPU backend it must not
+    fall to the dense reference either: each device runs the flash
+    kernel on its own (batch, head) shard. Values and grads == dense
+    (interpret mode on the CPU mesh; the CPU's own choice stays dense,
+    test_sp_absent_falls_back_to_dense)."""
+    q, k, v = _qkv(seed=8)
+    mesh = make_mesh((2, 2), ("dp", "tp"), devices=jax.devices()[:4])
+
+    @jax.jit
+    def sharded(q, k, v):
+        return ring_attention(q, k, v, mesh, causal=True,
+                              use_pallas=True, interpret=True)
+
+    got = sharded(q, k, v)
+    want = dense_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    gs = jax.grad(lambda *a: jnp.sum(sharded(*a) ** 2),
+                  argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(
+        lambda *a: jnp.sum(dense_attention(*a, causal=True) ** 2),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for a, b in zip(gs, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_pallas_ring_matches_dense(causal):
     """The fused (flash_chunk_update) ring == dense, values and grads

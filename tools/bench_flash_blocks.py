@@ -5,7 +5,7 @@ config moved to B16 — VERDICT r4 weak #5 asks for the sweep at the
 EXACT bench shape and a statement of whether the flash custom-calls
 (27.3% of the d512 step) are at the kernel's own roofline. The default
 shape is therefore DERIVED from ``bench_suite`` (the d512 flagship's
-batch + ``_TRANSFORMER_SIZES`` head geometry — H4/D128 since the
+batch + the zoo's ``WIDTHS`` head geometry — H4/D128 since the
 round-5 head flip), so the sweep cannot silently drift off the bench
 shape again. This tool measures, per (block_q, block_k):
 
@@ -36,20 +36,20 @@ def main():
     import jax.numpy as jnp
 
     from benchlib import (
-        enable_bench_compile_cache,
+        enable_compile_cache,
         module_device_times,
         peak_flops,
     )
     from elasticdl_tpu.ops.flash_attention import flash_attention
 
-    enable_bench_compile_cache()
+    enable_compile_cache()
     import bench_suite
 
-    sizes = bench_suite._TRANSFORMER_SIZES["transformer"]
+    sizes = bench_suite.lm_zoo().WIDTHS["transformer"]
     default_shape = [
         bench_suite.CONFIGS["transformer"][1],       # bench batch
         sizes["n_heads"],
-        bench_suite.TRANSFORMER_SEQ,
+        bench_suite.lm_zoo().SEQ_LEN,
         sizes["d_model"] // sizes["n_heads"],        # head dim (128)
     ]
     args = [int(a) for a in sys.argv[1:]]

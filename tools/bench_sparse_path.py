@@ -56,13 +56,6 @@ DEFAULT_REPORT = "BENCH_SPARSE_PATH.json"
 BENCH_VERSION = 1
 
 
-def _force_cpu_if_requested():
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
-
 def _make_delayed_service(delay_secs: float):
     """A deepfm-host row service whose pull/push handlers each sleep
     ``delay_secs`` before answering — the injected RPC latency."""
@@ -237,7 +230,6 @@ def main(argv=None) -> int:
     parser.add_argument("--workdir", default="")
     args = parser.parse_args(argv)
 
-    _force_cpu_if_requested()
     delay = args.rpc_delay_ms / 1000.0
     workdir = args.workdir or tempfile.mkdtemp(prefix="edl_sparse_bench_")
 

@@ -20,12 +20,12 @@ import sys
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
-from benchlib import enable_bench_compile_cache, measure_multi_step  # noqa: E402
+from benchlib import enable_compile_cache, measure_multi_step  # noqa: E402
 
 
 def main():
     name = sys.argv[1] if len(sys.argv) > 1 else "transformer"
-    enable_bench_compile_cache()
+    enable_compile_cache()
     from benchlib import load_config_harness
 
     spec, task, batch, steps, measure_tasks = load_config_harness(name)

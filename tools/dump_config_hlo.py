@@ -18,7 +18,7 @@ import sys
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
-from benchlib import enable_bench_compile_cache  # noqa: E402
+from benchlib import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -32,7 +32,7 @@ def main():
                     help="lines of fusion body to print per op")
     args = ap.parse_args()
 
-    enable_bench_compile_cache()
+    enable_compile_cache()
     import jax
 
     from benchlib import load_config_harness

@@ -12,7 +12,7 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
 from benchlib import (  # noqa: E402
-    enable_bench_compile_cache,
+    enable_compile_cache,
     load_config_harness,
     measure_multi_step,
 )
@@ -20,7 +20,7 @@ from benchlib import (  # noqa: E402
 
 def main():
     names = sys.argv[1:] or ["transformer"]
-    enable_bench_compile_cache()
+    enable_compile_cache()
     for name in names:
         spec, task, batch, steps, measure_tasks = load_config_harness(
             name

@@ -29,7 +29,7 @@ import numpy as np
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
-from benchlib import enable_bench_compile_cache, load_json  # noqa: E402
+from benchlib import enable_compile_cache, load_json  # noqa: E402
 
 PROFILES_FILE = os.path.join(HERE, "PROFILES.json")
 
@@ -119,7 +119,7 @@ def main():
                          "not written to PROFILES.json)")
     args = ap.parse_args()
 
-    enable_bench_compile_cache()
+    enable_compile_cache()
     import jax
 
     from benchlib import load_config_harness
