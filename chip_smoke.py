@@ -25,6 +25,7 @@ import json
 import math
 import os
 import re
+import resource
 import shutil
 import signal
 import socket
@@ -38,6 +39,11 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 MINIBATCH = 16
 MINIBATCHES_PER_TASK = 4
 CHECKPOINT_STEPS = 8
+# Full f32 state is 2.5 GiB a version at the flagship width, and the
+# driver's chip machine refused a file that large (EFBIG). 64 shard
+# files keep the largest near 180 MiB: a 128 MiB embedding-sized leaf
+# is the floor, leaves are not split.
+CHECKPOINT_SHARDS = 64
 LEG_A_RECORDS = 384     # 6 tasks, 24 optimizer steps
 LEG_B_RECORDS = 128     # 2 tasks, 8 optimizer steps
 # The width `python chip_smoke.py` runs: the suite's transformer_l cell
@@ -159,7 +165,7 @@ def _run_leg(tag, model_zoo, model_def, platform, records, workdir,
         "--fuse_task_steps", "true",
         "--checkpoint_dir", checkpoint_dir,
         "--checkpoint_steps", str(CHECKPOINT_STEPS),
-        # Full f32 state is ~2.6 GB a version at the flagship width.
+        "--checkpoint_shards", str(CHECKPOINT_SHARDS),
         "--keep_checkpoint_max", "2",
         "--job_name", f"chip-smoke-{tag}",
         "--master_addr", addr,
@@ -231,6 +237,12 @@ def _run_leg(tag, model_zoo, model_def, platform, records, workdir,
 
 def _cache_entries(cache_dir) -> int:
     return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+
+def _largest_file_bytes(top) -> int:
+    return max(
+        (os.path.getsize(os.path.join(d, name))
+         for d, _, names in os.walk(top) for name in names), default=0)
 
 
 def run_smoke(model_zoo, model_def, seq_len, vocab, platform,
@@ -327,6 +339,8 @@ def run_smoke(model_zoo, model_def, seq_len, vocab, platform,
             "task_losses": [loss_a, loss_b],
             "peak_bytes_in_use": [leg_a["report"]["peak_bytes_in_use"],
                                   leg_b["report"]["peak_bytes_in_use"]],
+            "largest_checkpoint_file_bytes":
+                _largest_file_bytes(checkpoint_dir),
             "compile_cache": {"dir": cache_dir,
                               "entries_after_a": cache_after_a,
                               "entries_after_b": _cache_entries(cache_dir)},
@@ -344,6 +358,12 @@ def main() -> int:
         )
     except SmokeFailure as exc:
         print(f"chip_smoke FAILED: {exc}", file=sys.stderr)
+        # What the machine allows, for reading a refusal from afar.
+        print(f"RLIMIT_FSIZE (soft, hard; -1 = unlimited): "
+              f"{resource.getrlimit(resource.RLIMIT_FSIZE)}; free in "
+              f"{tempfile.gettempdir()}: "
+              f"{shutil.disk_usage(tempfile.gettempdir()).free >> 20} MiB",
+              file=sys.stderr)
         return 1
     print(json.dumps({"record": record}))
     print(json.dumps({"ok": True, "device": record["device"]}))
