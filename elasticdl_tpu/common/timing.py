@@ -4,11 +4,11 @@ Phases mirror the reference's {task_process, batch_process, get_model,
 report_gradient}; this framework adds {compile, host_to_device} because those
 are the TPU-specific costs worth watching.
 
-Beyond the reference: per-phase min/max, and ``publish(registry)`` wires
-the accumulators into the unified metrics registry
-(elasticdl_tpu/observability/) — every phase duration then also lands in
-the ``edl_tpu_worker_phase_seconds{phase=...}`` histogram, so phase costs
-reach the master's ``/metrics`` instead of living in debug logs only.
+Beyond the reference: per-phase min/max. These accumulators serve the
+local executor's DEBUG log; the worker's phases, and the
+``edl_tpu_worker_phase_seconds{phase=...}`` histogram on the master's
+``/metrics``, come from the phase seam (``observability/tracing.py``,
+``Phases``).
 """
 
 import contextlib
@@ -20,7 +20,6 @@ class Timing:
     def __init__(self, enabled: bool = False, logger=None):
         self.enabled = enabled
         self._logger = logger
-        self._phase_hist = None
         self.reset()
 
     def reset(self):
@@ -29,19 +28,6 @@ class Timing:
         self._mins = {}
         self._maxs = {}
         self._starts = {}
-
-    def publish(self, registry) -> "Timing":
-        """Land phase durations in ``registry`` as histograms
-        (``edl_tpu_worker_phase_seconds{phase=...}``) from now on.
-        Publishing enables timing — asking for metrics means asking for
-        the data; the per-phase cost is two monotonic reads."""
-        self._phase_hist = registry.histogram(
-            "worker_phase_seconds",
-            "Wall-clock duration of worker host phases",
-            ["phase"],
-        )
-        self.enabled = True
-        return self
 
     def start_record_time(self, phase: str):
         if self.enabled:
@@ -56,8 +42,6 @@ class Timing:
                 self._mins[phase] = elapsed
             if phase not in self._maxs or elapsed > self._maxs[phase]:
                 self._maxs[phase] = elapsed
-            if self._phase_hist is not None:
-                self._phase_hist.labels(phase).observe(elapsed)
 
     @contextlib.contextmanager
     def record(self, phase: str):

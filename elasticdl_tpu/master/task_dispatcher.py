@@ -356,6 +356,14 @@ class TaskDispatcher:
             task = self._get(worker_id)
             if task is not None:
                 sp.set(task_id=int(task.task_id), type=str(task.type))
+                # Which records the task holds, for a check that reads
+                # the job from outside (it can then find the rows a
+                # task was fed without a hook in the model).
+                logger.info(
+                    "Task %d dispatched: type=%s shard=%s start=%d "
+                    "end=%d worker=%d", task.task_id, task.type,
+                    task.shard_name, task.start, task.end, worker_id,
+                )
             else:
                 # WAIT / drained polls would drown the dispatch stats.
                 sp.discard()

@@ -24,6 +24,15 @@ incremental cursor into the ring, and the master's ``TraceCollector``
 dedups by span id (several in-process workers may share one recorder).
 Export to Chrome/Perfetto JSON lives in ``trace_export.py``;
 critical-path / straggler attribution in ``critical_path.py``.
+
+**Phases** (``Phases.phase``) are the one seam the worker's task cycle
+and start-up are instrumented through: one entry, one name, three
+sinks. Always the ``worker_phase_seconds{phase}`` histogram; a span as
+above when a recorder is installed; and, while a ``jax.profiler`` step
+window is open (``utils/profiler.py`` opens and closes it here), a
+``TraceAnnotation("edl:<name>")`` — the only sink on the device trace's
+own clock (span clocks are ``time.monotonic()`` per process). The phase
+table is in docs/observability.md.
 """
 
 import threading
@@ -37,6 +46,11 @@ from typing import Dict, List, Optional, Tuple
 _RECORDER: Optional["FlightRecorder"] = None
 _PROCESS_ROLE: Tuple[str, str] = ("process", "0")
 _local = threading.local()  # .stack: [(trace_id, span_id, role, instance)]
+# The profiler backend's TraceAnnotation while a jax.profiler step
+# window is open, else None (set by utils/profiler.py; a trace is per
+# process, so this is too).
+_ANNOTATE = None
+ANNOTATION_PREFIX = "edl:"
 
 
 def enabled() -> bool:
@@ -297,6 +311,112 @@ def server_span(name: str, wire_ctx: Optional[dict], role: str,
                     str(wire_ctx.get("span_id") or "") or None,
                     role, instance, attrs)
     return Span(rec, name, _new_id(), None, role, instance, attrs)
+
+
+def open_trace_window(annotate):
+    """A ``jax.profiler`` trace has started: phases entered from now
+    on also enter ``annotate("edl:<name>")`` (the backend's
+    ``TraceAnnotation``; None where the backend has none)."""
+    global _ANNOTATE
+    _ANNOTATE = annotate
+
+
+def close_trace_window():
+    global _ANNOTATE
+    _ANNOTATE = None
+
+
+class _Phase:
+    """One entry of a phase: a context manager over the three sinks.
+    ``dur`` holds the duration after exit; ``set``/``discard`` reach
+    the span (no-ops on the shared null span)."""
+
+    __slots__ = ("_owner", "name", "_span", "_annotation", "_startup",
+                 "_discard", "t0", "dur")
+
+    def __init__(self, owner: "Phases", name: str, span, startup: bool):
+        self._owner = owner
+        self.name = name
+        self._span = span
+        self._annotation = None
+        self._startup = startup
+        self._discard = False
+        self.t0 = 0.0
+        self.dur = 0.0
+
+    def set(self, **attrs) -> "_Phase":
+        self._span.set(**attrs)
+        return self
+
+    def discard(self) -> "_Phase":
+        """Count this entry nowhere (a task cycle that turned out to be
+        a WAIT poll)."""
+        self._discard = True
+        self._span.discard()
+        return self
+
+    def __enter__(self) -> "_Phase":
+        annotate = _ANNOTATE
+        if annotate is not None:
+            self._annotation = annotate(ANNOTATION_PREFIX + self.name)
+            self._annotation.__enter__()
+        self._span.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.dur = time.monotonic() - self.t0
+        self._span.__exit__(exc_type, exc, tb)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if not self._discard:
+            self._owner._observe(self.name, self.dur, self._startup)
+        return False
+
+
+class Phases:
+    """The phase seam of one worker: ``phase(name)`` is entered once
+    per region and feeds every sink under that one name (module
+    docstring). ``durations`` sums the phases ended since its owner
+    last cleared it — the worker reads it once per task cycle for the
+    slow-task line. ``declare`` names the phases whose series exist
+    (at zero) from the start: a reader that takes a mean between two
+    snapshots needs the series in both, and the first snapshot of a
+    job is taken before the first task's later phases have ended."""
+
+    def __init__(self, registry, tracer: Tracer, declare=()):
+        self._tracer = tracer
+        self._hist = registry.histogram(
+            "worker_phase_seconds",
+            "Wall-clock duration of worker phases (task cycle and "
+            "start-up)",
+            ["phase"],
+        )
+        self._startup = registry.gauge(
+            "worker_startup_seconds",
+            "Duration of each start-up phase, entered once per process",
+            ["phase"],
+        )
+        self._series = {name: self._hist.labels(name) for name in declare}
+        self.durations: Dict[str, float] = {}
+
+    def phase(self, name: str, **attrs) -> _Phase:
+        return _Phase(self, name, self._tracer.span(name, **attrs), False)
+
+    def startup(self, name: str, **attrs) -> _Phase:
+        """A phase that is also kept as
+        ``worker_startup_seconds{phase}``: a gauge rides the snapshot
+        to the master whole, where the histogram would bucket it."""
+        return _Phase(self, name, self._tracer.span(name, **attrs), True)
+
+    def _observe(self, name: str, dur: float, startup: bool):
+        series = self._series.get(name)
+        if series is None:
+            series = self._series[name] = self._hist.labels(name)
+        series.observe(dur)
+        self.durations[name] = self.durations.get(name, 0.0) + dur
+        if startup:
+            self._startup.labels(name).set(dur)
 
 
 def record_span(name: str, t0: float, dur: float, *,

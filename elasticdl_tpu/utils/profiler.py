@@ -5,18 +5,20 @@ The reference's only tracing is wall-clock phase accumulators
 interesting time is *inside* the XLA program, which host timers cannot
 see — so this wraps ``jax.profiler``: a step-window trace capturing
 device timelines (HBM transfers, fusions, collective overlap) viewable
-in TensorBoard/Perfetto, plus named trace annotations that show host
-phases on the same timeline.
+in TensorBoard/Perfetto. While the window is open the worker's phases
+(``observability/tracing.py``, ``Phases``) also enter
+``TraceAnnotation("edl:<name>")``, so the host's side of a task cycle
+lies in the same file, on the same clock, as the device's lanes.
 
 Wired via ``--profile_dir`` (+ ``--profile_start_step/--profile_steps``):
 the worker starts the trace when the step window opens and stops it when
 it closes, so steady-state steps are captured rather than compile time.
 """
 
-import contextlib
 from typing import Optional
 
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.observability import tracing
 
 logger = get_logger("profiler")
 
@@ -32,7 +34,8 @@ class Profiler:
     doesn't raise "already started".
 
     ``backend`` defaults to ``jax.profiler`` (imported lazily); tests
-    inject a fake with the same ``start_trace``/``stop_trace`` surface.
+    inject a fake with the same ``start_trace``/``stop_trace`` surface
+    (and, where they want the phases' annotations, ``TraceAnnotation``).
     """
 
     def __init__(self, profile_dir: str = "", start_step: int = 5,
@@ -60,7 +63,11 @@ class Profiler:
         if not self.enabled or self._done:
             return
         if not self._active and step >= self.start_step:
-            self._get_backend().start_trace(self.profile_dir)
+            backend = self._get_backend()
+            backend.start_trace(self.profile_dir)
+            tracing.open_trace_window(
+                getattr(backend, "TraceAnnotation", None)
+            )
             self._active = True
             self._window_end = step + self.num_steps
             logger.info(
@@ -75,23 +82,11 @@ class Profiler:
 
     def stop(self):
         if self._active:
+            tracing.close_trace_window()
             self._get_backend().stop_trace()
             self._active = False
             self._done = True
             logger.info("profiler: trace written to %s", self.profile_dir)
-
-    @contextlib.contextmanager
-    def annotation(self, name: str):
-        """Host-phase annotation visible on the device timeline."""
-        if not self.enabled:
-            yield
-            return
-        annotate = getattr(self._get_backend(), "TraceAnnotation", None)
-        if annotate is None:  # fake backends need not implement it
-            yield
-            return
-        with annotate(name):
-            yield
 
 
 def from_args(args) -> Optional[Profiler]:

@@ -15,7 +15,6 @@ from elasticdl_tpu.common.args import parse_worker_args
 from elasticdl_tpu.common.constants import DistributionStrategy
 from elasticdl_tpu.common.jax_env import enable_compile_cache
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.common.timing import Timing
 from elasticdl_tpu.core.model_spec import get_model_spec
 from elasticdl_tpu.utils.profiler import from_args as profiler_from_args
 from elasticdl_tpu.data.factory import (
@@ -290,9 +289,6 @@ def build_worker(args, master_client=None) -> Worker:
         version_report_steps=getattr(args, "get_model_steps", 1),
         prediction_outputs_processor=spec.prediction_outputs_processor,
         callbacks=callbacks,
-        # Worker.__init__ publishes this into the process registry
-        # (phase histograms on /metrics), which also enables measuring.
-        timing=Timing(args.log_level.upper() == "DEBUG"),
         checkpoint_hook=checkpoint_hook,
         profiler=profiler_from_args(args),
         fuse_task_steps=getattr(args, "fuse_task_steps", False),
@@ -358,11 +354,23 @@ def device_report() -> dict:
 
 def main(argv=None):
     args = parse_worker_args(argv)
-    logger.info("XLA compilation cache at %s", enable_compile_cache())
-    worker = build_worker(args)
+    # Start-up is phases too (docs/observability.md): each is entered
+    # once and kept as edl_tpu_worker_startup_seconds{phase}. The
+    # worker's own seam takes over from here for state_init, restore
+    # and first_program.
+    from elasticdl_tpu.observability import default_registry, tracing
+
+    phases = tracing.Phases(
+        default_registry(), tracing.Tracer("worker", str(args.worker_id))
+    )
+    with phases.startup("build_worker"):
+        logger.info("XLA compilation cache at %s", enable_compile_cache())
+        worker = build_worker(args)
     # The first device query: a worker whose platform is missing dies
     # here, before it can pull (and fail) a single task.
-    logger.info("Worker %d runs on %s", args.worker_id, device_report())
+    with phases.startup("backend_up"):
+        runs_on = device_report()
+    logger.info("Worker %d runs on %s", args.worker_id, runs_on)
     # k8s sends SIGTERM ahead of the KILL: stop at the next batch
     # boundary, checkpoint the freshest state, hand the task back.
     import signal

@@ -36,14 +36,17 @@ class TaskDataService:
     def __init__(self, master_client, data_reader, dataset_fn,
                  minibatch_size: int, wait_sleep_secs: float = 2.0,
                  prefetch_depth: int = 2, on_wait=None, metrics_fn=None,
-                 on_metrics_delivered=None, tracer=None,
+                 on_metrics_delivered=None, phases=None,
                  master_reattach_grace: float = 60.0):
-        from elasticdl_tpu.observability import tracing
+        from elasticdl_tpu.observability import default_registry, tracing
 
         self._master = master_client
-        # Root-span factory for the task timeline (the worker passes
-        # its own so spans land on the right worker track).
-        self._tracer = tracer or tracing.Tracer("worker")
+        # The phase seam of the task timeline (the worker passes its
+        # own, so the ``task`` root and ``get_task`` land beside its
+        # other phases, on its track).
+        self._phases = phases or tracing.Phases(
+            default_registry(), tracing.Tracer("worker")
+        )
         # Called after a get_task that CARRIED a snapshot succeeds —
         # the worker commits its span-ring cursor there, so spans
         # offered on a failed RPC are re-offered instead of lost.
@@ -95,15 +98,19 @@ class TaskDataService:
         outage_deadline = None
         last_generation = getattr(self._master, "last_generation", None)
         while True:
-            # One root span per task cycle — opened BEFORE get_task so
+            # One root phase per task cycle — opened BEFORE get_task so
             # the master's dispatch spans join the task's tree; cycles
             # that turn out to be WAIT polls or failures are discarded
-            # (recording them would drown the latency stats). The span
-            # stays open across the yield: the worker consumes the
-            # batches on this same thread, so its step-phase spans nest
-            # under the task.
-            span = self._tracer.span("task")
+            # (recording them would drown the latency stats). It stays
+            # open across the yield: the worker consumes the batches on
+            # this same thread, so its phases nest under the task.
+            # ``get_task`` is the cycle's first leaf, from the poll to
+            # the batch stream's being built; it is counted with its
+            # task or not at all.
+            span = self._phases.phase("task")
             span.__enter__()
+            get_task = self._phases.phase("get_task")
+            get_task.__enter__()
             try:
                 try:
                     metrics = (
@@ -214,6 +221,8 @@ class TaskDataService:
                     continue
                 span.set(task_id=int(task.task_id), type=str(task.type))
                 if task.type == TaskType.TRAIN_END_CALLBACK:
+                    get_task.__exit__(None, None, None)
+                    get_task = None
                     yield task, None
                     continue
                 mode = _TASK_TYPE_TO_MODE.get(task.type)
@@ -239,8 +248,14 @@ class TaskDataService:
                     else contextlib.nullcontext(batches)
                 )
                 with ctx as batches:
+                    get_task.__exit__(None, None, None)
+                    get_task = None
                     yield task, batches
             finally:
+                if get_task is not None:
+                    # No task came of this poll (WAIT, failure, job
+                    # finished): the leaf is discarded with its root.
+                    get_task.discard().__exit__(None, None, None)
                 # Real exc_info (not Nones): an exception escaping the
                 # loop body must tag the task span with its error attr,
                 # or a crashed task reads as a fast successful one in

@@ -27,8 +27,11 @@ are globally sharded, the optimizer state is ZeRO-sharded (parallel/), and
 collectives ride ICI inside the compiled step (see parallel/mesh_runner.py).
 """
 
+import contextlib
+import statistics
 import time
 import traceback
+from collections import deque
 from typing import Optional
 
 import jax
@@ -39,7 +42,6 @@ from elasticdl_tpu.common.constants import (
     TaskType,
 )
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.common.timing import Timing
 from elasticdl_tpu.core.step import (
     build_eval_step,
     build_train_step,
@@ -48,6 +50,18 @@ from elasticdl_tpu.core.train_state import init_train_state
 from elasticdl_tpu.worker.task_data_service import TaskDataService
 
 logger = get_logger("worker")
+
+# The leaves of a training task's cycle, in order (docs/observability.md
+# has where each is entered). Together they tile the cycle: what they do
+# not cover is the slow-task line's ``other``.
+CYCLE_LEAVES = (
+    "get_task", "fetch", "stack", "dispatch", "device_wait",
+    "report_version", "checkpoint", "task_log", "report_task",
+)
+# A training task is slow when its cycle takes over SLOW_TASK_FACTOR x
+# the median of the last SLOW_TASK_WINDOW cycles.
+SLOW_TASK_FACTOR = 1.5
+SLOW_TASK_WINDOW = 32
 
 
 class WorkerStopped(Exception):
@@ -66,7 +80,6 @@ class Worker:
         version_report_steps: int = 1,
         prediction_outputs_processor=None,
         callbacks=None,
-        timing: Optional[Timing] = None,
         checkpoint_hook=None,
         checkpoint_dir_for_init: str = "",
         checkpoint_init_required: bool = True,
@@ -86,7 +99,6 @@ class Worker:
         self._version_report_steps = version_report_steps
         self._processor = prediction_outputs_processor
         self._callbacks = callbacks or []
-        self._timing = timing or Timing(False)
         # step_runner abstracts single-device vs mesh execution (stage 4);
         # None = plain jit on the local device.
         self._step_runner = step_runner
@@ -94,14 +106,24 @@ class Worker:
         self.last_batch = None
         self._train_step = None
         self._eval_step = build_eval_step()
-        # Tracing (observability/tracing.py): step-phase spans into the
-        # process flight recorder when one is installed; free otherwise
+        # Tracing (observability/tracing.py): spans into the process
+        # flight recorder when one is installed; free otherwise
         # (Tracer.span is one module-global read). Recorded spans ride
         # the same piggybacked snapshots as metrics, incrementally via
         # the ring cursor.
-        from elasticdl_tpu.observability import tracing
+        from elasticdl_tpu.observability import default_registry, tracing
 
         self._tracer = tracing.Tracer("worker", str(worker_id))
+        self._metrics = metrics_registry or default_registry()
+        # The phase seam: every region of the task cycle and of
+        # start-up is entered once, through ``self._phases``, and lands
+        # under one name in the phase histogram, in the flight recorder
+        # and (while a --profile_dir window is open) on the device
+        # trace. docs/observability.md has the table.
+        self._phases = tracing.Phases(
+            self._metrics, self._tracer,
+            declare=CYCLE_LEAVES + ("task", "device_step"),
+        )
         # Ring cursor of the last spans CONFIRMED delivered to the
         # master, plus the cursor offered on the in-flight snapshot —
         # committed only when the carrying RPC succeeds, so a failed
@@ -123,7 +145,7 @@ class Worker:
             # RPCs (rate-limited inside _metrics_snapshot).
             metrics_fn=self._metrics_snapshot,
             on_metrics_delivered=self._metrics_delivered,
-            tracer=self._tracer,
+            phases=self._phases,
             master_reattach_grace=master_reattach_grace,
         )
         self.last_metrics = None
@@ -171,8 +193,6 @@ class Worker:
         # report_version every metrics_report_secs (0 = every report,
         # for tests) so the master's cluster view stays fresh without a
         # dedicated RPC.
-        from elasticdl_tpu.observability import default_registry
-
         # Reporting RPCs ride out master unavailability for the same
         # grace window the task stream uses (_master_call below): the
         # stub's own retry budget covers blips of a few seconds, but a
@@ -182,17 +202,19 @@ class Worker:
         self._master_reattach_grace = max(
             float(master_reattach_grace), 0.1
         )
-        self._metrics = metrics_registry or default_registry()
         self._metrics_report_secs = float(metrics_report_secs)
         self._last_metrics_report = 0.0
         self._m_step = self._metrics.histogram(
             "worker_step_seconds",
-            "Device step latency (host-observed)", ["kind"],
+            "Device step: dispatch to the end of the first blocking "
+            "readback (host-observed)", ["kind"],
         )
         # Saturation signal for the autoscaler (master/autoscaler.py):
         # device-step seconds / wall seconds over each report window.
-        # ~1.0 = the device never waits (scaling up helps); ~0 = the
-        # worker is starved or idle (scaling down is safe).
+        # A step's seconds are its ``device_step`` phase, which ends
+        # when the device has answered, so ~1.0 = the device never
+        # waits (scaling up helps); ~0 = the worker is starved or idle
+        # (scaling down is safe).
         self._m_step_util = self._metrics.gauge(
             "worker_step_utilization",
             "Device-step seconds / wall seconds over the report window",
@@ -233,15 +255,31 @@ class Worker:
         # Per-step losses of the training task in flight (device
         # scalars; read back once per task for the task log line).
         self._task_losses = []
-        # Phase accumulators feed the registry too (publish enables
-        # timing; DEBUG log output stays gated on a logger being set).
-        self._timing.publish(self._metrics)
+        # The first call of the training program (load or compile, and
+        # the first run) is a start-up phase around the first
+        # ``device_step`` (_first_step).
+        self._first_step_done = False
+        # Slow-task line: the end of the last task cycle and the last
+        # SLOW_TASK_WINDOW training cycles' seconds. A cycle runs from
+        # the end of one task's report to the end of the next's.
+        self._cycle_end = None
+        self._cycle_secs = deque(maxlen=SLOW_TASK_WINDOW)
 
     # ---- state init ----------------------------------------------------
 
     def _maybe_init(self, batch):
         if self.state is not None:
             return
+        with self._phases.startup("state_init"):
+            self._init_state(batch)
+            # The weights are made asynchronously: wait here, once, or
+            # their seconds land in whatever blocks next.
+            jax.block_until_ready(self.state)
+        if self._checkpoint_dir_for_init:
+            with self._phases.startup("restore"):
+                self._restore_state()
+
+    def _init_state(self, batch):
         self._m_compiles.inc()
         from elasticdl_tpu.callbacks import apply_callbacks_to_optimizer
 
@@ -288,26 +326,27 @@ class Worker:
                 from elasticdl_tpu.core.step import build_multi_step
 
                 self._multi_step = build_multi_step(self._spec.loss)
-        if self._checkpoint_dir_for_init:
-            from elasticdl_tpu.checkpoint import restore_from_dir
 
-            self.state = restore_from_dir(
-                self.state, self._checkpoint_dir_for_init,
-                required=self._checkpoint_init_required,
-                host_tables=getattr(
-                    self._step_runner, "host_tables", None
-                ),
-            )
-            # Restored leaves are host arrays; re-place them with the
-            # runner's shardings or a mesh-sized table lands on one device.
-            if self._step_runner is not None and hasattr(
-                self._step_runner, "place_state"
-            ):
-                self.state = self._step_runner.place_state(self.state)
-            # The restored version is the save baseline — without this,
-            # interval-crossing counts pre-restore steps and writes a
-            # spurious checkpoint on the first post-restore step.
-            self._checkpoint.note_version(int(self.state.step))
+    def _restore_state(self):
+        from elasticdl_tpu.checkpoint import restore_from_dir
+
+        self.state = restore_from_dir(
+            self.state, self._checkpoint_dir_for_init,
+            required=self._checkpoint_init_required,
+            host_tables=getattr(
+                self._step_runner, "host_tables", None
+            ),
+        )
+        # Restored leaves are host arrays; re-place them with the
+        # runner's shardings or a mesh-sized table lands on one device.
+        if self._step_runner is not None and hasattr(
+            self._step_runner, "place_state"
+        ):
+            self.state = self._step_runner.place_state(self.state)
+        # The restored version is the save baseline — without this,
+        # interval-crossing counts pre-restore steps and writes a
+        # spurious checkpoint on the first post-restore step.
+        self._checkpoint.note_version(int(self.state.step))
 
     def set_state(self, state):
         """Install restored state (checkpoint resume / elastic re-init)."""
@@ -432,26 +471,38 @@ class Worker:
     def _report_task(self, task_id: int, err_reason: str = ""):
         """report_task_result with the metrics/span piggyback and the
         span-cursor delivery commit."""
-        snap = self._metrics_snapshot()
-        accepted = self._master_call(
-            lambda: self._master.report_task_result(
-                task_id, err_reason=err_reason, metrics=snap
-            ),
-            f"report_task_result({task_id})",
-        )
-        if snap is not None:
-            self._metrics_delivered()
+        with self._phases.phase("report_task"):
+            snap = self._metrics_snapshot()
+            accepted = self._master_call(
+                lambda: self._master.report_task_result(
+                    task_id, err_reason=err_reason, metrics=snap
+                ),
+                f"report_task_result({task_id})",
+            )
+            if snap is not None:
+                self._metrics_delivered()
         return accepted
+
+    def _report_version(self, version: int):
+        with self._phases.phase("report_version"):
+            snap = self._metrics_snapshot()
+            self._master_call(
+                lambda: self._master.report_version(
+                    version, metrics=snap
+                ),
+                f"report_version({version})",
+            )
+            if snap is not None:
+                self._metrics_delivered()
 
     def _traced_batches(self, batches):
         """Yield from ``batches`` with each blocking ``next()`` under a
-        ``fetch`` span — the input-wait phase of the step timeline
-        (decode / prefetch / row pull-ahead latency the device sits
-        idle for)."""
+        ``fetch`` phase — the input wait of the step timeline (decode /
+        prefetch / row pull-ahead latency the device sits idle for)."""
         it = iter(batches)
         sentinel = object()
         while True:
-            with self._tracer.span("fetch"):
+            with self._phases.phase("fetch"):
                 batch = next(it, sentinel)
             if batch is sentinel:
                 return
@@ -592,6 +643,7 @@ class Worker:
             # Idle worker: nothing to hand back; exit the task loop
             # (the post-loop path checkpoints whatever was trained).
             raise WorkerStopped()
+        self._cycle_end = None  # a cycle that waited is not a slow task
         if not self._in_task and not self._resizing:
             # An idle worker must still join a resize barrier (WAIT
             # responses carry the directive); mid-task ticks (report
@@ -670,12 +722,17 @@ class Worker:
     def _process_train_task(self, task, batches) -> int:
         self._task_losses = []
         if self._fuse_task_steps:
-            batch_list = list(batches)
+            # The whole input wait of a fused task: the program needs
+            # every minibatch before it can be dispatched.
+            with self._phases.phase("fetch") as fetch:
+                batch_list = list(batches)
+                nbytes = sum(self._batch_nbytes(b) for b in batch_list)
+                fetch.set(batches=len(batch_list), bytes=nbytes)
             if not batch_list:
                 return 0
             self._maybe_init(batch_list[0])
             if self._multi_step is not None and len(batch_list) > 1:
-                return self._process_train_task_fused(batch_list)
+                return self._process_train_task_fused(batch_list, nbytes)
             batches = iter(batch_list)
         # Host-tier runners: pull rows for upcoming minibatches on a
         # prefetch thread while the current one trains (the reference's
@@ -717,35 +774,27 @@ class Worker:
                     # Pre-step so the window [start, start+num) captures
                     # the steps it names.
                     self._profiler.observe_step(int(self.state.step))
-                step_t0 = time.monotonic()
-                with self._tracer.span("device_step", kind="train"):
-                    with self._timing.record("batch_process"):
-                        if self._profiler is not None:
-                            with self._profiler.annotation("train_step"):
-                                self._process_train_batch(batch)
-                        else:
-                            self._process_train_batch(batch)
-                self._observe_step("train", time.monotonic() - step_t0)
-                self._m_examples.labels(task.type).inc(
-                    self._batch_examples(raw)
-                )
-                self._m_h2d_bytes.inc(self._batch_nbytes(raw))
+                with self._first_step(), self._phases.phase(
+                    "device_step", kind="train"
+                ) as step:
+                    with self._phases.phase("dispatch"):
+                        self._process_train_batch(batch)
+                    # The step's counters, while the device works.
+                    self._m_examples.labels(task.type).inc(
+                        self._batch_examples(raw)
+                    )
+                    self._m_h2d_bytes.inc(self._batch_nbytes(raw))
+                    # The loop blocks here until the device has run the
+                    # step; inside the phase, so ``device_step`` is the
+                    # device's time and not the enqueue's.
+                    with self._phases.phase("device_wait"):
+                        version = int(self.state.step)
+                self._observe_step("train", step.dur)
                 count += 1
-                version = int(self.state.step)
                 if version % self._version_report_steps == 0:
-                    with self._timing.record("report_version"):
-                        snap = self._metrics_snapshot()
-                        self._master_call(
-                            lambda s=snap: self._master.report_version(
-                                version, metrics=s
-                            ),
-                            f"report_version({version})",
-                        )
-                        if snap is not None:
-                            self._metrics_delivered()
-                with self._tracer.span("checkpoint"):
-                    with self._timing.record("checkpoint"):
-                        self._checkpoint.maybe_save(self.state)
+                    self._report_version(version)
+                with self._phases.phase("checkpoint"):
+                    self._checkpoint.maybe_save(self.state)
         finally:
             if prepared_iter is not None:
                 prepared_iter.close()
@@ -776,7 +825,7 @@ class Worker:
                     )
         return count
 
-    def _process_train_task_fused(self, batch_list) -> int:
+    def _process_train_task_fused(self, batch_list, nbytes: int) -> int:
         """One compiled scan over the task's minibatches; version
         reporting and checkpointing at task granularity."""
         from elasticdl_tpu.core.step import stack_batches
@@ -784,25 +833,31 @@ class Worker:
         self.last_batch = batch_list[-1]
         if self._profiler is not None:
             self._profiler.observe_step(int(self.state.step))
-        stacked = stack_batches(batch_list)
-        step_t0 = time.monotonic()
-        with self._tracer.span(
+        with self._phases.phase("stack"):
+            stacked = stack_batches(batch_list)
+        with self._first_step(), self._phases.phase(
             "device_step", kind="train_fused", batches=len(batch_list)
-        ):
-            with self._timing.record("batch_process"):
+        ) as step:
+            with self._phases.phase("dispatch"):
                 self.state, metrics = self._multi_step(
                     self.state, stacked
                 )
-        self.last_metrics = {"loss": metrics["loss"][-1]}
-        self._task_losses.append(metrics["loss"])
-        self._observe_step("train_fused", time.monotonic() - step_t0)
-        self._m_examples.labels(TaskType.TRAINING).inc(
-            sum(self._batch_examples(b) for b in batch_list)
-        )
-        self._m_h2d_bytes.inc(
-            sum(self._batch_nbytes(b) for b in batch_list)
-        )
-        version = int(self.state.step)
+            # The task's bookkeeping, while the device works: the slice
+            # of the last loss is three small programs queued behind the
+            # task's, 2 ms of host time on a v5e that would lie in the
+            # gap between two task programs if it came after the wait
+            # (PERF.md, PR 24).
+            self.last_metrics = {"loss": metrics["loss"][-1]}
+            self._task_losses.append(metrics["loss"])
+            self._m_examples.labels(TaskType.TRAINING).inc(
+                sum(self._batch_examples(b) for b in batch_list)
+            )
+            self._m_h2d_bytes.inc(nbytes)
+            # As on the per-step path: the wait for the device lies
+            # inside ``device_step``.
+            with self._phases.phase("device_wait"):
+                version = int(self.state.step)
+        self._observe_step("train_fused", step.dur)
         # Same SSP gating as the per-step path, at task granularity:
         # report iff a version_report_steps boundary was crossed.
         prev = version - len(batch_list)
@@ -810,33 +865,70 @@ class Worker:
             version // self._version_report_steps
             > prev // self._version_report_steps
         ):
-            with self._timing.record("report_version"):
-                snap = self._metrics_snapshot()
-                self._master_call(
-                    lambda: self._master.report_version(
-                        version, metrics=snap
-                    ),
-                    f"report_version({version})",
-                )
-                if snap is not None:
-                    self._metrics_delivered()
-        with self._timing.record("checkpoint"):
+            self._report_version(version)
+        with self._phases.phase("checkpoint"):
             self._checkpoint.maybe_save(self.state)
         return len(batch_list)
 
+    def _first_step(self):
+        """The ``first_program`` start-up phase around the process's
+        first training ``device_step``; nothing after it."""
+        if self._first_step_done:
+            return contextlib.nullcontext()
+        self._first_step_done = True
+        return self._phases.startup("first_program")
+
     def _log_trained_task(self, task, trained: int):
-        """One line per training task: the job's loss trajectory at
-        task granularity (one host readback per task)."""
+        """Two lines per training task: the job's loss trajectory at
+        task granularity, then every step's loss (one host readback
+        per task for both). A checker outside the process replays the
+        steps against the second line; the first is what
+        ``benchmark/lib/procs.py`` parses, letter for letter."""
         if not self._task_losses:
             return
-        losses = np.concatenate(
-            [np.ravel(np.asarray(x)) for x in self._task_losses]
-        )
-        logger.info(
-            "Task %d trained: batches=%d version=%d mean_loss=%.6f",
-            task.task_id, trained, int(self.state.step),
-            float(losses.mean()),
-        )
+        with self._phases.phase("task_log"):
+            losses = np.concatenate(
+                [np.ravel(np.asarray(x)) for x in self._task_losses]
+            )
+            logger.info(
+                "Task %d trained: batches=%d version=%d mean_loss=%.6f",
+                task.task_id, trained, int(self.state.step),
+                float(losses.mean()),
+            )
+            logger.info(
+                "Task %d losses: [%s]", task.task_id,
+                ", ".join(f"{float(x):.6f}" for x in losses),
+            )
+
+    def _end_cycle(self, task, trained_ok: bool):
+        """A task's cycle has ended (its report is in): say why, if it
+        was a slow one. One WARNING line for a training task whose cycle
+        took over SLOW_TASK_FACTOR x the running median, with every leaf
+        of the cycle and ``other``, what the leaves do not cover (a
+        resize, the profiler's start and stop). A cycle that waited for
+        work (``_wait_tick``) is not judged."""
+        now = time.monotonic()
+        phases, self._phases.durations = self._phases.durations, {}
+        last_end, self._cycle_end = self._cycle_end, now
+        if (task.type != TaskType.TRAINING or not trained_ok
+                or last_end is None):
+            return
+        cycle = now - last_end
+        if len(self._cycle_secs) >= 4:
+            median = statistics.median(self._cycle_secs)
+            if cycle > SLOW_TASK_FACTOR * median:
+                leaves = {
+                    name: round(phases.get(name, 0.0), 4)
+                    for name in CYCLE_LEAVES
+                }
+                leaves["other"] = round(
+                    max(0.0, cycle - sum(leaves.values())), 4
+                )
+                logger.warning(
+                    "Task %d slow: cycle=%.3fs median=%.3fs phases=%s",
+                    task.task_id, cycle, median, leaves,
+                )
+        self._cycle_secs.append(cycle)
 
     def _drain_multihost(self):
         """Drain barrier: keep participating in other processes' steps
@@ -872,6 +964,18 @@ class Worker:
             return multihost.host_local_slice(preds)
         return np.asarray(preds)
 
+    def _forward_step(self, kind: str, batch):
+        """One forward ``device_step``: this process's rows of the
+        predictions, read back inside the phase (the readback is what
+        waits for the device)."""
+        with self._phases.phase("device_step", kind=kind) as step:
+            with self._phases.phase("dispatch"):
+                preds = self._eval_step(self.state, batch)
+            with self._phases.phase("device_wait"):
+                rows = self._local_rows(preds)
+        self._observe_step(kind, step.dur)
+        return rows
+
     def _process_eval_task(self, task, batches):
         outputs_acc, labels_acc = [], []
         for batch in batches:
@@ -881,14 +985,11 @@ class Worker:
                 from elasticdl_tpu.parallel import multihost
 
                 self._await_turn(multihost.STEP_FORWARD)
-            step_t0 = time.monotonic()
-            with self._tracer.span("device_step", kind="eval"):
-                preds = self._eval_step(self.state, batch)
-            self._observe_step("eval", time.monotonic() - step_t0)
+            rows = self._forward_step("eval", batch)
             real = int(np.sum(batch["mask"]))
             self._m_examples.labels(task.type).inc(real)
             self._m_h2d_bytes.inc(self._batch_nbytes(batch))
-            outputs_acc.append(self._local_rows(preds)[:real])
+            outputs_acc.append(rows[:real])
             labels_acc.append(np.asarray(batch["labels"])[:real])
         if outputs_acc:
             outputs = np.concatenate(outputs_acc, axis=0)
@@ -910,17 +1011,12 @@ class Worker:
                 from elasticdl_tpu.parallel import multihost
 
                 self._await_turn(multihost.STEP_FORWARD)
-            step_t0 = time.monotonic()
-            with self._tracer.span("device_step", kind="predict"):
-                preds = self._eval_step(self.state, batch)
-            self._observe_step("predict", time.monotonic() - step_t0)
+            rows = self._forward_step("predict", batch)
             real = int(np.sum(batch["mask"]))
             self._m_examples.labels(task.type).inc(real)
             self._m_h2d_bytes.inc(self._batch_nbytes(batch))
             if self._processor is not None:
-                self._processor.process(
-                    self._local_rows(preds)[:real], self._id
-                )
+                self._processor.process(rows[:real], self._id)
 
     def _run_train_end_callbacks(self):
         for cb in self._callbacks:
@@ -971,7 +1067,6 @@ class Worker:
             and not (self._multihost_sync and self._stop_requested)
         ):
             self._checkpoint.save_final(self.state)
-        self._timing.report_timing()
         return {
             "worker_id": self._id,
             "trained_batches": trained_batches,
@@ -1051,15 +1146,14 @@ class Worker:
             processed_ok = False
             self._in_task = True
             try:
-                with self._timing.record("task_process"):
-                    if task.type == TaskType.TRAINING:
-                        trained = self._process_train_task(task, batches)
-                        trained_batches += trained
-                        self._log_trained_task(task, trained)
-                    elif task.type == TaskType.EVALUATION:
-                        self._process_eval_task(task, batches)
-                    elif task.type == TaskType.PREDICTION:
-                        self._process_predict_task(task, batches)
+                if task.type == TaskType.TRAINING:
+                    trained = self._process_train_task(task, batches)
+                    trained_batches += trained
+                    self._log_trained_task(task, trained)
+                elif task.type == TaskType.EVALUATION:
+                    self._process_eval_task(task, batches)
+                elif task.type == TaskType.PREDICTION:
+                    self._process_predict_task(task, batches)
                 processed_ok = True
                 self._in_task = False
                 self._m_tasks.labels(task.type, "ok").inc()
@@ -1096,6 +1190,7 @@ class Worker:
                     # it failed on, and the next task would fail the
                     # same way. The master has the task back; die.
                     raise
+            self._end_cycle(task, processed_ok)
         if not self._stop_requested:
             # A stopping worker must not drain: the barrier drains only
             # when ALL processes are done, and peers aren't — its death

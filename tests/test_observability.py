@@ -5,7 +5,7 @@ metrics registry (counters/gauges/histograms, labeled families), the
 Prometheus text-format renderer against a golden exposition, the
 stdlib HTTP endpoint (/metrics + /healthz on an ephemeral port), the
 master-side cluster view (snapshot merge, TTL aging, immediate removal
-on elastic resize), the Timing→registry bridge, the SummaryWriter
+on elastic resize), the phases→registry bridge, the SummaryWriter
 context-manager contract, and the acceptance path: an in-process
 MiniCluster run whose master /metrics aggregates ≥2 workers' step
 histograms, dispatcher gauges, and embedding/row-service counters —
@@ -30,6 +30,7 @@ from elasticdl_tpu.observability import (
     MetricsPlane,
     MetricsRegistry,
     render_prometheus,
+    tracing,
 )
 from elasticdl_tpu.testing.cluster import MiniCluster
 from elasticdl_tpu.testing.data import (
@@ -530,23 +531,26 @@ def test_metrics_plane_tensorboard_bridge():
     assert len(writer.calls) == 2
 
 
-# ---- Timing → registry bridge ------------------------------------------
+# ---- phases → registry (the seam owns worker_phase_seconds) -------------
 
 
-def test_timing_minmax_and_publish():
+def test_phase_seam_lands_in_registry():
+    """What ``Timing.publish`` did before the seam: every entry of a
+    phase lands in ``edl_tpu_worker_phase_seconds{phase}``; ``Timing``
+    keeps its own min/max accumulators for the local executor."""
     reg = MetricsRegistry()
-    timing = Timing(enabled=False).publish(reg)
-    assert timing.enabled  # publishing implies measuring
+    phases = tracing.Phases(reg, tracing.Tracer("worker"))
+    timing = Timing(enabled=True)
     for _ in range(3):
-        with timing.record("batch_process"):
+        with phases.phase("device_step"), timing.record("batch_process"):
             pass
     stats = timing.summary()["batch_process"]
     assert stats["count"] == 3
     assert 0 <= stats["min_secs"] <= stats["max_secs"] <= stats["total_secs"]
-    (fam,) = reg.snapshot()["families"]
-    assert fam["name"] == "edl_tpu_worker_phase_seconds"
-    (series,) = fam["series"]
-    assert series["labels"] == ["batch_process"] and series["count"] == 3
+    families = {f["name"]: f for f in reg.snapshot()["families"]}
+    (series,) = families["edl_tpu_worker_phase_seconds"]["series"]
+    assert series["labels"] == ["device_step"] and series["count"] == 3
+    assert phases.durations["device_step"] == pytest.approx(series["sum"])
 
 
 # ---- SummaryWriter contract --------------------------------------------
@@ -619,8 +623,15 @@ def test_cluster_job_exposes_aggregated_metrics(tmp_path, capsys):
     assert "edl_tpu_embedding_lookup_ids_total" in text
     assert "edl_tpu_row_service_pulled_rows_total" in text
     assert "edl_tpu_row_service_pushed_rows_total" in text
-    # Phase accumulators landed as histograms (Timing.publish path).
-    assert 'edl_tpu_worker_phase_seconds_count{phase="batch_process"' in text
+    # The worker's phases landed as histograms (the phase seam).
+    for name in ("task", "get_task", "fetch", "device_step", "dispatch",
+                 "device_wait", "report_version", "report_task"):
+        assert (
+            f'edl_tpu_worker_phase_seconds_count{{phase="{name}"' in text
+        ), name
+    # Start-up phases ride the snapshot as gauges.
+    assert 'edl_tpu_worker_startup_seconds{phase="state_init"' in text
+    assert 'edl_tpu_worker_startup_seconds{phase="first_program"' in text
 
     # `make metrics` / tools/dump_metrics.py works against the cluster.
     assert dump_metrics_main([f"localhost:{port}"]) == 0

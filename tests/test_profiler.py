@@ -47,10 +47,27 @@ def test_from_args_gate():
 
 
 class _FakeBackend:
-    """jax.profiler stand-in: records start/stop calls, no tracing."""
+    """jax.profiler stand-in: records start/stop calls, no tracing.
+    ``annotations`` holds the name of every ``TraceAnnotation`` entered
+    (the phase seam's third sink, tests/test_tracing.py)."""
 
     def __init__(self):
         self.calls = []
+        self.annotations = []
+        backend = self
+
+        class TraceAnnotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                backend.annotations.append(self.name)
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        self.TraceAnnotation = TraceAnnotation
 
     def start_trace(self, logdir):
         self.calls.append(("start", logdir))

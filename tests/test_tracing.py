@@ -11,9 +11,14 @@ Chrome/Perfetto exporter + ``tools/check_trace.py`` schema checker, the
 critical-path straggler attribution, and the acceptance smoke: a traced
 2-worker MiniCluster job whose exported JSON holds a task tree crossing
 master → worker → row-service (the ``make trace-smoke`` lane).
+
+The phase seam (``tracing.Phases``): each of its three sinks alone and
+together, what it costs with no recorder and no trace window, that the
+worker's phases tile a fused task's cycle, and the slow-task line.
 """
 
 import json
+import logging
 import time
 
 import numpy as np
@@ -214,6 +219,318 @@ def test_null_span_overhead_unmeasurable():
 
     per_call = min(once() for _ in range(5))
     assert per_call < 5e-6, f"null span cost {per_call * 1e6:.2f}µs"
+
+
+# ---- the phase seam -----------------------------------------------------
+
+
+def _phase_series(reg):
+    families = {f["name"]: f for f in reg.snapshot()["families"]}
+    return {
+        s["labels"][0]: s
+        for s in families["edl_tpu_worker_phase_seconds"]["series"]
+    }
+
+
+@pytest.fixture
+def trace_window():
+    """A ``utils.profiler.Profiler`` over tests/test_profiler.py's fake
+    backend: the annotation sink is open between its ``start_trace``
+    and its ``stop``, as under ``--profile_dir``."""
+    from test_profiler import _FakeBackend
+
+    from elasticdl_tpu.utils.profiler import Profiler
+
+    fake = _FakeBackend()
+    prof = Profiler("/tmp/trace", start_step=1, num_steps=100,
+                    backend=fake)
+    yield prof, fake
+    prof.stop()
+    assert tracing._ANNOTATE is None
+
+
+@pytest.mark.parametrize("sinks", [
+    "histogram", "recorder", "annotation", "all", "error", "discard",
+    "startup",
+])
+def test_phase_feeds_each_sink_under_one_name(sinks, trace_window):
+    """One entry, one name, three sinks: the histogram always, a span
+    when a recorder is installed, ``edl:<name>`` on the profiler's
+    trace only between ``start_trace`` and ``stop``."""
+    from elasticdl_tpu.observability import MetricsRegistry
+
+    prof, fake = trace_window
+    reg = MetricsRegistry()
+    phases = tracing.Phases(reg, Tracer("worker", "7"))
+    rec = None
+    if sinks in ("recorder", "all", "error", "discard"):
+        rec = tracing.install_recorder(FlightRecorder(16))
+    with phases.phase("fetch"):
+        pass  # before the window: never annotated
+    assert fake.annotations == []
+    if sinks in ("annotation", "all"):
+        prof.observe_step(1)
+        assert fake.calls == [("start", "/tmp/trace")]
+
+    if sinks == "error":
+        with pytest.raises(KeyError):
+            with phases.phase("device_step", kind="train") as ph:
+                raise KeyError("boom")
+    elif sinks == "discard":
+        with phases.phase("device_step") as ph:
+            ph.discard()
+    elif sinks == "startup":
+        with phases.startup("device_step") as ph:
+            pass
+    else:
+        with phases.phase("device_step", kind="train") as ph:
+            with phases.phase("dispatch"):
+                pass
+            ph.set(batches=2)
+    assert ph.dur >= 0.0
+
+    series = _phase_series(reg)
+    if sinks == "discard":
+        assert "device_step" not in series
+        assert "device_step" not in phases.durations
+    else:
+        # The histogram observes whatever else is on, and an exception
+        # inside the phase too.
+        assert series["device_step"]["count"] == 1
+        assert series["device_step"]["sum"] == pytest.approx(ph.dur)
+        assert phases.durations["device_step"] == pytest.approx(ph.dur)
+    gauges = {
+        f["name"]: f for f in reg.snapshot()["families"]
+    }["edl_tpu_worker_startup_seconds"]["series"]
+    if sinks == "startup":
+        (gauge,) = gauges
+        assert gauge["labels"] == ["device_step"]
+        assert gauge["value"] == pytest.approx(ph.dur)
+    else:
+        assert gauges == []
+
+    spans = {s["name"]: s for s in rec.snapshot()} if rec else {}
+    if sinks in ("recorder", "all"):
+        step = spans["device_step"]
+        assert step["instance"] == "7" and step["role"] == "worker"
+        assert step["attrs"] == {"kind": "train", "batches": 2}
+        assert spans["dispatch"]["parent_id"] == step["span_id"]
+        assert step["dur"] == pytest.approx(ph.dur, abs=1e-3)
+    elif sinks == "error":
+        assert spans["device_step"]["attrs"]["error"] == "KeyError"
+    elif sinks == "discard":
+        assert "device_step" not in spans
+    else:
+        assert not tracing.enabled()
+
+    if sinks in ("annotation", "all"):
+        assert fake.annotations == ["edl:device_step", "edl:dispatch"]
+        prof.stop()
+        with phases.phase("fetch"):
+            pass  # after the window: never annotated
+        assert fake.annotations == ["edl:device_step", "edl:dispatch"]
+    else:
+        assert fake.annotations == []
+
+
+@pytest.mark.perf
+def test_phase_off_path_overhead():
+    """No recorder, no trace window: a phase costs what
+    ``Timing.record`` cost before it — two monotonic reads and one
+    histogram observe. Timed on the thread's own CPU clock (xdist
+    workers share the cores a wall clock would time), best of several
+    rounds; 20µs/entry (measured ~2µs) catches a span allocated, an
+    annotation entered or a registry lookup left on the always-on
+    path, and is 0.02% of a 1.5 s task's ten phases."""
+    from elasticdl_tpu.observability import MetricsRegistry
+
+    assert not tracing.enabled() and tracing._ANNOTATE is None
+    phases = tracing.Phases(MetricsRegistry(), Tracer("worker"))
+    n = 5000
+
+    def once() -> float:
+        t0 = time.thread_time()
+        for _ in range(n):
+            with phases.phase("dispatch"):
+                pass
+        return (time.thread_time() - t0) / n
+
+    per_call = min(once() for _ in range(7))
+    assert per_call < 20e-6, f"phase cost {per_call * 1e6:.2f}µs"
+
+
+class _Lines(logging.Handler):
+    """Collects a program logger's messages (its loggers do not
+    propagate, so caplog does not see them)."""
+
+    def __init__(self, *loggers):
+        super().__init__()
+        self.lines = []
+        self._loggers = loggers
+
+    def emit(self, record):
+        self.lines.append((record.levelname, record.getMessage()))
+
+    def __enter__(self):
+        for lg in self._loggers:
+            lg.addHandler(self)
+        return self
+
+    def __exit__(self, *exc):
+        for lg in self._loggers:
+            lg.removeHandler(self)
+
+
+def _fused_mnist_cluster(tmp_path, records=256, **kw):
+    from elasticdl_tpu.testing.cluster import MiniCluster
+    from elasticdl_tpu.testing.data import (
+        create_mnist_record_file,
+        model_zoo_dir,
+    )
+
+    train = create_mnist_record_file(
+        str(tmp_path / "t.rec"), records, seed=3
+    )
+    return MiniCluster(
+        model_zoo=model_zoo_dir(),
+        model_def="mnist.mnist_functional.custom_model",
+        training_data=train, minibatch_size=16,
+        num_minibatches_per_task=4, fuse_task_steps=True, **kw,
+    )
+
+
+def test_phases_tile_the_fused_task_cycle(tmp_path):
+    """A ``--fuse_task_steps`` job with the recorder on: the leaves of
+    every ``task`` span cover at least 90% of it, ``device_step`` is
+    ``dispatch`` + ``device_wait``, ``fetch`` is entered (with the
+    task's batches and bytes), the start-up phases are kept as gauges,
+    and the two lines a check outside the process reads are printed."""
+    from elasticdl_tpu.master import task_dispatcher
+    from elasticdl_tpu.worker import worker as worker_mod
+
+    rec = tracing.install_recorder(FlightRecorder(4096))
+    cluster = _fused_mnist_cluster(tmp_path)
+    with _Lines(worker_mod.logger, task_dispatcher.logger) as log:
+        cluster.run()
+    assert cluster.finished
+    # The worker's side: the master's own spans (its ``dispatch``
+    # under get_task) are no part of the tiling.
+    spans = [s for s in rec.snapshot() if s["role"] == "worker"]
+    _, children = critical_path.build_index(spans)
+    tasks = [s for s in spans if s["name"] == "task"
+             and s["attrs"].get("type") == "training"]
+    assert len(tasks) == 4
+    # The cycle's leaves, and in the first task the weights.
+    leaves = set(worker_mod.CYCLE_LEAVES) | {"state_init"}
+    for task in tasks:
+        tree = critical_path.subtree(task, children)
+        covered = sum(s["dur"] for s in tree if s["name"] in leaves)
+        assert covered >= 0.9 * task["dur"], (
+            covered, task["dur"],
+            {s["name"]: s["dur"] for s in tree},
+        )
+        names = [s["name"] for s in tree]
+        for leaf in ("get_task", "fetch", "stack", "dispatch",
+                     "device_wait", "report_version", "checkpoint",
+                     "task_log", "report_task"):
+            assert names.count(leaf) == 1, (leaf, names)
+        (fetch,) = [s for s in tree if s["name"] == "fetch"]
+        assert fetch["attrs"]["batches"] == 4
+        assert fetch["attrs"]["bytes"] > 0
+    steps = [s for s in spans if s["name"] == "device_step"]
+    assert len(steps) == 4
+    for step in steps:
+        kids = children[step["span_id"]]
+        assert [k["name"] for k in kids] == ["dispatch", "device_wait"]
+        assert sum(k["dur"] for k in kids) >= 0.9 * step["dur"]
+        assert step["attrs"]["kind"] == "train_fused"
+    # Start-up, once each: weights, then the first program around the
+    # first device_step.
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    assert len(by_name["state_init"]) == 1
+    (first,) = by_name["first_program"]
+    assert first["parent_id"] == tasks[0]["span_id"]
+    assert children[first["span_id"]][0]["name"] == "device_step"
+    registry = cluster.workers[0]._metrics
+    startup = {
+        s["labels"][0]: s["value"]
+        for f in registry.snapshot()["families"]
+        if f["name"] == "edl_tpu_worker_startup_seconds"
+        for s in f["series"]
+    }
+    assert startup["first_program"] == pytest.approx(first["dur"], abs=1e-3)
+    assert startup["state_init"] > 0
+    # step seconds are the device_step phase: dispatch and the wait.
+    step_series = {
+        s["labels"][0]: s
+        for f in registry.snapshot()["families"]
+        if f["name"] == "edl_tpu_worker_step_seconds"
+        for s in f["series"]
+    }
+    assert step_series["train_fused"]["sum"] >= sum(
+        s["dur"] for s in steps
+    ) - 1e-3
+    # The lines for a check that reads from outside.
+    messages = [m for _, m in log.lines]
+    trained = [m for m in messages if " trained: " in m]
+    losses = [m for m in messages if " losses: [" in m]
+    assert len(trained) == len(losses) == 4
+    for line_t, line_l in zip(trained, losses):
+        task_id = line_t.split()[1]
+        assert line_l.startswith(f"Task {task_id} losses: [")
+        values = [float(x) for x in
+                  line_l.split("[", 1)[1].rstrip("]").split(", ")]
+        assert len(values) == 4
+        mean = float(line_t.rsplit("mean_loss=", 1)[1])
+        assert sum(values) / 4 == pytest.approx(mean, abs=2e-6)
+    dispatched = [m for m in messages if " dispatched: " in m]
+    assert any(
+        "type=training shard=" in m and "start=0 end=64 worker=0" in m
+        for m in dispatched
+    ), dispatched
+
+
+def test_slow_task_line_names_the_phase(tmp_path):
+    """A task made slow by a sleeping reader gets one WARNING line that
+    says so: its cycle, the running median, and ``fetch`` holding the
+    difference."""
+    import ast
+
+    from elasticdl_tpu.worker import worker as worker_mod
+
+    cluster = _fused_mnist_cluster(tmp_path, records=640)
+    worker = cluster.workers[0]
+    reader = worker._reader
+    read_records = reader.read_records
+    seen = {"tasks": 0, "slept": None}
+
+    def sleepy(task):
+        seen["tasks"] += 1
+        if seen["tasks"] == 8:
+            seen["slept"] = task.task_id
+            time.sleep(1.0)  # on the prefetch thread: the loop waits
+        yield from read_records(task)
+
+    reader.read_records = sleepy
+    with _Lines(worker_mod.logger) as log:
+        cluster.run()
+    assert cluster.finished and seen["tasks"] == 10
+    # One line for the task that slept (a loaded box may make another
+    # task slow too: that one gets its own line).
+    slow = [m for level, m in log.lines if level == "WARNING"
+            and m.startswith(f"Task {seen['slept']} slow: ")]
+    assert len(slow) == 1, log.lines
+    line = slow[0]
+    cycle = float(line.split("cycle=")[1].split("s")[0])
+    median = float(line.split("median=")[1].split("s")[0])
+    phases = ast.literal_eval(line.split("phases=")[1])
+    assert set(phases) == set(worker_mod.CYCLE_LEAVES) | {"other"}
+    assert cycle > 1.5 * median and cycle >= 1.0
+    assert phases["fetch"] >= 1.0
+    assert max(phases, key=phases.get) == "fetch"
+    assert sum(phases.values()) == pytest.approx(cycle, abs=0.01)
 
 
 # ---- RPC propagation ----------------------------------------------------
