@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.ops.flash_attention import (
+    describe_tiles as describe_attention_tiles,
     flash_attention,
     log_traced as log_traced_attention,
     supports as flash_supports,
@@ -148,11 +149,14 @@ class SelfAttention(nn.Module):
         if self.mesh is not None:
             o = ring_attention(q, k, v, self.mesh, causal=True, scale=scale)
         elif backend == "tpu" and flash_supports(q.shape):
-            # Single-chip TPU hot path: fused Pallas kernel (O(S) HBM,
-            # causal block skipping) instead of the O(S^2) dense scores.
+            # Single-chip TPU hot path: fused Pallas kernel (O(S) HBM;
+            # the causal walk stops at the diagonal, by sub-tiles inside
+            # one grid tile, by grid tiles beyond it: the log line says
+            # how many) instead of the O(S^2) dense scores.
             log_traced_attention(
                 "pallas flash kernel",
-                "tpu backend, shape tiles the kernel blocks", q.shape,
+                "tpu backend, shape tiles the kernel blocks; "
+                + describe_attention_tiles(q.shape[1]), q.shape,
             )
             o = flash_attention(q, k, v, causal=True, scale=scale)
         else:

@@ -5,8 +5,19 @@ The transformer flagship's single-chip hot path. ``dense_attention``
 HBM — O(S²) memory and two extra HBM round-trips. This kernel tiles
 queries over the grid and streams K/V through VMEM with the standard
 online-softmax recurrence (running max m, denominator l, accumulator o),
-so scores only ever exist as (block_q, block_k) tiles on-chip, and the
-causal path skips fully-masked K blocks entirely (~2× fewer FLOPs).
+so scores only ever exist as (block_q, block_k) tiles on-chip.
+
+What the causal mask saves, and where. Across grid tiles (S over one
+block: S = 2,048 and up) a K tile strictly above the diagonal is
+predicated out (``pl.when``). Inside ONE grid tile, which is the whole
+sequence at S <= 1,024 with the default blocks, nothing can be
+predicated, so the three kernels walk the tile in ``SUB_TILE``-wide
+strips that stop at the diagonal wherever its place is known at trace
+time (``_walk``): 10 of 16 sub-tiles multiplied at S = 1,024, 3 of 4 at
+S = 512. Where the diagonal is traced (several grid tiles; the ring's
+prefetched offsets) the whole tile is computed and masked. Both forms
+share one definition of the tile mathematics (``_tile_scores``,
+``_tile_probs``, ``_bwd_tile_math``) and give the same bits.
 
 Backward is a custom VJP: the forward saves only o and the logsumexp
 L = m + log(l) (the flash-attention residual trick); the backward runs
@@ -22,7 +33,7 @@ float32 throughout.
 """
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,10 +50,12 @@ _NEG_INF = -1e30
 # 128-blocks; the round-3 device-time block sweep at S=1024
 # (B8/H8/D64, fwd+bwd, causal) went further: 1024x1024 blocks run
 # 1.083 ms vs 1.244 ms at 512x512 (+13%) — fewer grid steps beat the
-# causal block-skipping the smaller tiles enable. 1024 is the default;
-# blocks clamp to S for shorter sequences (S=512 uses 512x512). VMEM
-# per step at 1024 blocks: each f32 (block_q, block_k) tile is 4 MB and
-# the backward keeps several live (s, p, dp, ds). No vmem_limit_bytes
+# causal block-skipping the smaller GRID tiles enable (the skipping is
+# had inside the 1024 block instead, by ``_walk``'s strips). 1024 is
+# the default; blocks clamp to S for shorter sequences (S=512 uses
+# 512x512). VMEM per step at 1024 blocks, whole tile: each f32
+# (block_q, block_k) tile is 4 MB and the backward keeps several live
+# (s, p, dp, ds); a strip's are a quarter of that. No vmem_limit_bytes
 # is set: Mosaic (jax 0.9.0, libtpu 0.0.34, v5e) takes all three
 # kernels under its default scoped limit at the flagship geometry
 # (bf16, B*H=128, S=1024, D=128; tests/test_tpu_kernels.py). The
@@ -51,6 +64,97 @@ _NEG_INF = -1e30
 # was lowered on purpose): a larger default block would not fit.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
+# Edge of the sub-tiles a single grid tile is walked in (``_walk``).
+# v5e sweep, B*H=128, S=1024, bf16, forward + both backward kernels,
+# device ms a layer: whole tile 1.836; 512: 1.329; 256: 1.237; 128:
+# 1.336; within two microseconds of that at D=128, so ``d`` does not
+# enter the choice (tools/bench_flash_blocks.py prints the rows).
+SUB_TILE = 256
+
+
+class TilePlan(NamedTuple):
+    """How one grid tile of a kernel call is multiplied: ``rows[i]`` is
+    the number of sub-tiles, from the left, that sub-tile row ``i``
+    multiplies. The whole tile kept is one sub-tile: ``rows == (1,)``
+    and ``sub_q, sub_k`` the block itself."""
+    block_q: int
+    block_k: int
+    sub_q: int
+    sub_k: int
+    rows: Tuple[int, ...]
+
+    @property
+    def computed(self) -> int:
+        return sum(self.rows)
+
+    @property
+    def total(self) -> int:
+        return (self.block_q // self.sub_q) * (self.block_k // self.sub_k)
+
+    def describe(self) -> str:
+        return (
+            f"blocks {self.block_q}x{self.block_k}, sub-tiles "
+            f"{self.sub_q}x{self.sub_k}, {self.computed} of {self.total} "
+            "computed"
+        )
+
+
+def _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset, sub=None):
+    """The strip walk of a kernel call, or None where the whole tile is
+    kept. It needs the diagonal's place inside the tile at trace time:
+    a causal call, offsets that are Python ints (the ring's are traced),
+    one grid tile each way, and a block of at least two sub-tiles.
+
+    Returns, for each ``sub``-high row of sub-tiles, how many sub-tiles
+    from the left the mask leaves something of: sub-tile (i, j) has an
+    unmasked score iff its bottom-left one is, q_offset + (i+1)*sub - 1
+    >= k_offset + j*sub. Rows are prefixes and columns suffixes, so a
+    strip is one rectangle."""
+    sub = sub or SUB_TILE
+    if not (
+        causal
+        and isinstance(q_offset, int) and isinstance(k_offset, int)
+        and sq == block_q and sk == block_k
+        and block_q % sub == 0 and block_k % sub == 0
+        and block_q >= 2 * sub and block_k >= 2 * sub
+    ):
+        return None
+    n_k = block_k // sub
+    return tuple(
+        min(n_k, max(0, (q_offset + (i + 1) * sub - 1 - k_offset) // sub + 1))
+        for i in range(block_q // sub)
+    )
+
+
+def tile_plan(sq, sk, causal=True, block_q=0, block_k=0, q_offset=0,
+              k_offset=0, sub=None) -> TilePlan:
+    """What a kernel call with these arguments multiplies inside one
+    grid tile (the kernels ask ``_walk`` the same question): for the
+    line ``log_traced`` prints and for the tests. Traced offsets are
+    anything that is not an int."""
+    block_q, block_k = _blocks(sq, sk, block_q, block_k)
+    sub = sub or SUB_TILE
+    rows = _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset, sub)
+    if rows is None:
+        return TilePlan(block_q, block_k, block_q, block_k, (1,))
+    return TilePlan(block_q, block_k, sub, sub, rows)
+
+
+def describe_tiles(s_len, causal=True, traced_offsets=False) -> str:
+    """``tile_plan(...).describe()`` of the kernels a layer over a
+    sequence of ``s_len`` runs with the default blocks: the standalone
+    kernels, or the ring's chunk kernels (``traced_offsets``)."""
+    offset = None if traced_offsets else 0
+    return tile_plan(
+        s_len, s_len, causal, q_offset=offset, k_offset=offset
+    ).describe()
+
+
+def _diagonal_crosses(q_start, k_start, k_len):
+    """Whether a rectangle whose first query sits at ``q_start`` and
+    whose keys span ``k_len`` from ``k_start`` holds a masked score: its
+    top-right one is."""
+    return q_start < k_start + k_len - 1
 
 
 def _cost(bh, sq, sk, d, n_matmuls, causal, byte_tensors):
@@ -117,6 +221,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc, o_acc,
         l_ref[0] = m_acc[:] + jnp.log(l_safe)  # logsumexp residual
 
 
+def _causal_mask(shape, q_start, k_start):
+    """Where the query at global position q_start + row may see the key
+    at k_start + column."""
+    qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return qpos >= kpos
+
+
+def _tile_scores(q, k_blk, q_start, k_start, causal, scale):
+    """Scaled scores of one tile, f32 (rows of q, rows of k_blk), and
+    the causal mask (None when not causal): masked scores are set to
+    ``_NEG_INF``. ``q_start/k_start``: global positions of the tile's
+    first query and key, ints or traced scalars."""
+    s = jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    if not causal:
+        return s, None
+    mask = _causal_mask(s.shape, q_start, k_start)
+    return jnp.where(mask, s, _NEG_INF), mask
+
+
+def _tile_probs(s, mask, m):
+    """exp(s - m) with masked entries exactly zero."""
+    p = jnp.exp(s - m)
+    return p if mask is None else jnp.where(mask, p, 0.0)
+
+
 def _scratch_tile_update(q_ref, k_ref, v_ref, m_acc, l_acc, o_acc,
                          q_start, k_start, *, block_k, causal, scale):
     """The online-softmax recurrence for one K/V tile against the VMEM
@@ -125,27 +258,13 @@ def _scratch_tile_update(q_ref, k_ref, v_ref, m_acc, l_acc, o_acc,
     block_q = q_ref.shape[1]
 
     def _compute():
-        q = q_ref[0]
-        k_blk = k_ref[0]
         v_blk = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                      # (block_q, block_k)
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            kpos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            mask = qpos >= kpos
-            s = jnp.where(mask, s, _NEG_INF)
+        s, mask = _tile_scores(
+            q_ref[0], k_ref[0], q_start, k_start, causal, scale
+        )                              # (block_q, block_k)
         m_prev = m_acc[:]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
+        p = _tile_probs(s, mask, m_new)
         alpha = jnp.exp(m_prev - m_new)
         m_acc[:] = m_new
         l_acc[:] = l_acc[:] * alpha + p.sum(axis=1, keepdims=True)
@@ -163,15 +282,101 @@ def _scratch_tile_update(q_ref, k_ref, v_ref, m_acc, l_acc, o_acc,
         _compute()
 
 
+def _fwd_strips_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, rows, sub,
+                       scale):
+    """The forward over one grid tile whose diagonal starts at its
+    corner (``_walk`` with offsets 0, 0), one (batch*head) a grid step:
+    q strip ``i`` against the keys up to its own end, and no further.
+    A strip sees all its keys at once, so its softmax is the plain one
+    on values: the recurrence of ``_scratch_tile_update`` from an empty
+    state (m = -1e30, l = o = 0) in one step, to the same bits, without
+    stepping scratch accumulators (which costs more than the masked
+    half of the tile saves: 2.320 ms a layer against 1.836, v5e)."""
+    for i, n_k in enumerate(rows):
+        strip, keys = pl.ds(i * sub, sub), pl.ds(0, n_k * sub)
+        v_blk = v_ref[0, keys, :]
+        s, mask = _tile_scores(
+            q_ref[0, strip, :], k_ref[0, keys, :], i * sub, 0, True, scale
+        )                              # (sub, n_k * sub)
+        m = s.max(axis=1, keepdims=True)
+        p = _tile_probs(s, mask, m)
+        l_safe = jnp.maximum(p.sum(axis=1, keepdims=True), 1e-30)
+        o = jax.lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        o_ref[0, strip, :] = (o / l_safe).astype(o_ref.dtype)
+        l_ref[0, strip, :] = m + jnp.log(l_safe)
+
+
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                    block_k: int, interpret: bool):
     """q,k,v: (BH, S, D) -> (o (BH,S,D), L (BH,S,1))."""
-    bh, s_len, d = q.shape
+    s_len = q.shape[1]
     if s_len % block_q or s_len % block_k:
         raise ValueError(
             f"flash_attention: seq len {s_len} must tile by blocks "
             f"({block_q}, {block_k}); gate callers with supports()"
         )
+    rows = _walk(causal, s_len, s_len, block_q, block_k, 0, 0)
+    return _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
+                         interpret)
+
+
+def _shared_trace(*static_argnums):
+    """``jax.jit`` inlined into the caller's program, for what it does
+    while TRACING: a model's layers call the kernels with one set of
+    shapes and static arguments, so they share one trace of the kernel
+    body where each used to trace it again. A kernel body is dozens of
+    ``jnp`` calls, and the worker traces the 24 forward kernels once
+    for shapes in its ``state_init`` (``eval_shape`` of the model's
+    init) and all 72 in ``first_program``: on the chip's host, with
+    JAX's compile log on and the reader's thread busy, that was 2.5 s
+    of a 5.5 s ``state_init`` with the whole-tile kernel and 8.4 of
+    11.3 s with the strips (PERF.md, PR 26). Everything a trace depends
+    on has to be an argument: the plan (``rows``) is computed by the
+    caller, never read from ``SUB_TILE`` inside. ``inline=True`` keeps
+    the lowered program what it was (no call boundary, the custom
+    calls named from the caller's scope)."""
+    return functools.partial(
+        jax.jit, static_argnums=static_argnums, inline=True
+    )
+
+
+@_shared_trace(3, 4, 5, 6, 7, 8)
+def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
+                  interpret):
+    """The forward ``pallas_call``: the strip walk where ``rows`` (the
+    plan of ``_walk``) is given, else the grid of whole tiles."""
+    bh, s_len, d = q.shape
+    out_shape = [
+        jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
+        jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
+    ]
+    cost = _cost(
+        bh, s_len, s_len, d, n_matmuls=2, causal=causal,
+        byte_tensors=[(2, s_len, q.dtype.itemsize),
+                      (2, s_len, q.dtype.itemsize)],
+    )
+    if rows is not None:
+        whole = pl.BlockSpec((1, s_len, d), lambda b: (b, 0, 0))
+        return pl.pallas_call(
+            functools.partial(
+                _fwd_strips_kernel, rows=rows, sub=block_q // len(rows),
+                scale=scale,
+            ),
+            grid=(bh,),
+            in_specs=[whole, whole, whole],
+            out_specs=[
+                whole, pl.BlockSpec((1, s_len, 1), lambda b: (b, 0, 0)),
+            ],
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+            ),
+            cost_estimate=cost,
+            interpret=interpret,
+        )(q, k, v)
     grid = (bh, s_len // block_q, s_len // block_k)
     kernel = functools.partial(
         _fwd_kernel, block_k=block_k, causal=causal, scale=scale
@@ -190,10 +395,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             # block's last-two dims TPU-tileable (block_q % 8, 1 == dim).
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
@@ -202,11 +404,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        cost_estimate=_cost(
-            bh, s_len, s_len, d, n_matmuls=2, causal=causal,
-            byte_tensors=[(2, s_len, q.dtype.itemsize),
-                          (2, s_len, q.dtype.itemsize)],
-        ),
+        cost_estimate=cost,
         interpret=interpret,
     )(q, k, v)
 
@@ -302,12 +500,7 @@ def flash_chunk_update(
         scale = q.shape[-1] ** -0.5
     bh, sq, d = q.shape
     sk = k_chunk.shape[1]
-    block_q = min(block_q, sq) if block_q else (
-        _auto_block(sq, DEFAULT_BLOCK_Q) or min(DEFAULT_BLOCK_Q, sq)
-    )
-    block_k = min(block_k, sk) if block_k else (
-        _auto_block(sk, DEFAULT_BLOCK_K) or min(DEFAULT_BLOCK_K, sk)
-    )
+    block_q, block_k = _blocks(sq, sk, block_q, block_k)
     if sq % block_q or sk % block_k:
         raise ValueError(
             f"flash_chunk_update: shapes (Sq={sq}, Sk={sk}) must tile "
@@ -362,22 +555,16 @@ def flash_chunk_update(
 
 
 def _bwd_tile_math(q, k_blk, v_blk, do, lse, delta, q_start, k_start,
-                   block_q, block_k, causal, scale):
+                   causal, scale):
     """Shared backward tile: P = exp(S−lse); dS = P∘(dO·Vᵀ−Δ).
-    Returns (ds, p) as f32 (block_q, block_k)."""
+    Returns (ds, p) as f32 (rows of q, rows of k_blk)."""
     s = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
     p = jnp.exp(s - lse)
     if causal:
-        qpos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        kpos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        p = jnp.where(qpos >= kpos, p, 0.0)
+        p = jnp.where(_causal_mask(p.shape, q_start, k_start), p, 0.0)
     dp = jax.lax.dot_general(
         do, v_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -405,8 +592,7 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _compute():
         ds, _ = _bwd_tile_math(
             q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-            delta_ref[0], q_start, k_start, block_q, block_k, causal,
-            scale,
+            delta_ref[0], q_start, k_start, causal, scale,
         )
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
@@ -443,8 +629,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     def _compute():
         ds, p = _bwd_tile_math(
             q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-            delta_ref[0], q_start, k_start, block_q, block_k, causal,
-            scale,
+            delta_ref[0], q_start, k_start, causal, scale,
         )
         dk_acc[:] += jax.lax.dot_general(
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
@@ -466,39 +651,125 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_acc[:]
 
 
-def flash_chunk_grads(
-    q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
-    causal: bool = True, scale: Optional[float] = None,
-    block_q: int = 0, block_k: int = 0,
-    interpret: bool = False,
-):
-    """Backward of one attention chunk pairing, fully tiled.
+def _dq_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, *, rows, sub, q_offset, k_offset, scale):
+    """dq over one grid tile walked by ``_walk``: q strip ``i`` against
+    the keys its row of the plan reaches; a strip wholly above the
+    diagonal gets zeros. Each strip is written once, so no scratch."""
+    for i, n_k in enumerate(rows):
+        strip, keys = pl.ds(i * sub, sub), pl.ds(0, n_k * sub)
+        if not n_k:
+            dq_ref[0, strip, :] = jnp.zeros((sub, dq_ref.shape[2]),
+                                            dq_ref.dtype)
+            continue
+        k_blk = k_ref[0, keys, :]
+        q_start = q_offset + i * sub
+        ds, _ = _bwd_tile_math(
+            q_ref[0, strip, :], k_blk, v_ref[0, keys, :],
+            do_ref[0, strip, :], lse_ref[0, strip, :],
+            delta_ref[0, strip, :], q_start, k_offset,
+            _diagonal_crosses(q_start, k_offset, n_k * sub), scale,
+        )
+        dq_ref[0, strip, :] = jax.lax.dot_general(
+            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
 
-    q/do: (BH, Sq, D); k_chunk/v_chunk: (BH, Sk, D); lse/delta:
-    (BH, Sq, 1) f32. Returns (dq_partial, dk_chunk, dv_chunk) — f32,
-    the ring accumulates dq over chunks and rotates dk/dv home. Two
-    kernels (dq: k-sequential; dk/dv: q-sequential) so each output has
-    exactly one sequential accumulation dim; score tiles never leave
-    VMEM.
-    """
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
+
+def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dk_ref, dv_ref, *, rows, sub, q_offset, k_offset,
+                       scale):
+    """dk/dv over one grid tile walked by ``_walk``: k strip ``j``
+    against the queries from the first row whose plan reaches it to the
+    tile's end; a strip no query sees gets zeros."""
+    block_q = q_ref.shape[1]
+    for j in range(k_ref.shape[1] // sub):
+        strip = pl.ds(j * sub, sub)
+        first = next((i for i, n_k in enumerate(rows) if n_k > j), None)
+        if first is None:
+            zeros = jnp.zeros((sub, dk_ref.shape[2]), dk_ref.dtype)
+            dk_ref[0, strip, :] = zeros
+            dv_ref[0, strip, :] = zeros
+            continue
+        queries = pl.ds(first * sub, block_q - first * sub)
+        q, do = q_ref[0, queries, :], do_ref[0, queries, :]
+        q_start, k_start = q_offset + first * sub, k_offset + j * sub
+        ds, p = _bwd_tile_math(
+            q, k_ref[0, strip, :], v_ref[0, strip, :], do,
+            lse_ref[0, queries, :], delta_ref[0, queries, :], q_start,
+            k_start, _diagonal_crosses(q_start, k_start, sub), scale,
+        )
+        dk_ref[0, strip, :] = jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        dv_ref[0, strip, :] = jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+
+def _grads_costs(q, sk, causal):
+    """(dq kernel's, dk/dv kernel's) ``pl.CostEstimate``."""
+    bh, sq, d = q.shape
+    reads = [(2, sq, q.dtype.itemsize), (2, sk, q.dtype.itemsize)]
+    return tuple(
+        _cost(bh, sq, sk, d, n_matmuls=2, causal=causal,
+              byte_tensors=reads + [written])
+        for written in ((1, sq, 4), (2, sk, 4))
+    )
+
+
+@_shared_trace(6, 7, 8, 9, 10)
+def _strips_grads(q, k_chunk, v_chunk, do, lse, delta, rows, q_offset,
+                  k_offset, scale, interpret):
+    """``flash_chunk_grads`` where ``_walk`` has a plan: both kernels
+    take one (batch*head) a grid step and the whole tile as one block."""
     bh, sq, d = q.shape
     sk = k_chunk.shape[1]
-    block_q = min(block_q, sq) if block_q else (
-        _auto_block(sq, DEFAULT_BLOCK_Q) or min(DEFAULT_BLOCK_Q, sq)
+    dq_cost, dkv_cost = _grads_costs(q, sk, True)
+    q_rows = pl.BlockSpec((1, sq, d), lambda b: (b, 0, 0))
+    k_rows = pl.BlockSpec((1, sk, d), lambda b: (b, 0, 0))
+    q_col = pl.BlockSpec((1, sq, 1), lambda b: (b, 0, 0))
+    in_specs = [q_rows, k_rows, k_rows, q_rows, q_col, q_col]
+    common = dict(
+        rows=rows, sub=sq // len(rows), q_offset=q_offset,
+        k_offset=k_offset, scale=scale,
     )
-    block_k = min(block_k, sk) if block_k else (
-        _auto_block(sk, DEFAULT_BLOCK_K) or min(DEFAULT_BLOCK_K, sk)
-    )
-    if sq % block_q or sk % block_k:
-        raise ValueError(
-            f"flash_chunk_grads: shapes (Sq={sq}, Sk={sk}) must tile by "
-            f"blocks ({block_q}, {block_k})"
-        )
+    params = pltpu.CompilerParams(dimension_semantics=("parallel",))
+    operands = (q, k_chunk, v_chunk, do, lse, delta)
+    dq = pl.pallas_call(
+        functools.partial(_dq_strips_kernel, **common),
+        grid=(bh,), in_specs=in_specs, out_specs=q_rows,
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
+        compiler_params=params, cost_estimate=dq_cost,
+        interpret=interpret,
+    )(*operands)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_strips_kernel, **common),
+        grid=(bh,), in_specs=in_specs, out_specs=[k_rows, k_rows],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
+        ],
+        compiler_params=params, cost_estimate=dkv_cost,
+        interpret=interpret,
+    )(*operands)
+    return dq, dk, dv
+
+
+@_shared_trace(8, 9, 10, 11, 12)
+def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
+                 causal, scale, block_q, block_k, interpret):
+    """``flash_chunk_grads`` over the grid of whole tiles: the offsets
+    are traced (scalar-prefetched), a tile strictly above the diagonal
+    is predicated out."""
+    bh, sq, d = q.shape
+    sk = k_chunk.shape[1]
+    dq_cost, dkv_cost = _grads_costs(q, sk, causal)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape((1,))
     koff = jnp.asarray(k_offset, jnp.int32).reshape((1,))
-    common = dict(causal=causal, scale=float(scale))
+    common = dict(causal=causal, scale=scale)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, **common),
@@ -527,11 +798,7 @@ def flash_chunk_grads(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        cost_estimate=_cost(
-            bh, sq, sk, d, n_matmuls=2, causal=causal,
-            byte_tensors=[(2, sq, q.dtype.itemsize),
-                          (2, sk, q.dtype.itemsize), (1, sq, 4)],
-        ),
+        cost_estimate=dq_cost,
         interpret=interpret,
     )(qoff, koff, q, k_chunk, v_chunk, do, lse, delta)
 
@@ -572,14 +839,46 @@ def flash_chunk_grads(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        cost_estimate=_cost(
-            bh, sq, sk, d, n_matmuls=2, causal=causal,
-            byte_tensors=[(2, sq, q.dtype.itemsize),
-                          (2, sk, q.dtype.itemsize), (2, sk, 4)],
-        ),
+        cost_estimate=dkv_cost,
         interpret=interpret,
     )(qoff, koff, q, k_chunk, v_chunk, do, lse, delta)
     return dq, dk, dv
+
+
+def flash_chunk_grads(
+    q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
+    causal: bool = True, scale: Optional[float] = None,
+    block_q: int = 0, block_k: int = 0,
+    interpret: bool = False,
+):
+    """Backward of one attention chunk pairing, fully tiled.
+
+    q/do: (BH, Sq, D); k_chunk/v_chunk: (BH, Sk, D); lse/delta:
+    (BH, Sq, 1) f32. Returns (dq_partial, dk_chunk, dv_chunk) — f32,
+    the ring accumulates dq over chunks and rotates dk/dv home. Two
+    kernels (dq: k-sequential; dk/dv: q-sequential) so each output has
+    exactly one sequential accumulation dim; score tiles never leave
+    VMEM.
+    """
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    sq, sk = q.shape[1], k_chunk.shape[1]
+    block_q, block_k = _blocks(sq, sk, block_q, block_k)
+    if sq % block_q or sk % block_k:
+        raise ValueError(
+            f"flash_chunk_grads: shapes (Sq={sq}, Sk={sk}) must tile by "
+            f"blocks ({block_q}, {block_k})"
+        )
+    rows = _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset)
+    if rows is not None:
+        return _strips_grads(
+            q, k_chunk, v_chunk, do, lse, delta, rows, q_offset, k_offset,
+            float(scale), interpret,
+        )
+    return _tiles_grads(
+        q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset, causal,
+        float(scale), block_q, block_k, interpret,
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -587,7 +886,9 @@ def log_traced(implementation: str, why: str, q_shape: tuple):
     """Say which attention implementation a trace took and why, once
     per distinct choice in the process (every layer of every trace asks
     again). The dense reference standing in for the kernel is a
-    slowdown nothing else reports."""
+    slowdown nothing else reports; for the kernels, callers end ``why``
+    with ``describe_tiles``: how much of a grid tile the causal walk
+    multiplies is static, so this line is its counter."""
     logger.info(
         "attention: traced %s for q%s: %s", implementation, q_shape, why
     )
@@ -604,6 +905,19 @@ def _auto_block(s_len: int, requested: int) -> int:
         if s_len % cand == 0:
             return cand
     return 0
+
+
+def _blocks(sq: int, sk: int, block_q: int, block_k: int):
+    """The blocks a call runs with: an explicit one clamped to the
+    sequence, 0 = the largest lane-aligned default-or-smaller block
+    that tiles it (``_auto_block``)."""
+    block_q = min(block_q, sq) if block_q else (
+        _auto_block(sq, DEFAULT_BLOCK_Q) or min(DEFAULT_BLOCK_Q, sq)
+    )
+    block_k = min(block_k, sk) if block_k else (
+        _auto_block(sk, DEFAULT_BLOCK_K) or min(DEFAULT_BLOCK_K, sk)
+    )
+    return block_q, block_k
 
 
 def supports(q_shape, block_q: int = 0, block_k: int = 0) -> bool:
@@ -645,12 +959,7 @@ def flash_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, s_len, h, d = q.shape
-    block_q = min(block_q, s_len) if block_q else (
-        _auto_block(s_len, DEFAULT_BLOCK_Q) or min(DEFAULT_BLOCK_Q, s_len)
-    )
-    block_k = min(block_k, s_len) if block_k else (
-        _auto_block(s_len, DEFAULT_BLOCK_K) or min(DEFAULT_BLOCK_K, s_len)
-    )
+    block_q, block_k = _blocks(s_len, s_len, block_q, block_k)
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s_len, d)

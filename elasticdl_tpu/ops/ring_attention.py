@@ -411,6 +411,10 @@ def ring_attention(
         else f"dense reference (no usable {sp_axis} axis)"
     )
     mesh_str = "x".join(f"{a}{mesh.shape[a]}" for a in mesh.axis_names)
+    if use_pallas:
+        why += "; " + flash.describe_tiles(
+            s_loc, causal=causal, traced_offsets=ring
+        )
     flash.log_traced(f"{implementation} on {mesh_str}", why, q.shape)
     if not ring and not use_pallas:
         return dense_attention(q, k, v, causal=causal, scale=scale)

@@ -1,11 +1,15 @@
 """Flash attention (Pallas interpret mode) vs dense reference: values,
-gradients, causal block skipping, bf16."""
+gradients, causal block skipping and the strip walk inside one grid
+tile, bf16."""
+
+import logging
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from elasticdl_tpu.ops import flash_attention as flash
 from elasticdl_tpu.ops.flash_attention import flash_attention, supports
 from elasticdl_tpu.ops.ring_attention import dense_attention
 
@@ -128,3 +132,255 @@ def test_jit_and_under_vmapless_batch():
     want = dense_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# --- the strip walk inside one grid tile ---------------------------------
+
+
+@pytest.mark.parametrize(
+    "block,sub,computed,total",
+    [(1024, 256, 10, 16), (512, 256, 3, 4), (1024, 128, 36, 64),
+     (768, 256, 6, 9), (1024, 512, 3, 4)],
+)
+def test_plan_counts(block, sub, computed, total):
+    plan = flash.tile_plan(block, block, sub=sub)
+    assert (plan.computed, plan.total) == (computed, total)
+    assert (plan.sub_q, plan.sub_k) == (sub, sub)
+    assert plan.describe() == (
+        f"blocks {block}x{block}, sub-tiles {sub}x{sub}, "
+        f"{computed} of {total} computed"
+    )
+
+
+@pytest.mark.parametrize(
+    "why,kwargs",
+    [("non-causal", dict(sq=1024, sk=1024, causal=False)),
+     ("a block under two sub-tiles", dict(sq=256, sk=256)),
+     ("explicit small blocks", dict(sq=1024, sk=1024, block_q=256,
+                                    block_k=256)),
+     ("several grid tiles", dict(sq=2048, sk=2048)),
+     ("traced offsets", dict(sq=1024, sk=1024, q_offset=jnp.int32(0),
+                             k_offset=jnp.int32(0)))],
+)
+def test_plan_keeps_the_whole_tile(why, kwargs):
+    plan = flash.tile_plan(**kwargs)
+    assert plan.rows == (1,) and (plan.computed, plan.total) == (1, 1), why
+    assert (plan.sub_q, plan.sub_k) == (plan.block_q, plan.block_k)
+    assert plan.describe().endswith(
+        f"sub-tiles {plan.block_q}x{plan.block_k}, 1 of 1 computed")
+
+
+# (q_offset, k_offset) of a 64-long q block against a 64-long chunk,
+# walked in 16-wide sub-tiles.
+CHUNK_OFFSETS = {
+    "on_diagonal": (0, 0),
+    "below": (128, 0),      # every k visible: nothing is masked
+    "askew": (64, 48),      # the diagonal misses the sub-tiles' corners
+    "half_above": (0, 32),  # the upper strips see nothing
+    "above": (0, 64),       # nothing visible: nothing is computed
+}
+
+
+@pytest.mark.parametrize("where", sorted(CHUNK_OFFSETS))
+def test_plan_covers_what_the_mask_leaves(where):
+    """Sub-tile (i, j) is in the plan exactly when the mask leaves one
+    of its scores; rows are prefixes, so a strip is one rectangle."""
+    q_off, k_off = CHUNK_OFFSETS[where]
+    n, sub = 64, 16
+    rows = flash._walk(True, n, n, n, n, q_off, k_off, sub)
+    visible = (q_off + np.arange(n)[:, None]) >= (k_off + np.arange(n))
+    want = visible.reshape(n // sub, sub, n // sub, sub).any(axis=(1, 3))
+    got = np.array([[j < n_k for j in range(n // sub)] for n_k in rows])
+    np.testing.assert_array_equal(got, want)
+
+
+def _chunk_operands(bh=3, n=64, d=16, seed=11):
+    rng = np.random.RandomState(seed)
+    mk = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32) * 0.3
+    q, k, v, do = (mk(bh, n, d) for _ in range(4))
+    return q, k, v, do, mk(bh, n, 1) + 1.0, mk(bh, n, 1)
+
+
+@pytest.mark.parametrize("where", sorted(CHUNK_OFFSETS))
+def test_chunk_grads_strips_are_the_whole_tile(monkeypatch, where):
+    """Python-int offsets walk the tile in strips that stop at the
+    diagonal; traced offsets compute the whole tile and mask it. One
+    answer; strips wholly above the diagonal write zeros."""
+    monkeypatch.setattr(flash, "SUB_TILE", 16)
+    ops = _chunk_operands()
+    q_off, k_off = CHUNK_OFFSETS[where]
+    assert flash.tile_plan(64, 64, q_offset=q_off, k_offset=k_off).total == 16
+    strips = flash.flash_chunk_grads(
+        *ops, q_off, k_off, causal=True, interpret=True)
+    whole = flash.flash_chunk_grads(
+        *ops, jnp.int32(q_off), jnp.int32(k_off), causal=True,
+        interpret=True)
+    for got, want, name in zip(strips, whole, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6,
+            err_msg=name)
+    dq, dk, dv = (np.asarray(g) for g in strips)
+    if where == "above":
+        assert not dq.any() and not dk.any() and not dv.any()
+    if where == "half_above":
+        # q rows 0..31 see no key of the chunk; keys 32..63 no query
+        # but the last 32.
+        assert not dq[:, :32].any() and dq[:, 32:].any()
+        assert dk[:, :32].any() and dv[:, :32].any()
+
+
+def _value_and_grads(q, k, v):
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=True)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return (out, *grads)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [512, 1024])
+def test_strips_match_dense_and_whole_tile_at_real_blocks(monkeypatch, s, d):
+    """The default blocks at S = 512 and 1,024, heads of 64 and 128:
+    forward, dq, dk, dv against the dense reference, and against the
+    whole-tile kernels (the walk switched off by a sub-tile as large as
+    the block). o and dq bit for bit. dk and dv contract over the
+    queries, fewer of them in a strip than in the tile, and the CPU
+    backend's dot blocks that sum by its length: the last bits move at
+    D = 64 here (on the chip they do not: tests/test_tpu_kernels.py
+    holds all four to the bit)."""
+    rng = np.random.RandomState(s + d)
+    q, k, v = (jnp.asarray(rng.randn(1, s, 2, d), jnp.float32) * 0.3
+               for _ in range(3))
+    assert flash.tile_plan(s, s).computed < flash.tile_plan(s, s).total
+    strips = _value_and_grads(q, k, v)
+    monkeypatch.setattr(flash, "SUB_TILE", s)
+    assert flash.tile_plan(s, s).total == 1
+    whole = _value_and_grads(q, k, v)
+
+    def loss_dense(q, k, v):
+        out = dense_attention(q, k, v, causal=True)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    grads, out = jax.grad(loss_dense, argnums=(0, 1, 2),
+                          has_aux=True)(q, k, v)
+    for got, same, want, name in zip(strips, whole, (out, *grads),
+                                     ("o", "dq", "dk", "dv")):
+        got, same, want = (np.asarray(x) for x in (got, same, want))
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5,
+                                   err_msg=f"{name} against dense")
+        if name in ("o", "dq"):
+            np.testing.assert_array_equal(got, same, err_msg=name)
+        else:
+            np.testing.assert_allclose(
+                got, same, rtol=0, atol=1e-6 * np.abs(same).max(),
+                err_msg=f"{name} against the whole tile")
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize(
+    "s,block,edge",
+    [(64, 64, 16), (64, 64, 32), (96, 96, 32), (96, 96, 16),
+     (64, 32, 16)],   # the last: two grid tiles each way, traced diagonal
+)
+def test_sub_tiled_block_matches_dense(monkeypatch, causal, s, block, edge):
+    """One block holds several sub-tiles: values and dq/dk/dv are the
+    dense reference's, causal (the walk stops at the diagonal) and not
+    (the whole tile, whatever the edge)."""
+    monkeypatch.setattr(flash, "SUB_TILE", edge)
+    q, k, v = _qkv(seed=7, s=s)
+
+    def loss_flash(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, block_q=block,
+                              block_k=block, interpret=True)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    def loss_dense(q, k, v):
+        out = dense_attention(q, k, v, causal=causal)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    grad = lambda f: jax.grad(f, argnums=(0, 1, 2), has_aux=True)
+    gf, out = grad(loss_flash)(q, k, v)
+    gd, ref = grad(loss_dense)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_s2048_keeps_its_old_results():
+    """Several grid tiles: the diagonal is traced, the whole tile is
+    kept, and the answer is the dense reference's as before."""
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(1, 2048, 1, 64), jnp.float32) * 0.3
+               for _ in range(3))
+    assert flash.tile_plan(2048, 2048).total == 1
+    got = flash_attention(q, k, v, causal=True, interpret=True)
+    want = dense_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "q_shape,kwargs,tail",
+    [((8, 1024, 16, 64), {},
+      "blocks 1024x1024, sub-tiles 256x256, 10 of 16 computed"),
+     ((8, 512, 16, 64), {},
+      "blocks 512x512, sub-tiles 256x256, 3 of 4 computed"),
+     ((2, 2048, 16, 64), {},
+      "blocks 1024x1024, sub-tiles 1024x1024, 1 of 1 computed"),
+     ((8, 1024, 16, 64), {"causal": False},
+      "blocks 1024x1024, sub-tiles 1024x1024, 1 of 1 computed"),
+     ((8, 1024, 16, 64), {"traced_offsets": True},
+      "blocks 1024x1024, sub-tiles 1024x1024, 1 of 1 computed")],
+)
+def test_log_line_states_the_sub_tiles(q_shape, kwargs, tail):
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda record: records.append(record.getMessage())
+    flash.logger.addHandler(handler)
+    flash.log_traced.cache_clear()
+    try:
+        flash.log_traced(
+            "pallas flash kernel",
+            "why; " + flash.describe_tiles(q_shape[1], **kwargs), q_shape)
+    finally:
+        flash.logger.removeHandler(handler)
+        flash.log_traced.cache_clear()
+    assert records == [
+        f"attention: traced pallas flash kernel for q{q_shape}: why; {tail}"
+    ]
+
+
+def test_layers_share_one_trace_of_each_kernel(monkeypatch):
+    """A model's layers call the kernels with one set of shapes: the
+    kernel bodies are traced once for all of them, under ``eval_shape``
+    (the worker's state_init) and again under ``grad`` of a ``jit``
+    (its first program), not once a layer. A shape no other test uses,
+    so the process's trace cache is cold for it."""
+    counts = {}
+    for name in ("_fwd_strips_kernel", "_dq_strips_kernel",
+                 "_dkv_strips_kernel"):
+        def counting(*args, _real=getattr(flash, name), _name=name, **kw):
+            counts[_name] = counts.get(_name, 0) + 1
+            return _real(*args, **kw)
+
+        monkeypatch.setattr(flash, name, counting)
+    monkeypatch.setattr(flash, "SUB_TILE", 16)
+    rng = np.random.RandomState(9)
+    q, k, v = (jnp.asarray(rng.randn(1, 80, 3, 24), jnp.float32) * 0.3
+               for _ in range(3))
+
+    def layers(q, k, v):
+        x = q
+        for _ in range(3):
+            x = flash_attention(x, k, v, causal=True, block_q=80,
+                                block_k=80, interpret=True)
+        return jnp.sum(x * x)
+
+    jax.eval_shape(layers, q, k, v)
+    assert counts == {"_fwd_strips_kernel": 1}
+    jax.jit(jax.grad(layers, argnums=(0, 1, 2)))(q, k, v)
+    assert counts == {"_fwd_strips_kernel": 1, "_dq_strips_kernel": 1,
+                      "_dkv_strips_kernel": 1}

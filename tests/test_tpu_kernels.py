@@ -105,11 +105,18 @@ class TestFlashAttentionOnChip:
                 err_msg=f"d{name} mismatch on chip",
             )
 
-    def test_flagship_geometry_bf16_forward_and_backward(self, tpu):
-        """The shape the product runs (transformer_l: batch 16 x 8
-        heads = 128, S=1024, D=128, bf16, default 1024x1024 blocks)
-        against the float32 dense reference. Mosaic can refuse a tile
-        size that every smaller test shape passes."""
+    @pytest.mark.parametrize(
+        "b,h,d", [(16, 8, 128), (8, 16, 64)],
+        ids=["transformer_l_d128", "gpt2m_steady_d64"],
+    )
+    def test_flagship_geometry_bf16_forward_and_backward(self, tpu, b, h,
+                                                         d):
+        """The shapes the product runs (transformer_l: batch 16 x 8
+        heads = 128, S=1024, D=128; the benchmark's cell gpt2m_steady:
+        8 x 16 heads of 64; bf16, default 1024x1024 blocks walked in
+        256-wide strips to the diagonal) against the float32 dense
+        reference. Mosaic can refuse a tile size that every smaller
+        test shape passes."""
         import jax
         import jax.numpy as jnp
 
@@ -119,7 +126,7 @@ class TestFlashAttentionOnChip:
         )
         from elasticdl_tpu.ops.ring_attention import dense_attention
 
-        q, k, v = _qkv(b=16, s=1024, h=8, d=128, dtype="bfloat16")
+        q, k, v = _qkv(b=b, s=1024, h=h, d=d, dtype="bfloat16")
         assert supports(q.shape)
         f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
 
@@ -145,6 +152,79 @@ class TestFlashAttentionOnChip:
                 np.asarray(f32(g)), w, rtol=0,
                 atol=2e-2 * float(np.abs(w).max()),
                 err_msg=f"{name} mismatch at the flagship geometry",
+            )
+
+    @pytest.mark.parametrize(
+        "b,h,s,d", [(16, 8, 1024, 128), (8, 16, 1024, 64),
+                    (8, 16, 512, 64)],
+        ids=["transformer_l_d128", "gpt2m_steady_d64", "s512_d64"],
+    )
+    def test_strips_are_the_whole_tile_to_the_bit(self, tpu, monkeypatch,
+                                                  b, h, s, d):
+        """One grid tile walked in strips that stop at the diagonal
+        against the same tile computed whole and masked (the walk
+        switched off by a sub-tile as large as the block): forward, dq,
+        dk, dv, compiled under Mosaic's default scoped VMEM limit, are
+        the same bits, so a job's losses do not move with the walk."""
+        import jax
+        import jax.numpy as jnp
+
+        from elasticdl_tpu.ops import flash_attention as flash
+
+        q, k, v = _qkv(b=b, s=s, h=h, d=d, dtype="bfloat16")
+
+        def run():
+            def loss(q, k, v):
+                o = flash.flash_attention(q, k, v, causal=True)
+                return jnp.sum(o.astype(jnp.float32) ** 2), o
+
+            grads, out = jax.jit(
+                jax.grad(loss, argnums=(0, 1, 2), has_aux=True)
+            )(q, k, v)
+            return (out, *grads)
+
+        plan = flash.tile_plan(s, s)
+        assert plan.computed < plan.total
+        strips = run()
+        monkeypatch.setattr(flash, "SUB_TILE", s)
+        assert flash.tile_plan(s, s).total == 1
+        for got, want, name in zip(strips, run(), ("o", "dq", "dk", "dv")):
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(jnp.float32)),
+                np.asarray(want.astype(jnp.float32)),
+                err_msg=f"{name}: strips against the whole tile",
+            )
+
+    @pytest.mark.parametrize("q_offset,k_offset", [(0, 0), (1024, 0),
+                                                   (512, 768)])
+    def test_chunk_grads_strips_match_whole_tile(self, tpu, q_offset,
+                                                 k_offset):
+        """Offsets known at trace time walk the tile in strips; traced
+        offsets compute the whole tile and mask it. Compiled on the
+        chip the two are one answer: a chunk on the diagonal, wholly
+        below it, and crossed askew (its upper strips wholly above)."""
+        import jax
+        import jax.numpy as jnp
+
+        from elasticdl_tpu.ops.flash_attention import flash_chunk_grads
+
+        bh, s, d = 8, 1024, 64
+        rng = np.random.RandomState(5)
+        mk = lambda *shape, dtype="bfloat16": jnp.asarray(  # noqa: E731
+            rng.randn(*shape).astype(np.float32) * 0.3, dtype)
+        q, k, v, do = (mk(bh, s, d) for _ in range(4))
+        lse = mk(bh, s, 1, dtype="float32") + 3.0
+        delta = mk(bh, s, 1, dtype="float32")
+        strips = jax.jit(lambda *a: flash_chunk_grads(
+            *a, q_offset, k_offset, causal=True))
+        whole = jax.jit(lambda *a: flash_chunk_grads(
+            *a, jnp.int32(q_offset), jnp.int32(k_offset), causal=True))
+        for got, want, name in zip(strips(q, k, v, do, lse, delta),
+                                   whole(q, k, v, do, lse, delta),
+                                   ("dq", "dk", "dv")):
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want),
+                err_msg=f"{name}: strips against the whole tile",
             )
 
     def test_chunk_update_streams_to_full_answer(self, tpu):

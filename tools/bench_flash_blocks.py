@@ -1,48 +1,136 @@
-"""Flash-attention block sweep + kernel roofline at an exact shape.
+"""Flash-attention tile sweep at an exact shape, and what the kernels
+cost the host at start-up. Chip only.
 
-Round-4's block re-sweep ran at B8/H8/S1024/D64 while the d512 bench
-config moved to B16 — VERDICT r4 weak #5 asks for the sweep at the
-EXACT bench shape and a statement of whether the flash custom-calls
-(27.3% of the d512 step) are at the kernel's own roofline. The default
-shape is therefore DERIVED from ``bench_suite`` (the d512 flagship's
-batch + the zoo's ``WIDTHS`` head geometry — H4/D128 since the
-round-5 head flip), so the sweep cannot silently drift off the bench
-shape again. This tool measures, per (block_q, block_k):
+Usage:  python tools/bench_flash_blocks.py [B] [H] [S] [D] [--layers N]
 
-- device ms of the fwd+bwd flash program (jit of value_and_grad over
-  ``ops.flash_attention``, traced via benchlib.module_device_times —
-  the program IS the kernels plus trivial glue at these shapes), and
-- kernel-level model-FLOPs efficiency: the same conservative counting
-  the bench MFU uses (fwd QK+PV, bwd dP/dQ/dK/dV = 10*B*H*S^2*D
-  causal-discounted x0.5; in-kernel recomputes excluded) over bf16
-  peak — how much of the chip the attention kernels themselves hold.
+The default shape is DERIVED from ``bench_suite`` (the d512 flagship's
+batch + the zoo's ``WIDTHS`` head geometry), so the sweep cannot drift
+off the bench shape; the benchmark's cell is ``8 16 1024 64``,
+``transformer_l`` is ``16 8 1024 128``.
 
-Usage:  python tools/bench_flash_blocks.py [B] [H] [S] [D]
-Prints one JSON line per block config; smallest device-ms wins.
+One JSON line per row:
+
+- **sweep rows** — causal forward + backward (``jit`` of ``grad`` over
+  ``ops.flash_attention``) per tiling: the default block with the strip
+  walk off (``sub_tile`` = the block: the whole tile, what ran before
+  PR 26) and at each sub-tile edge, then smaller grid tiles. Each row
+  has the block, the sub-tile, how many sub-tiles of a grid tile are
+  computed (``tile_plan``), and device ms per kernel from the profiler's
+  ``XLA Ops`` lane. The kernels are the program's ``tpu_custom_call``
+  instructions, named from the compiled HLO and labelled forward, dq,
+  dk/dv in the order their names number them (the order the trace made
+  them). ``kernel_model_flops_frac_of_peak`` is the required work
+  (``ops/flash_attention._cost``'s convention: 2 matmuls a kernel,
+  causal half) over the three kernels' time, over the bf16 peak.
+- **start-up rows** (``"row": "startup"``) — ``--layers`` (24) causal
+  layers forward and backward in one ``jit``: seconds to trace, to
+  lower, and to load or compile (``cache_entries_written`` 0 = loaded
+  from the persistent cache), whole tile against the default walk.
+  The layers share one trace of each kernel body
+  (``flash_attention._shared_trace``), so ``trace_s`` should hardly
+  grow with ``--layers``; a kernel change that breaks the sharing, or
+  a body with many more ``jnp`` calls, shows here before it shows in
+  the worker's ``state_init`` and ``first_program`` start-up phases,
+  which ``setup_s`` holds to a bound (and where each traced ``jnp``
+  call costs several times what it costs in this quiet process:
+  PERF.md, PR 26).
 """
 
+import argparse
+import contextlib
 import json
 import os
+import re
 import sys
 import tempfile
+import time
 
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+SUB_TILES = (512, 256, 128)
+GRID_TILES = ((512, 512), (256, 256))
+RUNS = 8
 
-def main():
+
+@contextlib.contextmanager
+def sub_tile(flash, edge):
+    """Call the kernels with another sub-tile edge (``_walk`` reads the
+    module's constant when a call is made, i.e. while it is traced)."""
+    old, flash.SUB_TILE = flash.SUB_TILE, edge
+    try:
+        yield
+    finally:
+        flash.SUB_TILE = old
+
+
+def kernel_names(compiled):
+    """The compiled program's Pallas kernels, in the order their HLO
+    names number them."""
+    names = set(re.findall(
+        r"%?([\w.\-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call",
+        compiled.as_text(),
+    ))
+    def number(name):
+        suffix = name.rsplit(".", 1)[-1]
+        return int(suffix) if suffix.isdigit() else 0
+
+    return sorted(names, key=number)
+
+
+def device_ms(compiled, args, names):
+    """(ms per run of each named op, ms per run of the whole program)."""
+    import jax
+
+    from benchmark.lib import trace as trace_lib
+
+    jax.block_until_ready(compiled(*args))
+    with tempfile.TemporaryDirectory(prefix="flash_sweep_") as td:
+        jax.profiler.start_trace(td)
+        try:
+            for _ in range(RUNS):
+                out = compiled(*args)
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        path = trace_lib.find_trace(td)
+        if path is None:
+            raise RuntimeError("the profiler wrote no trace")
+        trace = trace_lib.Trace.load(path)
+    ops = {}
+    for _, dur, name in trace.lane("XLA Ops"):
+        if name in names:
+            ops[name] = ops.get(name, 0.0) + dur
+    if set(ops) != set(names):
+        raise RuntimeError(
+            f"kernels {sorted(names)} not all on the device's XLA Ops "
+            f"lane (found {sorted(ops)}); cannot report kernel time"
+        )
+    modules = [m[1] for m in trace.module_events()]
+    return (
+        [1e3 * ops[n] / RUNS for n in names],
+        1e3 * float(np.median(modules)) if modules else None,
+    )
+
+
+def main(argv=None):
     import jax
     import jax.numpy as jnp
 
-    from benchlib import (
-        enable_compile_cache,
-        module_device_times,
-        peak_flops,
-    )
-    from elasticdl_tpu.ops.flash_attention import flash_attention
+    from benchmark.lib import peaks
+    from elasticdl_tpu.common.jax_env import enable_compile_cache
+    from elasticdl_tpu.ops import flash_attention as flash
 
-    enable_compile_cache()
+    parser = argparse.ArgumentParser()
+    parser.add_argument("shape", nargs="*", type=int)
+    parser.add_argument("--layers", type=int, default=24)
+    args = parser.parse_args(argv)
+    cache_dir = enable_compile_cache()
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        raise SystemExit(
+            f"device times come from a chip; this is {device.platform}")
     import bench_suite
 
     sizes = bench_suite.lm_zoo().WIDTHS["transformer"]
@@ -52,61 +140,82 @@ def main():
         bench_suite.lm_zoo().SEQ_LEN,
         sizes["d_model"] // sizes["n_heads"],        # head dim (128)
     ]
-    args = [int(a) for a in sys.argv[1:]]
-    b, h, s, d = (args + default_shape[len(args):])[:4]
+    b, h, s, d = (args.shape + default_shape[len(args.shape):])[:4]
+    shape_name = f"B{b}/H{h}/S{s}/D{d}"
 
     rng = np.random.RandomState(0)
-    shape = (b, s, h, d)
-    q = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
-    k = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
-    v = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+    q, k, v = (jnp.asarray(rng.randn(b, s, h, d), jnp.bfloat16)
+               for _ in range(3))
+    # 2*BHSSD a matmul, 6 required matmuls (fwd QK, PV; bwd dP, dQ, dK,
+    # dV), causal half: the three kernels' ``_cost`` together.
+    model_flops = 12 * b * h * s * s * d * 0.5
+    peak = peaks.peak(device.device_kind, "bf16_flops_per_s")
 
-    # Conservative model-FLOP count, matching ops/flash_attention._cost
-    # and the bench MFU numerator: 2*BHSSD per matmul, 5 matmuls
-    # (fwd QK,PV; bwd dP,dQ,dK/dV share), causal x0.5.
-    model_flops = 10 * b * h * s * s * d * 0.5
-    peak = peak_flops(jax.devices()[0])
-
-    def step_fn(block_q, block_k):
+    def grads(block_q, block_k, layers=1):
         def loss(q, k, v):
-            o = flash_attention(
-                q, k, v, causal=True, block_q=block_q, block_k=block_k
-            )
-            return jnp.sum(o.astype(jnp.float32))
+            x = q
+            for _ in range(layers):
+                x = flash.flash_attention(
+                    x, k, v, causal=True, block_q=block_q, block_k=block_k
+                )
+            return jnp.sum(x.astype(jnp.float32))
 
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
-    results = []
-    for bq, bk in ((1024, 1024), (512, 1024), (1024, 512), (512, 512),
-                   (256, 256)):
-        if s % bq or s % bk:
-            continue
-        f = step_fn(bq, bk)
-        out = f(q, k, v)
-        jax.block_until_ready(out)
-        with tempfile.TemporaryDirectory(prefix="flash_sweep_") as td:
-            jax.profiler.start_trace(td)
-            try:
-                for _ in range(8):
-                    out = f(q, k, v)
-                jax.block_until_ready(out)
-            finally:
-                jax.profiler.stop_trace()
-            times = module_device_times(td, name_filter="loss")
-        ms = float(np.median(times)) if times else 0.0
-        eff = model_flops / (ms / 1e3) / peak if ms and peak else 0.0
-        rec = {
-            "block_q": bq, "block_k": bk,
-            "shape": f"B{b}/H{h}/S{s}/D{d}",
-            "device_ms": round(ms, 4),
-            "kernel_model_flops_frac_of_peak": round(eff, 4),
-        }
-        results.append(rec)
-        print(json.dumps(rec), flush=True)
-    if results:
-        best = min((r for r in results if r["device_ms"]),
-                   key=lambda r: r["device_ms"], default=None)
-        print(json.dumps({"best": best}))
+    block = flash.tile_plan(s, s).block_q
+    tilings = [(0, 0, block)] + [
+        (0, 0, edge) for edge in SUB_TILES
+        if block == s and block % edge == 0 and block >= 2 * edge
+    ] + [(bq, bk, block) for bq, bk in GRID_TILES if s > bq and s % bq == 0]
+    for block_q, block_k, edge in tilings:
+        with sub_tile(flash, edge):
+            plan = flash.tile_plan(s, s, True, block_q, block_k)
+            compiled = grads(block_q, block_k).lower(q, k, v).compile()
+        names = kernel_names(compiled)
+        if len(names) != 3:
+            raise RuntimeError(f"expected three kernels, found {names}")
+        per_kernel, program = device_ms(compiled, (q, k, v), names)
+        total = sum(per_kernel)
+        print(json.dumps({
+            "row": "sweep", "shape": shape_name,
+            "block": [plan.block_q, plan.block_k],
+            "sub_tile": [plan.sub_q, plan.sub_k],
+            "computed": plan.computed, "total": plan.total,
+            "device_ms": {
+                "fwd": round(per_kernel[0], 4), "dq": round(per_kernel[1], 4),
+                "dkv": round(per_kernel[2], 4), "kernels": round(total, 4),
+                "program": program and round(program, 4),
+            },
+            "kernel_model_flops_frac_of_peak": round(
+                model_flops / (total / 1e3) / peak, 4),
+        }), flush=True)
+
+    def entries():
+        return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+    seen = set()
+    for edge in (block, flash.SUB_TILE):
+        with sub_tile(flash, edge):
+            plan = flash.tile_plan(s, s)
+            if plan in seen:    # several grid tiles: one tiling only
+                continue
+            seen.add(plan)
+            before = entries()
+            t0 = time.perf_counter()
+            traced = grads(0, 0, args.layers).trace(q, k, v)
+            t1 = time.perf_counter()
+            lowered = traced.lower()
+            t2 = time.perf_counter()
+            lowered.compile()
+            t3 = time.perf_counter()
+        print(json.dumps({
+            "row": "startup", "shape": shape_name, "layers": args.layers,
+            "sub_tile": [plan.sub_q, plan.sub_k],
+            "computed": plan.computed, "total": plan.total,
+            "trace_s": round(t1 - t0, 3), "lower_s": round(t2 - t1, 3),
+            "load_or_compile_s": round(t3 - t2, 3),
+            "cache_entries_written": entries() - before,
+        }), flush=True)
 
 
 if __name__ == "__main__":
