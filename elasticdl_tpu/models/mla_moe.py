@@ -1,0 +1,550 @@
+"""Decoder-only LM of the DeepSeek-V3 block family: multi-head latent
+attention, a leading dense layer followed by sparse-expert layers with a
+shared expert, and a depth-1 multi-token-prediction module.
+
+A sibling of ``models/transformer.py`` (whose GPT-2 program stays what it
+is): it shares ``_Constrain``, ``_LMHead``, the attention kernels and
+the losses, and nothing else. Pre-RMSNorm blocks, no biases:
+
+- **MLA**: queries through a low-rank bottleneck (``q_lora_rank``), keys
+  and values through one shared latent (``kv_lora_rank``) plus one
+  rotary key part shared by all heads. RoPE turns the pairs (2i, 2i+1)
+  of the rotary part. q and k have head size ``qk_nope + qk_rope``, v
+  has ``v_head_dim``: the flash kernels take both as their arguments'
+  shapes say.
+- **Expert layer** (:class:`ExpertLayer`): the router scores ALL
+  ``router_width`` experts (sigmoid, float32), picks the top k of score +
+  selection bias, and weighs the chosen by their normalised scores. The
+  layer is told which experts it holds (``first_held``, ``n_held``) and
+  computes its own experts' part of the result, drop-free: token-choices
+  sorted by expert, grouped matrix products over the held groups, the
+  weighted rows gathered back. What absent experts would add is left
+  out. Under a mesh with an ``ep`` axis each member holds
+  ``n_held / ep`` of them and the parts are summed over ``ep``. The
+  selection bias only selects; what the backward pass returns for it is
+  its load's direction (:func:`_load_tap`), for an optimizer that moves
+  it by plain descent (auxiliary-loss-free balancing; the zoo's does).
+  ``routing=`` holds the layers to choices given from outside (routing
+  replay: another precision's, another engine's).
+- **MTP**: h'_i = W_eh [RMSNorm(h_i); RMSNorm(Emb(t_{i+1}))], one more
+  expert block, a last RMSNorm, and the main model's embedding and head.
+
+In training the model returns ``{"logits", "mtp_logits", "metrics"}``
+(or the fused-head triples under those keys): ``metrics`` are int32
+counters that leave the step beside the loss (``core/step.py``). In
+evaluation it returns the main logits alone.
+"""
+
+import functools
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
+
+from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.models.transformer import _Constrain, _LMHead
+from elasticdl_tpu.ops.flash_attention import (
+    describe_tiles as describe_attention_tiles,
+    flash_attention,
+    log_traced as log_traced_attention,
+    supports as flash_supports,
+)
+from elasticdl_tpu.ops.grouped_matmul import GROUPED_PRODUCT, grouped_matmul
+from elasticdl_tpu.ops.ring_attention import dense_attention
+
+logger = get_logger("experts")
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@dataclass(frozen=True)
+class MlaMoeConfig:
+    vocab_size: int = 256
+    hidden_size: int = 64
+    num_layers: int = 3             # leading dense layers included
+    first_k_dense: int = 1
+    intermediate_size: int = 128    # the dense layers' MLP width
+    moe_intermediate_size: int = 32  # every expert's width
+    num_heads: int = 4
+    q_lora_rank: int = 48
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-6
+    # The router always scores ``router_width`` experts; this member
+    # holds ``n_held`` of them, from ``first_held`` on.
+    router_width: int = 8
+    first_held: int = 0
+    n_held: int = 8
+    top_k: int = 2
+    routed_scaling_factor: float = 1.0
+    mtp_layers: int = 1             # 0 or 1
+    remat: bool = False
+    compute_dtype: jnp.dtype = jnp.bfloat16
+    fused_head: bool = False
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+def mla_moe_sharding_rules() -> Tuple[Tuple[str, P], ...]:
+    """Regex path -> PartitionSpec (``parallel/rules.py``): the stacked
+    expert weights on ``ep``, heads and MLP widths on ``tp``."""
+    return (
+        (r"moe/w_(gate|up|down)", P("ep", None, None)),
+        (r"attn/(q_b|kv_b)/kernel", P(None, "tp", None)),
+        (r"attn/out/kernel", P("tp", None, None)),
+        (r"(mlp|shared)/(gate|up)/kernel", P(None, "tp")),
+        (r"(mlp|shared)/down/kernel", P("tp", None)),
+        (r"token_embed/embedding", P("tp", None)),
+        (r"lm_head/kernel", P(None, "tp")),
+        (r"lm_head/bias", P("tp")),
+    )
+
+
+class RMSNorm(nn.Module):
+    """x / sqrt(mean(x^2) + eps) * g, the statistics in float32."""
+    eps: float
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.ones_init(), (x.shape[-1],),
+            jnp.float32,
+        )
+        x32 = x.astype(jnp.float32)
+        inv = jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps
+        )
+        return (x32 * inv * scale).astype(self.dtype)
+
+
+def rope_interleaved(x, theta: float, offset=0):
+    """Rotary embedding over the last axis of ``x`` (B, S, ..., R): the
+    pair (2i, 2i+1) is turned by position * theta^(-2i/R). float32
+    inside, ``x``'s dtype out."""
+    r = x.shape[-1]
+    seq = x.shape[1]
+    inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angle = (offset + jnp.arange(seq, dtype=jnp.float32))[:, None] * inv_freq
+    shape = (1, seq) + (1,) * (x.ndim - 3) + (r // 2,)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    turned = jnp.stack(
+        [even * cos - odd * sin, even * sin + odd * cos], axis=-1
+    )
+    return turned.reshape(x.shape).astype(x.dtype)
+
+
+def _dense(features, dtype, name):
+    return nn.DenseGeneral(features, use_bias=False, dtype=dtype, name=name)
+
+
+class LatentAttention(nn.Module):
+    cfg: MlaMoeConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        wsc = _Constrain(self.mesh)
+        h, nope, rope, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
+                             cfg.qk_rope_head_dim, cfg.v_head_dim)
+        b, s, _ = x.shape
+        c_q = RMSNorm(cfg.rms_eps, dt, name="q_norm")(
+            _dense(cfg.q_lora_rank, dt, "q_a")(x)
+        )
+        q = wsc(_dense((h, nope + rope), dt, "q_b")(c_q),
+                "dp", None, "tp", None)
+        kv = _dense(cfg.kv_lora_rank + rope, dt, "kv_a")(x)
+        c_kv = RMSNorm(cfg.rms_eps, dt, name="kv_norm")(
+            kv[..., :cfg.kv_lora_rank]
+        )
+        k_rope = rope_interleaved(
+            kv[..., cfg.kv_lora_rank:], cfg.rope_theta
+        )                                               # (B, S, rope)
+        k_v = wsc(_dense((h, nope + dv), dt, "kv_b")(c_kv),
+                  "dp", None, "tp", None)
+        q = jnp.concatenate(
+            [q[..., :nope], rope_interleaved(q[..., nope:], cfg.rope_theta)],
+            axis=-1,
+        )
+        k = jnp.concatenate(
+            [k_v[..., :nope],
+             jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, rope))],
+            axis=-1,
+        )
+        v = k_v[..., nope:]
+        scale = cfg.qk_head_dim ** -0.5
+        backend = jax.default_backend()
+        heads = f"head sizes q/k {nope + rope}, v {dv}"
+        if self.mesh is None and backend == "tpu" and flash_supports(q.shape):
+            log_traced_attention(
+                "pallas flash kernel",
+                f"tpu backend, shape tiles the kernel blocks; {heads}; "
+                + describe_attention_tiles(s), q.shape,
+            )
+            o = flash_attention(q, k, v, causal=True, scale=scale)
+        else:
+            log_traced_attention(
+                "dense reference",
+                (f"backend is {backend}" if backend != "tpu" else
+                 "a mesh, or a shape that does not tile the kernel blocks")
+                + f"; {heads}", q.shape,
+            )
+            o = dense_attention(q, k, v, causal=True, scale=scale)
+        o = nn.DenseGeneral(
+            cfg.hidden_size, axis=(-2, -1), use_bias=False, dtype=dt,
+            name="out",
+        )(o)
+        return wsc(o, "dp", None, None)
+
+
+class GatedMlp(nn.Module):
+    """W_down(silu(W_gate x) * (W_up x))."""
+    width: int
+    cfg: MlaMoeConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x):
+        dt = self.cfg.compute_dtype
+        wsc = _Constrain(self.mesh)
+        gate = _dense(self.width, dt, "gate")(x)
+        up = _dense(self.width, dt, "up")(x)
+        hidden = wsc(nn.silu(gate) * up, "dp", None, "tp")
+        return _dense(self.cfg.hidden_size, dt, "down")(hidden)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _spread(rows, order, inverse, live, k):
+    """rows (T, d) -> (T*k, d): token-choice ``order[j]`` (token
+    ``order[j] // k``) at sorted place j. Its transpose is
+    :func:`_gather_back`, so both directions are plain gathers.
+    ``live`` (T*k,) marks the sorted places that belong to a group: a
+    grouped product's cotangent is unspecified at the others (on a TPU:
+    whatever the memory held), and is cut off before it is summed into
+    the tokens'."""
+    return rows[order // k]
+
+
+def _spread_fwd(rows, order, inverse, live, k):
+    return _spread(rows, order, inverse, live, k), (order, inverse, live)
+
+
+def _spread_bwd(k, res, g):
+    order, inverse, live = res
+    g = jnp.where(live[:, None], g, 0).astype(g.dtype)
+    return _gather_back(g, order, inverse, live, k), None, None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gather_back(sorted_rows, order, inverse, live, k):
+    """sorted rows (T*k, d) -> (T, d): every token's k rows, summed."""
+    t = sorted_rows.shape[0] // k
+    picked = sorted_rows[inverse].reshape(t, k, sorted_rows.shape[1])
+    return picked.astype(jnp.float32).sum(axis=1).astype(sorted_rows.dtype)
+
+
+def _gather_back_fwd(sorted_rows, order, inverse, live, k):
+    return (_gather_back(sorted_rows, order, inverse, live, k),
+            (order, inverse, live))
+
+
+def _gather_back_bwd(k, res, g):
+    order, inverse, live = res
+    return _spread(g, order, inverse, live, k), None, None, None
+
+
+_spread.defvjp(_spread_fwd, _spread_bwd)
+_gather_back.defvjp(_gather_back_fwd, _gather_back_bwd)
+
+
+def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
+                      first_held):
+    """The held experts' part of an expert layer's result, drop-free.
+
+    rows (T, d); chosen (T, k) int32 expert ids over the router's whole
+    width; weights (T, k); w_gate/w_up (n, d, f), w_down (n, f, d): the
+    experts ``first_held .. first_held + n`` (``first_held`` may be
+    traced: a member's place on ``ep``). Token-choices are sorted by
+    expert with those of absent experts last; the grouped products run
+    over the n held groups; no capacity, so no choice of a held expert
+    is ever dropped. Returns (part (T, d), rows of every held expert
+    (n,) int32)."""
+    t, k = chosen.shape
+    n = w_gate.shape[0]
+    local = chosen - first_held
+    held = (local >= 0) & (local < n)
+    group = jnp.where(held, local, n).reshape(t * k)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32), unique_indices=True
+    )
+    sizes = jnp.sum(
+        group[:, None] == jnp.arange(n, dtype=group.dtype)[None, :],
+        axis=0, dtype=jnp.int32,
+    )
+    dt = rows.dtype
+    live = jnp.arange(t * k, dtype=jnp.int32) < jnp.sum(sizes)
+    sorted_rows = _spread(rows, order, inverse, live, k)
+    gate_up = grouped_matmul(
+        sorted_rows,
+        jnp.concatenate([w_gate.astype(dt), w_up.astype(dt)], axis=2),
+        sizes,
+    )
+    f = w_gate.shape[2]
+    hidden = nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+    out = grouped_matmul(hidden, w_down.astype(dt), sizes)
+    # Past the held rows a grouped product leaves what it likes, in its
+    # result and in its cotangent: those rows are cut off (selected
+    # away, here and in ``_spread``'s backward; a zero weight would
+    # carry a NaN on) before anything multiplies them.
+    row_weight = jnp.where(held, weights, 0.0).reshape(t * k)[order]
+    out = jnp.where(live[:, None], out, 0) * row_weight[:, None].astype(dt)
+    return _gather_back(out.astype(dt), order, inverse, live, k), sizes
+
+
+@functools.lru_cache(maxsize=None)
+def log_traced_experts(cfg: MlaMoeConfig, rows_bound: int, ep: int):
+    """One static line per traced expert layer shape (every layer of
+    every trace asks again), like the attention's: what is held, what
+    the router scores, the static bound of the grouped products' rows,
+    and which grouped product runs."""
+    logger.info(
+        "experts: traced drop-free layer holding experts [%d, %d) of "
+        "router width %d, top-%d, rows bound %d, grouped product %s%s",
+        cfg.first_held, cfg.first_held + cfg.n_held, cfg.router_width,
+        cfg.top_k, rows_bound, GROUPED_PRODUCT,
+        f", {cfg.n_held // ep} a member over ep={ep}" if ep > 1 else "",
+    )
+
+
+@jax.custom_vjp
+def _load_tap(bias, load):
+    """Zero, added to the layer's result. Its "gradient" for the
+    selection bias is not the loss's (the bias only selects, the loss
+    does not see it) but the load's: sign(an expert's token-choices -
+    the mean over the router's width), so that plain gradient descent at
+    the bias update speed is the auxiliary-loss-free balancing rule,
+    b += speed * sign(mean - load) (Wang et al. 2024; DeepSeek-V3,
+    section 2.1.2), in the same backward pass as everything else."""
+    return jnp.zeros((), jnp.float32)
+
+
+def _load_tap_fwd(bias, load):
+    return _load_tap(bias, load), load
+
+
+def _load_tap_bwd(load, _):
+    load = load.astype(jnp.float32)
+    return jnp.sign(load - jnp.mean(load)), None
+
+
+_load_tap.defvjp(_load_tap_fwd, _load_tap_bwd)
+
+
+class ExpertLayer(nn.Module):
+    """Shared expert + the held routed experts' weighted part.
+
+    ``routing`` (B, S, k) int32, where given, are the experts every
+    token goes to in place of the layer's own top k (routing replay:
+    the weights are still this layer's scores of them)."""
+    cfg: MlaMoeConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x, routing=None):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        b, s, d = x.shape
+        n, f, k = cfg.n_held, cfg.moe_intermediate_size, cfg.top_k
+        wsc = _Constrain(self.mesh)
+        router = self.param(
+            "router", nn.initializers.normal(0.02),
+            (d, cfg.router_width), jnp.float32,
+        )
+        # Selects only: it enters no product. What ``jax.grad`` returns
+        # for it is the load's direction (:func:`_load_tap`).
+        bias = self.param(
+            "router_bias", nn.initializers.zeros_init(),
+            (cfg.router_width,), jnp.float32,
+        )
+        init = nn.initializers.normal(0.02)
+        w_gate = self.param("w_gate", init, (n, d, f), jnp.float32)
+        w_up = self.param("w_up", init, (n, d, f), jnp.float32)
+        w_down = self.param("w_down", init, (n, f, d), jnp.float32)
+
+        rows = x.reshape(b * s, d)
+        scores = jax.nn.sigmoid(jnp.matmul(
+            rows.astype(jnp.float32), router, precision=HIGHEST
+        ))                                              # (T, width) f32
+        if routing is None:
+            _, chosen = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias), k
+            )
+        else:
+            chosen = routing.reshape(b * s, k).astype(jnp.int32)
+        self.sow("intermediates", "chosen", chosen.reshape(b, s, k))
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = picked / (
+            picked.sum(axis=-1, keepdims=True) + 1e-20
+        ) * cfg.routed_scaling_factor
+
+        ep = 1 if self.mesh is None else self.mesh.shape.get("ep", 1)
+        log_traced_experts(cfg, b * s * k, ep)
+        if ep > 1:
+            part, sizes = self._over_ep(
+                rows, chosen, weights, w_gate, w_up, w_down, ep
+            )
+        else:
+            part, sizes = held_experts_part(
+                rows, chosen, weights, w_gate, w_up, w_down, cfg.first_held
+            )
+        load = jnp.sum(
+            chosen[..., None] == jnp.arange(cfg.router_width), axis=(0, 1),
+            dtype=jnp.int32,
+        )
+        part = part + _load_tap(bias, load).astype(dt)
+        shared = GatedMlp(f, cfg, self.mesh, name="shared")(x)
+        counters = {
+            "moe_rows": jnp.sum(sizes), "moe_expert_rows_max": jnp.max(sizes)
+        }
+        out = wsc(shared + part.reshape(b, s, d), "dp", None, None)
+        return out, jax.lax.stop_gradient(counters)
+
+    def _over_ep(self, rows, chosen, weights, w_gate, w_up, w_down, ep):
+        """Each member of ``ep`` holds ``n_held / ep`` experts and is
+        told which; every member sees all rows (no exchange is written
+        here: the rows are replicated over ``ep``), and the parts add."""
+        cfg = self.cfg
+        if cfg.n_held % ep:
+            raise ValueError(
+                f"{cfg.n_held} held experts do not divide over ep={ep}"
+            )
+        per = cfg.n_held // ep
+
+        def member(rows, chosen, weights, w_gate, w_up, w_down):
+            first = cfg.first_held + jax.lax.axis_index("ep") * per
+            part, sizes = held_experts_part(
+                rows, chosen, weights, w_gate, w_up, w_down, first
+            )
+            return jax.lax.psum(part, "ep"), sizes
+
+        stacked = P("ep", None, None)
+        return jax.shard_map(
+            member, mesh=self.mesh,
+            in_specs=(P(), P(), P(), stacked, stacked, stacked),
+            out_specs=(P(), P("ep")), check_vma=False,
+        )(rows, chosen, weights, w_gate, w_up, w_down)
+
+
+class MlaBlock(nn.Module):
+    """x += Attn(RMSNorm(x)); x += FFN(RMSNorm(x)); FFN is the dense
+    gated MLP or the expert layer. Returns (x, the layer's counters)."""
+    cfg: MlaMoeConfig
+    mesh: Optional[Mesh] = None
+    use_experts: bool = True
+
+    @nn.compact
+    def __call__(self, x, routing=None):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        h = RMSNorm(cfg.rms_eps, dt, name="attn_norm")(x)
+        x = x + LatentAttention(cfg, self.mesh, name="attn")(h)
+        h = RMSNorm(cfg.rms_eps, dt, name="ffn_norm")(x)
+        if self.use_experts:
+            h, counters = ExpertLayer(cfg, self.mesh, name="moe")(h, routing)
+        else:
+            h = GatedMlp(
+                cfg.intermediate_size, cfg, self.mesh, name="mlp"
+            )(h)
+            counters = {}
+        return x + h, counters
+
+
+def _add_counters(total, counters):
+    return {
+        name: total.get(name, 0) + value for name, value in counters.items()
+    } if counters else total
+
+
+class MlaMoeLM(nn.Module):
+    """``features`` = int32 token ids (B, S); see the module docstring
+    for what training and evaluation return. ``routing``: one (B, S, k)
+    array of expert ids for every expert layer in order, the MTP
+    block's last, held in place of the layers' own choices."""
+
+    cfg: MlaMoeConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, features, training=False, routing=None):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        wsc = _Constrain(self.mesh)
+        tokens = features.astype(jnp.int32)
+        embed = nn.Embed(
+            cfg.vocab_size, cfg.hidden_size, dtype=dt, name="token_embed"
+        )
+        head = _LMHead(
+            cfg.vocab_size, dt, fused=(cfg.fused_head and training),
+            name="lm_head",
+        )
+        block_cls = nn.remat(MlaBlock) if cfg.remat else MlaBlock
+        x = wsc(embed(tokens), "dp", None, None)
+        counters = {}
+        held = iter(routing) if routing is not None else None
+        for i in range(cfg.num_layers):
+            experts = i >= cfg.first_k_dense
+            x, layer_counters = block_cls(
+                cfg, self.mesh, use_experts=experts, name=f"block_{i}",
+            )(x, next(held) if held and experts else None)
+            counters = _add_counters(counters, layer_counters)
+        logits = self._head(
+            head, RMSNorm(cfg.rms_eps, dt, name="final_norm")(x), wsc
+        )
+        # ``init`` runs the evaluation form and has to make the MTP
+        # module's parameters too.
+        if not (training or self.is_initializing()):
+            return logits
+        out = {"logits": logits}
+        if cfg.mtp_layers:
+            # t_{i+1} is the next input token; the last position has
+            # none, keeps its place (the kernels' shapes stay tiled)
+            # and is masked out of the loss.
+            joined = jnp.concatenate([
+                RMSNorm(cfg.rms_eps, dt, name="mtp_hnorm")(x),
+                RMSNorm(cfg.rms_eps, dt, name="mtp_enorm")(
+                    embed(jnp.roll(tokens, -1, axis=1))
+                ),
+            ], axis=-1)
+            h = _dense(cfg.hidden_size, dt, "mtp_eh_proj")(joined)
+            h, layer_counters = block_cls(
+                cfg, self.mesh, use_experts=True, name="mtp_block"
+            )(h, next(held) if held else None)
+            counters = _add_counters(counters, layer_counters)
+            out["mtp_logits"] = self._head(
+                head, RMSNorm(cfg.rms_eps, dt, name="mtp_final_norm")(h),
+                wsc,
+            )
+        out["metrics"] = counters
+        return out if training else logits
+
+    @staticmethod
+    def _head(head, x, wsc):
+        out = head(x)
+        if isinstance(out, tuple):
+            hidden, kernel, bias = out
+            return (wsc(hidden, "dp", None, None), wsc(kernel, None, "tp"),
+                    wsc(bias, "tp"))
+        return wsc(out.astype(jnp.float32), "dp", None, "tp")

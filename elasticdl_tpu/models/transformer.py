@@ -155,7 +155,8 @@ class SelfAttention(nn.Module):
             # how many) instead of the O(S^2) dense scores.
             log_traced_attention(
                 "pallas flash kernel",
-                "tpu backend, shape tiles the kernel blocks; "
+                "tpu backend, shape tiles the kernel blocks; head sizes "
+                f"q/k {q.shape[-1]}, v {v.shape[-1]}; "
                 + describe_attention_tiles(q.shape[1]), q.shape,
             )
             o = flash_attention(q, k, v, causal=True, scale=scale)

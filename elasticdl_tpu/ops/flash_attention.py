@@ -70,6 +70,26 @@ DEFAULT_BLOCK_K = 1024
 # 1.336; within two microseconds of that at D=128, so ``d`` does not
 # enter the choice (tools/bench_flash_blocks.py prints the rows).
 SUB_TILE = 256
+# A head wider than ``WIDE_HEAD`` (latent attention's q and k are 192)
+# takes the dk/dv kernel's operands, outputs and accumulators 0.5 MiB
+# past Mosaic's default 16 MiB of scoped VMEM at the default blocks (the
+# compiler's refusal, v5e): such calls raise the limit. v5e sweep, B*H =
+# 128, S = 4,096, q/k 192, v 128, bf16, forward + backward, ms a layer:
+# 1024 x 1024 under this limit 42.8; under the default limit 512 x 1024:
+# 45.4, 1024 x 512: 49.8, 512 x 512: 50.8
+# (tools/bench_mla_moe_parts.py). Calls with heads up to 128 pass no
+# limit, as before.
+WIDE_HEAD = 128
+WIDE_HEAD_VMEM_BYTES = 64 * 2 ** 20
+
+
+def _compiler_params(semantics, *head_sizes):
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=(
+            WIDE_HEAD_VMEM_BYTES if max(head_sizes) > WIDE_HEAD else None
+        ),
+    )
 
 
 class TilePlan(NamedTuple):
@@ -157,29 +177,31 @@ def _diagonal_crosses(q_start, k_start, k_len):
     return q_start < k_start + k_len - 1
 
 
-def _cost(bh, sq, sk, d, n_matmuls, causal, byte_tensors):
+def _cost(bh, sq, sk, d, dv, causal, byte_tensors):
     """pl.CostEstimate for one attention kernel, MODEL-FLOPs convention:
-    count the algorithmically required matmuls (fwd: QK+PV = 2; dq
-    kernel: dP+dQ = 2; dkv kernel: dK+dV = 2) and NOT the in-kernel
-    score recomputes (those are rematerialization — the same convention
-    under which benchlib.program_flops excludes jax.checkpoint
-    recompute). Causal discounts by 1/2 (the exact useful fraction is
+    count the two algorithmically required matmuls of each kernel, one
+    that contracts or produces the q/k head size ``d`` and one the v
+    head size ``dv`` (fwd: QK over d, PV over dv; dq kernel: dP over
+    dv, dQ over d; dkv kernel: dK over d, dV over dv), and NOT the
+    in-kernel score recomputes (those are rematerialization — the same
+    convention under which benchlib.program_flops excludes
+    jax.checkpoint recompute). Causal discounts by 1/2 (the exact useful fraction is
     (S+1)/2S; 1/2 is the conservative side, and ring chunks fully below
     the diagonal are also undercounted, never overcounted). XLA's cost
     analysis folds these into the program totals, so Pallas-kernel
     FLOPs stop reading as zero in the bench's MFU numerator
     (tools/measure_config.py, BASELINE.md round-4 note).
 
-    ``byte_tensors``: (count, seq_len, dtype_size) triples of
-    (BH, seq_len, D)-shaped operands/outputs for bytes_accessed."""
+    ``byte_tensors``: (count, seq_len, width, dtype_size) of
+    (BH, seq_len, width)-shaped operands/outputs for bytes_accessed."""
     frac = 0.5 if causal else 1.0
-    flops = int(2 * n_matmuls * bh * sq * sk * d * frac)
+    flops = int(2 * bh * sq * sk * (d + dv) * frac)
     # One exp per score element per kernel (fwd online-softmax; each
     # bwd kernel recomputes P once).
     transcendentals = int(bh * sq * sk * frac)
     nbytes = int(sum(
-        count * bh * s * d * size
-        for count, s, size in byte_tensors
+        count * bh * s * width * size
+        for count, s, width, size in byte_tensors
     ))
     return pl.CostEstimate(
         flops=flops, transcendentals=transcendentals,
@@ -311,7 +333,7 @@ def _fwd_strips_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, rows, sub,
 
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                    block_k: int, interpret: bool):
-    """q,k,v: (BH, S, D) -> (o (BH,S,D), L (BH,S,1))."""
+    """q,k: (BH, S, D), v: (BH, S, Dv) -> (o (BH,S,Dv), L (BH,S,1))."""
     s_len = q.shape[1]
     if s_len % block_q or s_len % block_k:
         raise ValueError(
@@ -349,31 +371,31 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
     """The forward ``pallas_call``: the strip walk where ``rows`` (the
     plan of ``_walk``) is given, else the grid of whole tiles."""
     bh, s_len, d = q.shape
+    dv = v.shape[2]
     out_shape = [
-        jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
+        jax.ShapeDtypeStruct((bh, s_len, dv), q.dtype),
         jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
     ]
     cost = _cost(
-        bh, s_len, s_len, d, n_matmuls=2, causal=causal,
-        byte_tensors=[(2, s_len, q.dtype.itemsize),
-                      (2, s_len, q.dtype.itemsize)],
+        bh, s_len, s_len, d, dv, causal=causal,
+        byte_tensors=[(2, s_len, d, q.dtype.itemsize),
+                      (2, s_len, dv, q.dtype.itemsize)],
     )
     if rows is not None:
         whole = pl.BlockSpec((1, s_len, d), lambda b: (b, 0, 0))
+        whole_v = pl.BlockSpec((1, s_len, dv), lambda b: (b, 0, 0))
         return pl.pallas_call(
             functools.partial(
                 _fwd_strips_kernel, rows=rows, sub=block_q // len(rows),
                 scale=scale,
             ),
             grid=(bh,),
-            in_specs=[whole, whole, whole],
+            in_specs=[whole, whole, whole_v],
             out_specs=[
-                whole, pl.BlockSpec((1, s_len, 1), lambda b: (b, 0, 0)),
+                whole_v, pl.BlockSpec((1, s_len, 1), lambda b: (b, 0, 0)),
             ],
             out_shape=out_shape,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",),
-            ),
+            compiler_params=_compiler_params(("parallel",), d, dv),
             cost_estimate=cost,
             interpret=interpret,
         )(q, k, v)
@@ -387,10 +409,10 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             # lse carried as (BH, S, 1): a trailing unit dim keeps the
             # block's last-two dims TPU-tileable (block_q % 8, 1 == dim).
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
@@ -399,10 +421,10 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((block_q, d), jnp.float32),   # running output
+            pltpu.VMEM((block_q, dv), jnp.float32),  # running output
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), d, dv
         ),
         cost_estimate=cost,
         interpret=interpret,
@@ -490,8 +512,8 @@ def flash_chunk_update(
 ):
     """Fold one K/V chunk into running flash accumulators.
 
-    q: (BH, Sq, D); k_chunk/v_chunk: (BH, Sk, D); m, l: (BH, Sq, 1) f32;
-    acc: (BH, Sq, D) f32; q_offset/k_offset: scalar global positions of
+    q: (BH, Sq, D); k_chunk: (BH, Sk, D); v_chunk: (BH, Sk, Dv); m, l:
+    (BH, Sq, 1) f32; acc: (BH, Sq, Dv) f32; q_offset/k_offset: scalar global positions of
     q[.,0] and k_chunk[.,0] (traced values fine — scalar-prefetched).
     Returns updated (m, l, acc); callers finalize with acc/max(l,eps)
     after the last chunk.
@@ -499,7 +521,7 @@ def flash_chunk_update(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     bh, sq, d = q.shape
-    sk = k_chunk.shape[1]
+    sk, dv = v_chunk.shape[1:]
     block_q, block_k = _blocks(sq, sk, block_q, block_k)
     if sq % block_q or sk % block_k:
         raise ValueError(
@@ -516,20 +538,20 @@ def flash_chunk_update(
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j, *_: (b, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j, *_: (b, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, 1), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j, *_: (b, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
     )
     qoff = jnp.asarray(q_offset, jnp.int32).reshape((1,))
@@ -540,15 +562,16 @@ def flash_chunk_update(
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, dv), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), d, dv
         ),
         cost_estimate=_cost(
-            bh, sq, sk, d, n_matmuls=2, causal=causal,
-            byte_tensors=[(1, sq, q.dtype.itemsize),
-                          (2, sk, q.dtype.itemsize), (2, sq, 4)],
+            bh, sq, sk, d, dv, causal=causal,
+            byte_tensors=[(1, sq, d, q.dtype.itemsize),
+                          (1, sk, d, q.dtype.itemsize),
+                          (1, sk, dv, q.dtype.itemsize), (2, sq, dv, 4)],
         ),
         interpret=interpret,
     )(qoff, koff, q, k_chunk, v_chunk, m, l, acc)
@@ -687,9 +710,10 @@ def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         strip = pl.ds(j * sub, sub)
         first = next((i for i, n_k in enumerate(rows) if n_k > j), None)
         if first is None:
-            zeros = jnp.zeros((sub, dk_ref.shape[2]), dk_ref.dtype)
-            dk_ref[0, strip, :] = zeros
-            dv_ref[0, strip, :] = zeros
+            dk_ref[0, strip, :] = jnp.zeros((sub, dk_ref.shape[2]),
+                                            dk_ref.dtype)
+            dv_ref[0, strip, :] = jnp.zeros((sub, dv_ref.shape[2]),
+                                            dv_ref.dtype)
             continue
         queries = pl.ds(first * sub, block_q - first * sub)
         q, do = q_ref[0, queries, :], do_ref[0, queries, :]
@@ -709,14 +733,18 @@ def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )
 
 
-def _grads_costs(q, sk, causal):
-    """(dq kernel's, dk/dv kernel's) ``pl.CostEstimate``."""
+def _grads_costs(q, v_chunk, causal):
+    """(dq kernel's, dk/dv kernel's) ``pl.CostEstimate``: both read q,
+    k (width d) and v, do (width dv)."""
     bh, sq, d = q.shape
-    reads = [(2, sq, q.dtype.itemsize), (2, sk, q.dtype.itemsize)]
+    sk, dv = v_chunk.shape[1:]
+    size = q.dtype.itemsize
+    reads = [(1, sq, d, size), (1, sq, dv, size),
+             (1, sk, d, size), (1, sk, dv, size)]
     return tuple(
-        _cost(bh, sq, sk, d, n_matmuls=2, causal=causal,
-              byte_tensors=reads + [written])
-        for written in ((1, sq, 4), (2, sk, 4))
+        _cost(bh, sq, sk, d, dv, causal=causal,
+              byte_tensors=reads + written)
+        for written in ([(1, sq, d, 4)], [(1, sk, d, 4), (1, sk, dv, 4)])
     )
 
 
@@ -726,17 +754,19 @@ def _strips_grads(q, k_chunk, v_chunk, do, lse, delta, rows, q_offset,
     """``flash_chunk_grads`` where ``_walk`` has a plan: both kernels
     take one (batch*head) a grid step and the whole tile as one block."""
     bh, sq, d = q.shape
-    sk = k_chunk.shape[1]
-    dq_cost, dkv_cost = _grads_costs(q, sk, True)
+    sk, dv = v_chunk.shape[1:]
+    dq_cost, dkv_cost = _grads_costs(q, v_chunk, True)
     q_rows = pl.BlockSpec((1, sq, d), lambda b: (b, 0, 0))
     k_rows = pl.BlockSpec((1, sk, d), lambda b: (b, 0, 0))
+    do_rows = pl.BlockSpec((1, sq, dv), lambda b: (b, 0, 0))
+    v_rows = pl.BlockSpec((1, sk, dv), lambda b: (b, 0, 0))
     q_col = pl.BlockSpec((1, sq, 1), lambda b: (b, 0, 0))
-    in_specs = [q_rows, k_rows, k_rows, q_rows, q_col, q_col]
+    in_specs = [q_rows, k_rows, v_rows, do_rows, q_col, q_col]
     common = dict(
         rows=rows, sub=sq // len(rows), q_offset=q_offset,
         k_offset=k_offset, scale=scale,
     )
-    params = pltpu.CompilerParams(dimension_semantics=("parallel",))
+    params = _compiler_params(("parallel",), d, dv)
     operands = (q, k_chunk, v_chunk, do, lse, delta)
     dq = pl.pallas_call(
         functools.partial(_dq_strips_kernel, **common),
@@ -747,10 +777,10 @@ def _strips_grads(q, k_chunk, v_chunk, do, lse, delta, rows, q_offset,
     )(*operands)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_strips_kernel, **common),
-        grid=(bh,), in_specs=in_specs, out_specs=[k_rows, k_rows],
+        grid=(bh,), in_specs=in_specs, out_specs=[k_rows, v_rows],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sk, dv), jnp.float32),
         ],
         compiler_params=params, cost_estimate=dkv_cost,
         interpret=interpret,
@@ -765,8 +795,8 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
     are traced (scalar-prefetched), a tile strictly above the diagonal
     is predicated out."""
     bh, sq, d = q.shape
-    sk = k_chunk.shape[1]
-    dq_cost, dkv_cost = _grads_costs(q, sk, causal)
+    sk, dv = v_chunk.shape[1:]
+    dq_cost, dkv_cost = _grads_costs(q, v_chunk, causal)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape((1,))
     koff = jnp.asarray(k_offset, jnp.int32).reshape((1,))
     common = dict(causal=causal, scale=scale)
@@ -781,9 +811,9 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
                              lambda b, i, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, block_k, d),
                              lambda b, i, j, *_: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d),
+                pl.BlockSpec((1, block_k, dv),
                              lambda b, i, j, *_: (b, j, 0)),
-                pl.BlockSpec((1, block_q, d),
+                pl.BlockSpec((1, block_q, dv),
                              lambda b, i, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1),
                              lambda b, i, j, *_: (b, i, 0)),
@@ -795,8 +825,8 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), d, dv
         ),
         cost_estimate=dq_cost,
         interpret=interpret,
@@ -812,9 +842,9 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
                              lambda b, i, j, *_: (b, j, 0)),
                 pl.BlockSpec((1, block_k, d),
                              lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d),
+                pl.BlockSpec((1, block_k, dv),
                              lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, block_q, d),
+                pl.BlockSpec((1, block_q, dv),
                              lambda b, i, j, *_: (b, j, 0)),
                 pl.BlockSpec((1, block_q, 1),
                              lambda b, i, j, *_: (b, j, 0)),
@@ -824,20 +854,20 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
             out_specs=[
                 pl.BlockSpec((1, block_k, d),
                              lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d),
+                pl.BlockSpec((1, block_k, dv),
                              lambda b, i, j, *_: (b, i, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, dv), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sk, dv), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), d, dv
         ),
         cost_estimate=dkv_cost,
         interpret=interpret,
@@ -853,8 +883,8 @@ def flash_chunk_grads(
 ):
     """Backward of one attention chunk pairing, fully tiled.
 
-    q/do: (BH, Sq, D); k_chunk/v_chunk: (BH, Sk, D); lse/delta:
-    (BH, Sq, 1) f32. Returns (dq_partial, dk_chunk, dv_chunk) — f32,
+    q: (BH, Sq, D); k_chunk: (BH, Sk, D); v_chunk: (BH, Sk, Dv); do:
+    (BH, Sq, Dv); lse/delta: (BH, Sq, 1) f32. Returns (dq_partial, dk_chunk, dv_chunk) — f32,
     the ring accumulates dq over chunks and rotates dk/dv home. Two
     kernels (dq: k-sequential; dk/dv: q-sequential) so each output has
     exactly one sequential accumulation dim; score tiles never leave
@@ -948,7 +978,9 @@ def flash_attention(
     block_k: int = 0,
     interpret: bool = False,
 ):
-    """Fused attention. q,k,v: (B, S, H, D); returns (B, S, H, D).
+    """Fused attention. q, k: (B, S, H, D); v: (B, S, H, Dv), whose head
+    size may differ from q's and k's (latent attention: 192 against
+    128); returns (B, S, H, Dv).
 
     ``block_q/block_k`` 0 = auto: the largest lane-aligned default-or-
     smaller block that tiles S (``_auto_block`` — gate callers check
@@ -962,10 +994,10 @@ def flash_attention(
     block_q, block_k = _blocks(s_len, s_len, block_q, block_k)
 
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s_len, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s_len, x.shape[3])
 
     o = _flash(
         to_bh(q), to_bh(k), to_bh(v), causal, float(scale), block_q,
         block_k, interpret,
     )
-    return o.reshape(b, h, s_len, d).transpose(0, 2, 1, 3)
+    return o.reshape(b, h, s_len, v.shape[3]).transpose(0, 2, 1, 3)

@@ -65,9 +65,9 @@ def fused_next_token_cross_entropy(labels, outputs, mask,
     hidden, kernel, bias = outputs
     b, s, d = hidden.shape
     labels = labels.astype(jnp.int32)
-    weights = jnp.broadcast_to(
-        mask.astype(jnp.float32)[:, None], (b, s)
-    )
+    weights = mask.astype(jnp.float32)
+    if weights.ndim == 1:
+        weights = jnp.broadcast_to(weights[:, None], (b, s))
     chunk = min(chunk_size, s)
     if s % chunk:
         raise ValueError(f"seq len {s} must tile by chunk {chunk}")
@@ -100,7 +100,8 @@ def fused_next_token_cross_entropy(labels, outputs, mask,
 
 def masked_next_token_cross_entropy(labels, logits, mask):
     """Per-token LM cross entropy: labels (B, S) int, logits (B, S, V),
-    ``mask`` the (B,) padded-row mask broadcast over tokens.
+    ``mask`` the (B,) padded-row mask broadcast over tokens, or (B, S)
+    weights of every position (the fused form takes either too).
 
     Formulated as ``logsumexp(x) - x[label]`` rather than gathering from
     ``log_softmax(x)``: identical math (logsumexp is max-stabilized),
@@ -119,5 +120,7 @@ def masked_next_token_cross_entropy(labels, logits, mask):
         logits32, labels[..., None].astype(jnp.int32), axis=-1
     )[..., 0]
     ll = lab_logit - lse
-    weights = jnp.broadcast_to(mask[:, None], ll.shape)
+    weights = mask if mask.ndim == 2 else jnp.broadcast_to(
+        mask[:, None], ll.shape
+    )
     return -jnp.sum(ll * weights) / jnp.maximum(jnp.sum(weights), 1.0)

@@ -255,6 +255,21 @@ class Worker:
         # Per-step losses of the training task in flight (device
         # scalars; read back once per task for the task log line).
         self._task_losses = []
+        # What the model counted in those steps beside the loss
+        # (core/step.py::_model_metrics), e.g. an expert layer's routed
+        # rows: {name: device array} per program call, read back with
+        # the losses.
+        self._task_counters = []
+        self._m_moe_rows = self._metrics.counter(
+            "worker_moe_rows_total",
+            "Token-choices routed to experts this worker holds, summed "
+            "over expert layers and steps",
+        )
+        self._m_moe_rows_max = self._metrics.gauge(
+            "worker_moe_expert_rows_max",
+            "Largest per-step sum over expert layers of the fullest "
+            "held expert's rows, in the last trained task",
+        )
         # The first call of the training program (load or compile, and
         # the first run) is a start-up phase around the first
         # ``device_step`` (_first_step).
@@ -708,7 +723,7 @@ class Worker:
             self._await_turn(multihost.STEP_TRAIN)
         self.state, metrics = self._train_step(self.state, batch)
         self.last_metrics = metrics
-        self._task_losses.append(metrics["loss"])
+        self._note_step_metrics(metrics)
 
     def request_stop(self):
         """Ask the worker to stop at the next TASK boundary, saving a
@@ -719,8 +734,17 @@ class Worker:
         the grace period falls back to the ordinary pod-death path.)"""
         self._stop_requested = True
 
+    def _note_step_metrics(self, metrics):
+        """Keep a program call's losses and counters on the device
+        until the task's one readback (_log_trained_task)."""
+        self._task_losses.append(metrics["loss"])
+        counters = {k: v for k, v in metrics.items() if k != "loss"}
+        if counters:
+            self._task_counters.append(counters)
+
     def _process_train_task(self, task, batches) -> int:
         self._task_losses = []
+        self._task_counters = []
         if self._fuse_task_steps:
             # The whole input wait of a fused task: the program needs
             # every minibatch before it can be dispatched.
@@ -848,7 +872,7 @@ class Worker:
             # gap between two task programs if it came after the wait
             # (PERF.md, PR 24).
             self.last_metrics = {"loss": metrics["loss"][-1]}
-            self._task_losses.append(metrics["loss"])
+            self._note_step_metrics(metrics)
             self._m_examples.labels(TaskType.TRAINING).inc(
                 sum(self._batch_examples(b) for b in batch_list)
             )
@@ -883,13 +907,17 @@ class Worker:
         task granularity, then every step's loss (one host readback
         per task for both). A checker outside the process replays the
         steps against the second line; the first is what
-        ``benchmark/lib/procs.py`` parses, letter for letter."""
+        ``benchmark/lib/procs.py`` parses, letter for letter. A model
+        that counts (an expert layer's routed rows) gets a third line,
+        ``Task N routing:``, with every step's counters, from the same
+        readback."""
         if not self._task_losses:
             return
         with self._phases.phase("task_log"):
-            losses = np.concatenate(
-                [np.ravel(np.asarray(x)) for x in self._task_losses]
+            task_losses, task_counters = jax.device_get(
+                (self._task_losses, self._task_counters)
             )
+            losses = np.concatenate([np.ravel(x) for x in task_losses])
             logger.info(
                 "Task %d trained: batches=%d version=%d mean_loss=%.6f",
                 task.task_id, trained, int(self.state.step),
@@ -898,6 +926,29 @@ class Worker:
             logger.info(
                 "Task %d losses: [%s]", task.task_id,
                 ", ".join(f"{float(x):.6f}" for x in losses),
+            )
+            if task_counters:
+                self._log_task_counters(task, task_counters)
+
+    def _log_task_counters(self, task, task_counters):
+        steps = {
+            name: np.concatenate(
+                [np.ravel(c[name]) for c in task_counters]
+            ).astype(np.int64)
+            for name in task_counters[0]
+        }
+        logger.info(
+            "Task %d routing: %s", task.task_id,
+            " ".join(
+                f"{name}=[{', '.join(str(int(x)) for x in values)}]"
+                for name, values in sorted(steps.items())
+            ),
+        )
+        if "moe_rows" in steps:
+            self._m_moe_rows.inc(int(steps["moe_rows"].sum()))
+        if "moe_expert_rows_max" in steps:
+            self._m_moe_rows_max.set(
+                int(steps["moe_expert_rows_max"].max())
             )
 
     def _end_cycle(self, task, trained_ok: bool):

@@ -1,0 +1,588 @@
+"""The latent-attention sparse-expert LM (``models/mla_moe.py``) against
+its plain reference (``benchmark/reference/mla_moe.py``) at a tiny size,
+seeded weights, float32: logits, both loss terms, every gradient leaf;
+the expert layer's shares, its drop-free dispatch and its counters; the
+interleaved RoPE; the flash kernels with a v head narrower than q/k's."""
+
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import mla_moe as reference
+from elasticdl_tpu.core.model_spec import load_module
+from elasticdl_tpu.core.step import build_multi_step, build_train_step
+from elasticdl_tpu.core.train_state import init_train_state
+from elasticdl_tpu.models import mla_moe
+from elasticdl_tpu.models.mla_moe import (
+    ExpertLayer,
+    MlaMoeConfig,
+    MlaMoeLM,
+    held_experts_part,
+    rope_interleaved,
+)
+from elasticdl_tpu.ops.flash_attention import flash_attention
+from elasticdl_tpu.ops.ring_attention import dense_attention
+
+ZOO = load_module("model_zoo/mla_moe/mla_moe_lm.py")
+
+# The reference's names for the sizes (the published config.json's).
+CFG = {
+    "name": "tiny", "hidden_size": 32, "num_attention_heads": 2,
+    "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "v_head_dim": 8,
+    "q_lora_rank": 24, "kv_lora_rank": 16, "intermediate_size": 48,
+    "moe_intermediate_size": 16, "n_routed_experts": 4, "router_width": 8,
+    "first_held": 2, "num_experts_per_tok": 3, "vocab_size": 64,
+    "first_k_dense_replace": 1, "num_hidden_layers": 3,
+    "num_nextn_predict_layers": 1, "rms_norm_eps": 1e-6,
+    "rope_theta": 10000.0, "routed_scaling_factor": 2.5,
+    "mtp_loss_weight": ZOO.MTP_LOSS_WEIGHT, "initializer_range": 0.2,
+    "router_bias_std": 0.1,
+}
+ROWS, SEQ = 2, 16
+
+
+def program_config(cfg=CFG, **changes) -> MlaMoeConfig:
+    base = dict(
+        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
+        num_layers=cfg["num_hidden_layers"],
+        first_k_dense=cfg["first_k_dense_replace"],
+        intermediate_size=cfg["intermediate_size"],
+        moe_intermediate_size=cfg["moe_intermediate_size"],
+        num_heads=cfg["num_attention_heads"],
+        q_lora_rank=cfg["q_lora_rank"], kv_lora_rank=cfg["kv_lora_rank"],
+        qk_nope_head_dim=cfg["qk_nope_head_dim"],
+        qk_rope_head_dim=cfg["qk_rope_head_dim"],
+        v_head_dim=cfg["v_head_dim"], rope_theta=cfg["rope_theta"],
+        rms_eps=cfg["rms_norm_eps"], router_width=cfg["router_width"],
+        first_held=cfg["first_held"], n_held=cfg["n_routed_experts"],
+        top_k=cfg["num_experts_per_tok"],
+        routed_scaling_factor=cfg["routed_scaling_factor"],
+        mtp_layers=cfg["num_nextn_predict_layers"],
+        compute_dtype=jnp.float32,
+    )
+    base.update(changes)
+    return MlaMoeConfig(**base)
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    weights = reference.weights(CFG, jax.random.PRNGKey(7))
+    rows = np.random.default_rng(3).integers(
+        0, CFG["vocab_size"], (ROWS, SEQ + 1))
+    return weights, jnp.asarray(rows[:, :-1]), jnp.asarray(rows[:, 1:])
+
+
+@pytest.fixture(scope="module")
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def test_reference_tree_is_the_programs(seeded):
+    weights, tokens, _ = seeded
+    model = MlaMoeLM(program_config())
+    want = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, tokens, training=False))
+    got = {"params": reference.to_program_tree(weights, CFG)}
+    assert jax.tree.structure(want) == jax.tree.structure(got)
+    assert [x.shape for x in jax.tree.leaves(want)] == [
+        x.shape for x in jax.tree.leaves(got)]
+
+
+def test_logits_and_both_loss_terms_match_the_reference(seeded, highest):
+    weights, tokens, labels = seeded
+    params = reference.to_program_tree(weights, CFG)
+    model = MlaMoeLM(program_config())
+    out = model.apply({"params": params}, tokens, training=True)
+    row_logits = jax.jit(lambda w, row: reference.row_logits(w, row, CFG))
+    want = [row_logits(weights, tokens[r]) for r in range(ROWS)]
+    np.testing.assert_allclose(
+        out["logits"], np.stack([w[0] for w in want]), atol=2e-5)
+    np.testing.assert_allclose(
+        out["mtp_logits"], np.stack([w[1] for w in want]), atol=2e-5)
+    # Evaluation returns the main logits alone.
+    np.testing.assert_allclose(
+        model.apply({"params": params}, tokens, training=False),
+        out["logits"], atol=1e-6)
+    main, mtp = ZOO.loss_terms(labels, out, jnp.ones((ROWS,)))
+    row_loss = jax.jit(lambda w, row, lab: reference.row_loss(w, row, lab, CFG))
+    terms = [row_loss(weights, tokens[r], labels[r])[1] for r in range(ROWS)]
+    assert float(main) == pytest.approx(
+        np.mean([t["main"] for t in terms]), abs=1e-5)
+    assert float(mtp) == pytest.approx(
+        np.mean([t["mtp"] for t in terms]), abs=1e-5)
+    assert float(ZOO.loss(labels, out, jnp.ones((ROWS,)))) == pytest.approx(
+        float(main) + ZOO.MTP_LOSS_WEIGHT * float(mtp), abs=1e-6)
+    assert int(out["metrics"]["moe_rows"]) == sum(
+        int(t["routed_rows"]) for t in terms)
+
+
+def test_every_gradient_leaf_matches_the_reference(seeded, highest):
+    weights, tokens, labels = seeded
+    params = reference.to_program_tree(weights, CFG)
+    model = MlaMoeLM(program_config())
+
+    def program_loss(p):
+        out = model.apply({"params": p}, tokens, training=True)
+        return ZOO.loss(labels, out, jnp.ones((ROWS,)))
+
+    loss, grads = jax.value_and_grad(program_loss)(params)
+    want_loss, want = jax.jit(
+        lambda w, t, l: reference.loss_and_grads(w, t, l, CFG)
+    )(weights, tokens, labels)
+    assert float(loss) == pytest.approx(float(want_loss), abs=1e-5)
+    want = reference.to_program_tree(want, CFG)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(flat, jax.tree.leaves(want)):
+        scale = max(float(jnp.abs(ref).max()), 1e-6)
+        np.testing.assert_allclose(
+            got, ref, atol=2e-4 * scale, rtol=2e-4,
+            err_msg=jax.tree_util.keystr(path))
+    # The selection bias only selects: what comes back for it is its
+    # load's direction (the loop above held it to the reference's).
+    assert set(np.unique(grads["block_1"]["moe"]["router_bias"])) <= {
+        -1.0, 0.0, 1.0}
+
+
+def _own_choices(model, params, tokens):
+    _, sown = model.apply({"params": params}, tokens, training=True,
+                          mutable=["intermediates"])
+    blocks = sown["intermediates"]
+    return [blocks[name]["moe"]["chosen"][0]
+            for name in reference.expert_blocks(CFG)]
+
+
+def test_routing_replay_holds_the_layers_to_the_choices_given(
+        seeded, highest):
+    weights, tokens, labels = seeded
+    params = reference.to_program_tree(weights, CFG)
+    model = MlaMoeLM(program_config())
+    own = _own_choices(model, params, tokens)
+    want = jax.jit(lambda w, t: reference.choices(w, t, CFG))(weights, tokens)
+    assert len(own) == 3            # block_1, block_2, the MTP block
+    for a, b in zip(own, want):
+        np.testing.assert_array_equal(np.sort(a, -1), np.sort(b, -1))
+    out = model.apply({"params": params}, tokens, training=True)
+    again = model.apply({"params": params}, tokens, training=True,
+                        routing=own)
+    np.testing.assert_array_equal(again["logits"], out["logits"])
+    # Other experts than its own: every token to the three after them.
+    other = [(c + 3) % CFG["router_width"] for c in own]
+    moved = model.apply({"params": params}, tokens, training=True,
+                        routing=other)
+    main, mtp, chosen = jax.jit(lambda w, t, held: reference.hidden_states(
+        w, t, CFG, held=held))(
+            weights, tokens, dict(zip(reference.expert_blocks(CFG), other)))
+    for name, held in zip(reference.expert_blocks(CFG), other):
+        np.testing.assert_array_equal(chosen[name], held)
+    np.testing.assert_allclose(
+        moved["logits"],
+        main @ weights["head_w"] + weights["head_b"], atol=2e-5)
+    assert int(moved["metrics"]["moe_rows"]) == sum(
+        int(reference.held_count(c, CFG)) for c in other)
+    assert float(jnp.abs(moved["logits"] - out["logits"]).max()) > 1e-3
+
+
+def test_selection_bias_moves_against_its_load_and_nothing_else_does(
+        seeded, highest):
+    """One step of the zoo's optimizer: every selection bias moves by the
+    speed, down where its expert got more than the mean of the choices
+    and up where it got fewer (counted here in numpy); Adam moves the
+    rest."""
+    weights, tokens, labels = seeded
+    params = reference.to_program_tree(weights, CFG)
+    model = MlaMoeLM(program_config())
+    own = _own_choices(model, params, tokens)
+    speed = 0.25
+    tx = ZOO.optimizer(1e-3, speed)
+
+    def loss(p):
+        out = model.apply({"params": p}, tokens, training=True)
+        return ZOO.loss(labels, out, jnp.ones((ROWS,)))
+
+    grads = jax.grad(loss)(params)
+    updates, _ = tx.update(grads, tx.init(params), params)
+    width = CFG["router_width"]
+    for name, chosen in zip(reference.expert_blocks(CFG), own):
+        load = np.bincount(np.asarray(chosen).ravel(), minlength=width)
+        assert load.sum() == ROWS * SEQ * CFG["num_experts_per_tok"]
+        np.testing.assert_allclose(
+            updates[name]["moe"]["router_bias"],
+            -speed * np.sign(load - load.mean()), atol=1e-7)
+        np.testing.assert_array_equal(
+            reference.load_direction(chosen, CFG),
+            np.sign(load - load.mean()))
+    step = np.abs(np.asarray(updates["block_1"]["moe"]["router"]))
+    assert 0 < step.max() <= 1.001e-3
+
+
+def _layer_inputs(width=8, held=8, d=32, f=16, tokens=24, seed=0):
+    rng = np.random.default_rng(seed)
+    mk = lambda *shape: jnp.asarray(rng.normal(0, 0.3, shape), jnp.float32)
+    return {
+        "x": mk(2, tokens // 2, d),
+        "params": {
+            "router": mk(d, width), "router_bias": mk(width) * 0.3,
+            "w_gate": mk(held, d, f), "w_up": mk(held, d, f),
+            "w_down": mk(held, f, d),
+            "shared": {n: {"kernel": mk(*s)} for n, s in (
+                ("gate", (d, f)), ("up", (d, f)), ("down", (f, d)))},
+        },
+    }
+
+
+def _reference_layer(x, params, first, held, k, scaling=2.5):
+    """The reference's expert layer over (tokens, d) rows."""
+    z = {"first": first, "held": held, "k": k}
+    w = {"router": params["router"], "router_b": params["router_bias"],
+         "e_gate": params["w_gate"], "e_up": params["w_up"],
+         "e_down": params["w_down"],
+         **{f"s_{n}": params["shared"][n]["kernel"]
+            for n in ("gate", "up", "down")}}
+    out, chosen = reference.expert_layer(
+        x.reshape(-1, x.shape[-1]), w, z,
+        {"routed_scaling_factor": scaling}, "f32")
+    local = np.asarray(chosen) - first
+    return out, int(((local >= 0) & (local < held)).sum())
+
+
+def test_shares_add_up_to_the_uncut_layer(highest):
+    """Four shares of two experts each: their routed parts, with the
+    shared expert counted once, are the uncut reference's layer."""
+    width, k = 8, 3
+    given = _layer_inputs(width=width, held=width)
+    x, params = given["x"], given["params"]
+    whole, whole_count = _reference_layer(x, params, 0, width, k)
+    assert int(whole_count) == x.shape[0] * x.shape[1] * k
+    shared = reference.gated_mlp(
+        x.reshape(-1, x.shape[-1]),
+        *(params["shared"][n]["kernel"] for n in ("gate", "up", "down")),
+        "f32")
+    total, rows = shared, 0
+    for first in range(0, width, 2):
+        cfg = program_config(
+            router_width=width, first_held=first, n_held=2, top_k=k,
+            moe_intermediate_size=16)
+        share = dict(params, **{
+            name: params[name][first:first + 2]
+            for name in ("w_gate", "w_up", "w_down")})
+        out, counters = ExpertLayer(cfg).apply({"params": share}, x)
+        total = total + (out.reshape(shared.shape) - shared)
+        rows += int(counters["moe_rows"])
+    assert rows == int(whole_count)
+    np.testing.assert_allclose(total, whole, atol=2e-5)
+
+
+@pytest.mark.parametrize("held_first", [0, 2])
+def test_no_row_is_lost_when_every_token_chooses_one_expert(
+        held_first, highest):
+    """A selection bias that sends every token to experts 2, 3, 4: far
+    over any capacity a dropping dispatch would give them."""
+    width, k = 8, 3
+    given = _layer_inputs(width=width, held=4, seed=1)
+    x, params = given["x"], given["params"]
+    params["router_bias"] = jnp.where(
+        (jnp.arange(width) >= 2) & (jnp.arange(width) < 5), 50.0, 0.0)
+    cfg = program_config(
+        router_width=width, first_held=held_first, n_held=4, top_k=k,
+        moe_intermediate_size=16)
+    out, counters = ExpertLayer(cfg).apply({"params": params}, x)
+    want, count = _reference_layer(x, params, held_first, 4, k)
+    tokens = x.shape[0] * x.shape[1]
+    # Experts [0, 4) hold 2 and 3 of the chosen three; [2, 6) all three.
+    assert int(count) == tokens * (2 if held_first == 0 else 3)
+    assert int(counters["moe_rows"]) == int(count)
+    assert int(counters["moe_expert_rows_max"]) == tokens
+    np.testing.assert_allclose(
+        out.reshape(want.shape), want, atol=2e-5)
+
+
+def test_routing_counters_match_a_count_in_numpy(highest):
+    width, k, first, held = 8, 3, 1, 5
+    given = _layer_inputs(width=width, held=held, seed=2)
+    x, params = given["x"], given["params"]
+    cfg = program_config(
+        router_width=width, first_held=first, n_held=held, top_k=k,
+        moe_intermediate_size=16)
+    _, counters = ExpertLayer(cfg).apply({"params": params}, x)
+    rows = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
+    scores = 1 / (1 + np.exp(-rows @ np.asarray(params["router"],
+                                                 np.float64)))
+    biased = scores + np.asarray(params["router_bias"], np.float64)
+    chosen = np.argsort(-biased, axis=1)[:, :k]
+    per_expert = np.bincount(chosen.ravel(), minlength=width)[
+        first:first + held]
+    assert int(counters["moe_rows"]) == per_expert.sum()
+    assert int(counters["moe_expert_rows_max"]) == per_expert.max()
+
+
+def test_held_part_under_any_split_of_the_rows(highest):
+    """The grouped products' static bound is tokens x k rows: the part is
+    the same whether three or all eight experts are held."""
+    rng = np.random.default_rng(5)
+    t, k, d, f, n = 12, 2, 8, 4, 8
+    rows = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    chosen = jnp.asarray(
+        np.stack([rng.permutation(n)[:k] for _ in range(t)]), jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.1, 1, (t, k)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.normal(size=(n, d, f)), jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.normal(size=(n, f, d)), jnp.float32)
+    whole, sizes = held_experts_part(rows, chosen, weights, wg, wu, wd, 0)
+    assert int(sizes.sum()) == t * k
+    parts = [held_experts_part(rows, chosen, weights, wg[a:b], wu[a:b],
+                               wd[a:b], a)[0] for a, b in ((0, 3), (3, 8))]
+    np.testing.assert_allclose(parts[0] + parts[1], whole, atol=1e-4)
+
+
+def test_rows_of_no_group_may_hold_anything(monkeypatch, highest):
+    """A grouped product says nothing of the rows past its groups, in its
+    result or in its cotangent (a TPU leaves what the memory held; the
+    CPU writes zeros, which hid it). With both poisoned the layer and its
+    gradients are what they were."""
+    from elasticdl_tpu.ops.grouped_matmul import grouped_matmul
+
+    def in_a_group(x, sizes):
+        return (jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None]
+
+    @jax.custom_vjp
+    def poisoned(lhs, rhs, sizes):
+        return jnp.where(in_a_group(lhs, sizes),
+                         grouped_matmul(lhs, rhs, sizes), jnp.nan)
+
+    def forward(lhs, rhs, sizes):
+        return poisoned(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def backward(res, g):
+        lhs, rhs, sizes = res
+        _, pull = jax.vjp(lambda a, b: grouped_matmul(a, b, sizes), lhs, rhs)
+        d_lhs, d_rhs = pull(jnp.where(in_a_group(lhs, sizes), g, 0))
+        return jnp.where(in_a_group(lhs, sizes), d_lhs, jnp.nan), d_rhs, None
+
+    poisoned.defvjp(forward, backward)
+    given = _layer_inputs(width=8, held=3, seed=7)
+    x, params = given["x"], given["params"]
+    cfg = program_config(router_width=8, first_held=1, n_held=3, top_k=3,
+                         moe_intermediate_size=16)
+
+    def loss(params, x):
+        out, _ = ExpertLayer(cfg).apply({"params": params}, x)
+        return jnp.sum(out ** 2)
+
+    want = jax.grad(loss, argnums=(0, 1))(params, x)
+    monkeypatch.setattr(mla_moe, "grouped_matmul", poisoned)
+    got = jax.grad(loss, argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.isfinite(a).all())
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def test_interleaved_rope_turns_the_pairs_by_hand():
+    """Rotary size 4, theta 100: pair i of position p turns by
+    p * 100^(-i/2): angles p and p / 10."""
+    x = jnp.asarray(np.arange(1, 13, dtype=np.float32).reshape(1, 3, 4))
+    got = np.asarray(rope_interleaved(x, 100.0))
+    for p in range(3):
+        a, b, c, d = np.asarray(x[0, p], np.float64)
+        want = [a * np.cos(p) - b * np.sin(p), a * np.sin(p) + b * np.cos(p),
+                c * np.cos(p / 10) - d * np.sin(p / 10),
+                c * np.sin(p / 10) + d * np.cos(p / 10)]
+        np.testing.assert_allclose(got[0, p], want, rtol=1e-5, atol=1e-5)
+    # Position 1, the pair (1, 0): (cos 1, sin 1).
+    unit = jnp.zeros((1, 2, 2)).at[0, 1, 0].set(1.0)
+    np.testing.assert_allclose(
+        rope_interleaved(unit, 100.0)[0, 1], [np.cos(1), np.sin(1)],
+        rtol=1e-6)
+    # The reference's own RoPE (rows without the batch axis) agrees.
+    np.testing.assert_allclose(
+        reference.rope(x[0], 100.0), got[0], rtol=1e-6, atol=1e-6)
+
+
+def _narrow_v(seed, s=64, d=24, dv=16):
+    rng = np.random.RandomState(seed)
+    mk = lambda width: jnp.asarray(rng.randn(2, s, 2, width), jnp.float32) * 0.3
+    return mk(d), mk(d), mk(dv)
+
+
+# Blocks of 16: the grid of whole tiles; one 64 block: the strip walk
+# inside a grid tile (``SUB_TILE`` is lowered for it).
+@pytest.mark.parametrize("blocks,sub", [((16, 16), None), ((32, 16), None),
+                                        ((64, 64), 16)])
+def test_flash_with_a_narrower_v_head_matches_dense(blocks, sub,
+                                                    monkeypatch):
+    from elasticdl_tpu.ops import flash_attention as flash
+
+    if sub:
+        monkeypatch.setattr(flash, "SUB_TILE", sub)
+    q, k, v = _narrow_v(4)
+    scale = q.shape[-1] ** -0.5
+
+    def loss(attend, q, k, v):
+        out = attend(q, k, v)
+        assert out.shape == v.shape
+        return jnp.sum(out ** 2), out
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               block_q=blocks[0], block_k=blocks[1],
+                               interpret=True)
+
+    def dense(q, k, v):
+        return dense_attention(q, k, v, causal=True, scale=scale)
+
+    (_, got), got_grads = jax.value_and_grad(
+        lambda *a: loss(kernels, *a), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want), want_grads = jax.value_and_grad(
+        lambda *a: loss(dense, *a), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    for name, a, b in zip("qkv", got_grads, want_grads):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5,
+                                   err_msg=f"d{name}")
+
+
+def test_wide_heads_raise_the_kernels_vmem_limit_and_others_do_not():
+    from elasticdl_tpu.ops import flash_attention as flash
+
+    narrow = flash._compiler_params(("parallel",), 64, 64)
+    assert narrow.vmem_limit_bytes is None
+    assert flash._compiler_params(("parallel",), 128, 128) == (
+        flash.pltpu.CompilerParams(dimension_semantics=("parallel",)))
+    wide = flash._compiler_params(("parallel",), 192, 128)
+    assert wide.vmem_limit_bytes == flash.WIDE_HEAD_VMEM_BYTES
+    assert flash._blocks(4096, 4096, 0, 0) == (1024, 1024)
+
+
+def test_step_metrics_carry_the_models_counters(seeded):
+    """``core/step.py``: the model's ``metrics`` leave the step beside
+    the loss, per step of a fused task too."""
+    _, tokens, labels = seeded
+    model = MlaMoeLM(program_config(first_held=0, n_held=8))
+    batch = {"features": np.asarray(tokens), "labels": np.asarray(labels),
+             "mask": np.ones((ROWS,), np.float32)}
+    state = init_train_state(model, ZOO.optimizer(), batch)
+    state, metrics = build_train_step(ZOO.loss)(state, batch)
+    assert set(metrics) == {"loss", "moe_rows", "moe_expert_rows_max"}
+    # All eight experts held: every choice of every expert layer (and
+    # the MTP block's) is a held one.
+    layers = CFG["num_hidden_layers"] - CFG["first_k_dense_replace"] + 1
+    assert int(metrics["moe_rows"]) == (
+        ROWS * SEQ * CFG["num_experts_per_tok"] * layers)
+    stacked = jax.tree.map(lambda x: np.stack([x, x]), batch)
+    state, metrics = build_multi_step(ZOO.loss)(state, stacked)
+    assert metrics["moe_rows"].shape == (2,)
+    assert metrics["loss"].shape == (2,)
+
+
+def test_expert_layer_line_is_logged_once():
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda record: records.append(record.getMessage())
+    mla_moe.logger.addHandler(handler)
+    mla_moe.log_traced_experts.cache_clear()
+    try:
+        for _ in range(2):
+            mla_moe.log_traced_experts(program_config(), 96, 1)
+    finally:
+        mla_moe.logger.removeHandler(handler)
+        mla_moe.log_traced_experts.cache_clear()
+    assert records == [
+        "experts: traced drop-free layer holding experts [2, 6) of router "
+        "width 8, top-3, rows bound 96, grouped product ragged_dot"]
+
+
+def test_over_an_ep_mesh_the_members_parts_add_up(highest):
+    """ep = 4 on the virtual CPU devices: each member holds two of the
+    eight experts; the layer's result is the single-chip layer's."""
+    from jax.sharding import Mesh
+
+    given = _layer_inputs(width=8, held=8, seed=6)
+    x, params = given["x"], given["params"]
+    cfg = program_config(router_width=8, first_held=0, n_held=8, top_k=3,
+                         moe_intermediate_size=16)
+    want, want_counters = ExpertLayer(cfg).apply({"params": params}, x)
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(1, 4), ("dp", "ep"))
+    got, counters = jax.jit(
+        lambda p, x: ExpertLayer(cfg, mesh).apply({"params": p}, x)
+    )(params, x)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert int(counters["moe_rows"]) == int(want_counters["moe_rows"])
+    rules = dict(mla_moe.mla_moe_sharding_rules())
+    assert rules[r"moe/w_(gate|up|down)"][0] == "ep"
+
+
+class _Lines(logging.Handler):
+    """Collects a program logger's messages (its loggers do not
+    propagate, so caplog does not see them)."""
+
+    def __init__(self, logger):
+        super().__init__()
+        self.lines = []
+        self._logger = logger
+
+    def emit(self, record):
+        self.lines.append(record.getMessage())
+
+    def __enter__(self):
+        self._logger.addHandler(self)
+        return self
+
+    def __exit__(self, *exc):
+        self._logger.removeHandler(self)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_worker_logs_the_routing_line_and_counts(tmp_path, fused):
+    """The unchanged worker runs the zoo module; every trained task gets
+    a third line with each step's counters, and the page's counters move
+    with them."""
+    from elasticdl_tpu.testing.cluster import MiniCluster
+    from elasticdl_tpu.testing.data import (
+        create_lm_record_file,
+        model_zoo_dir,
+    )
+    from elasticdl_tpu.worker import worker as worker_mod
+
+    train = create_lm_record_file(
+        str(tmp_path / "t.rec"), 32, seed=5, seq_len=16, vocab=256)
+    cluster = MiniCluster(
+        model_zoo=model_zoo_dir(),
+        model_def="mla_moe.mla_moe_lm.custom_model",
+        training_data=train, minibatch_size=4,
+        num_minibatches_per_task=4, fuse_task_steps=fused,
+    )
+    def page():
+        return {
+            f["name"]: f["series"][0]["value"]
+            for f in cluster.workers[0]._metrics.snapshot()["families"]
+            if f["name"].startswith("edl_tpu_worker_moe") and f["series"]
+        }
+
+    # The registry is the process's: other tests' workers count there.
+    before = page().get("edl_tpu_worker_moe_rows_total", 0)
+    with _Lines(worker_mod.logger) as log:
+        cluster.run()
+    assert cluster.finished
+    trained = [m for m in log.lines if " trained: " in m]
+    routing = [m for m in log.lines if " routing: " in m]
+    assert len(trained) == len(routing) == 2
+    total, largest = 0, 0
+    for line_t, line_r in zip(trained, routing):
+        assert line_r.startswith(f"Task {line_t.split()[1]} routing: ")
+        fields = dict(
+            part.split("=", 1) for part in
+            line_r.split("routing: ", 1)[1].replace(", ", ",").split())
+        rows = [int(x) for x in fields["moe_rows"].strip("[]").split(",")]
+        maxes = [int(x) for x in
+                 fields["moe_expert_rows_max"].strip("[]").split(",")]
+        assert len(rows) == len(maxes) == 4
+        # The zoo's CONFIG holds all 8 experts: 4 rows x 16 tokens x
+        # top-2, over two expert layers and the MTP block.
+        assert rows == [4 * 16 * 2 * 3] * 4
+        total += sum(rows)
+        largest = max(maxes)
+    assert page()["edl_tpu_worker_moe_rows_total"] - before == total
+    assert page()["edl_tpu_worker_moe_expert_rows_max"] == largest
