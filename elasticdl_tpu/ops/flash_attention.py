@@ -7,17 +7,26 @@ queries over the grid and streams K/V through VMEM with the standard
 online-softmax recurrence (running max m, denominator l, accumulator o),
 so scores only ever exist as (block_q, block_k) tiles on-chip.
 
-What the causal mask saves, and where. Across grid tiles (S over one
-block: S = 2,048 and up) a K tile strictly above the diagonal is
-predicated out (``pl.when``). Inside ONE grid tile, which is the whole
-sequence at S <= 1,024 with the default blocks, nothing can be
-predicated, so the three kernels walk the tile in ``SUB_TILE``-wide
-strips that stop at the diagonal wherever its place is known at trace
-time (``_walk``): 10 of 16 sub-tiles multiplied at S = 1,024, 3 of 4 at
-S = 512. Where the diagonal is traced (several grid tiles; the ring's
-prefetched offsets) the whole tile is computed and masked. Both forms
-share one definition of the tile mathematics (``_tile_scores``,
-``_tile_probs``, ``_bwd_tile_math``) and give the same bits.
+What the causal mask saves, and where: all of it rests on knowing the
+diagonal's place at trace time (``_walk``: a causal call, Python-int
+offsets). Inside ONE grid tile, which is the whole sequence at S <=
+1,024 with the default blocks, the three kernels walk the tile in
+``SUB_TILE``-wide strips that stop at the diagonal: 10 of 16 sub-tiles
+multiplied at S = 1,024, 3 of 4 at S = 512. Over a square GRID of tiles
+(S over one block: 2,048, 4,096; equal offsets) grid step (qi, kt) is a
+diagonal tile iff qi == kt, and the grid kernels branch on it: the
+diagonal tiles walk the same strips (each row's accumulators stepped
+once a tile, as the whole tile steps them), the tiles below the
+diagonal are multiplied whole with NO mask built (no iota, compare or
+select: every score is visible), and the steps above it are dead: their
+bodies are predicated out and their index maps name the diagonal's
+block, which is resident or wanted next, so nothing is fetched for
+them. At S = 4,096: 6 tiles whole and unmasked, 4 walked 10 of 16, 6
+dead. Where the diagonal is traced (the ring's prefetched offsets;
+unequal blocks or offsets) every tile is computed whole under a traced
+compare, as before. All forms share one definition of the tile
+mathematics (``_tile_scores``, ``_tile_probs``, ``_bwd_tile_math``) and
+give the same bits: the rows dropped are exact zeros of the mask.
 
 Backward is a custom VJP: the forward saves only o and the logsumexp
 L = m + log(l) (the flash-attention residual trick); the backward runs
@@ -64,11 +73,26 @@ _NEG_INF = -1e30
 # was lowered on purpose): a larger default block would not fit.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
-# Edge of the sub-tiles a single grid tile is walked in (``_walk``).
-# v5e sweep, B*H=128, S=1024, bf16, forward + both backward kernels,
-# device ms a layer: whole tile 1.836; 512: 1.329; 256: 1.237; 128:
-# 1.336; within two microseconds of that at D=128, so ``d`` does not
-# enter the choice (tools/bench_flash_blocks.py prints the rows).
+# Edge of the sub-tiles a grid tile on the diagonal is walked in
+# (``_walk``). v5e sweep, B*H=128, S=1024, bf16, forward + both backward
+# kernels, device ms a layer: whole tile 1.836; 512: 1.329; 256: 1.237;
+# 128: 1.336; within two microseconds of that at D=128, so ``d`` does
+# not enter the choice (tools/bench_flash_blocks.py prints the rows).
+# Over a grid of several tiles (PR 28; same tool, ``grid`` rows; v5e,
+# B*H=128, bf16, ms a layer fwd / dq / dk+dv / sum), what each part of
+# the walk buys over the grid of whole tiles under a traced compare:
+#   S 4,096, q/k 192, v 128   whole tiles  8.778 11.862 14.482 35.122
+#     strips in the diagonal tiles         8.098 10.544 12.987 31.629
+#     + no mask built below them           7.791 10.488 12.930 31.209
+#     + dead steps fetch nothing           7.234  9.991 10.739 27.964
+#   S 4,096, D 64             whole tiles  6.491  7.493 10.279 24.262
+#     strips / + no mask / + no fetch     22.720 / 22.323 / 19.846 (sums)
+#   S 2,048, D 64             whole tiles  1.888  2.200  2.900  6.988
+#     strips / + no mask / + no fetch      6.122 /  6.050 /  5.386 (sums)
+# All three parts pay and are kept; every row gives the whole tiles'
+# bits. The dead steps cost what they did because a dk/dv step fetched
+# q, do and two (block, 1) f32 columns (lse, delta), whose rows are 4
+# bytes each: 2.9 us a dead step, 768 of them a call.
 SUB_TILE = 256
 # A head wider than ``WIDE_HEAD`` (latent attention's q and k are 192)
 # takes the dk/dv kernel's operands, outputs and accumulators 0.5 MiB
@@ -93,15 +117,18 @@ def _compiler_params(semantics, *head_sizes):
 
 
 class TilePlan(NamedTuple):
-    """How one grid tile of a kernel call is multiplied: ``rows[i]`` is
-    the number of sub-tiles, from the left, that sub-tile row ``i``
-    multiplies. The whole tile kept is one sub-tile: ``rows == (1,)``
-    and ``sub_q, sub_k`` the block itself."""
+    """How a kernel call spends its grid, and how one grid tile of it is
+    multiplied: ``rows[i]`` is the number of sub-tiles, from the left,
+    that sub-tile row ``i`` multiplies. The whole tile kept is one
+    sub-tile: ``rows == (1,)`` and ``sub_q, sub_k`` the block itself.
+    Over a ``grid`` of several tiles ``rows`` is the plan of the tiles
+    on the diagonal (``tiles`` counts them and the others)."""
     block_q: int
     block_k: int
     sub_q: int
     sub_k: int
     rows: Tuple[int, ...]
+    grid: Tuple[int, int] = (1, 1)
 
     @property
     def computed(self) -> int:
@@ -111,19 +138,45 @@ class TilePlan(NamedTuple):
     def total(self) -> int:
         return (self.block_q // self.sub_q) * (self.block_k // self.sub_k)
 
+    @property
+    def tiles(self) -> Tuple[int, int, int]:
+        """(multiplied whole, walked by ``rows``, skipped) grid tiles.
+        A walked grid is square with the diagonal through the corners
+        of its diagonal tiles: those are walked, the tiles below them
+        are whole and build no mask, the tiles above are skipped. A
+        grid that is not walked visits every tile whole (what its
+        traced compare predicates out is not known here)."""
+        n_q, n_k = self.grid
+        if self.rows == (1,):
+            return n_q * n_k, 0, 0
+        off_diagonal = n_q * (n_k - 1) // 2
+        return off_diagonal, n_q, off_diagonal
+
     def describe(self) -> str:
+        blocks = f"blocks {self.block_q}x{self.block_k}"
+        sub_tiles = f"sub-tiles {self.sub_q}x{self.sub_k}"
+        walked = f"{self.computed} of {self.total}"
+        if self.grid == (1, 1) or self.rows == (1,):
+            return f"{blocks}, {sub_tiles}, {walked} computed"
+        whole, diagonal, skipped = self.tiles
         return (
-            f"blocks {self.block_q}x{self.block_k}, sub-tiles "
-            f"{self.sub_q}x{self.sub_k}, {self.computed} of {self.total} "
-            "computed"
+            f"grid {self.grid[0]}x{self.grid[1]} of {blocks}: {whole} "
+            f"tile{'s' if whole != 1 else ''} whole and unmasked, "
+            f"{diagonal} diagonal tiles walked {walked} {sub_tiles}, "
+            f"{skipped} skipped"
         )
 
 
 def _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset, sub=None):
-    """The strip walk of a kernel call, or None where the whole tile is
-    kept. It needs the diagonal's place inside the tile at trace time:
-    a causal call, offsets that are Python ints (the ring's are traced),
-    one grid tile each way, and a block of at least two sub-tiles.
+    """The strip walk of a kernel call's diagonal, or None where every
+    tile is kept whole. It needs the diagonal's place inside a tile at
+    trace time: a causal call, offsets that are Python ints (the ring's
+    are traced), a block of at least two sub-tiles, and either one grid
+    tile each way (the offsets place the diagonal in it) or a square
+    grid of square tiles with equal offsets: then grid tile (qi, kt)
+    holds the diagonal iff qi == kt, from its corner, so the plan for
+    offsets (0, 0) is every diagonal tile's, the tiles below hold no
+    masked score and the tiles above no visible one.
 
     Returns, for each ``sub``-high row of sub-tiles, how many sub-tiles
     from the left the mask leaves something of: sub-tile (i, j) has an
@@ -134,11 +187,14 @@ def _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset, sub=None):
     if not (
         causal
         and isinstance(q_offset, int) and isinstance(k_offset, int)
-        and sq == block_q and sk == block_k
         and block_q % sub == 0 and block_k % sub == 0
         and block_q >= 2 * sub and block_k >= 2 * sub
     ):
         return None
+    if not _one_tile(sq, sk, block_q, block_k):
+        if not (sq == sk and block_q == block_k and q_offset == k_offset):
+            return None
+        q_offset = k_offset = 0
     n_k = block_k // sub
     return tuple(
         min(n_k, max(0, (q_offset + (i + 1) * sub - 1 - k_offset) // sub + 1))
@@ -146,18 +202,26 @@ def _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset, sub=None):
     )
 
 
+def _one_tile(sq, sk, block_q, block_k):
+    """Whether a call's grid is one tile: then a plan of ``_walk`` is
+    run by the strips kernels, else by the grid kernels' diagonal
+    steps."""
+    return sq == block_q and sk == block_k
+
+
 def tile_plan(sq, sk, causal=True, block_q=0, block_k=0, q_offset=0,
               k_offset=0, sub=None) -> TilePlan:
-    """What a kernel call with these arguments multiplies inside one
-    grid tile (the kernels ask ``_walk`` the same question): for the
-    line ``log_traced`` prints and for the tests. Traced offsets are
-    anything that is not an int."""
+    """What a kernel call with these arguments multiplies (the kernels
+    ask ``_walk`` the same question): for the line ``log_traced`` prints
+    and for the tests. Traced offsets are anything that is not an
+    int."""
     block_q, block_k = _blocks(sq, sk, block_q, block_k)
     sub = sub or SUB_TILE
+    grid = (sq // block_q, sk // block_k)
     rows = _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset, sub)
     if rows is None:
-        return TilePlan(block_q, block_k, block_q, block_k, (1,))
-    return TilePlan(block_q, block_k, sub, sub, rows)
+        return TilePlan(block_q, block_k, block_q, block_k, (1,), grid)
+    return TilePlan(block_q, block_k, sub, sub, rows, grid)
 
 
 def describe_tiles(s_len, causal=True, traced_offsets=False) -> str:
@@ -305,15 +369,22 @@ def _scratch_tile_update(q_ref, k_ref, v_ref, m_acc, l_acc, o_acc,
 
 
 def _fwd_strips_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, rows, sub,
-                       scale):
+                       scale, carried=None):
     """The forward over one grid tile whose diagonal starts at its
-    corner (``_walk`` with offsets 0, 0), one (batch*head) a grid step:
-    q strip ``i`` against the keys up to its own end, and no further.
-    A strip sees all its keys at once, so its softmax is the plain one
-    on values: the recurrence of ``_scratch_tile_update`` from an empty
-    state (m = -1e30, l = o = 0) in one step, to the same bits, without
-    stepping scratch accumulators (which costs more than the masked
-    half of the tile saves: 2.320 ms a layer against 1.836, v5e)."""
+    corner (``_walk`` with offsets 0, 0): q strip ``i`` against the keys
+    up to its own end, and no further. A strip sees all its keys of the
+    tile at once, so a row's softmax state is stepped once a tile:
+    stepping scratch accumulators once a SUB-tile costs more than the
+    masked half of the tile saves (2.320 ms a layer against 1.836, v5e).
+
+    Without ``carried`` the tile is the whole sequence, one (batch*head)
+    a grid step, and a strip's softmax the plain one on values: the
+    recurrence of ``_scratch_tile_update`` from an empty state (m =
+    -1e30, l = o = 0) in one step, to the same bits. ``carried`` =
+    (m_acc, l_acc, o_acc), the scratch state the tiles to the left have
+    left (``_fwd_grid_kernel``): the strip takes one step of that
+    recurrence from it. Either way the diagonal tile is the last that
+    contributes to its queries, so the strip is finalised here."""
     for i, n_k in enumerate(rows):
         strip, keys = pl.ds(i * sub, sub), pl.ds(0, n_k * sub)
         v_blk = v_ref[0, keys, :]
@@ -321,14 +392,56 @@ def _fwd_strips_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, rows, sub,
             q_ref[0, strip, :], k_ref[0, keys, :], i * sub, 0, True, scale
         )                              # (sub, n_k * sub)
         m = s.max(axis=1, keepdims=True)
+        if carried is not None:
+            m_acc, l_acc, o_acc = carried
+            m_prev = m_acc[strip, :]
+            m = jnp.maximum(m_prev, m)
         p = _tile_probs(s, mask, m)
-        l_safe = jnp.maximum(p.sum(axis=1, keepdims=True), 1e-30)
+        l = p.sum(axis=1, keepdims=True)
+        if carried is not None:
+            alpha = jnp.exp(m_prev - m)
+            l = l_acc[strip, :] * alpha + l
+        l_safe = jnp.maximum(l, 1e-30)
         o = jax.lax.dot_general(
             p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if carried is not None:
+            o = o_acc[strip, :] * alpha + o
         o_ref[0, strip, :] = (o / l_safe).astype(o_ref.dtype)
         l_ref[0, strip, :] = m + jnp.log(l_safe)
+
+
+def _fwd_grid_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc,
+                     o_acc, *, rows, sub, scale):
+    """One (batch*head, q-block, k-block) grid step of a call whose
+    diagonal tiles are walked (``_walk`` over several tiles): the k
+    block lies wholly below the diagonal (every score visible: the tile
+    update with no mask built), on it (the strips of ``rows``, finalised
+    there), or above it (nothing: the step is predicated out, and the
+    index maps of ``_forward_call`` name no new block for it)."""
+    qi = pl.program_id(1)
+    kb = pl.program_id(2)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_acc[:] = jnp.full_like(m_acc, _NEG_INF)
+        l_acc[:] = jnp.zeros_like(l_acc)
+        o_acc[:] = jnp.zeros_like(o_acc)
+
+    @pl.when(kb < qi)
+    def _below():
+        _scratch_tile_update(
+            q_ref, k_ref, v_ref, m_acc, l_acc, o_acc, 0, 0,
+            block_k=k_ref.shape[1], causal=False, scale=scale,
+        )
+
+    @pl.when(kb == qi)
+    def _diagonal():
+        _fwd_strips_kernel(
+            q_ref, k_ref, v_ref, o_ref, l_ref, rows=rows, sub=sub,
+            scale=scale, carried=(m_acc, l_acc, o_acc),
+        )
 
 
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
@@ -368,20 +481,14 @@ def _shared_trace(*static_argnums):
 @_shared_trace(3, 4, 5, 6, 7, 8)
 def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
                   interpret):
-    """The forward ``pallas_call``: the strip walk where ``rows`` (the
-    plan of ``_walk``) is given, else the grid of whole tiles."""
+    """The forward ``pallas_call``. ``rows`` is the plan of ``_walk``:
+    given and the sequence one tile, the strips kernel; given over a
+    grid of tiles, the grid kernel that walks the diagonal tiles; None,
+    the grid of whole tiles."""
     bh, s_len, d = q.shape
     dv = v.shape[2]
-    out_shape = [
-        jax.ShapeDtypeStruct((bh, s_len, dv), q.dtype),
-        jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
-    ]
-    cost = _cost(
-        bh, s_len, s_len, d, dv, causal=causal,
-        byte_tensors=[(2, s_len, d, q.dtype.itemsize),
-                      (2, s_len, dv, q.dtype.itemsize)],
-    )
-    if rows is not None:
+    if rows is not None and _one_tile(s_len, s_len, block_q, block_k):
+        out_shape, cost = _forward_outputs(q, v, causal)
         whole = pl.BlockSpec((1, s_len, d), lambda b: (b, 0, 0))
         whole_v = pl.BlockSpec((1, s_len, dv), lambda b: (b, 0, 0))
         return pl.pallas_call(
@@ -399,17 +506,67 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
             cost_estimate=cost,
             interpret=interpret,
         )(q, k, v)
-    grid = (bh, s_len // block_q, s_len // block_k)
-    kernel = functools.partial(
-        _fwd_kernel, block_k=block_k, causal=causal, scale=scale
+    if rows is not None:
+        kernel = functools.partial(
+            _fwd_grid_kernel, rows=rows, sub=block_q // len(rows),
+            scale=scale,
+        )
+        kv_tile = jnp.minimum
+    else:
+        kernel = functools.partial(
+            _fwd_kernel, block_k=block_k, causal=causal, scale=scale
+        )
+        kv_tile = _streamed
+    return _grid_forward(kernel, kv_tile, q, k, v, block_q, block_k, causal,
+                         interpret)
+
+
+def _forward_outputs(q, v, causal):
+    """(``out_shape``, ``cost_estimate``) of a forward ``pallas_call``:
+    o and the logsumexp, carried as (BH, S, 1)."""
+    bh, s_len, d = q.shape
+    dv = v.shape[2]
+    out_shape = [
+        jax.ShapeDtypeStruct((bh, s_len, dv), q.dtype),
+        jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
+    ]
+    cost = _cost(
+        bh, s_len, s_len, d, dv, causal=causal,
+        byte_tensors=[(2, s_len, d, q.dtype.itemsize),
+                      (2, s_len, dv, q.dtype.itemsize)],
     )
+    return out_shape, cost
+
+
+def _streamed(resident, streamed):
+    """The tile a grid step names of the operands that stream past the
+    resident block: the step's own. Where the diagonal tiles are walked
+    the steps past the diagonal are dead, and ``jnp.minimum`` (K/V past
+    a q block) or ``jnp.maximum`` (q, do, lse, delta before a k block)
+    take this function's place: a dead step then names the diagonal's
+    tile, which is resident or wanted next, and no DMA is issued for
+    it."""
+    del resident
+    return streamed
+
+
+def _grid_forward(kernel, kv_tile, q, k, v, block_q, block_k, causal,
+                  interpret):
+    """The forward ``pallas_call`` over the grid of tiles: ``kernel`` is
+    a grid step's body, ``kv_tile(i, j)`` the K/V tile that step j of q
+    block i names."""
+    bh, s_len, d = q.shape
+    dv = v.shape[2]
+    out_shape, cost = _forward_outputs(q, v, causal)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, s_len // block_q, s_len // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b, i, j: (b, kv_tile(i, j), 0)),
+            pl.BlockSpec((1, block_k, dv),
+                         lambda b, i, j: (b, kv_tile(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
@@ -596,6 +753,38 @@ def _bwd_tile_math(q, k_blk, v_blk, do, lse, delta, q_start, k_start,
     return ds, p
 
 
+def _dq_tile_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dq_acc, q_start, k_start, causal, scale):
+    """One whole K/V tile's part of dq, added to the scratch
+    accumulator."""
+    ds, _ = _bwd_tile_math(
+        q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
+        delta_ref[0], q_start, k_start, causal, scale,
+    )
+    dq_acc[:] += jax.lax.dot_general(
+        ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+
+
+def _dkv_tile_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dk_acc, dv_acc, q_start, k_start, causal, scale):
+    """One whole q/do/lse/delta tile's part of dk and dv, added to the
+    scratch accumulators."""
+    ds, p = _bwd_tile_math(
+        q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
+        delta_ref[0], q_start, k_start, causal, scale,
+    )
+    dk_acc[:] += jax.lax.dot_general(
+        ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    dv_acc[:] += jax.lax.dot_general(
+        p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                delta_ref, dq_ref, dq_acc, *, block_k: int, causal: bool,
                scale: float):
@@ -613,14 +802,10 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def _compute():
-        ds, _ = _bwd_tile_math(
-            q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-            delta_ref[0], q_start, k_start, causal, scale,
+        _dq_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc,
+            q_start, k_start, causal, scale,
         )
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
 
     if causal:
         pl.when(q_start + block_q - 1 >= k_start)(_compute)
@@ -650,17 +835,9 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def _compute():
-        ds, p = _bwd_tile_math(
-            q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-            delta_ref[0], q_start, k_start, causal, scale,
-        )
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        _dkv_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
+            dv_acc, q_start, k_start, causal, scale,
         )
 
     if causal:
@@ -674,16 +851,28 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_acc[:]
 
 
+def _strip_of(ref, strip):
+    """The index of a strip of rows in a kernel's (1, rows, width)
+    block, or in a (rows, width) scratch accumulator."""
+    return (0, strip, slice(None)) if len(ref.shape) == 3 else (
+        strip, slice(None))
+
+
 def _dq_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, *, rows, sub, q_offset, k_offset, scale):
+                      dq_ref, *, rows, sub, q_offset, k_offset, scale,
+                      dq_acc=None):
     """dq over one grid tile walked by ``_walk``: q strip ``i`` against
     the keys its row of the plan reaches; a strip wholly above the
-    diagonal gets zeros. Each strip is written once, so no scratch."""
+    diagonal gets zeros. Each strip is written once, so no scratch of
+    its own; ``dq_acc`` is what the tiles to the left have summed
+    (``_dq_grid_kernel``: the diagonal tile is the last that
+    contributes, so the strip is flushed with its own part added)."""
     for i, n_k in enumerate(rows):
         strip, keys = pl.ds(i * sub, sub), pl.ds(0, n_k * sub)
         if not n_k:
-            dq_ref[0, strip, :] = jnp.zeros((sub, dq_ref.shape[2]),
-                                            dq_ref.dtype)
+            dq_ref[0, strip, :] = (
+                jnp.zeros((sub, dq_ref.shape[2]), dq_ref.dtype)
+                if dq_acc is None else dq_acc[strip, :])
             continue
         k_blk = k_ref[0, keys, :]
         q_start = q_offset + i * sub
@@ -693,10 +882,11 @@ def _dq_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             delta_ref[0, strip, :], q_start, k_offset,
             _diagonal_crosses(q_start, k_offset, n_k * sub), scale,
         )
-        dq_ref[0, strip, :] = jax.lax.dot_general(
+        dq = jax.lax.dot_general(
             ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
+        dq_ref[0, strip, :] = dq if dq_acc is None else dq_acc[strip, :] + dq
 
 
 def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -704,16 +894,20 @@ def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        scale):
     """dk/dv over one grid tile walked by ``_walk``: k strip ``j``
     against the queries from the first row whose plan reaches it to the
-    tile's end; a strip no query sees gets zeros."""
+    tile's end; a strip no query sees gets zeros. ``dk_ref, dv_ref``:
+    the output blocks, or the scratch accumulators of
+    ``_dkv_grid_kernel`` (the diagonal tile is the first that
+    contributes to its keys, so it sets them)."""
     block_q = q_ref.shape[1]
     for j in range(k_ref.shape[1] // sub):
         strip = pl.ds(j * sub, sub)
+        dk_strip, dv_strip = _strip_of(dk_ref, strip), _strip_of(dv_ref, strip)
         first = next((i for i, n_k in enumerate(rows) if n_k > j), None)
         if first is None:
-            dk_ref[0, strip, :] = jnp.zeros((sub, dk_ref.shape[2]),
-                                            dk_ref.dtype)
-            dv_ref[0, strip, :] = jnp.zeros((sub, dv_ref.shape[2]),
-                                            dv_ref.dtype)
+            dk_ref[dk_strip] = jnp.zeros((sub, dk_ref.shape[-1]),
+                                         dk_ref.dtype)
+            dv_ref[dv_strip] = jnp.zeros((sub, dv_ref.shape[-1]),
+                                         dv_ref.dtype)
             continue
         queries = pl.ds(first * sub, block_q - first * sub)
         q, do = q_ref[0, queries, :], do_ref[0, queries, :]
@@ -723,14 +917,78 @@ def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             lse_ref[0, queries, :], delta_ref[0, queries, :], q_start,
             k_start, _diagonal_crosses(q_start, k_start, sub), scale,
         )
-        dk_ref[0, strip, :] = jax.lax.dot_general(
+        dk_ref[dk_strip] = jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
-        dv_ref[0, strip, :] = jax.lax.dot_general(
+        dv_ref[dv_strip] = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+
+def _dq_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, dq_ref, dq_acc, *, rows, sub,
+                    scale):
+    """``_dq_kernel`` where the diagonal tiles are walked (``_walk``
+    over several tiles; the offsets are equal and do not enter): a k
+    tile below the diagonal adds its part with no mask built, the
+    diagonal tile's strips add theirs and flush, the tiles above are
+    dead steps."""
+    del qoff_ref, koff_ref
+    qi = pl.program_id(1)
+    kt = pl.program_id(2)
+
+    @pl.when(kt == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(kt < qi)
+    def _below():
+        _dq_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, 0, 0,
+            False, scale,
+        )
+
+    @pl.when(kt == qi)
+    def _diagonal():
+        _dq_strips_kernel(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+            rows=rows, sub=sub, q_offset=0, k_offset=0, scale=scale,
+            dq_acc=dq_acc,
+        )
+
+
+def _dkv_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                     *, rows, sub, scale):
+    """``_dkv_kernel`` where the diagonal tiles are walked: the q tiles
+    before the diagonal are dead steps, the diagonal tile's strips set
+    the accumulators, a q tile below the diagonal adds its part with no
+    mask built."""
+    del qoff_ref, koff_ref
+    ki = pl.program_id(1)
+    qt = pl.program_id(2)
+
+    @pl.when(qt == ki)
+    def _diagonal():
+        _dkv_strips_kernel(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
+            dv_acc, rows=rows, sub=sub, q_offset=0, k_offset=0,
+            scale=scale,
+        )
+
+    @pl.when(qt > ki)
+    def _below():
+        _dkv_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
+            dv_acc, 0, 0, False, scale,
+        )
+
+    @pl.when(qt == pl.num_programs(2) - 1)
+    def _flush():
+        dk_ref[0] = dk_acc[:]
+        dv_ref[0] = dv_acc[:]
 
 
 def _grads_costs(q, v_chunk, causal):
@@ -788,21 +1046,47 @@ def _strips_grads(q, k_chunk, v_chunk, do, lse, delta, rows, q_offset,
     return dq, dk, dv
 
 
-@_shared_trace(8, 9, 10, 11, 12)
+@_shared_trace(8, 9, 10, 11, 12, 13)
 def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
-                 causal, scale, block_q, block_k, interpret):
-    """``flash_chunk_grads`` over the grid of whole tiles: the offsets
-    are traced (scalar-prefetched), a tile strictly above the diagonal
-    is predicated out."""
+                 causal, scale, block_q, block_k, rows, interpret):
+    """``flash_chunk_grads`` over a grid of tiles. ``rows`` None: whole
+    tiles, the offsets traced (scalar-prefetched), a tile strictly above
+    the diagonal predicated out. ``rows`` the plan of ``_walk`` over
+    several tiles: the diagonal tiles walked, dead steps naming the
+    diagonal's tiles."""
+    if rows is None:
+        common = dict(causal=causal, scale=scale)
+        dq_kernel = functools.partial(_dq_kernel, block_k=block_k, **common)
+        dkv_kernel = functools.partial(_dkv_kernel, block_q=block_q,
+                                       **common)
+        kv_tile = q_tile = _streamed
+    else:
+        common = dict(rows=rows, sub=block_q // len(rows), scale=scale)
+        dq_kernel = functools.partial(_dq_grid_kernel, **common)
+        dkv_kernel = functools.partial(_dkv_grid_kernel, **common)
+        kv_tile, q_tile = jnp.minimum, jnp.maximum
+    return _grid_grads(
+        dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk, v_chunk, do,
+        lse, delta, q_offset, k_offset, block_q, block_k, causal,
+        interpret,
+    )
+
+
+def _grid_grads(dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk,
+                v_chunk, do, lse, delta, q_offset, k_offset, block_q,
+                block_k, causal, interpret):
+    """The two backward ``pallas_call``s over the grid of tiles:
+    ``kv_tile(i, j)`` is the K/V tile that step j of q block i names in
+    the dq kernel, ``q_tile(i, j)`` the q/do/lse/delta tile that step j
+    of k block i names in the dk/dv kernel."""
     bh, sq, d = q.shape
     sk, dv = v_chunk.shape[1:]
     dq_cost, dkv_cost = _grads_costs(q, v_chunk, causal)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape((1,))
     koff = jnp.asarray(k_offset, jnp.int32).reshape((1,))
-    common = dict(causal=causal, scale=scale)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=block_k, **common),
+        dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, sq // block_q, sk // block_k),
@@ -810,9 +1094,9 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
                 pl.BlockSpec((1, block_q, d),
                              lambda b, i, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, block_k, d),
-                             lambda b, i, j, *_: (b, j, 0)),
+                             lambda b, i, j, *_: (b, kv_tile(i, j), 0)),
                 pl.BlockSpec((1, block_k, dv),
-                             lambda b, i, j, *_: (b, j, 0)),
+                             lambda b, i, j, *_: (b, kv_tile(i, j), 0)),
                 pl.BlockSpec((1, block_q, dv),
                              lambda b, i, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1),
@@ -833,23 +1117,23 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
     )(qoff, koff, q, k_chunk, v_chunk, do, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, **common),
+        dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, sk // block_k, sq // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d),
-                             lambda b, i, j, *_: (b, j, 0)),
+                             lambda b, i, j, *_: (b, q_tile(i, j), 0)),
                 pl.BlockSpec((1, block_k, d),
                              lambda b, i, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, block_k, dv),
                              lambda b, i, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, block_q, dv),
-                             lambda b, i, j, *_: (b, j, 0)),
+                             lambda b, i, j, *_: (b, q_tile(i, j), 0)),
                 pl.BlockSpec((1, block_q, 1),
-                             lambda b, i, j, *_: (b, j, 0)),
+                             lambda b, i, j, *_: (b, q_tile(i, j), 0)),
                 pl.BlockSpec((1, block_q, 1),
-                             lambda b, i, j, *_: (b, j, 0)),
+                             lambda b, i, j, *_: (b, q_tile(i, j), 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_k, d),
@@ -900,14 +1184,14 @@ def flash_chunk_grads(
             f"blocks ({block_q}, {block_k})"
         )
     rows = _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset)
-    if rows is not None:
+    if rows is not None and _one_tile(sq, sk, block_q, block_k):
         return _strips_grads(
             q, k_chunk, v_chunk, do, lse, delta, rows, q_offset, k_offset,
             float(scale), interpret,
         )
     return _tiles_grads(
         q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset, causal,
-        float(scale), block_q, block_k, interpret,
+        float(scale), block_q, block_k, rows, interpret,
     )
 
 

@@ -1,6 +1,6 @@
 """Flash attention (Pallas interpret mode) vs dense reference: values,
-gradients, causal block skipping and the strip walk inside one grid
-tile, bf16."""
+gradients, causal block skipping, the strip walk inside one grid tile
+and in the diagonal tiles of a grid of several, bf16."""
 
 import logging
 
@@ -158,16 +158,66 @@ def test_plan_counts(block, sub, computed, total):
      ("a block under two sub-tiles", dict(sq=256, sk=256)),
      ("explicit small blocks", dict(sq=1024, sk=1024, block_q=256,
                                     block_k=256)),
-     ("several grid tiles", dict(sq=2048, sk=2048)),
      ("traced offsets", dict(sq=1024, sk=1024, q_offset=jnp.int32(0),
-                             k_offset=jnp.int32(0)))],
+                             k_offset=jnp.int32(0))),
+     # Several grid tiles whose diagonal the trace cannot place.
+     ("several tiles, non-causal", dict(sq=2048, sk=2048, causal=False)),
+     ("several tiles, traced offsets",
+      dict(sq=2048, sk=2048, q_offset=jnp.int32(0), k_offset=jnp.int32(0))),
+     ("several tiles, unequal blocks",
+      dict(sq=2048, sk=2048, block_q=1024, block_k=512)),
+     ("several tiles, unequal offsets",
+      dict(sq=2048, sk=2048, q_offset=2048, k_offset=0)),
+     ("several tiles, unequal lengths", dict(sq=2048, sk=4096)),
+     ("several tiles, a block under two sub-tiles",
+      dict(sq=1024, sk=1024, block_q=256, block_k=256)),
+     ("several tiles, a block of no whole sub-tiles",
+      dict(sq=3584, sk=3584))],
 )
 def test_plan_keeps_the_whole_tile(why, kwargs):
     plan = flash.tile_plan(**kwargs)
     assert plan.rows == (1,) and (plan.computed, plan.total) == (1, 1), why
     assert (plan.sub_q, plan.sub_k) == (plan.block_q, plan.block_k)
-    assert plan.describe().endswith(
-        f"sub-tiles {plan.block_q}x{plan.block_k}, 1 of 1 computed")
+    assert plan.tiles == (plan.grid[0] * plan.grid[1], 0, 0), why
+    assert plan.describe() == (
+        f"blocks {plan.block_q}x{plan.block_k}, sub-tiles "
+        f"{plan.block_q}x{plan.block_k}, 1 of 1 computed")
+
+
+@pytest.mark.parametrize(
+    "s,kwargs,grid,block,sub,computed,total,tiles,line",
+    [(2048, {}, 2, 1024, 256, 10, 16, (1, 2, 1),
+      "grid 2x2 of blocks 1024x1024: 1 tile whole and unmasked, 2 "
+      "diagonal tiles walked 10 of 16 sub-tiles 256x256, 1 skipped"),
+     (4096, {}, 4, 1024, 256, 10, 16, (6, 4, 6),
+      "grid 4x4 of blocks 1024x1024: 6 tiles whole and unmasked, 4 "
+      "diagonal tiles walked 10 of 16 sub-tiles 256x256, 6 skipped"),
+     (1536, {}, 2, 768, 256, 6, 9, (1, 2, 1),
+      "grid 2x2 of blocks 768x768: 1 tile whole and unmasked, 2 "
+      "diagonal tiles walked 6 of 9 sub-tiles 256x256, 1 skipped"),
+     (1536, dict(block_q=512, block_k=512), 3, 512, 256, 3, 4, (3, 3, 3),
+      "grid 3x3 of blocks 512x512: 3 tiles whole and unmasked, 3 "
+      "diagonal tiles walked 3 of 4 sub-tiles 256x256, 3 skipped"),
+     (4096, dict(sub=512, q_offset=4096, k_offset=4096), 4, 1024, 512, 3,
+      4, (6, 4, 6),
+      "grid 4x4 of blocks 1024x1024: 6 tiles whole and unmasked, 4 "
+      "diagonal tiles walked 3 of 4 sub-tiles 512x512, 6 skipped")],
+)
+def test_plan_walks_the_diagonal_tiles_of_a_grid(s, kwargs, grid, block,
+                                                 sub, computed, total,
+                                                 tiles, line):
+    """Several grid tiles, square, equal offsets known at trace time:
+    tile (qi, kt) holds the diagonal iff qi == kt, from its corner, so
+    every diagonal tile takes the single tile's plan, the tiles below
+    are whole with no mask and the tiles above are skipped."""
+    plan = flash.tile_plan(s, s, **kwargs)
+    assert plan.grid == (grid, grid)
+    assert (plan.block_q, plan.block_k) == (block, block)
+    assert (plan.sub_q, plan.sub_k) == (sub, sub)
+    assert plan.rows == flash.tile_plan(block, block, sub=sub).rows
+    assert (plan.computed, plan.total) == (computed, total)
+    assert plan.tiles == tiles and sum(tiles) == grid * grid
+    assert plan.describe() == line
 
 
 # (q_offset, k_offset) of a 64-long q block against a 64-long chunk,
@@ -230,12 +280,9 @@ def test_chunk_grads_strips_are_the_whole_tile(monkeypatch, where):
 
 
 def _value_and_grads(q, k, v):
-    def loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True, interpret=True)
-        return jnp.sum(out * jnp.cos(out)), out
-
-    grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-    return (out, *grads)
+    return _attend_and_grads(
+        q, k, v, lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=True))
 
 
 @pytest.mark.parametrize("d", [64, 128])
@@ -309,13 +356,156 @@ def test_sub_tiled_block_matches_dense(monkeypatch, causal, s, block, edge):
                                    rtol=2e-4, atol=2e-5)
 
 
+def _attend_and_grads(q, k, v, attend):
+    def loss(q, k, v):
+        out = attend(q, k, v)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return (out, *grads)
+
+
+@pytest.mark.parametrize("heads", [(16, 16), (24, 16)],
+                         ids=["equal_heads", "wider_qk"])
+@pytest.mark.parametrize("sub_tiles", [2, 3, 4])
+@pytest.mark.parametrize("grid", [2, 3, 4])
+def test_grid_walk_matches_dense_and_the_whole_tiles(monkeypatch, grid,
+                                                     sub_tiles, heads):
+    """A grid of several tiles whose diagonal tiles are walked in strips
+    (the tiles below them unmasked, the steps above them dead, naming
+    the diagonal's blocks): forward, dq, dk, dv are the dense
+    reference's, and those of the same call with the walk switched off
+    (a sub-tile as large as the block: whole tiles, masked where the
+    traced compare says), to the CPU dot's last bits."""
+    sub = 8
+    block = sub_tiles * sub
+    s = grid * block
+    d, dv = heads
+    rng = np.random.RandomState(100 * grid + 10 * sub_tiles + d)
+    q, k = (jnp.asarray(rng.randn(2, s, 2, d), jnp.float32) * 0.3
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(2, s, 2, dv), jnp.float32) * 0.3
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block,
+                               block_k=block, interpret=True)
+
+    monkeypatch.setattr(flash, "SUB_TILE", sub)
+    plan = flash.tile_plan(s, s, block_q=block, block_k=block)
+    assert plan.grid == (grid, grid)
+    assert plan.tiles == (grid * (grid - 1) // 2, grid, grid * (grid - 1) // 2)
+    assert (plan.computed, plan.total) == (
+        sub_tiles * (sub_tiles + 1) // 2, sub_tiles ** 2)
+    walked = _attend_and_grads(q, k, v, kernels)
+    monkeypatch.setattr(flash, "SUB_TILE", block)
+    assert flash.tile_plan(s, s, block_q=block, block_k=block).rows == (1,)
+    whole = _attend_and_grads(q, k, v, kernels)
+    dense = _attend_and_grads(
+        q, k, v, lambda q, k, v: dense_attention(q, k, v, causal=True))
+    for got, same, want, name in zip(walked, whole, dense,
+                                     ("o", "dq", "dk", "dv")):
+        got, same, want = (np.asarray(x) for x in (got, same, want))
+        assert got.shape == want.shape
+        np.testing.assert_allclose(
+            got, want, rtol=2e-5 if name == "o" else 2e-4, atol=2e-5,
+            err_msg=f"{name} against dense")
+        np.testing.assert_allclose(
+            got, same, rtol=0, atol=1e-6 * np.abs(same).max(),
+            err_msg=f"{name} against the whole tiles")
+
+
+def _count_traces(monkeypatch, *names):
+    """How often each named kernel body of the module is traced from
+    here on."""
+    counts = {}
+    for name in names:
+        def counting(*args, _real=getattr(flash, name), _name=name, **kw):
+            counts[_name] = counts.get(_name, 0) + 1
+            return _real(*args, **kw)
+
+        monkeypatch.setattr(flash, name, counting)
+    return counts
+
+
+GRID_KERNELS = ("_fwd_grid_kernel", "_dq_grid_kernel", "_dkv_grid_kernel")
+
+
+@pytest.mark.parametrize(
+    "why,kwargs",
+    [("non-causal", dict(causal=False)),
+     ("unequal blocks", dict(block_q=24, block_k=12)),
+     ("a block under two sub-tiles", dict(block_q=6, block_k=6))],
+)
+def test_a_call_the_walk_cannot_place_takes_whole_tiles(monkeypatch, why,
+                                                        kwargs):
+    """Several grid tiles, but no diagonal known through their corners:
+    the plan says whole tiles, the grid kernels that walk are not
+    traced, and the answer is the dense reference's."""
+    monkeypatch.setattr(flash, "SUB_TILE", 6)
+    counts = _count_traces(monkeypatch, *GRID_KERNELS)
+    kwargs = dict(dict(causal=True, block_q=24, block_k=24), **kwargs)
+    s = 48
+    assert flash.tile_plan(s, s, **kwargs).rows == (1,), why
+    rng = np.random.RandomState(17)
+    q, k, v = (jnp.asarray(rng.randn(1, s, 3, 20), jnp.float32) * 0.3
+               for _ in range(3))
+    got = _attend_and_grads(
+        q, k, v, lambda q, k, v: flash_attention(
+            q, k, v, interpret=True, **kwargs))
+    want = _attend_and_grads(
+        q, k, v, lambda q, k, v: dense_attention(
+            q, k, v, causal=kwargs["causal"]))
+    assert counts == {}, why
+    for a, b, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
+            err_msg=name)
+
+
+@pytest.mark.parametrize(
+    "why,offsets,walked",
+    [("equal ints", (96, 96), True),
+     ("traced offsets", (jnp.int32(96), jnp.int32(96)), False),
+     ("q_offset != k_offset", (96, 48), False)],
+)
+def test_chunk_grads_walk_a_grid_only_on_equal_int_offsets(monkeypatch, why,
+                                                           offsets, walked):
+    """``flash_chunk_grads`` over a 2x2 grid: Python-int offsets that
+    are equal put the diagonal through the corners of the diagonal
+    tiles, and those are walked; traced or unequal offsets keep whole
+    tiles under the traced compare. One answer either way."""
+    monkeypatch.setattr(flash, "SUB_TILE", 12)
+    counts = _count_traces(monkeypatch, "_dq_grid_kernel",
+                           "_dkv_grid_kernel")
+    ops = _chunk_operands(bh=2, n=96, d=20, seed=23)
+    plan = flash.tile_plan(96, 96, block_q=48, block_k=48,
+                           q_offset=offsets[0], k_offset=offsets[1])
+    assert plan.grid == (2, 2) and (plan.rows != (1,)) == walked, why
+    got = flash.flash_chunk_grads(
+        *ops, *offsets, causal=True, block_q=48, block_k=48,
+        interpret=True)
+    assert counts == (
+        {"_dq_grid_kernel": 1, "_dkv_grid_kernel": 1} if walked else {}
+    ), why
+    # The same pairing with the walk switched off.
+    monkeypatch.setattr(flash, "SUB_TILE", 48)
+    want = flash.flash_chunk_grads(
+        *ops, *(jnp.int32(o) for o in offsets), causal=True, block_q=48,
+        block_k=48, interpret=True)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6,
+            err_msg=name)
+
+
 def test_s2048_keeps_its_old_results():
-    """Several grid tiles: the diagonal is traced, the whole tile is
-    kept, and the answer is the dense reference's as before."""
+    """Several grid tiles at the default blocks: the diagonal tiles are
+    walked now (1 tile whole, 2 walked, 1 skipped), and the answer is
+    the dense reference's as before."""
     rng = np.random.RandomState(3)
     q, k, v = (jnp.asarray(rng.randn(1, 2048, 1, 64), jnp.float32) * 0.3
                for _ in range(3))
-    assert flash.tile_plan(2048, 2048).total == 1
+    assert flash.tile_plan(2048, 2048).tiles == (1, 2, 1)
     got = flash_attention(q, k, v, causal=True, interpret=True)
     want = dense_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -329,6 +519,14 @@ def test_s2048_keeps_its_old_results():
      ((8, 512, 16, 64), {},
       "blocks 512x512, sub-tiles 256x256, 3 of 4 computed"),
      ((2, 2048, 16, 64), {},
+      "grid 2x2 of blocks 1024x1024: 1 tile whole and unmasked, 2 "
+      "diagonal tiles walked 10 of 16 sub-tiles 256x256, 1 skipped"),
+     ((4, 4096, 32, 192), {},
+      "grid 4x4 of blocks 1024x1024: 6 tiles whole and unmasked, 4 "
+      "diagonal tiles walked 10 of 16 sub-tiles 256x256, 6 skipped"),
+     ((2, 2048, 16, 64), {"causal": False},
+      "blocks 1024x1024, sub-tiles 1024x1024, 1 of 1 computed"),
+     ((2, 4096, 16, 64), {"traced_offsets": True},
       "blocks 1024x1024, sub-tiles 1024x1024, 1 of 1 computed"),
      ((8, 1024, 16, 64), {"causal": False},
       "blocks 1024x1024, sub-tiles 1024x1024, 1 of 1 computed"),
@@ -353,34 +551,37 @@ def test_log_line_states_the_sub_tiles(q_shape, kwargs, tail):
     ]
 
 
-def test_layers_share_one_trace_of_each_kernel(monkeypatch):
+@pytest.mark.parametrize(
+    "s,block,kernels",
+    [(80, 80, ("_fwd_strips_kernel", "_dq_strips_kernel",
+               "_dkv_strips_kernel")),
+     (96, 32, GRID_KERNELS)],
+    ids=["one_tile", "grid_of_tiles"],
+)
+def test_layers_share_one_trace_of_each_kernel(monkeypatch, s, block,
+                                               kernels):
     """A model's layers call the kernels with one set of shapes: the
     kernel bodies are traced once for all of them, under ``eval_shape``
     (the worker's state_init) and again under ``grad`` of a ``jit``
-    (its first program), not once a layer. A shape no other test uses,
-    so the process's trace cache is cold for it."""
-    counts = {}
-    for name in ("_fwd_strips_kernel", "_dq_strips_kernel",
-                 "_dkv_strips_kernel"):
-        def counting(*args, _real=getattr(flash, name), _name=name, **kw):
-            counts[_name] = counts.get(_name, 0) + 1
-            return _real(*args, **kw)
-
-        monkeypatch.setattr(flash, name, counting)
+    (its first program), not once a layer; the strips kernels of one
+    grid tile and the grid kernels that walk the diagonal tiles alike.
+    Shapes no other test uses, so the process's trace cache is cold for
+    them."""
+    counts = _count_traces(monkeypatch, *kernels)
     monkeypatch.setattr(flash, "SUB_TILE", 16)
+    assert flash.tile_plan(s, s, block_q=block, block_k=block).rows != (1,)
     rng = np.random.RandomState(9)
-    q, k, v = (jnp.asarray(rng.randn(1, 80, 3, 24), jnp.float32) * 0.3
+    q, k, v = (jnp.asarray(rng.randn(1, s, 3, 24), jnp.float32) * 0.3
                for _ in range(3))
 
     def layers(q, k, v):
         x = q
         for _ in range(3):
-            x = flash_attention(x, k, v, causal=True, block_q=80,
-                                block_k=80, interpret=True)
+            x = flash_attention(x, k, v, causal=True, block_q=block,
+                                block_k=block, interpret=True)
         return jnp.sum(x * x)
 
     jax.eval_shape(layers, q, k, v)
-    assert counts == {"_fwd_strips_kernel": 1}
+    assert counts == {kernels[0]: 1}
     jax.jit(jax.grad(layers, argnums=(0, 1, 2)))(q, k, v)
-    assert counts == {"_fwd_strips_kernel": 1, "_dq_strips_kernel": 1,
-                      "_dkv_strips_kernel": 1}
+    assert counts == {name: 1 for name in kernels}

@@ -408,15 +408,23 @@ def _narrow_v(seed, s=64, d=24, dv=16):
 
 
 # Blocks of 16: the grid of whole tiles; one 64 block: the strip walk
-# inside a grid tile (``SUB_TILE`` is lowered for it).
-@pytest.mark.parametrize("blocks,sub", [((16, 16), None), ((32, 16), None),
-                                        ((64, 64), 16)])
-def test_flash_with_a_narrower_v_head_matches_dense(blocks, sub,
+# inside a grid tile (``SUB_TILE`` is lowered for it); blocks of 32 and
+# 16 over sub-tiles of 16 and 8: grids of 2 x 2 and 4 x 4 tiles whose
+# diagonal tiles are walked (the benchmark cell's S = 4,096 is the
+# latter at 1024 over 256).
+@pytest.mark.parametrize(
+    "blocks,sub,tiles",
+    [((16, 16), None, (16, 0, 0)), ((32, 16), None, (8, 0, 0)),
+     ((64, 64), 16, (0, 1, 0)), ((32, 32), 16, (1, 2, 1)),
+     ((16, 16), 8, (6, 4, 6))])
+def test_flash_with_a_narrower_v_head_matches_dense(blocks, sub, tiles,
                                                     monkeypatch):
     from elasticdl_tpu.ops import flash_attention as flash
 
     if sub:
         monkeypatch.setattr(flash, "SUB_TILE", sub)
+    assert flash.tile_plan(64, 64, block_q=blocks[0],
+                           block_k=blocks[1]).tiles == tiles
     q, k, v = _narrow_v(4)
     scale = q.shape[-1] ** -0.5
 
@@ -442,6 +450,43 @@ def test_flash_with_a_narrower_v_head_matches_dense(blocks, sub,
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5,
                                    err_msg=f"d{name}")
+
+
+def test_attention_line_reports_how_the_cells_grid_is_spent(monkeypatch):
+    """The worker's attention line for the benchmark cell's shape (4 x
+    4,096 tokens, 32 heads of 192 over 128): the head sizes, then the
+    grid's spending as ``tile_plan`` counts it."""
+    import logging
+
+    from elasticdl_tpu.models import mla_moe
+    from elasticdl_tpu.ops import flash_attention as flash
+
+    cfg = program_config(
+        hidden_size=64, num_heads=32, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128)
+    plan = flash.tile_plan(4096, 4096)
+    assert plan.tiles == (6, 4, 6)
+    assert (plan.computed, plan.total) == (10, 16)
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda record: records.append(record.getMessage())
+    flash.logger.addHandler(handler)
+    flash.log_traced.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        x = jax.ShapeDtypeStruct((4, 4096, cfg.hidden_size), jnp.float32)
+        jax.eval_shape(
+            lambda x: mla_moe.LatentAttention(cfg).init(
+                jax.random.PRNGKey(0), x), x)
+    finally:
+        flash.logger.removeHandler(handler)
+        flash.log_traced.cache_clear()
+    assert records == [
+        "attention: traced pallas flash kernel for q(4, 4096, 32, 192): tpu "
+        "backend, shape tiles the kernel blocks; head sizes q/k 192, v 128; "
+        "grid 4x4 of blocks 1024x1024: 6 tiles whole and unmasked, 4 "
+        "diagonal tiles walked 10 of 16 sub-tiles 256x256, 6 skipped"
+    ]
 
 
 def test_wide_heads_raise_the_kernels_vmem_limit_and_others_do_not():

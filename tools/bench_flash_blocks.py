@@ -2,6 +2,7 @@
 cost the host at start-up. Chip only.
 
 Usage:  python tools/bench_flash_blocks.py [B] [H] [S] [D] [--layers N]
+                                            [--only sweep grid startup]
 
 The default shape is DERIVED from ``bench_suite`` (the d512 flagship's
 batch + the zoo's ``WIDTHS`` head geometry), so the sweep cannot drift
@@ -22,6 +23,18 @@ One JSON line per row:
   them). ``kernel_model_flops_frac_of_peak`` is the required work
   (``ops/flash_attention._cost``'s convention: 2 matmuls a kernel,
   causal half) over the three kernels' time, over the bf16 peak.
+- **grid rows** (``"row": "grid"``), where the call has several grid
+  tiles whose diagonal tiles are walked (S over one block: 2,048,
+  4,096): what each part of that walk buys, the three kernels built
+  from ``ops.flash_attention``'s own kernel functions and called on
+  (B*H, S, D) operands: ``today`` the grid of whole tiles under the
+  traced compare (what ran before PR 28); ``1`` strips in the diagonal
+  tiles; ``1+2`` and no mask built in the tiles below them; ``1+2+3``
+  and the dead steps' index maps naming the diagonal's tile (what
+  ``flash_attention`` runs; a last row, ``shipped``, is that call
+  itself). ``same_bits_as_today`` says whether o, lse, dq, dk, dv are.
+  ``tools/bench_mla_moe_parts.py attention`` prints the same rows at
+  latent attention's head sizes.
 - **start-up rows** (``"row": "startup"``) — ``--layers`` (24) causal
   layers forward and backward in one ``jit``: seconds to trace, to
   lower, and to load or compile (``cache_entries_written`` 0 = loaded
@@ -49,6 +62,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+ROWS = ("sweep", "grid", "startup")
 SUB_TILES = (512, 256, 128)
 GRID_TILES = ((512, 512), (256, 256))
 RUNS = 8
@@ -114,6 +128,165 @@ def device_ms(compiled, args, names):
     )
 
 
+GRID_PARTS = ("today", "1", "1+2", "1+2+3", "shipped")
+
+
+def grid_kernels(flash, part, rows, scale, block):
+    """fn(q, k, v, do) -> (o, lse, dq, dk, dv) on (B*H, S, D) operands:
+    the forward, dq and dk/dv kernels of a causal call over a square
+    grid of ``block`` tiles, as ``part`` of GRID_PARTS builds them from
+    the module's kernel functions. Part 1 alone keeps today's tile
+    below the diagonal: masked by a compare of iotas that every score of
+    it passes."""
+    import functools
+
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    sub = block // len(rows)
+
+    def fwd_1(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc, o_acc):
+        qi, kb = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(kb == 0)
+        def _init():
+            m_acc[:] = jnp.full_like(m_acc, flash._NEG_INF)
+            l_acc[:] = jnp.zeros_like(l_acc)
+            o_acc[:] = jnp.zeros_like(o_acc)
+
+        @pl.when(kb < qi)
+        def _below():
+            flash._scratch_tile_update(
+                q_ref, k_ref, v_ref, m_acc, l_acc, o_acc, qi * block,
+                kb * block, block_k=block, causal=True, scale=scale)
+
+        @pl.when(kb == qi)
+        def _diagonal():
+            flash._fwd_strips_kernel(
+                q_ref, k_ref, v_ref, o_ref, l_ref, rows=rows, sub=sub,
+                scale=scale, carried=(m_acc, l_acc, o_acc))
+
+    def dq_1(qoff, koff, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+             dq_ref, dq_acc):
+        qi, kt = pl.program_id(1), pl.program_id(2)
+        refs = (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref)
+
+        @pl.when(kt == 0)
+        def _init():
+            dq_acc[:] = jnp.zeros_like(dq_acc)
+
+        @pl.when(kt < qi)
+        def _below():
+            flash._dq_tile_update(*refs, dq_acc, qi * block, kt * block,
+                                  True, scale)
+
+        @pl.when(kt == qi)
+        def _diagonal():
+            flash._dq_strips_kernel(
+                *refs, dq_ref, rows=rows, sub=sub, q_offset=0, k_offset=0,
+                scale=scale, dq_acc=dq_acc)
+
+    def dkv_1(qoff, koff, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+              dk_ref, dv_ref, dk_acc, dv_acc):
+        ki, qt = pl.program_id(1), pl.program_id(2)
+        refs = (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref)
+
+        @pl.when(qt == ki)
+        def _diagonal():
+            flash._dkv_strips_kernel(
+                *refs, dk_acc, dv_acc, rows=rows, sub=sub, q_offset=0,
+                k_offset=0, scale=scale)
+
+        @pl.when(qt > ki)
+        def _below():
+            flash._dkv_tile_update(*refs, dk_acc, dv_acc, qt * block,
+                                   ki * block, True, scale)
+
+        @pl.when(qt == pl.num_programs(2) - 1)
+        def _flush():
+            dk_ref[0] = dk_acc[:]
+            dv_ref[0] = dv_acc[:]
+
+    walked = dict(rows=rows, sub=sub, scale=scale)
+    fwd_kernel, dq_kernel, dkv_kernel = {
+        "1": (fwd_1, dq_1, dkv_1),
+    }.get(part, tuple(
+        functools.partial(kernel, **walked) for kernel in (
+            flash._fwd_grid_kernel, flash._dq_grid_kernel,
+            flash._dkv_grid_kernel)))
+    clamped = part == "1+2+3"
+    kv_tile = jnp.minimum if clamped else flash._streamed
+    q_tile = jnp.maximum if clamped else flash._streamed
+
+    module = part in ("today", "shipped")
+    plan = rows if part == "shipped" else None
+
+    def kernels(q, k, v, do):
+        if module:
+            o, lse = flash._forward_call(q, k, v, True, scale, block, block,
+                                         plan, False)
+        else:
+            o, lse = flash._grid_forward(fwd_kernel, kv_tile, q, k, v, block,
+                                         block, True, False)
+        delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+            axis=-1, keepdims=True)
+        if module:
+            grads = flash._tiles_grads(q, k, v, do, lse, delta, 0, 0, True,
+                                       scale, block, block, plan, False)
+        else:
+            grads = flash._grid_grads(
+                dq_kernel, dkv_kernel, kv_tile, q_tile, q, k, v, do, lse,
+                delta, 0, 0, block, block, True, False)
+        return (o, lse, *grads)
+
+    return kernels
+
+
+def grid_rows(flash, shape_name, bh, s, d, dv):
+    """One ``"row": "grid"`` line for each of GRID_PARTS at this shape,
+    or nothing where the call's diagonal tiles are not walked."""
+    import jax
+    import jax.numpy as jnp
+
+    plan = flash.tile_plan(s, s)
+    if plan.grid == (1, 1) or plan.rows == (1,):
+        return
+    rng = np.random.RandomState(1)
+    q, k = (jnp.asarray(rng.randn(bh, s, d), jnp.bfloat16) for _ in range(2))
+    v, do = (jnp.asarray(rng.randn(bh, s, dv), jnp.bfloat16)
+             for _ in range(2))
+    today = None
+    for part in GRID_PARTS:
+        compiled = jax.jit(grid_kernels(
+            flash, part, plan.rows, d ** -0.5, plan.block_q,
+        )).lower(q, k, v, do).compile()
+        names = kernel_names(compiled)
+        if len(names) != 3:
+            raise RuntimeError(f"expected three kernels, found {names}")
+        outputs = compiled(q, k, v, do)
+        today = today or outputs
+        gaps = {
+            name: (bool(jnp.array_equal(got, want)),
+                   float(jnp.abs(got.astype(jnp.float32)
+                                 - want.astype(jnp.float32)).max()))
+            for name, got, want
+            in zip(("o", "lse", "dq", "dk", "dv"), outputs, today)
+        }
+        per_kernel, program = device_ms(compiled, (q, k, v, do), names)
+        print(json.dumps({
+            "row": "grid", "shape": shape_name, "head_sizes": [d, dv],
+            "part": part, "plan": plan.describe(),
+            "device_ms": {
+                "fwd": round(per_kernel[0], 4), "dq": round(per_kernel[1], 4),
+                "dkv": round(per_kernel[2], 4),
+                "kernels": round(sum(per_kernel), 4),
+                "program": program and round(program, 4),
+            },
+            "same_bits_as_today": {n: same for n, (same, _) in gaps.items()},
+            "max_abs_gap_to_today": {n: gap for n, (_, gap) in gaps.items()},
+        }), flush=True)
+
+
 def main(argv=None):
     import jax
     import jax.numpy as jnp
@@ -125,6 +298,8 @@ def main(argv=None):
     parser = argparse.ArgumentParser()
     parser.add_argument("shape", nargs="*", type=int)
     parser.add_argument("--layers", type=int, default=24)
+    parser.add_argument("--only", nargs="+", default=list(ROWS), choices=ROWS,
+                        help="which kinds of row to print")
     args = parser.parse_args(argv)
     cache_dir = enable_compile_cache()
     device = jax.devices()[0]
@@ -167,7 +342,7 @@ def main(argv=None):
         (0, 0, edge) for edge in SUB_TILES
         if block == s and block % edge == 0 and block >= 2 * edge
     ] + [(bq, bk, block) for bq, bk in GRID_TILES if s > bq and s % bq == 0]
-    for block_q, block_k, edge in tilings:
+    for block_q, block_k, edge in tilings if "sweep" in args.only else ():
         with sub_tile(flash, edge):
             plan = flash.tile_plan(s, s, True, block_q, block_k)
             compiled = grads(block_q, block_k).lower(q, k, v).compile()
@@ -190,11 +365,14 @@ def main(argv=None):
                 model_flops / (total / 1e3) / peak, 4),
         }), flush=True)
 
+    if "grid" in args.only:
+        grid_rows(flash, shape_name, b * h, s, d, d)
+
     def entries():
         return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
 
     seen = set()
-    for edge in (block, flash.SUB_TILE):
+    for edge in (block, flash.SUB_TILE) if "startup" in args.only else ():
         with sub_tile(flash, edge):
             plan = flash.tile_plan(s, s)
             if plan in seen:    # several grid tiles: one tiling only

@@ -1,7 +1,7 @@
 """The two sweeps behind ``models/mla_moe.py``'s choices, at the
 benchmark cell's shapes. Chip only; one JSON line a row.
 
-    python tools/bench_mla_moe_parts.py [attention] [grouped]
+    python tools/bench_mla_moe_parts.py [attention] [grid] [grouped]
 
 - **attention**: causal forward + backward of ``ops.flash_attention``
   at q/k head 192, v head 128 (B 4, H 32, S 4,096, bf16): the default
@@ -9,6 +9,10 @@ benchmark cell's shapes. Chip only; one JSON line a row.
   do for wide heads), then smaller blocks under the default limit, and
   1024 x 1024 under it (refused). ms a layer, wall clock around
   ``block_until_ready`` over ``RUNS`` calls.
+- **grid**: what each part of the walk over that call's 4 x 4 grid of
+  tiles buys, per kernel, from the profiler's device times
+  (``tools/bench_flash_blocks.py``'s ``grid`` rows, at these head
+  sizes).
 - **grouped**: the expert layer's grouped products, forward + backward
   of gate+up, silu, down, over the static bound of 131,072 rows with
   8,192 of them live (the cell's expected share) evenly over 16 experts,
@@ -72,6 +76,15 @@ def attention_rows():
             row["refused"] = str(exc)[-300:]
         print(json.dumps(row), flush=True)
     flash.WIDE_HEAD_VMEM_BYTES = raised
+    jax.clear_caches()
+
+
+def grid_rows():
+    import bench_flash_blocks
+
+    from elasticdl_tpu.ops import flash_attention as flash
+
+    bench_flash_blocks.grid_rows(flash, "B4/H32/S4096", 4 * 32, 4096, 192, 128)
 
 
 def grouped_rows():
@@ -129,9 +142,11 @@ def main(argv):
         raise SystemExit(f"chip only: this is {device.platform}")
     print(json.dumps({"row": "device", "kind": device.device_kind}),
           flush=True)
-    which = argv or ["attention", "grouped"]
+    which = argv or ["attention", "grid", "grouped"]
     if "attention" in which:
         attention_rows()
+    if "grid" in which:
+        grid_rows()
     if "grouped" in which:
         grouped_rows()
 
