@@ -19,11 +19,10 @@ from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.task import Task
 from elasticdl_tpu.core.model_spec import get_model_spec
 from elasticdl_tpu.core.step import (
-    build_eval_step,
     concat_eval_accumulators,
     evaluate_metrics,
+    runner_for_spec,
 )
-from elasticdl_tpu.core.train_state import init_train_state
 from elasticdl_tpu.data.batcher import batch_records
 from elasticdl_tpu.data.factory import (
     create_data_reader,
@@ -66,13 +65,8 @@ class EvalPredictExecutor:
         self.state = None
         # Host-tier models: rows come back from the checkpoint into the
         # runner's tables; its eval step reads them per batch.
-        self._step_runner = (
-            self._spec.make_host_runner()
-            if self._spec.make_host_runner else None
-        )
-        self._eval_step = (
-            None if self._step_runner is not None else build_eval_step()
-        )
+        self._step_runner = runner_for_spec(self._spec)
+        self._eval_step = None
 
     def _batches(self):
         data_mode = (
@@ -109,16 +103,13 @@ class EvalPredictExecutor:
             self._spec.make_optimizer(),
             self._spec.callbacks_fn() if self._spec.callbacks_fn else [],
         )
-        if self._step_runner is not None:
-            self.state = self._step_runner.init_state(
-                self._spec.model, tx, batch
-            )
-            self._eval_step = self._step_runner.eval_step()
-        else:
-            self.state = init_train_state(self._spec.model, tx, batch)
+        self.state = self._step_runner.init_state(
+            self._spec.model, tx, batch
+        )
+        self._eval_step = self._step_runner.eval_step()
         self.state = restore_from_dir(
             self.state, self._ckpt_dir,
-            host_tables=getattr(self._step_runner, "host_tables", None),
+            host_tables=self._step_runner.host_tables,
         )
         logger.info(
             "Restored model version %d from %s",

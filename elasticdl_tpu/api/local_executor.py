@@ -19,12 +19,10 @@ from elasticdl_tpu.common.task import Task
 from elasticdl_tpu.common.timing import Timing
 from elasticdl_tpu.core.model_spec import get_model_spec
 from elasticdl_tpu.core.step import (
-    build_eval_step,
-    build_train_step,
     concat_eval_accumulators,
     evaluate_metrics,
+    runner_for_spec,
 )
-from elasticdl_tpu.core.train_state import init_train_state
 from elasticdl_tpu.data.batcher import batch_records
 from elasticdl_tpu.checkpoint import CheckpointHook, restore_from_dir
 from elasticdl_tpu.data.factory import (
@@ -67,22 +65,11 @@ class LocalExecutor:
         self._timing = Timing(args.log_level.upper() == "DEBUG", self._logger)
         self.state = None
         self.last_batch = None
-        # Host-tier models (make_host_runner in the zoo module) run
-        # through their runner; its steps are built at state init (the
-        # row-block template needs an example batch).
-        self._step_runner = (
-            self._spec.make_host_runner()
-            if self._spec.make_host_runner else (
-                self._spec.make_sparse_runner()
-                if self._spec.make_sparse_runner else None
-            )
-        )
-        if self._step_runner is None:
-            self._train_step = build_train_step(self._spec.loss)
-            self._eval_step = build_eval_step()
-        else:
-            self._train_step = None
-            self._eval_step = None
+        # The runner's steps are built at state init (a host-tier
+        # runner's row-block template needs an example batch).
+        self._step_runner = runner_for_spec(self._spec)
+        self._train_step = None
+        self._eval_step = None
         self.last_train_metrics = None
         # Checkpointing (reference save inside push_gradients every
         # checkpoint_steps versions, ps/servicer.py:242-257; restore-at-init
@@ -94,7 +81,7 @@ class LocalExecutor:
             # 0 is a legal explicit value meaning "keep everything"
             # (CheckpointSaver.gc); only an absent flag falls back to 3.
             keep_max=getattr(args, "keep_checkpoint_max", 3),
-            host_tables=getattr(self._step_runner, "host_tables", None),
+            host_tables=self._step_runner.host_tables,
             delta_chain_max=getattr(args, "checkpoint_delta_chain", 0),
         )
         self._init_checkpoint_dir = getattr(
@@ -168,26 +155,16 @@ class LocalExecutor:
             tx = apply_callbacks_to_optimizer(
                 self._spec.make_optimizer(), self._callbacks
             )
-            if self._step_runner is not None:
-                self.state = self._step_runner.init_state(
-                    self._spec.model, tx, batch,
-                    seed=getattr(self._args, "random_seed", 0),
-                )
-                self._train_step = self._step_runner.train_step(
-                    self._spec.loss
-                )
-                self._eval_step = self._step_runner.eval_step()
-            else:
-                self.state = init_train_state(
-                    self._spec.model, tx, batch,
-                    seed=getattr(self._args, "random_seed", 0),
-                )
+            self.state = self._step_runner.init_state(
+                self._spec.model, tx, batch,
+                seed=getattr(self._args, "random_seed", 0),
+            )
+            self._train_step = self._step_runner.train_step(self._spec.loss)
+            self._eval_step = self._step_runner.eval_step()
             if self._init_checkpoint_dir:
                 self.state = restore_from_dir(
                     self.state, self._init_checkpoint_dir,
-                    host_tables=getattr(
-                        self._step_runner, "host_tables", None
-                    ),
+                    host_tables=self._step_runner.host_tables,
                 )
 
     def _maybe_checkpoint(self):

@@ -40,7 +40,9 @@ coverage gates are process-wide; per-shard top-K attribution (the
 ``/usage`` endpoint's ``shards`` block) is covered by unit tests
 over ``MetricsPlane`` ingest. Report is validated by
 ``tools/check_usage.py`` and fsck'd under the ``usage`` kind.
-Fast-lane equivalent: ``tests/test_usage.py::test_usage_drill_passes``.
+Fast-lane twin: ``tests/test_usage.py::test_usage_drill_passes`` holds
+purity and coverage, which are exact; the overhead gate is a wall-clock
+ratio and is held here alone (``make usage-smoke``, a quiet machine).
 """
 
 import argparse

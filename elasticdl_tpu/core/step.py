@@ -15,6 +15,8 @@ from typing import Callable, Dict
 import jax
 import jax.numpy as jnp
 
+from elasticdl_tpu.core.train_state import init_train_state
+
 
 def _call_loss(loss_fn, labels, predictions, mask):
     """Call the user loss; pass the padding mask iff it accepts 3 args."""
@@ -54,52 +56,66 @@ def _model_metrics(preds) -> dict:
     return dict(preds.get("metrics", {})) if isinstance(preds, dict) else {}
 
 
-def _train_step_body(loss_fn: Callable, state, batch):
-    """One forward+backward+apply; shared by the per-batch and fused
-    multi-batch (scan) step builders."""
-    state, rng = state.next_rng()
-
-    def compute_loss(params):
-        preds, new_batch_stats = _apply_model(
-            state, params, batch, training=True, rng=rng
-        )
-        loss = _call_loss(loss_fn, batch["labels"], preds, batch["mask"])
-        return loss, (preds, new_batch_stats)
-
-    grad_fn = jax.value_and_grad(compute_loss, has_aux=True)
-    (loss, (preds, new_batch_stats)), grads = grad_fn(state.params)
-    # Padded rows are masked out of the loss but BatchNorm would still
-    # fold them into running stats — keep the old stats for any batch
-    # that contains padding.
-    if state.batch_stats:
-        is_full = jnp.all(batch["mask"] > 0)
-        new_batch_stats = jax.tree.map(
-            lambda new, old: jnp.where(is_full, new, old),
-            new_batch_stats, state.batch_stats,
-        )
-    new_state = state.apply_gradients(
-        grads=grads, batch_stats=new_batch_stats
-    )
-    return new_state, {"loss": loss, **_model_metrics(preds)}
-
-
-def build_train_step(loss_fn: Callable) -> Callable:
-    """Build ``(state, batch) -> (state, metrics)``, jitted.
-
-    The returned function is pure and jit/pjit-compatible: the mesh layer
-    (parallel/) wraps it with sharding constraints unchanged.
-    """
+def _train_step_body(loss_fn: Callable) -> Callable:
+    """The dense step body ``(state, batch) -> (state, metrics)``: one
+    forward+backward+apply. Every dense program, per batch or per task,
+    on one device or on a mesh, is built from it."""
 
     def train_step(state, batch):
-        return _train_step_body(loss_fn, state, batch)
+        state, rng = state.next_rng()
 
-    return jax.jit(train_step, donate_argnums=(0,))
+        def compute_loss(params):
+            preds, new_batch_stats = _apply_model(
+                state, params, batch, training=True, rng=rng
+            )
+            loss = _call_loss(
+                loss_fn, batch["labels"], preds, batch["mask"]
+            )
+            return loss, (preds, new_batch_stats)
+
+        grad_fn = jax.value_and_grad(compute_loss, has_aux=True)
+        (loss, (preds, new_batch_stats)), grads = grad_fn(state.params)
+        # Padded rows are masked out of the loss but BatchNorm would
+        # still fold them into running stats — keep the old stats for
+        # any batch that contains padding.
+        if state.batch_stats:
+            is_full = jnp.all(batch["mask"] > 0)
+            new_batch_stats = jax.tree.map(
+                lambda new, old: jnp.where(is_full, new, old),
+                new_batch_stats, state.batch_stats,
+            )
+        new_state = state.apply_gradients(
+            grads=grads, batch_stats=new_batch_stats
+        )
+        return new_state, {"loss": loss, **_model_metrics(preds)}
+
+    return train_step
 
 
-def build_multi_step(loss_fn: Callable) -> Callable:
-    """Build ``(state, batches) -> (state, metrics)`` where ``batches``
-    leaves carry a leading task dim T: T optimizer steps fused into ONE
-    XLA program via ``lax.scan``.
+def jit_step(body: Callable, state_shardings=None, batch_shardings=None,
+             donate: bool = True) -> Callable:
+    """Compile a step body ``(state, batch) -> (state, metrics)``. With
+    ``state_shardings`` the state goes in and comes out laid out so
+    (``batch_shardings`` None: taken from the placed batch); without,
+    the program is the one-device one. This and ``jit_task`` are the
+    only places a training program is compiled."""
+    shardings = {}
+    if state_shardings is not None:
+        shardings = dict(
+            in_shardings=(state_shardings, batch_shardings),
+            out_shardings=(state_shardings, None),
+        )
+    return jax.jit(
+        body, donate_argnums=(0,) if donate else (), **shardings
+    )
+
+
+def jit_task(body: Callable, state_shardings=None,
+             donate: bool = True) -> Callable:
+    """Compile ``(state, batches) -> (state, metrics)`` where ``batches``
+    leaves carry a leading task dim T: T steps of ``body`` fused into
+    ONE XLA program via ``lax.scan``, jitted as ``jit_step`` would (the
+    task's layout is taken from the placed batches).
 
     This is the task-granular execution mode: the reference's unit of
     work is already a task of ``num_minibatches_per_task`` minibatches
@@ -115,13 +131,21 @@ def build_multi_step(loss_fn: Callable) -> Callable:
     unrolled by 4, 1,389.5 ms against 1,313.3 (PERF.md, PR 27).
     """
 
+    # The benchmark finds the task programs on the trace by this name.
     def multi_step(state, batches):
-        def body(state, batch):
-            return _train_step_body(loss_fn, state, batch)
-
         return jax.lax.scan(body, state, batches)
 
-    return jax.jit(multi_step, donate_argnums=(0,))
+    return jit_step(multi_step, state_shardings, donate=donate)
+
+
+def build_train_step(loss_fn: Callable) -> Callable:
+    """Build the one-device ``(state, batch) -> (state, metrics)``."""
+    return jit_step(_train_step_body(loss_fn))
+
+
+def build_multi_step(loss_fn: Callable) -> Callable:
+    """Build the one-device task program (``jit_task``)."""
+    return jit_task(_train_step_body(loss_fn))
 
 
 def stack_batches(batches):
@@ -170,6 +194,51 @@ def build_apply_gradients() -> Callable:
         return state.apply_gradients(grads=scaled)
 
     return apply_step
+
+
+class StepRunner:
+    """The one-device runner, and the seam every runner answers: what
+    the worker and the executors ask in place of asking what a runner
+    is. ``parallel/mesh_runner.py``, ``embedding/device_sparse.py`` and
+    ``embedding/host_engine.py`` override what they do differently; the
+    compiled functions come back as built, with nothing around them."""
+
+    mesh = None  # the Mesh the state lives on; None = one device
+    accum_steps = 1  # > 1: train_step carries a gradient window
+    host_tables = None  # host-resident tables a checkpoint must carry
+    pull_ahead = False  # True: the task loop feeds ``iter_prepared``
+    can_fuse = True  # False: no ``train_multi_step``
+    can_resize = False  # True: ``resize(new_mesh, state)`` reshards
+
+    def init_state(self, model, tx, batch, seed: int = 0):
+        return init_train_state(model, tx, batch, seed=seed)
+
+    def train_step(self, loss_fn: Callable) -> Callable:
+        return build_train_step(loss_fn)
+
+    def train_multi_step(self, loss_fn: Callable) -> Callable:
+        return build_multi_step(loss_fn)
+
+    def eval_step(self) -> Callable:
+        return build_eval_step()
+
+    def place_state(self, state):
+        """Re-place a host-restored state where the runner keeps it."""
+        return state
+
+    def flush(self):
+        """Wait for work the runner does off the step's thread."""
+
+
+def runner_for_spec(spec, **host_runner_args) -> StepRunner:
+    """The runner a model spec trains under without a mesh: its
+    host-tier runner, else its device-tier sparse runner, else the
+    one-device runner."""
+    if spec.make_host_runner is not None:
+        return spec.make_host_runner(**host_runner_args)
+    if spec.make_sparse_runner is not None:
+        return spec.make_sparse_runner()
+    return StepRunner()
 
 
 def tree_add(a, b):

@@ -35,6 +35,12 @@ from flax import linen as nn
 from flax import struct
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from elasticdl_tpu.core.step import (
+    StepRunner,
+    _call_loss,
+    jit_step,
+    jit_task,
+)
 from elasticdl_tpu.core.train_state import TrainState
 from elasticdl_tpu.embedding.combiner import COMBINERS, RaggedIds, combine
 from elasticdl_tpu.embedding.optimizer import (
@@ -206,8 +212,8 @@ def build_sparse_train_step(
     jittable program covering lookup, model fwd/bwd, dense apply, and
     the sparse row-kernel apply. ``template`` is the model's
     ``sparse_emb`` collection structure (``sparse_template``).
-    Composable with ``lax.scan`` for the fused multi-step task path
-    (``build_sparse_multi_step``).
+    ``core/step.py`` compiles it, per batch (``jit_step``) or scanned
+    over a task (``jit_task``).
 
     With ``mesh``, tables named in ``sharded_tables`` are row-sharded
     over ``axis``: lookup goes through
@@ -223,7 +229,6 @@ def build_sparse_train_step(
     measured scatter-latency win (optimizer.sparse_apply_packed).
     Single-mesh only; forward narrows gathered rows to the first D
     columns."""
-    from elasticdl_tpu.core.step import _call_loss
     from elasticdl_tpu.embedding.host_engine import _nest_rows
     from elasticdl_tpu.ops.pallas_embedding import (
         lookup_combine,
@@ -344,40 +349,6 @@ def build_sparse_train_step(
     return train_step
 
 
-def build_sparse_multi_step(loss_fn, specs, row_opt, template,
-                            use_pallas: str = "auto",
-                            interpret: bool = False,
-                            unroll: int = 1,
-                            mesh=None, axis: str = "dp",
-                            sharded_tables: FrozenSet[str] = frozenset(),
-                            state_shardings=None,
-                            packed_slots: bool = False) -> Callable:
-    """T fused sparse steps per XLA program (the task-granular mode —
-    core/step.build_multi_step for the sparse plane)."""
-    step = build_sparse_train_step(
-        loss_fn, specs, row_opt, template, use_pallas=use_pallas,
-        interpret=interpret, mesh=mesh, axis=axis,
-        sharded_tables=sharded_tables, packed_slots=packed_slots,
-    )
-
-    def multi_step(state, batches):
-        def body(state, batch):
-            return step(state, batch)
-
-        num_steps = jax.tree.leaves(batches)[0].shape[0]
-        return jax.lax.scan(
-            body, state, batches, unroll=max(1, min(unroll, num_steps))
-        )
-
-    kwargs = {}
-    if state_shardings is not None:
-        kwargs = dict(
-            in_shardings=(state_shardings, None),
-            out_shardings=(state_shardings, None),
-        )
-    return jax.jit(multi_step, donate_argnums=(0,), **kwargs)
-
-
 def init_sparse_state(
     model, tx, example_batch, specs: Tuple[TableSpec, ...],
     row_opt: RowOptimizer, seed: int = 0,
@@ -441,10 +412,9 @@ def init_sparse_state(
     return state, template
 
 
-class DeviceSparseRunner:
-    """Worker-compatible step runner (init_state/train_step/eval_step +
-    train_multi_step) for device-tier sparse models — the deployment
-    adapter the host tier has in HostStepRunner.
+class DeviceSparseRunner(StepRunner):
+    """The runner seam (core/step.py::StepRunner) for device-tier sparse
+    models — the deployment adapter the host tier has in HostStepRunner.
 
     With ``mesh``, every TableSpec table over ``partition_threshold_bytes``
     whose vocab divides the ``axis`` size is ROW-SHARDED over the mesh
@@ -456,6 +426,8 @@ class DeviceSparseRunner:
     (``docs/designs/parameter_server.md`` "Model Parameter Partition"):
     row ranges instead of id%N, XLA collectives over ICI instead of
     gRPC pull/push."""
+
+    can_resize = True
 
     def __init__(self, specs: Tuple[TableSpec, ...],
                  row_opt: RowOptimizer, use_pallas: str = "auto",
@@ -614,35 +586,23 @@ class DeviceSparseRunner:
 
         return reshard_lib.live_reshard(state, shardings_fn)
 
-    def _jit_step(self, step):
-        if self.mesh is None:
-            return jax.jit(step, donate_argnums=(0,))
-        return jax.jit(
-            step, donate_argnums=(0,),
-            in_shardings=(self._state_shardings,
-                          self._batch_shardings),
-            out_shardings=(self._state_shardings, None),
+    def _step_body(self, loss_fn):
+        return build_sparse_train_step(
+            loss_fn, self.specs, self.row_opt, self._template,
+            use_pallas=self.use_pallas, interpret=self.interpret,
+            mesh=self.mesh, axis=self.axis,
+            sharded_tables=self.sharded_tables,
+            packed_slots=self.packed_slots,
         )
 
     def train_step(self, loss_fn):
-        step = build_sparse_train_step(
-            loss_fn, self.specs, self.row_opt, self._template,
-            use_pallas=self.use_pallas, interpret=self.interpret,
-            mesh=self.mesh, axis=self.axis,
-            sharded_tables=self.sharded_tables,
-            packed_slots=self.packed_slots,
+        return jit_step(
+            self._step_body(loss_fn), self._state_shardings,
+            self._batch_shardings,
         )
-        return self._jit_step(step)
 
     def train_multi_step(self, loss_fn):
-        return build_sparse_multi_step(
-            loss_fn, self.specs, self.row_opt, self._template,
-            use_pallas=self.use_pallas, interpret=self.interpret,
-            mesh=self.mesh, axis=self.axis,
-            sharded_tables=self.sharded_tables,
-            state_shardings=self._state_shardings,
-            packed_slots=self.packed_slots,
-        )
+        return jit_task(self._step_body(loss_fn), self._state_shardings)
 
     def eval_step(self):
         from elasticdl_tpu.embedding.host_engine import _nest_rows

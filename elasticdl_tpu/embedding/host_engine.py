@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from elasticdl_tpu.core.step import StepRunner, _call_loss
 from elasticdl_tpu.embedding.combiner import RaggedIds, combine
 
 
@@ -154,8 +155,6 @@ def build_host_train_step(loss_fn: Callable, rows_template) -> Callable:
     ``host_rows_template``. BatchNorm models are supported the same way
     as the core step (running stats frozen on padded batches).
     """
-    from elasticdl_tpu.core.step import _call_loss
-
     def train_step(state, batch, host_rows):
         state, rng = state.next_rng()
 
@@ -580,11 +579,11 @@ class HostEmbeddingEngine:
         return staged(prepared, self.place_on_device, depth=1)
 
 
-class HostStepRunner:
+class HostStepRunner(StepRunner):
     """Step-runner adapter: drive host-tier models through the standard
-    Worker/MiniCluster loop (worker.py accepts any runner exposing
-    init_state/train_step/eval_step). prepare/apply happen inside the
-    wrapped step so the worker's (state, batch) contract is unchanged —
+    Worker/MiniCluster loop (the runner seam, core/step.py::StepRunner).
+    prepare/apply happen inside the wrapped step so the worker's
+    (state, batch) contract is unchanged —
     the role the reference worker's PS stubs played inline
     (worker.py:869-908), collapsed into the runner.
 
@@ -608,6 +607,9 @@ class HostStepRunner:
       leaves the critical path too). Staleness-window math on
       ``prepared_batches``.
     """
+
+    # Host-side work per batch cannot fuse into one XLA program.
+    can_fuse = False
 
     def __init__(self, engine: HostEmbeddingEngine,
                  async_apply: bool = True):
@@ -703,13 +705,11 @@ class HostStepRunner:
         )
 
     def init_state(self, model, tx, batch, seed: int = 0):
-        from elasticdl_tpu.core.train_state import init_train_state
-
         self.flush()
         prepared, _, _ = self.engine.prepare_batch(batch)
         self._template = host_rows_template(model, prepared, seed=seed)
         self._model = model
-        return init_train_state(model, tx, prepared, seed=seed)
+        return super().init_state(model, tx, prepared, seed=seed)
 
     def train_step(self, loss_fn: Callable) -> Callable:
         host_step = build_host_train_step(loss_fn, self._template)

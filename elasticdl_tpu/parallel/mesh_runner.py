@@ -37,8 +37,10 @@ from elasticdl_tpu.parallel import rules as rules_lib
 logger = get_logger("mesh_runner")
 
 
-class MeshRunner:
-    """Implements the Worker ``step_runner`` interface over a Mesh."""
+class MeshRunner(step_lib.StepRunner):
+    """The runner seam (core/step.py::StepRunner) over a Mesh."""
+
+    can_resize = True
 
     def __init__(
         self,
@@ -256,55 +258,23 @@ class MeshRunner:
         # step compiled for a different loss.
         return self._memoized(
             "train", loss_fn,
-            lambda: self._plain_train_step(loss_fn),
+            lambda: self._placed(
+                step_lib.jit_step, loss_fn, self.place_batch
+            ),
         )
 
-    def _plain_train_step(self, loss_fn: Callable) -> Callable:
-        base_step = self._build_step(loss_fn)
-        runner = self
+    def _placed(self, jit, loss_fn: Callable, place) -> Callable:
+        """The dense step compiled by ``jit`` (core/step.py) with this
+        mesh's state shardings, fed host batches through ``place``."""
+        jitted = jit(
+            step_lib._train_step_body(loss_fn),
+            self._require_shardings(), donate=self._donate_state,
+        )
 
         def wrapped(state, batch):
-            batch = runner.place_batch(batch)
-            return base_step(state, batch)
+            return jitted(state, place(batch))
 
         return wrapped
-
-    def _build_step(self, loss_fn: Callable):
-        shardings = self._require_shardings()
-
-        def train_step(state, batch):
-            state, rng = state.next_rng()
-
-            def compute_loss(params):
-                preds, new_bs = step_lib._apply_model(
-                    state, params, batch, training=True, rng=rng
-                )
-                loss = step_lib._call_loss(
-                    loss_fn, batch["labels"], preds, batch["mask"]
-                )
-                return loss, new_bs
-
-            (loss, new_bs), grads = jax.value_and_grad(
-                compute_loss, has_aux=True
-            )(state.params)
-            if state.batch_stats:
-                is_full = jnp.all(batch["mask"] > 0)
-                new_bs = jax.tree.map(
-                    lambda new, old: jnp.where(is_full, new, old),
-                    new_bs, state.batch_stats,
-                )
-            new_state = state.apply_gradients(
-                grads=grads, batch_stats=new_bs
-            )
-            return new_state, {"loss": loss}
-
-        batch_shardings = None  # inferred from placed batch
-        return jax.jit(
-            train_step,
-            in_shardings=(shardings, batch_shardings),
-            out_shardings=(shardings, None),
-            donate_argnums=(0,) if self._donate_state else (),
-        )
 
     def _accum_train_step(self, loss_fn: Callable):
         """Gradient accumulation: the mesh-native mapping of the reference
@@ -406,44 +376,17 @@ class MeshRunner:
 
         return wrapped
 
-    def train_multi_step(
-        self, loss_fn: Callable, unroll: int = 4
-    ) -> Callable:
+    def train_multi_step(self, loss_fn: Callable) -> Callable:
         """Fused task-granular step: scan a whole task's minibatches
         (stacked with a leading T dim) through one compiled SPMD
-        program (core/step.build_multi_step, mesh edition — same
-        default partial unroll). Only the plain (accum_steps == 1)
+        program (core/step.jit_task). Only the plain (accum_steps == 1)
         path fuses — accumulation already carries cross-call state."""
         return self._memoized(
-            ("multi", unroll), loss_fn,
-            lambda: self._build_multi_step(loss_fn, unroll),
+            "multi", loss_fn,
+            lambda: self._placed(
+                step_lib.jit_task, loss_fn, self.place_task
+            ),
         )
-
-    def _build_multi_step(self, loss_fn: Callable, unroll: int):
-        shardings = self._require_shardings()
-        runner = self
-
-        def multi_step(state, batches):
-            def body(state, batch):
-                return step_lib._train_step_body(loss_fn, state, batch)
-
-            num_steps = jax.tree.leaves(batches)[0].shape[0]
-            return jax.lax.scan(
-                body, state, batches,
-                unroll=max(1, min(unroll, num_steps)),
-            )
-
-        jitted = jax.jit(
-            multi_step,
-            in_shardings=(shardings, None),
-            out_shardings=(shardings, None),
-            donate_argnums=(0,) if self._donate_state else (),
-        )
-
-        def wrapped(state, batches):
-            return jitted(state, runner.place_task(batches))
-
-        return wrapped
 
     def place_task(self, batches):
         """Place a stacked task ({k: (T, B, ...)}) on the mesh: per-leaf

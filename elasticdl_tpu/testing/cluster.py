@@ -13,6 +13,7 @@ from typing import Dict, List, Optional
 
 from elasticdl_tpu.comm.rpc import RpcServer
 from elasticdl_tpu.core.model_spec import get_model_spec
+from elasticdl_tpu.core.step import runner_for_spec
 from elasticdl_tpu.data.factory import create_data_reader
 from elasticdl_tpu.master.evaluation_service import EvaluationService
 from elasticdl_tpu.master.servicer import SERVICE_NAME, MasterServicer
@@ -184,19 +185,13 @@ class MiniCluster:
                 "localhost:0", {SERVICE_NAME: self.servicer.handlers()}
             ).start()
 
-        if step_runner_factory is None and self.spec.make_host_runner:
-            # Host-tier default: ONE runner shared by every worker so all
-            # threads train the same row stores (the PS-sharing shape);
-            # a per-worker factory would silently fork the tables.
-            shared_runner = self.spec.make_host_runner()
+        if step_runner_factory is None:
+            # ONE runner shared by every worker: host-tier workers must
+            # all train the same row stores (the PS-sharing shape; a
+            # per-worker factory would silently fork the tables), and
+            # the other runners hold no state of a worker's.
+            shared_runner = runner_for_spec(self.spec)
             step_runner_factory = lambda: shared_runner  # noqa: E731
-        elif step_runner_factory is None and self.spec.make_sparse_runner:
-            # Device-tier sparse models: tables ride the TrainState, so
-            # a per-worker runner is only step-builder config — but the
-            # single-device in-process cluster still shares one (the
-            # state itself is worker-owned).
-            sparse_runner = self.spec.make_sparse_runner()
-            step_runner_factory = lambda: sparse_runner  # noqa: E731
         task_reader = (
             self.train_reader or self.eval_reader or self.predict_reader
         )
@@ -212,9 +207,7 @@ class MiniCluster:
                 client = self.make_inprocess_client(
                     wid, callbacks=worker_callbacks
                 )
-            runner = (
-                step_runner_factory() if step_runner_factory else None
-            )
+            runner = step_runner_factory()
             if wid == 0 and checkpoint_dir:
                 from elasticdl_tpu.checkpoint import CheckpointHook
 
@@ -223,7 +216,7 @@ class MiniCluster:
                 hook = CheckpointHook(
                     checkpoint_dir=checkpoint_dir,
                     checkpoint_steps=checkpoint_steps,
-                    host_tables=getattr(runner, "host_tables", None),
+                    host_tables=runner.host_tables,
                     async_save=checkpoint_async,
                     delta_chain_max=checkpoint_delta_chain,
                 )

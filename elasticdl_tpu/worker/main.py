@@ -16,6 +16,7 @@ from elasticdl_tpu.common.constants import DistributionStrategy
 from elasticdl_tpu.common.jax_env import enable_compile_cache
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.core.model_spec import get_model_spec
+from elasticdl_tpu.core.step import runner_for_spec
 from elasticdl_tpu.utils.profiler import from_args as profiler_from_args
 from elasticdl_tpu.data.factory import (
     create_data_reader,
@@ -155,6 +156,7 @@ def build_worker(args, master_client=None) -> Worker:
                     and getattr(args, "lr_staleness_modulation", False)
                 ),
             )
+    host_runner_args = {}
     if spec.make_host_runner is not None:
         # Host-tier model (>HBM tables, embedding/host_engine.py): the
         # zoo module supplies the runner holding its row stores.
@@ -182,22 +184,19 @@ def build_worker(args, master_client=None) -> Worker:
                     f"{args.model_def}: make_host_runner must accept "
                     "remote_addr=... to run against --row_service_addr"
                 )
-            step_runner = spec.make_host_runner(remote_addr=row_addr)
-        else:
-            if getattr(args, "num_workers", 1) > 1:
-                # Per-process tables would silently fork: each pod would
-                # train (and lose) its own rows.
-                raise ValueError(
-                    "host-tier models with num_workers > 1 need a shared "
-                    "row service: start embedding.row_service and pass "
-                    "--row_service_addr"
-                )
-            step_runner = spec.make_host_runner()
-    if step_runner is None and spec.make_sparse_runner is not None:
-        # Device-tier sparse model under the default strategy: the
-        # plain single-device runner (tables in HBM next to the model)
-        # — same wiring LocalExecutor uses.
-        step_runner = spec.make_sparse_runner()
+            host_runner_args["remote_addr"] = row_addr
+        elif getattr(args, "num_workers", 1) > 1:
+            # Per-process tables would silently fork: each pod would
+            # train (and lose) its own rows.
+            raise ValueError(
+                "host-tier models with num_workers > 1 need a shared "
+                "row service: start embedding.row_service and pass "
+                "--row_service_addr"
+            )
+    if step_runner is None:
+        # No mesh: the spec's host-tier runner, its device-tier sparse
+        # runner (tables in HBM next to the model), or one device.
+        step_runner = runner_for_spec(spec, **host_runner_args)
     if master_client is None:
         master_client = MasterClient(
             args.master_addr, worker_id=args.worker_id
@@ -258,7 +257,7 @@ def build_worker(args, master_client=None) -> Worker:
             # the barrier aligns save versions. Non-mesh strategies keep
             # the native per-process saver.
             backend="orbax" if mesh_multihost else "native",
-            host_tables=getattr(step_runner, "host_tables", None),
+            host_tables=step_runner.host_tables,
             delta_chain_max=(
                 0 if mesh_multihost
                 else getattr(args, "checkpoint_delta_chain", 0)

@@ -42,11 +42,7 @@ from elasticdl_tpu.common.constants import (
     TaskType,
 )
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.core.step import (
-    build_eval_step,
-    build_train_step,
-)
-from elasticdl_tpu.core.train_state import init_train_state
+from elasticdl_tpu.core.step import StepRunner
 from elasticdl_tpu.worker.task_data_service import TaskDataService
 
 logger = get_logger("worker")
@@ -99,13 +95,14 @@ class Worker:
         self._version_report_steps = version_report_steps
         self._processor = prediction_outputs_processor
         self._callbacks = callbacks or []
-        # step_runner abstracts single-device vs mesh execution (stage 4);
-        # None = plain jit on the local device.
-        self._step_runner = step_runner
+        # The runner builds the state and every program, and answers
+        # what differs between one device, a mesh and the sparse tiers
+        # (core/step.py::StepRunner: the one-device runner, and the seam).
+        self._step_runner = step_runner or StepRunner()
         self.state = None
         self.last_batch = None
         self._train_step = None
-        self._eval_step = build_eval_step()
+        self._eval_step = None
         # Tracing (observability/tracing.py): spans into the process
         # flight recorder when one is installed; free otherwise
         # (Tracer.span is one module-global read). Recorded spans ride
@@ -301,46 +298,29 @@ class Worker:
         tx = apply_callbacks_to_optimizer(
             self._spec.make_optimizer(), self._callbacks
         )
-        if self._step_runner is not None:
-            import jax as _jax
-
-            self._multihost_sync = (
-                _jax.process_count() > 1
-                and hasattr(self._step_runner, "mesh")
+        runner = self._step_runner
+        self._multihost_sync = (
+            jax.process_count() > 1 and runner.mesh is not None
+        )
+        if self._multihost_sync and self._fuse_task_steps:
+            logger.warning(
+                "fuse_task_steps disabled under multi-host sync "
+                "(unequal task sizes would desync step counts)"
             )
-            if self._multihost_sync and self._fuse_task_steps:
+            self._fuse_task_steps = False
+        self.state = runner.init_state(self._spec.model, tx, batch)
+        self._train_step = runner.train_step(self._spec.loss)
+        self._eval_step = runner.eval_step()
+        if self._fuse_task_steps and runner.accum_steps == 1:
+            if runner.can_fuse:
+                self._multi_step = runner.train_multi_step(self._spec.loss)
+            else:
+                # e.g. HostStepRunner: host-side work per batch can't
+                # fuse into one XLA program; fall back to per-step.
                 logger.warning(
-                    "fuse_task_steps disabled under multi-host sync "
-                    "(unequal task sizes would desync step counts)"
+                    "fuse_task_steps ignored: %s cannot fuse a task's "
+                    "steps", type(runner).__name__,
                 )
-                self._fuse_task_steps = False
-            self.state = self._step_runner.init_state(
-                self._spec.model, tx, batch
-            )
-            self._train_step = self._step_runner.train_step(self._spec.loss)
-            self._eval_step = self._step_runner.eval_step()
-            if self._fuse_task_steps and getattr(
-                self._step_runner, "accum_steps", 1
-            ) == 1:
-                if hasattr(self._step_runner, "train_multi_step"):
-                    self._multi_step = self._step_runner.train_multi_step(
-                        self._spec.loss
-                    )
-                else:
-                    # e.g. HostStepRunner: host-side work per batch can't
-                    # fuse into one XLA program; fall back to per-step.
-                    logger.warning(
-                        "fuse_task_steps ignored: %s has no "
-                        "train_multi_step",
-                        type(self._step_runner).__name__,
-                    )
-        else:
-            self.state = init_train_state(self._spec.model, tx, batch)
-            self._train_step = build_train_step(self._spec.loss)
-            if self._fuse_task_steps:
-                from elasticdl_tpu.core.step import build_multi_step
-
-                self._multi_step = build_multi_step(self._spec.loss)
 
     def _restore_state(self):
         from elasticdl_tpu.checkpoint import restore_from_dir
@@ -348,24 +328,15 @@ class Worker:
         self.state = restore_from_dir(
             self.state, self._checkpoint_dir_for_init,
             required=self._checkpoint_init_required,
-            host_tables=getattr(
-                self._step_runner, "host_tables", None
-            ),
+            host_tables=self._step_runner.host_tables,
         )
         # Restored leaves are host arrays; re-place them with the
         # runner's shardings or a mesh-sized table lands on one device.
-        if self._step_runner is not None and hasattr(
-            self._step_runner, "place_state"
-        ):
-            self.state = self._step_runner.place_state(self.state)
+        self.state = self._step_runner.place_state(self.state)
         # The restored version is the save baseline — without this,
         # interval-crossing counts pre-restore steps and writes a
         # spurious checkpoint on the first post-restore step.
         self._checkpoint.note_version(int(self.state.step))
-
-    def set_state(self, state):
-        """Install restored state (checkpoint resume / elastic re-init)."""
-        self.state = state
 
     # ---- telemetry ------------------------------------------------------
 
@@ -573,11 +544,7 @@ class Worker:
             send_ack("applied")
             return
         runner = self._step_runner
-        if (
-            runner is None
-            or not hasattr(runner, "resize")
-            or self._multihost_sync
-        ):
+        if not runner.can_resize or self._multihost_sync:
             # Nothing mesh-resident to reshard: plain-jit and host-tier
             # runners keep dense state on one device and sparse rows in
             # the row service; multi-host jobs resize by gang restart.
@@ -613,9 +580,7 @@ class Worker:
                     self._m_compiles.inc()
                     self._train_step = runner.train_step(self._spec.loss)
                     self._eval_step = runner.eval_step()
-                    if self._multi_step is not None and hasattr(
-                        runner, "train_multi_step"
-                    ):
+                    if self._multi_step is not None:
                         self._multi_step = runner.train_multi_step(
                             self._spec.loss
                         )
@@ -765,11 +730,7 @@ class Worker:
         # raw batches (dummy participation uses them directly).
         batches = iter(batches)
         prepared_iter = None
-        if (
-            self._step_runner is not None
-            and getattr(self._step_runner, "pull_ahead", False)
-            and not self._multihost_sync
-        ):
+        if self._step_runner.pull_ahead and not self._multihost_sync:
             first = next(batches, None)
             if first is None:
                 return 0
@@ -826,27 +787,25 @@ class Worker:
             # a row-service push failure must fail THIS task (and a
             # task-complete report must cover its last step's pushes —
             # nothing may ride a daemon thread past process exit).
-            flush = getattr(self._step_runner, "flush", None)
-            if flush is not None:
-                import sys as _sys
+            import sys as _sys
 
-                # Snapshot whether an exception is already propagating
-                # BEFORE calling flush — inside an except block
-                # exc_info() would report the flush's own error and the
-                # re-raise would be unreachable, silently downgrading a
-                # lost-push failure to a warning.
-                unwinding = _sys.exc_info()[0] is not None
-                try:
-                    flush()
-                except Exception:
-                    if not unwinding:
-                        raise
-                    # Don't mask the in-flight exception with the
-                    # flush's own.
-                    logger.warning(
-                        "row applier flush failed during task "
-                        "unwind:\n%s", traceback.format_exc(),
-                    )
+            # Snapshot whether an exception is already propagating
+            # BEFORE calling flush — inside an except block exc_info()
+            # would report the flush's own error and the re-raise would
+            # be unreachable, silently downgrading a lost-push failure
+            # to a warning.
+            unwinding = _sys.exc_info()[0] is not None
+            try:
+                self._step_runner.flush()
+            except Exception:
+                if not unwinding:
+                    raise
+                # Don't mask the in-flight exception with the flush's
+                # own.
+                logger.warning(
+                    "row applier flush failed during task "
+                    "unwind:\n%s", traceback.format_exc(),
+                )
         return count
 
     def _process_train_task_fused(self, batch_list, nbytes: int) -> int:

@@ -98,6 +98,22 @@ def test_fused_worker_drains_and_learns(tmp_path):
 def test_fused_mesh_runner_matches_stepwise():
     """MeshRunner.train_multi_step == stepwise mesh training (transformer
     with dp/sp/tp batch rules: place_task shifts specs right one dim)."""
+    _fused_mesh_matches_stepwise(
+        dict(d_model=32, n_heads=4, n_layers=1, d_ff=64),
+        (2, 2, 2), ("dp", "sp", "tp"),
+    )
+
+
+def test_fused_mesh_task_matches_stepwise_on_dp2_tp2():
+    """The same on the fast lane, at a size that runs in seconds: the
+    fused task program is the one every job on a mesh runs."""
+    _fused_mesh_matches_stepwise(
+        dict(d_model=64, n_heads=4, n_layers=2, d_ff=128),
+        (2, 2), ("dp", "tp"),
+    )
+
+
+def _fused_mesh_matches_stepwise(widths, mesh_shape, mesh_axes):
     import importlib.util
     import os
 
@@ -119,11 +135,10 @@ def test_fused_mesh_runner_matches_stepwise():
     zspec.loader.exec_module(zoo)
 
     cfg = TransformerConfig(
-        vocab_size=32, d_model=32, n_heads=4, n_layers=1, d_ff=64,
-        max_len=32, compute_dtype=np.float32,
+        vocab_size=32, max_len=32, compute_dtype=np.float32, **widths
     )
-    mesh = make_mesh((2, 2, 2), ("dp", "sp", "tp"),
-                     devices=jax.devices()[:8])
+    mesh = make_mesh(mesh_shape, mesh_axes,
+                     devices=jax.devices()[:int(np.prod(mesh_shape))])
 
     rng = np.random.RandomState(0)
 

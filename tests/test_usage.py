@@ -387,14 +387,31 @@ def test_check_trace_flags_partial_principal(tmp_path):
 
 
 def test_usage_drill_passes(tmp_path, monkeypatch):
-    """Fast-lane twin of ``make usage-smoke`` (shrunk schedule):
-    purity, coverage, and overhead gates through a live 2->3 split."""
+    """Fast-lane twin of ``make usage-smoke`` (shrunk schedule): the
+    purity and coverage gates through a live 2->3 split, which are
+    exact. The overhead gate (``P99_GATE``, a ratio of two wall-clock
+    p99s) is measured and reported here but held only by ``make
+    usage-smoke``, on a quiet machine: beside five other test workers
+    it read over 1.05 with nothing wrong. The drill reads the process
+    registry, which is its own in ``make usage-smoke``; here it gets a
+    fresh one, or the unattributed handler time of whatever test files
+    ran before in this worker process counts against its coverage."""
     from elasticdl_tpu.chaos import usage_drill
 
     monkeypatch.setattr(usage_drill, "PUSHES", 80)
     monkeypatch.setattr(usage_drill, "SPLIT_AT", 40)
     monkeypatch.setattr(usage_drill, "WARMUP", 10)
-    report = usage_drill.run_drill(str(tmp_path), seed=7)
-    assert report["passed"], report["problems"]
-    assert report["purity"]["ok"]
+    with _fresh_default_registry():
+        report = usage_drill.run_drill(str(tmp_path), seed=7)
+    assert report["purity"]["ok"], report["purity"]["problems"]
+    assert all(
+        report["purity"]["bytes_by_method"][method] > 0
+        for method in ("ingest_rows", "replica_refresh")
+    )
+    assert report["attribution"]["ok"], report["attribution"]
     assert report["attribution"]["attributed_handler_share"] >= 0.95
+    ratios = [a["ratio"] for a in report["latency"]["attempts"]]
+    assert ratios and all(0 < r < float("inf") for r in ratios)
+    assert [
+        p for p in report["problems"] if "attributed p99" not in p
+    ] == []

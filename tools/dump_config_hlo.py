@@ -36,29 +36,15 @@ def main():
     import jax
 
     from benchlib import load_config_harness
-    from elasticdl_tpu.core.step import build_multi_step
-    from elasticdl_tpu.core.train_state import init_train_state
+    from elasticdl_tpu.core.step import runner_for_spec
 
     spec, task, batch, steps, _ = load_config_harness(args.config)
-    if getattr(spec, "make_sparse_runner", None):
-        # Device-tier sparse configs compile the runner's program, not
-        # the dense multi_step (same branch as measure_multi_step).
-        runner = spec.make_sparse_runner()
-        state = runner.init_state(
-            spec.model, spec.make_optimizer(),
-            jax.tree.map(lambda x: x[0], task), seed=0,
-        )
-        multi_step = runner.train_multi_step(spec.loss)
-        lowered = multi_step.lower(state, task)
-    else:
-        state = init_train_state(
-            spec.model, spec.make_optimizer(),
-            jax.tree.map(lambda x: x[0], task), seed=0,
-        )
-        multi_step = build_multi_step(spec.loss)
-        lowered = jax.jit(
-            multi_step, donate_argnums=(0,)
-        ).lower(state, task)
+    runner = runner_for_spec(spec)
+    state = runner.init_state(
+        spec.model, spec.make_optimizer(),
+        jax.tree.map(lambda x: x[0], task), seed=0,
+    )
+    lowered = runner.train_multi_step(spec.loss).lower(state, task)
     compiled = lowered.compile()
     text = compiled.as_text()
     if args.out:

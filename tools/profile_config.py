@@ -123,26 +123,16 @@ def main():
     import jax
 
     from benchlib import load_config_harness
-    from elasticdl_tpu.core.step import build_multi_step
-    from elasticdl_tpu.core.train_state import init_train_state
+    from elasticdl_tpu.core.step import runner_for_spec
 
     name = args.config
     spec, task, batch, steps, measure_tasks = load_config_harness(name)
-    if getattr(spec, "make_sparse_runner", None):
-        # Sparse-plane configs (recsys) need their runner's step —
-        # mirrors benchlib.measure_multi_step's branch.
-        runner = spec.make_sparse_runner()
-        state = runner.init_state(
-            spec.model, spec.make_optimizer(),
-            jax.tree.map(lambda x: x[0], task), seed=0,
-        )
-        multi_step = runner.train_multi_step(spec.loss)
-    else:
-        state = init_train_state(
-            spec.model, spec.make_optimizer(),
-            jax.tree.map(lambda x: x[0], task), seed=0,
-        )
-        multi_step = build_multi_step(spec.loss)
+    runner = runner_for_spec(spec)
+    state = runner.init_state(
+        spec.model, spec.make_optimizer(),
+        jax.tree.map(lambda x: x[0], task), seed=0,
+    )
+    multi_step = runner.train_multi_step(spec.loss)
     for _ in range(2):  # warmup/compile
         state, metrics = multi_step(state, task)
     float(np.asarray(metrics["loss"][-1]))
