@@ -47,9 +47,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.models.transformer import _Constrain, _LMHead
 from elasticdl_tpu.ops.flash_attention import (
+    describe_kept as describe_attention_kept,
     describe_tiles as describe_attention_tiles,
     flash_attention,
     log_traced as log_traced_attention,
+    remat_policy as attention_remat_policy,
     supports as flash_supports,
 )
 from elasticdl_tpu.ops.grouped_matmul import GROUPED_PRODUCT, grouped_matmul
@@ -191,7 +193,9 @@ class LatentAttention(nn.Module):
             log_traced_attention(
                 "pallas flash kernel",
                 f"tpu backend, shape tiles the kernel blocks; {heads}; "
-                + describe_attention_tiles(s), q.shape,
+                + describe_attention_tiles(s)
+                + ("; " + describe_attention_kept(v)
+                   if cfg.remat else ""), q.shape,
             )
             o = flash_attention(q, k, v, causal=True, scale=scale)
         else:
@@ -500,7 +504,10 @@ class MlaMoeLM(nn.Module):
             cfg.vocab_size, dt, fused=(cfg.fused_head and training),
             name="lm_head",
         )
-        block_cls = nn.remat(MlaBlock) if cfg.remat else MlaBlock
+        block_cls = (
+            nn.remat(MlaBlock, policy=attention_remat_policy())
+            if cfg.remat else MlaBlock
+        )
         x = wsc(embed(tokens), "dp", None, None)
         counters = {}
         held = iter(routing) if routing is not None else None

@@ -30,9 +30,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.ops.flash_attention import (
+    describe_kept as describe_attention_kept,
     describe_tiles as describe_attention_tiles,
     flash_attention,
     log_traced as log_traced_attention,
+    remat_policy as attention_remat_policy,
     supports as flash_supports,
 )
 from elasticdl_tpu.ops.ring_attention import dense_attention, ring_attention
@@ -59,6 +61,9 @@ class TransformerConfig:
     # Rematerialize each block on backward (jax.checkpoint): trades
     # ~1/3 more FLOPs for O(n_layers) less activation HBM — the lever
     # for deep/long-context configs (HBM is the usual TPU bottleneck).
+    # A block that ran the Pallas attention kernel keeps its o and
+    # logsumexp (flash_attention.remat_policy) and never runs the
+    # forward kernel twice.
     remat: bool = False
     compute_dtype: jnp.dtype = jnp.bfloat16
     # Fused head+loss mode: during TRAINING the model returns
@@ -157,7 +162,9 @@ class SelfAttention(nn.Module):
                 "pallas flash kernel",
                 "tpu backend, shape tiles the kernel blocks; head sizes "
                 f"q/k {q.shape[-1]}, v {v.shape[-1]}; "
-                + describe_attention_tiles(q.shape[1]), q.shape,
+                + describe_attention_tiles(q.shape[1])
+                + ("; " + describe_attention_kept(v)
+                   if cfg.remat else ""), q.shape,
             )
             o = flash_attention(q, k, v, causal=True, scale=scale)
         else:
@@ -473,7 +480,8 @@ class TransformerLM(nn.Module):
         # dropout's Python bool branch still works under remat. Decode
         # (inference) never remats.
         block_cls = (
-            nn.remat(Block, static_argnums=(2,))
+            nn.remat(Block, static_argnums=(2,),
+                     policy=attention_remat_policy())
             if cfg.remat and not self.decode else Block
         )
         for i in range(cfg.n_layers):

@@ -46,6 +46,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -597,10 +598,46 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
     return o
 
 
+# What the forward kernel alone can give: a block that recomputes
+# (``jax.checkpoint``, ``nn.remat``) under ``remat_policy()`` keeps
+# these two and so never runs the kernel a second time; q, k and v are
+# projections its recomputation makes again anyway. Without a checkpoint
+# around it a name lowers to nothing.
+_KEPT_O = "flash_attention_o"
+_KEPT_LSE = "flash_attention_lse"
+
+
+def remat_policy():
+    """The policy for a recomputed block that may hold this module's
+    kernel: keep the forward kernel's o and logsumexp, recompute
+    everything else. Where no kernel is traced (the dense reference, the
+    ring) no value bears the names and nothing is kept."""
+    return jax.checkpoint_policies.save_only_these_names(
+        _KEPT_O, _KEPT_LSE
+    )
+
+
+def describe_kept(v) -> str:
+    """The clause the attention line of a model with remat on ends with,
+    for v (B, S, H, Dv): what ``remat_policy()`` keeps of one block, o
+    in v's shape and type and a float32 logsumexp."""
+    b, s_len, h, dv = v.shape
+    kept = b * h * s_len * (dv * v.dtype.itemsize + 4)
+    return (
+        f"under remat the block keeps o and logsumexp ({kept / 1e6:.1f} "
+        "MB), the forward kernel is not run again"
+    )
+
+
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     o, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                             interpret)
-    return o, (q, k, v, o, lse[..., 0])
+    # The names have to sit here, inside the forward rule: one on
+    # ``flash_attention``'s result alone would keep o and still run the
+    # kernel again for the logsumexp.
+    o = checkpoint_name(o, _KEPT_O)
+    lse = checkpoint_name(lse[..., 0], _KEPT_LSE)
+    return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):

@@ -6,6 +6,8 @@ Mesh/sharding logic must be testable without TPU hardware (SURVEY.md §7
 
 import os
 
+import pytest
+
 # grpc's C-core INFO logs (GOAWAY notices on every server teardown)
 # splice into pytest's dot-progress lines and corrupt the plain-text
 # test output the CI lane parses; only errors are worth the noise.
@@ -89,8 +91,6 @@ _SLOW_TESTS = {
 
 
 def pytest_collection_modifyitems(config, items):
-    import pytest
-
     for item in items:
         mod = getattr(item.module, "__name__", "")
         base = item.name.split("[")[0]
@@ -101,3 +101,21 @@ def pytest_collection_modifyitems(config, items):
                 reason="TPU lane: set ELASTICDL_TPU_TESTS=1 "
                        "(make test-tpu) to run on the real chip"
             ))
+
+
+@pytest.fixture
+def kernels_traced(monkeypatch):
+    """Both model families take their TPU branch and the attention
+    kernels are interpreted: for tracing here (jaxprs, saved residuals),
+    where nothing of it is run."""
+    import functools
+
+    import jax
+
+    from elasticdl_tpu.models import mla_moe, transformer
+    from elasticdl_tpu.ops.flash_attention import flash_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    interpreted = functools.partial(flash_attention, interpret=True)
+    monkeypatch.setattr(mla_moe, "flash_attention", interpreted)
+    monkeypatch.setattr(transformer, "flash_attention", interpreted)

@@ -585,3 +585,68 @@ def test_layers_share_one_trace_of_each_kernel(monkeypatch, s, block,
     assert counts == {kernels[0]: 1}
     jax.jit(jax.grad(layers, argnums=(0, 1, 2)))(q, k, v)
     assert counts == {name: 1 for name in kernels}
+
+
+def count_calls(jaxpr, primitive="pallas_call"):
+    """Equations of ``primitive`` in a jaxpr and every jaxpr under it."""
+    return sum(
+        (eqn.primitive.name == primitive)
+        + sum(count_calls(sub, primitive)
+              for sub in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns
+    )
+
+
+@pytest.mark.parametrize("heads", [(16, 16), (24, 16)],
+                         ids=["equal_heads", "wider_qk"])
+@pytest.mark.parametrize("s,block", [(32, 32), (64, 16)],
+                         ids=["one_tile", "grid_of_tiles"])
+def test_a_recomputed_block_runs_the_forward_kernel_once(monkeypatch, s,
+                                                         block, heads):
+    """A block under ``jax.checkpoint`` with ``remat_policy()`` keeps the
+    forward kernel's o and logsumexp: its gradient holds the three
+    kernel calls of a block that is not recomputed, where a plain
+    checkpoint runs the forward kernel again, and dq, dk, dv are the
+    same bits in all three."""
+    d, dv = heads
+    monkeypatch.setattr(flash, "SUB_TILE", 8)
+    assert flash.tile_plan(s, s, block_q=block, block_k=block).rows != (1,)
+    rng = np.random.RandomState(s + d)
+    q, k = (jnp.asarray(rng.randn(2, s, 2, d), jnp.float32) * 0.3
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(2, s, 2, dv), jnp.float32) * 0.3
+    w = jnp.asarray(rng.randn(dv, dv), jnp.float32)
+
+    def block_fn(q, k, v):
+        o = flash_attention(jnp.tanh(q), k, v, causal=True, block_q=block,
+                            block_k=block, interpret=True)
+        return jnp.sum(jnp.sin(o @ w))
+
+    grads, calls = {}, {}
+    for name, fn in [
+        ("no_checkpoint", block_fn),
+        ("plain", jax.checkpoint(block_fn)),
+        ("policy", jax.checkpoint(block_fn, policy=flash.remat_policy())),
+    ]:
+        grad = jax.grad(fn, argnums=(0, 1, 2))
+        calls[name] = count_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+        grads[name] = jax.jit(grad)(q, k, v)
+    assert calls == {"no_checkpoint": 3, "plain": 4, "policy": 3}
+    for name in ("plain", "policy"):
+        for got, want, leaf in zip(grads[name], grads["no_checkpoint"],
+                                   ("dq", "dk", "dv")):
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want), err_msg=f"{name} {leaf}")
+
+
+@pytest.mark.parametrize(
+    "v_shape,megabytes",
+    [((4, 4096, 32, 128), "136.3"), ((8, 1024, 16, 64), "17.3")],
+    ids=["joyai_ep16", "gpt2_medium"],
+)
+def test_kept_clause_counts_o_and_logsumexp(v_shape, megabytes):
+    """(B*H, S, Dv) of o in v's type and (B*H, S) float32 a block."""
+    v = jax.ShapeDtypeStruct(v_shape, jnp.bfloat16)
+    assert flash.describe_kept(v) == (
+        f"under remat the block keeps o and logsumexp ({megabytes} MB), "
+        "the forward kernel is not run again")

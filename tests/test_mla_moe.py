@@ -18,6 +18,7 @@ from elasticdl_tpu.core.train_state import init_train_state
 from elasticdl_tpu.models import mla_moe
 from elasticdl_tpu.models.mla_moe import (
     ExpertLayer,
+    MlaBlock,
     MlaMoeConfig,
     MlaMoeLM,
     held_experts_part,
@@ -452,18 +453,25 @@ def test_flash_with_a_narrower_v_head_matches_dense(blocks, sub, tiles,
                                    err_msg=f"d{name}")
 
 
-def test_attention_line_reports_how_the_cells_grid_is_spent(monkeypatch):
+@pytest.mark.parametrize(
+    "remat,kept",
+    [(False, ""),
+     (True, "; under remat the block keeps o and logsumexp (136.3 MB), the "
+            "forward kernel is not run again")],
+    ids=["no_remat", "remat"],
+)
+def test_attention_line_reports_how_the_cells_grid_is_spent(monkeypatch,
+                                                            remat, kept):
     """The worker's attention line for the benchmark cell's shape (4 x
-    4,096 tokens, 32 heads of 192 over 128): the head sizes, then the
-    grid's spending as ``tile_plan`` counts it."""
-    import logging
-
-    from elasticdl_tpu.models import mla_moe
+    4,096 tokens, 32 heads of 192 over 128, bfloat16): the head sizes,
+    the grid's spending as ``tile_plan`` counts it and, with remat on,
+    what the recomputed block keeps of the kernel."""
     from elasticdl_tpu.ops import flash_attention as flash
 
     cfg = program_config(
         hidden_size=64, num_heads=32, qk_nope_head_dim=128,
-        qk_rope_head_dim=64, v_head_dim=128)
+        qk_rope_head_dim=64, v_head_dim=128, remat=remat,
+        compute_dtype=jnp.bfloat16)
     plan = flash.tile_plan(4096, 4096)
     assert plan.tiles == (6, 4, 6)
     assert (plan.computed, plan.total) == (10, 16)
@@ -485,8 +493,88 @@ def test_attention_line_reports_how_the_cells_grid_is_spent(monkeypatch):
         "attention: traced pallas flash kernel for q(4, 4096, 32, 192): tpu "
         "backend, shape tiles the kernel blocks; head sizes q/k 192, v 128; "
         "grid 4x4 of blocks 1024x1024: 6 tiles whole and unmasked, 4 "
-        "diagonal tiles walked 10 of 16 sub-tiles 256x256, 6 skipped"
+        "diagonal tiles walked 10 of 16 sub-tiles 256x256, 6 skipped" + kept
     ]
+
+
+def test_remat_gives_the_plain_models_bits_where_no_kernel_is_traced(
+        seeded):
+    """On the CPU path (the dense reference: no value bears the kernel's
+    names, the policy keeps nothing) ``remat=True`` recomputes every
+    block and gives the loss and every gradient leaf of ``remat=False``
+    to the bit, each compiled as one program (op by op a block under
+    remat is still run as a program of its own, and rounds as one)."""
+    weights, tokens, labels = seeded
+    params = reference.to_program_tree(weights, CFG)
+
+    def loss_and_grads(remat):
+        model = MlaMoeLM(program_config(remat=remat))
+
+        def program_loss(p):
+            out = model.apply({"params": p}, tokens, training=True)
+            return ZOO.loss(labels, out, jnp.ones((ROWS,)))
+
+        return jax.jit(jax.value_and_grad(program_loss))(params)
+
+    loss, grads = loss_and_grads(True)
+    want_loss, want = loss_and_grads(False)
+    assert float(loss) == float(want_loss)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(flat, jax.tree.leaves(want)):
+        np.testing.assert_array_equal(
+            got, ref, err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("use_experts", [False, True],
+                         ids=["dense_block", "expert_block"])
+def test_a_recomputed_block_keeps_its_arguments_and_the_kernels_two(
+        kernels_traced, capsys, use_experts):
+    """What a block under ``nn.remat`` with the kernels' policy holds
+    for its backward pass: its arguments (and RoPE's two constants), o
+    and the logsumexp of its attention kernel, nothing else."""
+    import flax.linen as nn
+    import jax.ad_checkpoint
+
+    from elasticdl_tpu.ops import flash_attention as flash
+
+    cfg = program_config()
+    seq = 128                       # the shortest the default blocks tile
+    x = jnp.zeros((ROWS, seq, cfg.hidden_size), jnp.float32)
+    params = jax.eval_shape(
+        lambda: MlaBlock(cfg, use_experts=use_experts).init(
+            jax.random.PRNGKey(0), x))["params"]
+    block = nn.remat(MlaBlock, policy=flash.remat_policy())(
+        cfg, use_experts=use_experts)
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda params, x: block.apply({"params": params}, x)[0], params, x)
+    kept = [line for line in capsys.readouterr().out.splitlines()
+            if " from the argument " not in line
+            and not line.endswith("from a constant")]
+    heads = ROWS * cfg.num_heads
+    assert len(kept) == 2
+    assert kept[0].startswith(f"f32[{heads},{seq},{cfg.v_head_dim}] ")
+    assert kept[1].startswith(
+        f"f32[{heads},{seq}] named 'flash_attention_lse' ")
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["no_remat", "remat"])
+def test_the_models_gradient_runs_each_forward_kernel_once(
+        kernels_traced, remat):
+    """Three kernel calls a block (forward, dq, dk/dv) in the gradient
+    of the whole model, recomputed or not: 3 blocks and the MTP block."""
+    from tests.test_flash_attention import count_calls
+
+    model = MlaMoeLM(program_config(remat=remat))
+    tokens = jnp.zeros((ROWS, 128), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, tokens, training=False))["params"]
+
+    def program_loss(p):
+        out = model.apply({"params": p}, tokens, training=True)
+        return ZOO.loss(tokens, out, jnp.ones((ROWS,)))
+
+    jaxpr = jax.make_jaxpr(jax.grad(program_loss))(params)
+    assert count_calls(jaxpr.jaxpr) == 3 * 4
 
 
 def test_wide_heads_raise_the_kernels_vmem_limit_and_others_do_not():

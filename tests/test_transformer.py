@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.core.step import build_train_step
@@ -196,6 +197,93 @@ def test_remat_matches_plain():
                                rtol=1e-5, atol=1e-6)
 
 
+def test_remat_gives_the_plain_models_bits_where_no_kernel_is_traced():
+    """The quick twin of ``test_remat_matches_plain``: on the CPU path
+    (the dense reference: no value bears the kernel's names, the policy
+    keeps nothing) ``remat=True`` gives the loss and every gradient leaf
+    of ``remat=False`` to the bit, each compiled as one program."""
+    import dataclasses
+
+    batch = _batch(b=2)
+    loss_fn = _lm_loss()
+    params = TransformerLM(CFG).init(
+        jax.random.PRNGKey(0), batch["features"])["params"]
+
+    def loss_and_grads(remat):
+        model = TransformerLM(dataclasses.replace(CFG, remat=remat))
+
+        def program_loss(p):
+            out = model.apply({"params": p}, batch["features"],
+                              training=True)
+            return loss_fn(batch["labels"], out, batch["mask"])
+
+        return jax.jit(jax.value_and_grad(program_loss))(params)
+
+    loss, grads = loss_and_grads(True)
+    want_loss, want = loss_and_grads(False)
+    assert float(loss) == float(want_loss)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, got), ref in zip(flat, jax.tree.leaves(want)):
+        np.testing.assert_array_equal(
+            got, ref, err_msg=jax.tree_util.keystr(path))
+
+
+def test_a_recomputed_block_keeps_its_arguments_and_the_kernels_two(
+        kernels_traced, capsys):
+    """What a block under ``nn.remat`` with the kernels' policy holds
+    for its backward pass: its arguments, o and the logsumexp of its
+    attention kernel, nothing else."""
+    import dataclasses
+
+    import flax.linen as nn
+    import jax.ad_checkpoint
+
+    from elasticdl_tpu.models.transformer import Block
+    from elasticdl_tpu.ops import flash_attention as flash
+
+    seq = 128                       # the shortest the default blocks tile
+    cfg = dataclasses.replace(CFG, max_len=seq, remat=True)
+    x = jnp.zeros((2, seq, cfg.d_model), jnp.float32)
+    params = jax.eval_shape(
+        lambda: Block(cfg).init(jax.random.PRNGKey(0), x))["params"]
+    block = nn.remat(Block, static_argnums=(2,),
+                     policy=flash.remat_policy())(cfg)
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda params, x: block.apply({"params": params}, x, True),
+        params, x)
+    kept = [line for line in capsys.readouterr().out.splitlines()
+            if " from the argument " not in line]
+    heads = 2 * cfg.n_heads
+    assert len(kept) == 2
+    assert kept[0].startswith(f"f32[{heads},{seq},{cfg.head_dim}] ")
+    assert kept[1].startswith(
+        f"f32[{heads},{seq}] named 'flash_attention_lse' ")
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["no_remat", "remat"])
+def test_the_models_gradient_runs_each_forward_kernel_once(
+        kernels_traced, remat):
+    """Three kernel calls a block (forward, dq, dk/dv) in the gradient
+    of the whole model, recomputed or not."""
+    import dataclasses
+
+    from tests.test_flash_attention import count_calls
+
+    cfg = dataclasses.replace(CFG, max_len=128, remat=remat)
+    model = TransformerLM(cfg)
+    tokens = jnp.zeros((2, cfg.max_len), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens))["params"]
+    loss_fn = _lm_loss()
+
+    def program_loss(p):
+        out = model.apply({"params": p}, tokens, training=True)
+        return loss_fn(tokens, out, jnp.ones((2,)))
+
+    jaxpr = jax.make_jaxpr(jax.grad(program_loss))(params)
+    assert count_calls(jaxpr.jaxpr) == 3 * cfg.n_layers
+
+
 def test_moe_top2_routing():
     """k=2: combine weights are the renormalized top-2 gates (sum to 1,
     exactly two nonzero experts per token); training still learns."""
@@ -342,7 +430,6 @@ def test_moe_scatter_expert_parallel():
 
 
 def test_moe_dispatch_validated():
-    import pytest
     import dataclasses
 
     from elasticdl_tpu.models.transformer import MoE
