@@ -89,6 +89,10 @@ class MlaMoeConfig:
     remat: bool = False
     compute_dtype: jnp.dtype = jnp.bfloat16
     fused_head: bool = False
+    # What an expert computes (``EXPERT_FORMS``), and the shared
+    # expert's width where it is not a routed one's (0: the same).
+    expert_form: str = "silu_gated"
+    shared_intermediate_size: int = 0
 
     @property
     def qk_head_dim(self) -> int:
@@ -229,6 +233,31 @@ class GatedMlp(nn.Module):
         return _dense(self.cfg.hidden_size, dt, "down")(hidden)
 
 
+def relu2(x):
+    return jnp.square(nn.relu(x))
+
+
+class Relu2Mlp(nn.Module):
+    """W_down(relu(W_up x)^2): two matrices, no gate."""
+    width: int
+    cfg: MlaMoeConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, x):
+        dt = self.cfg.compute_dtype
+        wsc = _Constrain(self.mesh)
+        hidden = wsc(relu2(_dense(self.width, dt, "up")(x)),
+                     "dp", None, "tp")
+        return _dense(self.cfg.hidden_size, dt, "down")(hidden)
+
+
+# An expert's form: (the shared expert's module, whether the routed
+# experts have a gate matrix). ``silu_gated``: DeepSeek-V3's, three
+# matrices; ``relu2``: Nemotron-H's, two.
+EXPERT_FORMS = {"silu_gated": (GatedMlp, True), "relu2": (Relu2Mlp, False)}
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _spread(rows, order, inverse, live, k):
     """rows (T, d) -> (T*k, d): token-choice ``order[j]`` (token
@@ -280,13 +309,15 @@ def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
     rows (T, d); chosen (T, k) int32 expert ids over the router's whole
     width; weights (T, k); w_gate/w_up (n, d, f), w_down (n, f, d): the
     experts ``first_held .. first_held + n`` (``first_held`` may be
-    traced: a member's place on ``ep``). Token-choices are sorted by
+    traced: a member's place on ``ep``). ``w_gate`` None: the experts
+    have no gate and are ``w_down(relu(w_up x)^2)``, else
+    ``w_down(silu(w_gate x) * (w_up x))``. Token-choices are sorted by
     expert with those of absent experts last; the grouped products run
     over the n held groups; no capacity, so no choice of a held expert
     is ever dropped. Returns (part (T, d), rows of every held expert
     (n,) int32)."""
     t, k = chosen.shape
-    n = w_gate.shape[0]
+    n = w_up.shape[0]
     local = chosen - first_held
     held = (local >= 0) & (local < n)
     group = jnp.where(held, local, n).reshape(t * k)
@@ -301,13 +332,16 @@ def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
     dt = rows.dtype
     live = jnp.arange(t * k, dtype=jnp.int32) < jnp.sum(sizes)
     sorted_rows = _spread(rows, order, inverse, live, k)
-    gate_up = grouped_matmul(
-        sorted_rows,
-        jnp.concatenate([w_gate.astype(dt), w_up.astype(dt)], axis=2),
-        sizes,
-    )
-    f = w_gate.shape[2]
-    hidden = nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+    if w_gate is None:
+        hidden = relu2(grouped_matmul(sorted_rows, w_up.astype(dt), sizes))
+    else:
+        gate_up = grouped_matmul(
+            sorted_rows,
+            jnp.concatenate([w_gate.astype(dt), w_up.astype(dt)], axis=2),
+            sizes,
+        )
+        f = w_gate.shape[2]
+        hidden = nn.silu(gate_up[:, :f]) * gate_up[:, f:]
     out = grouped_matmul(hidden, w_down.astype(dt), sizes)
     # Past the held rows a grouped product leaves what it likes, in its
     # result and in its cotangent: those rows are cut off (selected
@@ -324,12 +358,16 @@ def log_traced_experts(cfg: MlaMoeConfig, rows_bound: int, ep: int):
     every trace asks again), like the attention's: what is held, what
     the router scores, the static bound of the grouped products' rows,
     and which grouped product runs."""
+    shared = cfg.shared_intermediate_size or cfg.moe_intermediate_size
     logger.info(
         "experts: traced drop-free layer holding experts [%d, %d) of "
-        "router width %d, top-%d, rows bound %d, grouped product %s%s",
+        "router width %d, top-%d, rows bound %d, grouped product %s%s%s",
         cfg.first_held, cfg.first_held + cfg.n_held, cfg.router_width,
         cfg.top_k, rows_bound, GROUPED_PRODUCT,
         f", {cfg.n_held // ep} a member over ep={ep}" if ep > 1 else "",
+        "" if cfg.expert_form == "silu_gated" else (
+            f", experts {cfg.expert_form} of width "
+            f"{cfg.moe_intermediate_size}, shared expert {shared}"),
     )
 
 
@@ -357,12 +395,37 @@ def _load_tap_bwd(load, _):
 _load_tap.defvjp(_load_tap_fwd, _load_tap_bwd)
 
 
+def balanced_adam(lr, bias_update_speed, warmup_steps=0):
+    """The optimizer of a model with :class:`ExpertLayer`s: Adam at
+    ``lr``, reached linearly from 0 over ``warmup_steps`` steps where
+    given (the first step then moves nothing); the selection biases
+    (``router_bias``) by plain descent at ``bias_update_speed``, which
+    with :func:`_load_tap` is the auxiliary-loss-free balancing rule."""
+    import optax
+
+    def kinds(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "selection_bias" if path[-1].key == "router_bias"
+            else "weights", params)
+
+    rate = optax.linear_schedule(0.0, lr, warmup_steps) if warmup_steps else lr
+    return optax.multi_transform(
+        {"weights": optax.adam(rate),
+         "selection_bias": optax.sgd(bias_update_speed)}, kinds)
+
+
 class ExpertLayer(nn.Module):
     """Shared expert + the held routed experts' weighted part.
 
     ``routing`` (B, S, k) int32, where given, are the experts every
     token goes to in place of the layer's own top k (routing replay:
-    the weights are still this layer's scores of them)."""
+    the weights are still this layer's scores of them).
+
+    ``cfg`` is any configuration with the expert layer's fields
+    (``n_held``, ``first_held``, ``router_width``, ``top_k``,
+    ``routed_scaling_factor``, ``moe_intermediate_size``,
+    ``shared_intermediate_size``, ``expert_form``, ``compute_dtype``,
+    ``hidden_size``): this family's or ``models/nemotron_h.py``'s."""
     cfg: MlaMoeConfig
     mesh: Optional[Mesh] = None
 
@@ -384,7 +447,9 @@ class ExpertLayer(nn.Module):
             (cfg.router_width,), jnp.float32,
         )
         init = nn.initializers.normal(0.02)
-        w_gate = self.param("w_gate", init, (n, d, f), jnp.float32)
+        shared_mlp, gated = EXPERT_FORMS[cfg.expert_form]
+        w_gate = (self.param("w_gate", init, (n, d, f), jnp.float32)
+                  if gated else None)
         w_up = self.param("w_up", init, (n, d, f), jnp.float32)
         w_down = self.param("w_down", init, (n, f, d), jnp.float32)
 
@@ -419,7 +484,9 @@ class ExpertLayer(nn.Module):
             dtype=jnp.int32,
         )
         part = part + _load_tap(bias, load).astype(dt)
-        shared = GatedMlp(f, cfg, self.mesh, name="shared")(x)
+        shared = shared_mlp(
+            cfg.shared_intermediate_size or f, cfg, self.mesh, name="shared"
+        )(x)
         counters = {
             "moe_rows": jnp.sum(sizes), "moe_expert_rows_max": jnp.max(sizes)
         }
@@ -447,7 +514,8 @@ class ExpertLayer(nn.Module):
         stacked = P("ep", None, None)
         return jax.shard_map(
             member, mesh=self.mesh,
-            in_specs=(P(), P(), P(), stacked, stacked, stacked),
+            in_specs=(P(), P(), P(), None if w_gate is None else stacked,
+                      stacked, stacked),
             out_specs=(P(), P("ep")), check_vma=False,
         )(rows, chosen, weights, w_gate, w_up, w_down)
 

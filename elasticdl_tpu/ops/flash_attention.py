@@ -130,6 +130,8 @@ class TilePlan(NamedTuple):
     sub_k: int
     rows: Tuple[int, ...]
     grid: Tuple[int, int] = (1, 1)
+    # Query heads that read one key/value head (``_kv_row``).
+    group: int = 1
 
     @property
     def computed(self) -> int:
@@ -157,14 +159,18 @@ class TilePlan(NamedTuple):
         blocks = f"blocks {self.block_q}x{self.block_k}"
         sub_tiles = f"sub-tiles {self.sub_q}x{self.sub_k}"
         walked = f"{self.computed} of {self.total}"
+        shared = (
+            f"; one key/value head read in place by {self.group} query "
+            "heads, dk/dv summed over them" if self.group > 1 else ""
+        )
         if self.grid == (1, 1) or self.rows == (1,):
-            return f"{blocks}, {sub_tiles}, {walked} computed"
+            return f"{blocks}, {sub_tiles}, {walked} computed{shared}"
         whole, diagonal, skipped = self.tiles
         return (
             f"grid {self.grid[0]}x{self.grid[1]} of {blocks}: {whole} "
             f"tile{'s' if whole != 1 else ''} whole and unmasked, "
             f"{diagonal} diagonal tiles walked {walked} {sub_tiles}, "
-            f"{skipped} skipped"
+            f"{skipped} skipped{shared}"
         )
 
 
@@ -211,7 +217,7 @@ def _one_tile(sq, sk, block_q, block_k):
 
 
 def tile_plan(sq, sk, causal=True, block_q=0, block_k=0, q_offset=0,
-              k_offset=0, sub=None) -> TilePlan:
+              k_offset=0, sub=None, group=1) -> TilePlan:
     """What a kernel call with these arguments multiplies (the kernels
     ask ``_walk`` the same question): for the line ``log_traced`` prints
     and for the tests. Traced offsets are anything that is not an
@@ -221,17 +227,21 @@ def tile_plan(sq, sk, causal=True, block_q=0, block_k=0, q_offset=0,
     grid = (sq // block_q, sk // block_k)
     rows = _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset, sub)
     if rows is None:
-        return TilePlan(block_q, block_k, block_q, block_k, (1,), grid)
-    return TilePlan(block_q, block_k, sub, sub, rows, grid)
+        return TilePlan(block_q, block_k, block_q, block_k, (1,), grid,
+                        group)
+    return TilePlan(block_q, block_k, sub, sub, rows, grid, group)
 
 
-def describe_tiles(s_len, causal=True, traced_offsets=False) -> str:
+def describe_tiles(s_len, causal=True, traced_offsets=False,
+                   group=1) -> str:
     """``tile_plan(...).describe()`` of the kernels a layer over a
     sequence of ``s_len`` runs with the default blocks: the standalone
-    kernels, or the ring's chunk kernels (``traced_offsets``)."""
+    kernels, or the ring's chunk kernels (``traced_offsets``); ``group``
+    query heads to a key/value head."""
     offset = None if traced_offsets else 0
     return tile_plan(
-        s_len, s_len, causal, q_offset=offset, k_offset=offset
+        s_len, s_len, causal, q_offset=offset, k_offset=offset,
+        group=group,
     ).describe()
 
 
@@ -258,7 +268,8 @@ def _cost(bh, sq, sk, d, dv, causal, byte_tensors):
     (tools/measure_config.py, BASELINE.md round-4 note).
 
     ``byte_tensors``: (count, seq_len, width, dtype_size) of
-    (BH, seq_len, width)-shaped operands/outputs for bytes_accessed."""
+    (BH, seq_len, width)-shaped operands/outputs for bytes_accessed;
+    k and v with fewer heads than q count ``_kv_share`` of one."""
     frac = 0.5 if causal else 1.0
     flops = int(2 * bh * sq * sk * (d + dv) * frac)
     # One exp per score element per kernel (fwd online-softmax; each
@@ -272,6 +283,26 @@ def _cost(bh, sq, sk, d, dv, causal, byte_tensors):
         flops=flops, transcendentals=transcendentals,
         bytes_accessed=nbytes,
     )
+
+
+def _kv_row(q, k):
+    """The (batch*head) row of k and v that query row ``b`` of the grid
+    reads: q (B*H, S, D) against k, v (B*Hkv, S, .), query head h
+    reading key/value head ``h // (H / Hkv)``, which is row ``b //
+    group`` because H = Hkv * group. The key/value heads are never
+    repeated in HBM: the index maps name the shared row, and
+    consecutive grid rows of one group find its tile resident. With as
+    many key/value heads as query heads the maps are what they were."""
+    group = q.shape[0] // k.shape[0]
+    if group == 1:
+        return lambda b: b
+    return lambda b: jax.lax.div(b, jnp.int32(group))
+
+
+def _kv_share(q, k):
+    """k's and v's rows as a share of q's, for ``_cost``'s bytes."""
+    group = q.shape[0] // k.shape[0]
+    return 1 if group == 1 else 1.0 / group
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc, o_acc,
@@ -489,7 +520,8 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
     bh, s_len, d = q.shape
     dv = v.shape[2]
     if rows is not None and _one_tile(s_len, s_len, block_q, block_k):
-        out_shape, cost = _forward_outputs(q, v, causal)
+        out_shape, cost = _forward_outputs(q, k, v, causal)
+        kv_row = _kv_row(q, k)
         whole = pl.BlockSpec((1, s_len, d), lambda b: (b, 0, 0))
         whole_v = pl.BlockSpec((1, s_len, dv), lambda b: (b, 0, 0))
         return pl.pallas_call(
@@ -498,7 +530,11 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
                 scale=scale,
             ),
             grid=(bh,),
-            in_specs=[whole, whole, whole_v],
+            in_specs=[
+                whole,
+                pl.BlockSpec((1, s_len, d), lambda b: (kv_row(b), 0, 0)),
+                pl.BlockSpec((1, s_len, dv), lambda b: (kv_row(b), 0, 0)),
+            ],
             out_specs=[
                 whole_v, pl.BlockSpec((1, s_len, 1), lambda b: (b, 0, 0)),
             ],
@@ -522,7 +558,7 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
                          interpret)
 
 
-def _forward_outputs(q, v, causal):
+def _forward_outputs(q, k, v, causal):
     """(``out_shape``, ``cost_estimate``) of a forward ``pallas_call``:
     o and the logsumexp, carried as (BH, S, 1)."""
     bh, s_len, d = q.shape
@@ -531,10 +567,11 @@ def _forward_outputs(q, v, causal):
         jax.ShapeDtypeStruct((bh, s_len, dv), q.dtype),
         jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
     ]
+    kv = _kv_share(q, k)
     cost = _cost(
         bh, s_len, s_len, d, dv, causal=causal,
-        byte_tensors=[(2, s_len, d, q.dtype.itemsize),
-                      (2, s_len, dv, q.dtype.itemsize)],
+        byte_tensors=[(1 + kv, s_len, d, q.dtype.itemsize),
+                      (1 + kv, s_len, dv, q.dtype.itemsize)],
     )
     return out_shape, cost
 
@@ -558,16 +595,17 @@ def _grid_forward(kernel, kv_tile, q, k, v, block_q, block_k, causal,
     block i names."""
     bh, s_len, d = q.shape
     dv = v.shape[2]
-    out_shape, cost = _forward_outputs(q, v, causal)
+    out_shape, cost = _forward_outputs(q, k, v, causal)
+    kv_row = _kv_row(q, k)
     return pl.pallas_call(
         kernel,
         grid=(bh, s_len // block_q, s_len // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d),
-                         lambda b, i, j: (b, kv_tile(i, j), 0)),
+                         lambda b, i, j: (kv_row(b), kv_tile(i, j), 0)),
             pl.BlockSpec((1, block_k, dv),
-                         lambda b, i, j: (b, kv_tile(i, j), 0)),
+                         lambda b, i, j: (kv_row(b), kv_tile(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
@@ -657,6 +695,14 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
         scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
     )
+    group = q.shape[0] // k.shape[0]
+    if group > 1:
+        # The dk/dv kernel gives every query head's part (float32);
+        # a key/value head's gradient is the sum over its group.
+        dk, dv = (
+            x.reshape((k.shape[0], group) + x.shape[1:]).sum(axis=1)
+            for x in (dk, dv)
+        )
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -1034,8 +1080,9 @@ def _grads_costs(q, v_chunk, causal):
     bh, sq, d = q.shape
     sk, dv = v_chunk.shape[1:]
     size = q.dtype.itemsize
+    kv = _kv_share(q, v_chunk)
     reads = [(1, sq, d, size), (1, sq, dv, size),
-             (1, sk, d, size), (1, sk, dv, size)]
+             (kv, sk, d, size), (kv, sk, dv, size)]
     return tuple(
         _cost(bh, sq, sk, d, dv, causal=causal,
               byte_tensors=reads + written)
@@ -1051,12 +1098,18 @@ def _strips_grads(q, k_chunk, v_chunk, do, lse, delta, rows, q_offset,
     bh, sq, d = q.shape
     sk, dv = v_chunk.shape[1:]
     dq_cost, dkv_cost = _grads_costs(q, v_chunk, True)
+    kv_row = _kv_row(q, k_chunk)
     q_rows = pl.BlockSpec((1, sq, d), lambda b: (b, 0, 0))
     k_rows = pl.BlockSpec((1, sk, d), lambda b: (b, 0, 0))
     do_rows = pl.BlockSpec((1, sq, dv), lambda b: (b, 0, 0))
     v_rows = pl.BlockSpec((1, sk, dv), lambda b: (b, 0, 0))
     q_col = pl.BlockSpec((1, sq, 1), lambda b: (b, 0, 0))
-    in_specs = [q_rows, k_rows, v_rows, do_rows, q_col, q_col]
+    in_specs = [
+        q_rows,
+        pl.BlockSpec((1, sk, d), lambda b: (kv_row(b), 0, 0)),
+        pl.BlockSpec((1, sk, dv), lambda b: (kv_row(b), 0, 0)),
+        do_rows, q_col, q_col,
+    ]
     common = dict(
         rows=rows, sub=sq // len(rows), q_offset=q_offset,
         k_offset=k_offset, scale=scale,
@@ -1119,6 +1172,7 @@ def _grid_grads(dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk,
     bh, sq, d = q.shape
     sk, dv = v_chunk.shape[1:]
     dq_cost, dkv_cost = _grads_costs(q, v_chunk, causal)
+    kv_row = _kv_row(q, k_chunk)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape((1,))
     koff = jnp.asarray(k_offset, jnp.int32).reshape((1,))
 
@@ -1130,10 +1184,12 @@ def _grid_grads(dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk,
             in_specs=[
                 pl.BlockSpec((1, block_q, d),
                              lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d),
-                             lambda b, i, j, *_: (b, kv_tile(i, j), 0)),
-                pl.BlockSpec((1, block_k, dv),
-                             lambda b, i, j, *_: (b, kv_tile(i, j), 0)),
+                pl.BlockSpec(
+                    (1, block_k, d),
+                    lambda b, i, j, *_: (kv_row(b), kv_tile(i, j), 0)),
+                pl.BlockSpec(
+                    (1, block_k, dv),
+                    lambda b, i, j, *_: (kv_row(b), kv_tile(i, j), 0)),
                 pl.BlockSpec((1, block_q, dv),
                              lambda b, i, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, block_q, 1),
@@ -1162,9 +1218,9 @@ def _grid_grads(dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk,
                 pl.BlockSpec((1, block_q, d),
                              lambda b, i, j, *_: (b, q_tile(i, j), 0)),
                 pl.BlockSpec((1, block_k, d),
-                             lambda b, i, j, *_: (b, i, 0)),
+                             lambda b, i, j, *_: (kv_row(b), i, 0)),
                 pl.BlockSpec((1, block_k, dv),
-                             lambda b, i, j, *_: (b, i, 0)),
+                             lambda b, i, j, *_: (kv_row(b), i, 0)),
                 pl.BlockSpec((1, block_q, dv),
                              lambda b, i, j, *_: (b, q_tile(i, j), 0)),
                 pl.BlockSpec((1, block_q, 1),
@@ -1206,7 +1262,10 @@ def flash_chunk_grads(
 
     q: (BH, Sq, D); k_chunk: (BH, Sk, D); v_chunk: (BH, Sk, Dv); do:
     (BH, Sq, Dv); lse/delta: (BH, Sq, 1) f32. Returns (dq_partial, dk_chunk, dv_chunk) — f32,
-    the ring accumulates dq over chunks and rotates dk/dv home. Two
+    the ring accumulates dq over chunks and rotates dk/dv home. k and v
+    may have fewer rows than q, B*Hkv (``_kv_row``): dk and dv still
+    come back with q's BH rows, every query head's part, for the caller
+    to sum over a group. Two
     kernels (dq: k-sequential; dk/dv: q-sequential) so each output has
     exactly one sequential accumulation dim; score tiles never leave
     VMEM.
@@ -1299,9 +1358,11 @@ def flash_attention(
     block_k: int = 0,
     interpret: bool = False,
 ):
-    """Fused attention. q, k: (B, S, H, D); v: (B, S, H, Dv), whose head
-    size may differ from q's and k's (latent attention: 192 against
-    128); returns (B, S, H, Dv).
+    """Fused attention. q: (B, S, H, D); k: (B, S, Hkv, D); v: (B, S,
+    Hkv, Dv), whose head size may differ from q's and k's (latent
+    attention: 192 against 128); returns (B, S, H, Dv). Hkv divides H:
+    query head h reads key/value head ``h // (H / Hkv)``, in place
+    (``_kv_row``).
 
     ``block_q/block_k`` 0 = auto: the largest lane-aligned default-or-
     smaller block that tiles S (``_auto_block`` — gate callers check
@@ -1312,10 +1373,15 @@ def flash_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, s_len, h, d = q.shape
+    if h % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(
+            f"flash_attention: {h} query heads over {k.shape[2]} key and "
+            f"{v.shape[2]} value heads")
     block_q, block_k = _blocks(s_len, s_len, block_q, block_k)
 
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s_len, x.shape[3])
+        return x.transpose(0, 2, 1, 3).reshape(
+            b * x.shape[2], s_len, x.shape[3])
 
     o = _flash(
         to_bh(q), to_bh(k), to_bh(v), causal, float(scale), block_q,

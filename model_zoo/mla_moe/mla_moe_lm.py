@@ -26,6 +26,7 @@ from elasticdl_tpu.common import tensor_utils
 from elasticdl_tpu.models.mla_moe import (
     MlaMoeConfig,
     MlaMoeLM,
+    balanced_adam,
     mla_moe_sharding_rules,
 )
 from elasticdl_tpu.parallel import rules as rules_lib
@@ -91,18 +92,7 @@ def optimizer(lr=1e-3, bias_update_speed=BIAS_UPDATE_SPEED, warmup_steps=0):
     """Adam at ``lr``, reached linearly from 0 over ``warmup_steps``
     steps where given (the first step then moves nothing); the selection
     biases by plain descent at ``bias_update_speed``."""
-    import jax
-    import optax
-
-    def kinds(params):
-        return jax.tree_util.tree_map_with_path(
-            lambda path, _: "selection_bias" if path[-1].key == "router_bias"
-            else "weights", params)
-
-    rate = optax.linear_schedule(0.0, lr, warmup_steps) if warmup_steps else lr
-    return optax.multi_transform(
-        {"weights": optax.adam(rate),
-         "selection_bias": optax.sgd(bias_update_speed)}, kinds)
+    return balanced_adam(lr, bias_update_speed, warmup_steps)
 
 
 def dataset_fn(records, mode, metadata):

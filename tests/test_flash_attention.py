@@ -650,3 +650,117 @@ def test_kept_clause_counts_o_and_logsumexp(v_shape, megabytes):
     assert flash.describe_kept(v) == (
         f"under remat the block keeps o and logsumexp ({megabytes} MB), "
         "the forward kernel is not run again")
+
+
+# Fewer key/value heads than query heads (PR 32): query head h reads
+# key/value head h // group in place.
+
+def _grouped_inputs(s, heads, group, d=16, dv=8, seed=0):
+    rng = np.random.RandomState(seed + s + group)
+    mk = lambda h, w: jnp.asarray(  # noqa: E731
+        rng.randn(2, s, h, w), jnp.float32) * 0.5
+    return (mk(heads, d), mk(heads // group, d), mk(heads // group, dv),
+            mk(heads, dv))
+
+
+@pytest.mark.parametrize("group", [1, 4, 16])
+@pytest.mark.parametrize("s,block,sub", [(64, 64, 16), (128, 32, 8),
+                                         (64, 16, 64)],
+                         ids=["one_tile_strips", "grid_walked",
+                              "whole_tiles"])
+def test_grouped_key_value_heads_match_dense(monkeypatch, group, s, block,
+                                             sub):
+    """Output and all three gradients against dense attention over
+    key/value heads repeated ``group`` times, whose own gradient sums
+    dk and dv over a group: through the strips kernels, the grid
+    kernels that walk the diagonal, and whole tiles."""
+    monkeypatch.setattr(flash, "SUB_TILE", sub)
+    plan = flash.tile_plan(s, s, block_q=block, block_k=block, group=group)
+    assert (plan.rows == (1,)) == (sub == 64) and plan.group == group
+    q, k, v, weight = _grouped_inputs(s, 16, group)
+    assert k.shape[2] == 16 // group
+
+    def kernel(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, block_q=block, block_k=block, interpret=True) * weight)
+
+    def dense(q, k, v):
+        return jnp.sum(dense_attention(
+            q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+            causal=True) * weight)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(kernel, (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def _index_map_primitives(fn, *args):
+    """For every ``pallas_call`` under ``fn``'s jaxpr, the primitives of
+    each operand's index map."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    return [
+        [[e.primitive.name for e in mapping.index_map_jaxpr.jaxpr.eqns]
+         for mapping in eqn.params["grid_mapping"].block_mappings]
+        for eqn in calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    ]
+
+
+@pytest.mark.parametrize("s,block", [(64, 64), (128, 32)],
+                         ids=["one_tile", "grid_of_tiles"])
+def test_equal_head_counts_trace_the_kernels_they_traced(s, block):
+    """With as many key/value heads as query heads no index map gains an
+    operation (the accepted cells' programs do not move: their jaxprs
+    were compared with the parent's, line numbers blanked, in PR 32);
+    with fewer, k's and v's maps divide the row and nothing else
+    changes."""
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, block_q=block, block_k=block, interpret=True)),
+            (0, 1, 2))(q, k, v)
+
+    q, k, v, _ = _grouped_inputs(s, 4, 1)
+    equal = _index_map_primitives(grads, q, k, v)
+    assert len(equal) == 3
+    assert not any("div" in ops for call in equal for ops in call)
+    q, k, v, _ = _grouped_inputs(s, 4, 4)
+    grouped = _index_map_primitives(grads, q, k, v)
+    for call_equal, call_grouped in zip(equal, grouped):
+        changed = [i for i, (a, b) in enumerate(zip(call_equal, call_grouped))
+                   if a != b]
+        # k and v: operands 1 and 2 after the scalar-prefetched offsets
+        # (two of them, in the backward kernels' grids of tiles).
+        assert len(changed) == 2 and changed[1] == changed[0] + 1
+        for i in changed:
+            assert call_grouped[i] == ["div"] + call_equal[i]
+
+
+def test_head_counts_that_do_not_divide_are_refused():
+    q, k, v, _ = _grouped_inputs(64, 4, 1)
+    with pytest.raises(ValueError, match="4 query heads over 3 key"):
+        flash_attention(q, k[:, :, :3], v[:, :, :3], interpret=True)
+
+
+def test_cost_and_plan_say_the_two_head_counts():
+    q = jnp.zeros((32, 64, 16), jnp.bfloat16)
+    k = jnp.zeros((2, 64, 16), jnp.bfloat16)
+    v = jnp.zeros((2, 64, 8), jnp.bfloat16)
+    _, cost = flash._forward_outputs(q, k, v, True)
+    # q and o whole, k and v a sixteenth of their rows.
+    assert cost.bytes_accessed == int(
+        (1 + 1 / 16) * 32 * 64 * (16 + 8) * 2)
+    _, equal = flash._forward_outputs(q, q, q[..., :8], True)
+    assert equal.bytes_accessed == 2 * 32 * 64 * (16 + 8) * 2
+    assert flash.describe_tiles(8192, group=16).endswith(
+        "28 skipped; one key/value head read in place by 16 query heads, "
+        "dk/dv summed over them")
+    assert "key/value" not in flash.describe_tiles(4096)
