@@ -54,7 +54,12 @@ from elasticdl_tpu.ops.flash_attention import (
     remat_policy as attention_remat_policy,
     supports as flash_supports,
 )
-from elasticdl_tpu.ops.grouped_matmul import GROUPED_PRODUCT, grouped_matmul
+from elasticdl_tpu.ops.grouped_matmul import (
+    GROUPED_PRODUCT,
+    grouped_matmul,
+    product_width,
+    zero_padded,
+)
 from elasticdl_tpu.ops.ring_attention import dense_attention
 
 logger = get_logger("experts")
@@ -332,17 +337,27 @@ def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
     dt = rows.dtype
     live = jnp.arange(t * k, dtype=jnp.int32) < jnp.sum(sizes)
     sorted_rows = _spread(rows, order, inverse, live, k)
+    # The two products run at ``product_width`` of the experts' width:
+    # the compute-type copies of the weights are zero-padded to it (the
+    # parameters and their gradients keep their shapes: a pad's
+    # transpose cuts), and ``hidden`` stays that wide from the first
+    # product to the second. The added columns are act(0) = 0 in every
+    # live row and meet zero rows of ``w_down``.
+    wide = product_width(w_up.shape[2])
+
+    def widened(w, axis):
+        return zero_padded(w.astype(dt), axis, wide)
+
     if w_gate is None:
-        hidden = relu2(grouped_matmul(sorted_rows, w_up.astype(dt), sizes))
+        hidden = relu2(grouped_matmul(sorted_rows, widened(w_up, 2), sizes))
     else:
         gate_up = grouped_matmul(
             sorted_rows,
-            jnp.concatenate([w_gate.astype(dt), w_up.astype(dt)], axis=2),
+            jnp.concatenate([widened(w_gate, 2), widened(w_up, 2)], axis=2),
             sizes,
         )
-        f = w_gate.shape[2]
-        hidden = nn.silu(gate_up[:, :f]) * gate_up[:, f:]
-    out = grouped_matmul(hidden, w_down.astype(dt), sizes)
+        hidden = nn.silu(gate_up[:, :wide]) * gate_up[:, wide:]
+    out = grouped_matmul(hidden, widened(w_down, 1), sizes)
     # Past the held rows a grouped product leaves what it likes, in its
     # result and in its cotangent: those rows are cut off (selected
     # away, here and in ``_spread``'s backward; a zero weight would
@@ -357,17 +372,21 @@ def log_traced_experts(cfg: MlaMoeConfig, rows_bound: int, ep: int):
     """One static line per traced expert layer shape (every layer of
     every trace asks again), like the attention's: what is held, what
     the router scores, the static bound of the grouped products' rows,
-    and which grouped product runs."""
-    shared = cfg.shared_intermediate_size or cfg.moe_intermediate_size
+    which grouped product runs, and the width it runs at where that is
+    not the experts' own (:func:`product_width`)."""
+    f = cfg.moe_intermediate_size
+    shared = cfg.shared_intermediate_size or f
+    wide = product_width(f)
+    products = f", products at {wide} (zero columns)" if wide != f else ""
     logger.info(
         "experts: traced drop-free layer holding experts [%d, %d) of "
         "router width %d, top-%d, rows bound %d, grouped product %s%s%s",
         cfg.first_held, cfg.first_held + cfg.n_held, cfg.router_width,
         cfg.top_k, rows_bound, GROUPED_PRODUCT,
         f", {cfg.n_held // ep} a member over ep={ep}" if ep > 1 else "",
-        "" if cfg.expert_form == "silu_gated" else (
-            f", experts {cfg.expert_form} of width "
-            f"{cfg.moe_intermediate_size}, shared expert {shared}"),
+        products if cfg.expert_form == "silu_gated" else (
+            f", experts {cfg.expert_form} of width {f}{products}, "
+            f"shared expert {shared}"),
     )
 
 

@@ -234,10 +234,13 @@ def test_relu2_experts_over_an_ep_mesh_add_up(highest):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=1e-4)
 
 
-def test_rows_of_no_group_may_hold_anything(monkeypatch, highest):
+@pytest.mark.parametrize("f", [16, 1856])
+def test_rows_of_no_group_may_hold_anything(monkeypatch, highest, f):
     """The relu^2 layer with the grouped product's dead rows poisoned,
     in its result and in its cotangent (a TPU leaves what the memory
-    held there): the layer and its gradients are what they were."""
+    held there): the layer and its gradients are what they were. At
+    1,856 the products run 2,048 wide, and the zero columns hold what
+    they like past the live rows too."""
     from elasticdl_tpu.ops.grouped_matmul import grouped_matmul
 
     def in_a_group(x, sizes):
@@ -258,9 +261,10 @@ def test_rows_of_no_group_may_hold_anything(monkeypatch, highest):
         return jnp.where(in_a_group(lhs, sizes), d_lhs, jnp.nan), d_rhs, None
 
     poisoned.defvjp(forward, backward)
-    given = _layer_inputs(width=8, held=3, seed=7)
+    given = _layer_inputs(width=8, held=3, seed=7, f=f)
     x, params = given["x"], given["params"]
-    cfg = program_config(router_width=8, first_held=1, n_held=3, top_k=3)
+    cfg = program_config(router_width=8, first_held=1, n_held=3, top_k=3,
+                         moe_intermediate_size=f)
 
     def loss(params, x):
         out, _ = ExpertLayer(cfg).apply({"params": params}, x)
@@ -271,7 +275,8 @@ def test_rows_of_no_group_may_hold_anything(monkeypatch, highest):
     got = jax.grad(loss, argnums=(0, 1))(params, x)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert bool(jnp.isfinite(a).all())
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5 * f / 16)
 
 
 def test_mixer_parts_by_hand():
@@ -402,6 +407,17 @@ def test_lines_say_the_scan_the_head_counts_and_the_experts_form():
     assert any(line.endswith(
         "grouped product ragged_dot, experts relu2 of width 16, shared "
         "expert 24") for line in log.lines)
+    # The published width: the line says where the products run.
+    with _Lines(mla_moe.logger) as log:
+        mla_moe.log_traced_experts(program_config(
+            moe_intermediate_size=1856, shared_intermediate_size=3712,
+            first_held=0, n_held=8, router_width=128, top_k=6), 98304, 1)
+    mla_moe.log_traced_experts.cache_clear()
+    assert log.lines == [
+        "experts: traced drop-free layer holding experts [0, 8) of router "
+        "width 128, top-6, rows bound 98304, grouped product ragged_dot, "
+        "experts relu2 of width 1856, products at 2048 (zero columns), "
+        "shared expert 3712"]
 
 
 @pytest.mark.parametrize("fused", [True, False])

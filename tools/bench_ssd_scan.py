@@ -119,32 +119,138 @@ def attention():
             forward_and_backward_ms=timed(both, q, k, v))
 
 
-def experts():
-    """The relu^2 experts' two grouped products, forward and backward,
-    at the cell's bound (98,304 rows, 6,144 of them live, 8 groups) and
-    three widths: the published 1,856 is 14.5 lane tiles."""
+def _products(rows, sizes, wide, up, down, gate=None):
+    """A layer's two grouped products as ``held_experts_part`` runs
+    them: the weights cast to bfloat16 and zero-padded to ``wide``
+    columns, ``hidden`` left that wide between the products."""
+    from flax import linen as nn
+
     from elasticdl_tpu.models.mla_moe import relu2
-    from elasticdl_tpu.ops.grouped_matmul import grouped_matmul
+    from elasticdl_tpu.ops.grouped_matmul import grouped_matmul, zero_padded
 
-    bound, live, groups, d = 98304, 6144, 8, 2688
+    def widened(w, axis):
+        return zero_padded(w.astype(jnp.bfloat16), axis, wide)
+
+    if gate is None:
+        hidden = relu2(grouped_matmul(rows, widened(up, 2), sizes))
+    else:
+        gate_up = grouped_matmul(rows, jnp.concatenate(
+            [widened(gate, 2), widened(up, 2)], axis=2), sizes)
+        hidden = nn.silu(gate_up[:, :wide]) * gate_up[:, wide:]
+    return grouped_matmul(hidden, widened(down, 1), sizes)
+
+
+def _expert_weights(groups, d, f, gated, params):
+    """(w_up, w_down[, w_gate]) of type ``params``."""
+    shapes = [(groups, d, f), (groups, f, d)] + [(groups, d, f)] * gated
+    keys = jax.random.split(jax.random.PRNGKey(4), len(shapes))
+    return [(jax.random.normal(key, shape) * 0.02).astype(params)
+            for key, shape in zip(keys, shapes)]
+
+
+def _expert_layer(bound, live, groups, d, f, wide, gated, params):
+    """The products over ``bound`` rows given as they are, dead rows
+    selected away. Returns (forward, forward with backward) and their
+    arguments."""
     sizes = jnp.full((groups,), live // groups, jnp.int32)
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    rows = jax.random.normal(ks[0], (bound, d), jnp.bfloat16)
+    rows = jax.random.normal(jax.random.PRNGKey(3), (bound, d), jnp.bfloat16)
     in_a_group = (jnp.arange(bound) < live)[:, None]
-    for width in (1856, 1920, 2048):
-        up = jax.random.normal(ks[1], (groups, d, width), jnp.bfloat16) * 0.02
-        down = jax.random.normal(ks[2], (groups, width, d), jnp.bfloat16) * 0.02
+    weights = _expert_weights(groups, d, f, gated, params)
 
-        def loss(rows, up, down):
-            out = grouped_matmul(
-                relu2(grouped_matmul(rows, up, sizes)), down, sizes)
-            return jnp.sum(jnp.where(in_a_group, out, 0).astype(jnp.float32))
+    def loss(rows, *weights):
+        out = _products(rows, sizes, wide, *weights)
+        return jnp.sum(jnp.where(in_a_group, out, 0).astype(jnp.float32))
 
-        both = jax.jit(jax.grad(loss, (0, 1, 2)))
-        say(what=f"relu2 experts, {live} live rows of {bound}, 8 groups, "
-            f"2688 -> {width} -> 2688, a layer",
-            forward_ms=timed(jax.jit(loss), rows, up, down),
-            forward_and_backward_ms=timed(both, rows, up, down))
+    every = tuple(range(1 + len(weights)))
+    return jax.jit(loss), jax.jit(jax.grad(loss, every)), (rows, *weights)
+
+
+def _hidden_size(bound, live, groups, d, d_wide, f, wide, k=6):
+    """The same layer from the tokens' side, for the hidden size: tokens
+    (bound / k, d) zero-padded to ``d_wide``, spread to the bound's rows
+    and gathered back by the program's own ``_spread`` and
+    ``_gather_back``, ``w_up``'s rows and ``w_down``'s columns
+    zero-padded, the result cut at ``d``."""
+    from elasticdl_tpu.models.mla_moe import _gather_back, _spread
+    from elasticdl_tpu.ops.grouped_matmul import zero_padded
+
+    sizes = jnp.full((groups,), live // groups, jnp.int32)
+    tokens = jax.random.normal(
+        jax.random.PRNGKey(3), (bound // k, d), jnp.bfloat16)
+    order = jnp.arange(bound, dtype=jnp.int32)
+    in_a_group = order < live
+
+    def loss(tokens, up, down):
+        rows = _spread(zero_padded(tokens, 1, d_wide), order, order,
+                       in_a_group, k)
+        out = _products(
+            rows, sizes, wide,
+            zero_padded(up.astype(jnp.bfloat16), 1, d_wide),
+            zero_padded(down.astype(jnp.bfloat16), 2, d_wide))
+        out = jnp.where(in_a_group[:, None], out, 0)
+        out = _gather_back(out, order, order, in_a_group, k)[:, :d]
+        return jnp.sum(out.astype(jnp.float32))
+
+    return (jax.jit(loss), jax.jit(jax.grad(loss, (0, 1, 2))),
+            (tokens, *_expert_weights(groups, d, f, False, jnp.float32)))
+
+
+def experts():
+    """The sweep ``ops/grouped_matmul.py::product_width`` is written
+    from, and the sizing of what it leaves (PERF.md section 7, row 28):
+    an expert layer's two grouped products, forward and forward with
+    backward; a recomputed layer pays the one and then the other.
+
+    The third family's cell (bound 98,304 rows, 6,144 live, 8 groups,
+    2,688 -> f -> 2,688, relu^2): bfloat16 weights of width f itself,
+    then what the program does, float32 parameters of the published
+    1,856 cast and zero-padded. The second family's cell (bound 131,072,
+    8,192 live, 16 groups, 2,048 -> 2 x 768 -> 2,048, silu-gated) at 768
+    and padded to 1,024. The static bound at 6,144 live rows. The
+    hidden size 2,688 (21 lane tiles) against 3,072: the products alone
+    with weights and rows 3,072 wide, then from the tokens' side with
+    what padding to it would add."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    cell = dict(bound=98304, live=6144, groups=8, d=2688)
+    joyai = dict(bound=131072, live=8192, groups=16, d=2048)
+    readings = [
+        (f"weights of width {f}", dict(cell, f=f, wide=f, gated=False,
+                                       params=bf16))
+        for f in (1856, 1920, 2048, 2304, 2560)
+    ] + [
+        (f"float32 parameters of width 1856, products at {wide}",
+         dict(cell, f=1856, wide=wide, gated=False, params=f32))
+        for wide in (1856, 2048)
+    ] + [
+        (f"float32 parameters of width 768, products at {wide}",
+         dict(joyai, f=768, wide=wide, gated=True, params=f32))
+        for wide in (768, 1024)
+    ] + [
+        (f"float32 parameters of width 1856, products at 2048, bound {bound}",
+         dict(cell, bound=bound, f=1856, wide=2048, gated=False, params=f32))
+        for bound in (24576, 12288)
+    ] + [
+        ("float32 parameters of width 1856, products at 2048",
+         dict(cell, d=3072, f=1856, wide=2048, gated=False, params=f32))
+    ]
+    for what, shape in readings:
+        forward, both, args = _expert_layer(**shape)
+        form = "silu-gated" if shape["gated"] else "relu2"
+        say(what=f"{form} experts, {shape['live']} live rows of "
+            f"{shape['bound']}, {shape['groups']} groups, hidden size "
+            f"{shape['d']}, {what}, a layer",
+            forward_ms=timed(forward, *args),
+            forward_and_backward_ms=timed(both, *args))
+        del forward, both, args
+    for d_wide in (2688, 3072):
+        forward, both, args = _hidden_size(**cell, d_wide=d_wide, f=1856,
+                                           wide=2048)
+        say(what="relu2 experts from the tokens' side (the program's spread, "
+            "products at 2048, its gather back, cut), 6144 live rows of "
+            f"98304, hidden size 2688 run at {d_wide}, a layer",
+            forward_ms=timed(forward, *args),
+            forward_and_backward_ms=timed(both, *args))
+        del forward, both, args
 
 
 if __name__ == "__main__":
