@@ -1,0 +1,23 @@
+"""Model FLOP/s utilisation of the block-diffusion sparse-expert
+family: the operations a step requires of what this chip holds
+(``lib/counts_sdar_moe.py``: recomputation excluded, the attention's
+visible pairs only, the routed experts' for the rows the program
+counted) times the window's steps per second, over the chip's published
+bfloat16 peak. A step is ``minibatch x seq_len`` data tokens; the model
+runs over twice as many positions, which the count holds."""
+from benchmark.lib import counts_sdar_moe, peaks
+from benchmark.metrics._common import tokens_per_second
+from benchmark.metrics._mla_moe import routed_rows_per_step
+
+
+def read(run):
+    rate, rows = tokens_per_second(run), routed_rows_per_step(run)
+    if rate is None or rows is None:
+        return None
+    cfg, device = run["cfg"], run["device"]
+    step_tokens = cfg["minibatch"] * cfg["seq_len"]
+    flops = counts_sdar_moe.train_flops_per_step(
+        cfg, cfg["minibatch"], cfg["seq_len"], rows)
+    peak = peaks.peak(device["device_kind"], "bf16_flops_per_s")
+    return 100.0 * flops * (rate / step_tokens) / (
+        peak * device["device_count"])

@@ -51,8 +51,9 @@ def _apply_model(state, params, batch, training, rng):
 def _model_metrics(preds) -> dict:
     """What a model's training output says should leave the step beside
     the loss: where the output is a mapping, its ``metrics`` entry (a
-    flat dict of scalars the model counted, e.g. an expert layer's
-    routed rows). Every other output says nothing."""
+    flat dict of scalars the model counted: an expert layer's routed
+    rows, the tokens a diffusion model's noising masked). Every other
+    output says nothing."""
     return dict(preds.get("metrics", {})) if isinstance(preds, dict) else {}
 
 

@@ -98,6 +98,11 @@ class MlaMoeConfig:
     # expert's width where it is not a routed one's (0: the same).
     expert_form: str = "silu_gated"
     shared_intermediate_size: int = 0
+    # How the router scores (``SCORINGS``), and whether the layer has a
+    # selection bias and a shared expert (this family has both).
+    scoring: str = "sigmoid"
+    selection_bias: bool = True
+    shared_expert: bool = True
 
     @property
     def qk_head_dim(self) -> int:
@@ -262,6 +267,16 @@ class Relu2Mlp(nn.Module):
 # matrices; ``relu2``: Nemotron-H's, two.
 EXPERT_FORMS = {"silu_gated": (GatedMlp, True), "relu2": (Relu2Mlp, False)}
 
+# A router's scores of ALL its experts from their float32 logits (T,
+# width): ``sigmoid``, each expert on its own (DeepSeek-V3's,
+# Nemotron-H's); ``softmax``, probabilities over the whole width
+# (Qwen3-MoE's). Either way the chosen experts' scores are normalised
+# to sum to one.
+SCORINGS = {
+    "sigmoid": jax.nn.sigmoid,
+    "softmax": functools.partial(jax.nn.softmax, axis=-1),
+}
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _spread(rows, order, inverse, live, k):
@@ -380,13 +395,18 @@ def log_traced_experts(cfg: MlaMoeConfig, rows_bound: int, ep: int):
     products = f", products at {wide} (zero columns)" if wide != f else ""
     logger.info(
         "experts: traced drop-free layer holding experts [%d, %d) of "
-        "router width %d, top-%d, rows bound %d, grouped product %s%s%s",
+        "router width %d, top-%d, rows bound %d, grouped product %s%s%s%s",
         cfg.first_held, cfg.first_held + cfg.n_held, cfg.router_width,
         cfg.top_k, rows_bound, GROUPED_PRODUCT,
         f", {cfg.n_held // ep} a member over ep={ep}" if ep > 1 else "",
         products if cfg.expert_form == "silu_gated" else (
             f", experts {cfg.expert_form} of width {f}{products}, "
             f"shared expert {shared}"),
+        "" if (cfg.scoring, cfg.selection_bias, cfg.shared_expert) == (
+            "sigmoid", True, True) else (
+            f", {cfg.scoring} scores"
+            + ("" if cfg.selection_bias else ", no selection bias")
+            + ("" if cfg.shared_expert else ", no shared expert")),
     )
 
 
@@ -434,7 +454,8 @@ def balanced_adam(lr, bias_update_speed, warmup_steps=0):
 
 
 class ExpertLayer(nn.Module):
-    """Shared expert + the held routed experts' weighted part.
+    """Shared expert (where the configuration has one) + the held routed
+    experts' weighted part.
 
     ``routing`` (B, S, k) int32, where given, are the experts every
     token goes to in place of the layer's own top k (routing replay:
@@ -443,8 +464,10 @@ class ExpertLayer(nn.Module):
     ``cfg`` is any configuration with the expert layer's fields
     (``n_held``, ``first_held``, ``router_width``, ``top_k``,
     ``routed_scaling_factor``, ``moe_intermediate_size``,
-    ``shared_intermediate_size``, ``expert_form``, ``compute_dtype``,
-    ``hidden_size``): this family's or ``models/nemotron_h.py``'s."""
+    ``shared_intermediate_size``, ``expert_form``, ``scoring``,
+    ``selection_bias``, ``shared_expert``, ``compute_dtype``,
+    ``hidden_size``): this family's, ``models/nemotron_h.py``'s or
+    ``models/sdar_moe.py``'s."""
     cfg: MlaMoeConfig
     mesh: Optional[Mesh] = None
 
@@ -464,7 +487,7 @@ class ExpertLayer(nn.Module):
         bias = self.param(
             "router_bias", nn.initializers.zeros_init(),
             (cfg.router_width,), jnp.float32,
-        )
+        ) if cfg.selection_bias else None
         init = nn.initializers.normal(0.02)
         shared_mlp, gated = EXPERT_FORMS[cfg.expert_form]
         w_gate = (self.param("w_gate", init, (n, d, f), jnp.float32)
@@ -473,12 +496,13 @@ class ExpertLayer(nn.Module):
         w_down = self.param("w_down", init, (n, f, d), jnp.float32)
 
         rows = x.reshape(b * s, d)
-        scores = jax.nn.sigmoid(jnp.matmul(
+        scores = SCORINGS[cfg.scoring](jnp.matmul(
             rows.astype(jnp.float32), router, precision=HIGHEST
         ))                                              # (T, width) f32
         if routing is None:
             _, chosen = jax.lax.top_k(
-                scores + jax.lax.stop_gradient(bias), k
+                scores if bias is None
+                else scores + jax.lax.stop_gradient(bias), k
             )
         else:
             chosen = routing.reshape(b * s, k).astype(jnp.int32)
@@ -502,14 +526,17 @@ class ExpertLayer(nn.Module):
             chosen[..., None] == jnp.arange(cfg.router_width), axis=(0, 1),
             dtype=jnp.int32,
         )
-        part = part + _load_tap(bias, load).astype(dt)
+        if bias is not None:
+            part = part + _load_tap(bias, load).astype(dt)
         shared = shared_mlp(
             cfg.shared_intermediate_size or f, cfg, self.mesh, name="shared"
-        )(x)
+        )(x) if cfg.shared_expert else None
         counters = {
             "moe_rows": jnp.sum(sizes), "moe_expert_rows_max": jnp.max(sizes)
         }
-        out = wsc(shared + part.reshape(b, s, d), "dp", None, None)
+        part = part.reshape(b, s, d)
+        out = wsc(part if shared is None else shared + part,
+                  "dp", None, None)
         return out, jax.lax.stop_gradient(counters)
 
     def _over_ep(self, rows, chosen, weights, w_gate, w_up, w_down, ep):

@@ -83,6 +83,9 @@ class NemotronHConfig:
     top_k: int = 2
     routed_scaling_factor: float = 1.0
     expert_form: str = "relu2"
+    scoring: str = "sigmoid"
+    selection_bias: bool = True
+    shared_expert: bool = True
     rms_eps: float = 1e-5
     remat: bool = False
     compute_dtype: jnp.dtype = jnp.bfloat16

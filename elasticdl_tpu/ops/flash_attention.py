@@ -117,13 +117,168 @@ def _compiler_params(semantics, *head_sizes):
     )
 
 
+class BlockDiffusion(NamedTuple):
+    """The mask of block-diffusion training (BD3-LM, arXiv:2503.09573):
+    the sequence is a row twice over, ``[x_t ; x_0]``, ``half`` noised
+    positions and then the same ``half`` clean, in blocks of ``block``.
+    With n(i) = (i mod half) // block, query i sees key j iff
+
+    - both noised and n(i) == n(j): a noised block sees itself, both
+      ways;
+    - i noised, j clean and n(j) < n(i): and the clean blocks before it;
+    - both clean and n(j) <= n(i): block-causal, both ways in a block;
+
+    and a clean query sees no noised key. ``flash_attention(...,
+    mask=BlockDiffusion(half, block))`` runs it through the kernels
+    (``_block_diffusion_plan``), ``visible`` is the same rule on
+    positions, for ``dense_attention`` and the tests."""
+    half: int
+    block: int
+
+    def visible(self, qpos, kpos):
+        q_block = qpos % self.half // self.block
+        k_block = kpos % self.half // self.block
+        q_clean, k_clean = qpos >= self.half, kpos >= self.half
+        return jnp.where(
+            q_clean, k_clean & (k_block <= q_block),
+            jnp.where(k_clean, k_block < q_block, k_block == q_block))
+
+    @property
+    def pairs(self) -> int:
+        """Visible (query, key) pairs of one row and head: half (half +
+        block) / 2 clean on clean, half (half - block) / 2 noised on
+        clean, half x block noised on noised."""
+        return self.half * (self.half + self.block)
+
+
+class _Rule(NamedTuple):
+    """What a boundary tile of a block-diffusion call masks, on
+    positions counted from the tile's corner (a block never straddles a
+    tile: ``block`` divides the sub-tile): ``block_causal`` n(k) <=
+    n(q), ``block_strict`` n(k) < n(q), ``block_diagonal`` n(k) ==
+    n(q). ``block`` is a power of two, so the block of a position is
+    its high bits."""
+    kind: str
+    block: int
+
+    def visible(self, qpos, kpos):
+        low = self.block - 1
+        if self.kind == "block_causal":
+            return (qpos | low) >= kpos
+        if self.kind == "block_strict":
+            return (qpos & ~low) > kpos
+        return (qpos ^ kpos) <= low
+
+    def _blocks(self, q0, q_len, k0, k_len):
+        b = self.block
+        return q0 // b, (q0 + q_len - 1) // b, k0 // b, (k0 + k_len - 1) // b
+
+    def any_visible(self, q0, q_len, k0, k_len) -> bool:
+        q_lo, q_hi, k_lo, k_hi = self._blocks(q0, q_len, k0, k_len)
+        if self.kind == "block_causal":
+            return k_lo <= q_hi
+        if self.kind == "block_strict":
+            return k_lo < q_hi
+        return k_lo <= q_hi and q_lo <= k_hi
+
+    def any_masked(self, q0, q_len, k0, k_len) -> bool:
+        q_lo, q_hi, k_lo, k_hi = self._blocks(q0, q_len, k0, k_len)
+        if self.kind == "block_causal":
+            return k_hi > q_lo
+        if self.kind == "block_strict":
+            return k_hi >= q_lo
+        return not q_lo == q_hi == k_lo == k_hi
+
+
+class _Strips(NamedTuple):
+    """How a boundary tile is walked: sub-tile row ``i`` multiplies the
+    sub-tiles ``starts[i] .. rows[i]`` (none where they are equal) under
+    ``rule``."""
+    rule: _Rule
+    starts: Tuple[int, ...]
+    rows: Tuple[int, ...]
+
+    @property
+    def computed(self) -> int:
+        return sum(self.rows) - sum(self.starts)
+
+
+def _strips(rule: _Rule, block: int, sub: int) -> _Strips:
+    starts, rows = [], []
+    for i in range(block // sub):
+        live = [j for j in range(block // sub)
+                if rule.any_visible(i * sub, sub, j * sub, sub)]
+        starts.append(live[0] if live else 0)
+        rows.append(live[-1] + 1 if live else 0)
+    return _Strips(rule, tuple(starts), tuple(rows))
+
+
+class _BlockDiffusionPlan(NamedTuple):
+    """The static plan of a :class:`BlockDiffusion` call over a square
+    grid of 2n x 2n tiles, n to a half. Query tile row r of a half sees:
+    where noised, its own noised tile (``own``: the blocks on the
+    diagonal and nothing else), the clean tiles before r whole, and
+    clean tile r under ``before``; where clean, the clean tiles before
+    r whole and clean tile r under ``clean``. Every other tile is dead.
+    n (n - 1) tiles whole and unmasked, 3 n boundary tiles, the rest
+    of the 4 n^2 skipped."""
+    n: int
+    sub: int
+    own: _Strips
+    before: _Strips
+    clean: _Strips
+
+    def kv_tile(self, i, j):
+        """The K/V tile step j of query row i names (forward, dq): a
+        live step its own, a dead one the next live one's, or the last
+        live one's past it: nothing is fetched for a dead step."""
+        n = self.n
+        noised = jnp.where(
+            j <= i, i, jnp.where(j < n, n, jnp.minimum(j, n + i)))
+        return jnp.where(i < n, noised, jnp.clip(j, n, i))
+
+    def q_tile(self, i, j):
+        """The q/do/lse/delta tile step j of key column i names (dk/dv):
+        a noised column is seen by its own tile alone; clean column c by
+        the noised tiles from c on and the clean tiles from c on."""
+        n = self.n
+        clean = jnp.where(
+            j < n, jnp.maximum(j, i - n), jnp.maximum(j, i))
+        return jnp.where(i < n, i, clean)
+
+
+def _block_diffusion_plan(mask: BlockDiffusion, s_len, block_q, block_k,
+                          sub=None) -> Optional[_BlockDiffusionPlan]:
+    """The plan of a block-diffusion call, or None where the kernels do
+    not tile it: the sequence is the two halves, the tiles are square
+    and divide a half, a tile is at least two sub-tiles each way, and
+    the block length is a power of two that divides the sub-tile (then
+    no block straddles a sub-tile, and every boundary is known at trace
+    time)."""
+    sub = sub or SUB_TILE
+    half, b = mask.half, mask.block
+    if not (
+        s_len == 2 * half and block_q == block_k
+        and half % block_q == 0 and block_q % sub == 0
+        and block_q >= 2 * sub and b & (b - 1) == 0 and sub % b == 0
+        and half % b == 0
+    ):
+        return None
+    return _BlockDiffusionPlan(
+        half // block_q, sub,
+        *(_strips(_Rule(kind, b), block_q, sub)
+          for kind in ("block_diagonal", "block_strict", "block_causal")))
+
+
 class TilePlan(NamedTuple):
     """How a kernel call spends its grid, and how one grid tile of it is
     multiplied: ``rows[i]`` is the number of sub-tiles, from the left,
     that sub-tile row ``i`` multiplies. The whole tile kept is one
     sub-tile: ``rows == (1,)`` and ``sub_q, sub_k`` the block itself.
     Over a ``grid`` of several tiles ``rows`` is the plan of the tiles
-    on the diagonal (``tiles`` counts them and the others)."""
+    on the diagonal (``tiles`` counts them and the others). Under a
+    block-diffusion mask ``diffusion`` is the call's plan and ``rows``
+    its clean boundary tiles'."""
     block_q: int
     block_k: int
     sub_q: int
@@ -132,6 +287,7 @@ class TilePlan(NamedTuple):
     grid: Tuple[int, int] = (1, 1)
     # Query heads that read one key/value head (``_kv_row``).
     group: int = 1
+    diffusion: Optional[_BlockDiffusionPlan] = None
 
     @property
     def computed(self) -> int:
@@ -150,6 +306,9 @@ class TilePlan(NamedTuple):
         grid that is not walked visits every tile whole (what its
         traced compare predicates out is not known here)."""
         n_q, n_k = self.grid
+        if self.diffusion is not None:
+            n = self.diffusion.n
+            return n * (n - 1), 3 * n, n_q * n_k - n * (n - 1) - 3 * n
         if self.rows == (1,):
             return n_q * n_k, 0, 0
         off_diagonal = n_q * (n_k - 1) // 2
@@ -163,6 +322,18 @@ class TilePlan(NamedTuple):
             f"; one key/value head read in place by {self.group} query "
             "heads, dk/dv summed over them" if self.group > 1 else ""
         )
+        if self.diffusion is not None:
+            plan, n = self.diffusion, self.diffusion.n
+            whole, boundary, skipped = self.tiles
+            return (
+                f"grid {self.grid[0]}x{self.grid[1]} of {blocks}: {whole} "
+                f"tile{'s' if whole != 1 else ''} whole and unmasked, "
+                f"{boundary} boundary tiles walked ({n} noised on their "
+                f"own blocks {plan.own.computed}, {n} noised on the clean "
+                f"blocks before {plan.before.computed}, {n} clean "
+                f"{plan.clean.computed} of {self.total} {sub_tiles}), "
+                f"{skipped} skipped{shared}"
+            )
         if self.grid == (1, 1) or self.rows == (1,):
             return f"{blocks}, {sub_tiles}, {walked} computed{shared}"
         whole, diagonal, skipped = self.tiles
@@ -217,13 +388,18 @@ def _one_tile(sq, sk, block_q, block_k):
 
 
 def tile_plan(sq, sk, causal=True, block_q=0, block_k=0, q_offset=0,
-              k_offset=0, sub=None, group=1) -> TilePlan:
+              k_offset=0, sub=None, group=1, mask=None) -> TilePlan:
     """What a kernel call with these arguments multiplies (the kernels
-    ask ``_walk`` the same question): for the line ``log_traced`` prints
-    and for the tests. Traced offsets are anything that is not an
-    int."""
-    block_q, block_k = _blocks(sq, sk, block_q, block_k)
+    ask ``_walk`` the same question, or ``_block_diffusion_plan`` under
+    a ``mask``): for the line ``log_traced`` prints and for the tests.
+    Traced offsets are anything that is not an int."""
     sub = sub or SUB_TILE
+    if mask is not None:
+        block_q, block_k = _blocks(mask.half, mask.half, block_q, block_k)
+        plan = _mask_plan(mask, sq, block_q, block_k, sub)
+        return TilePlan(block_q, block_k, sub, sub, plan.clean.rows,
+                        (sq // block_q, sk // block_k), group, plan)
+    block_q, block_k = _blocks(sq, sk, block_q, block_k)
     grid = (sq // block_q, sk // block_k)
     rows = _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset, sub)
     if rows is None:
@@ -233,15 +409,15 @@ def tile_plan(sq, sk, causal=True, block_q=0, block_k=0, q_offset=0,
 
 
 def describe_tiles(s_len, causal=True, traced_offsets=False,
-                   group=1) -> str:
+                   group=1, mask=None) -> str:
     """``tile_plan(...).describe()`` of the kernels a layer over a
     sequence of ``s_len`` runs with the default blocks: the standalone
     kernels, or the ring's chunk kernels (``traced_offsets``); ``group``
-    query heads to a key/value head."""
+    query heads to a key/value head; ``mask`` as ``flash_attention``'s."""
     offset = None if traced_offsets else 0
     return tile_plan(
         s_len, s_len, causal, q_offset=offset, k_offset=offset,
-        group=group,
+        group=group, mask=mask,
     ).describe()
 
 
@@ -252,7 +428,7 @@ def _diagonal_crosses(q_start, k_start, k_len):
     return q_start < k_start + k_len - 1
 
 
-def _cost(bh, sq, sk, d, dv, causal, byte_tensors):
+def _cost(bh, sq, sk, d, dv, causal, byte_tensors, mask=None):
     """pl.CostEstimate for one attention kernel, MODEL-FLOPs convention:
     count the two algorithmically required matmuls of each kernel, one
     that contracts or produces the q/k head size ``d`` and one the v
@@ -269,8 +445,13 @@ def _cost(bh, sq, sk, d, dv, causal, byte_tensors):
 
     ``byte_tensors``: (count, seq_len, width, dtype_size) of
     (BH, seq_len, width)-shaped operands/outputs for bytes_accessed;
-    k and v with fewer heads than q count ``_kv_share`` of one."""
-    frac = 0.5 if causal else 1.0
+    k and v with fewer heads than q count ``_kv_share`` of one. Under a
+    ``mask`` the count is its visible pairs', and ``causal`` unread."""
+    if mask is not None:
+        # The visible pairs, whatever tiles the kernel visits.
+        frac = mask.pairs / (sq * sk)
+    else:
+        frac = 0.5 if causal else 1.0
     flops = int(2 * bh * sq * sk * (d + dv) * frac)
     # One exp per score element per kernel (fwd online-softmax; each
     # bwd kernel recomputes P once).
@@ -347,18 +528,40 @@ def _causal_mask(shape, q_start, k_start):
     return qpos >= kpos
 
 
+def _visible(shape, q_start, k_start, causal):
+    """The mask of a tile of ``shape``: the causal one (``causal``
+    True), or a boundary tile's of a block-diffusion call (a
+    :class:`_Rule`, positions from the tile's corner)."""
+    if causal is True:
+        return _causal_mask(shape, q_start, k_start)
+    qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return causal.visible(qpos, kpos)
+
+
+def _holds_masked(rule, q_start, q_len, k_start, k_len):
+    """What a strip's tile mathematics takes as ``causal`` over the
+    rectangle of ``q_len`` queries from ``q_start`` and ``k_len`` keys
+    from ``k_start``: False where no score of it is masked (no mask is
+    built), else the rule."""
+    if rule is True:
+        return _diagonal_crosses(q_start, k_start, k_len)
+    return rule.any_masked(q_start, q_len, k_start, k_len) and rule
+
+
 def _tile_scores(q, k_blk, q_start, k_start, causal, scale):
     """Scaled scores of one tile, f32 (rows of q, rows of k_blk), and
-    the causal mask (None when not causal): masked scores are set to
-    ``_NEG_INF``. ``q_start/k_start``: global positions of the tile's
-    first query and key, ints or traced scalars."""
+    the mask (None when not ``causal``; ``_visible`` says what it may
+    be): masked scores are set to ``_NEG_INF``. ``q_start/k_start``:
+    global positions of the tile's first query and key, ints or traced
+    scalars."""
     s = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
     if not causal:
         return s, None
-    mask = _causal_mask(s.shape, q_start, k_start)
+    mask = _visible(s.shape, q_start, k_start, causal)
     return jnp.where(mask, s, _NEG_INF), mask
 
 
@@ -401,7 +604,8 @@ def _scratch_tile_update(q_ref, k_ref, v_ref, m_acc, l_acc, o_acc,
 
 
 def _fwd_strips_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, rows, sub,
-                       scale, carried=None):
+                       scale, carried=None, starts=None, rule=True,
+                       final=True):
     """The forward over one grid tile whose diagonal starts at its
     corner (``_walk`` with offsets 0, 0): q strip ``i`` against the keys
     up to its own end, and no further. A strip sees all its keys of the
@@ -416,13 +620,30 @@ def _fwd_strips_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, rows, sub,
     (m_acc, l_acc, o_acc), the scratch state the tiles to the left have
     left (``_fwd_grid_kernel``): the strip takes one step of that
     recurrence from it. Either way the diagonal tile is the last that
-    contributes to its queries, so the strip is finalised here."""
+    contributes to its queries, so the strip is finalised here.
+
+    A boundary tile of a block-diffusion call (``_fwd_diffusion_kernel``)
+    is walked likewise, under its ``rule`` and from sub-tile
+    ``starts[i]`` on; ``final`` False (a noised tile on its own blocks:
+    clean tiles follow) leaves the stepped state in ``carried``."""
     for i, n_k in enumerate(rows):
-        strip, keys = pl.ds(i * sub, sub), pl.ds(0, n_k * sub)
+        first = starts[i] if starts else 0
+        strip = pl.ds(i * sub, sub)
+        keys = pl.ds(first * sub, (n_k - first) * sub)
+        if n_k == first:
+            # Only under a rule: the strip sees nothing of this tile.
+            if final:
+                m_acc, l_acc, o_acc = carried
+                l_safe = jnp.maximum(l_acc[strip, :], 1e-30)
+                o_ref[0, strip, :] = (
+                    o_acc[strip, :] / l_safe).astype(o_ref.dtype)
+                l_ref[0, strip, :] = m_acc[strip, :] + jnp.log(l_safe)
+            continue
         v_blk = v_ref[0, keys, :]
         s, mask = _tile_scores(
-            q_ref[0, strip, :], k_ref[0, keys, :], i * sub, 0, True, scale
-        )                              # (sub, n_k * sub)
+            q_ref[0, strip, :], k_ref[0, keys, :], i * sub, first * sub,
+            rule, scale
+        )                              # (sub, (n_k - first) * sub)
         m = s.max(axis=1, keepdims=True)
         if carried is not None:
             m_acc, l_acc, o_acc = carried
@@ -433,13 +654,17 @@ def _fwd_strips_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, rows, sub,
         if carried is not None:
             alpha = jnp.exp(m_prev - m)
             l = l_acc[strip, :] * alpha + l
-        l_safe = jnp.maximum(l, 1e-30)
+        if final:
+            l_safe = jnp.maximum(l, 1e-30)
         o = jax.lax.dot_general(
             p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if carried is not None:
             o = o_acc[strip, :] * alpha + o
+        if not final:
+            m_acc[strip, :], l_acc[strip, :], o_acc[strip, :] = m, l, o
+            continue
         o_ref[0, strip, :] = (o / l_safe).astype(o_ref.dtype)
         l_ref[0, strip, :] = m + jnp.log(l_safe)
 
@@ -476,8 +701,77 @@ def _fwd_grid_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc,
         )
 
 
+def _diffusion_steps(plan, row, step):
+    """Which of a block-diffusion call's tiles grid step ``step`` of
+    tile row (forward, dq) or key column (dk/dv) ``row`` is, as traced
+    booleans: (the row lies in the noised half, its place in its half,
+    whether the step is the noised tile of the same place, whether it
+    is the clean one)."""
+    n = plan.n
+    noised = row < n
+    place = jnp.where(noised, row, row - n)
+    return noised, place, step == place, step == n + place
+
+
+def _fwd_diffusion_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc,
+                          o_acc, *, plan, scale):
+    """One (batch*head, q-block, k-block) grid step of a call under a
+    :class:`BlockDiffusion` mask (``_BlockDiffusionPlan``). A noised q
+    tile steps its state over its own noised tile (the blocks on the
+    diagonal), the clean tiles before its place whole with no mask
+    built, and the clean tile of its place under ``before``, where it is
+    finalised; a clean q tile over the clean tiles before it whole and
+    its own under ``clean``. Every other step is dead: predicated out,
+    and ``plan.kv_tile`` names no new block for it."""
+    qi = pl.program_id(1)
+    kb = pl.program_id(2)
+    noised, place, on_noised, on_clean = _diffusion_steps(plan, qi, kb)
+    carried = (m_acc, l_acc, o_acc)
+
+    def strips(walk, final):
+        _fwd_strips_kernel(
+            q_ref, k_ref, v_ref, o_ref, l_ref, rows=walk.rows,
+            sub=plan.sub, scale=scale, carried=carried,
+            starts=walk.starts, rule=walk.rule, final=final,
+        )
+
+    @pl.when(kb == 0)
+    def _init():
+        m_acc[:] = jnp.full_like(m_acc, _NEG_INF)
+        l_acc[:] = jnp.zeros_like(l_acc)
+        o_acc[:] = jnp.zeros_like(o_acc)
+
+    pl.when(noised & on_noised)(lambda: strips(plan.own, False))
+
+    @pl.when((kb >= plan.n) & (kb - plan.n < place))
+    def _whole():
+        _scratch_tile_update(
+            q_ref, k_ref, v_ref, m_acc, l_acc, o_acc, 0, 0,
+            block_k=k_ref.shape[1], causal=False, scale=scale,
+        )
+
+    pl.when(noised & on_clean)(lambda: strips(plan.before, True))
+    pl.when(jnp.logical_not(noised) & on_clean)(
+        lambda: strips(plan.clean, True))
+
+
+def _mask_plan(mask, s_len, block_q, block_k, sub=None):
+    """The static plan the kernels of a call under ``mask`` run by (the
+    whole sequence against itself); None without a mask."""
+    if mask is None:
+        return None
+    plan = _block_diffusion_plan(mask, s_len, block_q, block_k, sub)
+    if plan is None:
+        raise ValueError(
+            f"flash_attention: {mask} over {s_len} positions in blocks "
+            f"({block_q}, {block_k}) has no kernel plan; gate callers "
+            "with supports()")
+    return plan
+
+
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
-                   block_k: int, interpret: bool):
+                   block_k: int, interpret: bool,
+                   mask: Optional[BlockDiffusion] = None):
     """q,k: (BH, S, D), v: (BH, S, Dv) -> (o (BH,S,Dv), L (BH,S,1))."""
     s_len = q.shape[1]
     if s_len % block_q or s_len % block_k:
@@ -485,9 +779,11 @@ def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
             f"flash_attention: seq len {s_len} must tile by blocks "
             f"({block_q}, {block_k}); gate callers with supports()"
         )
-    rows = _walk(causal, s_len, s_len, block_q, block_k, 0, 0)
-    return _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
-                         interpret)
+    rows = None if mask is not None else _walk(
+        causal, s_len, s_len, block_q, block_k, 0, 0)
+    return _forward_call(
+        q, k, v, causal, scale, block_q, block_k, rows, interpret, mask,
+        _mask_plan(mask, s_len, block_q, block_k))
 
 
 def _shared_trace(*static_argnums):
@@ -510,13 +806,15 @@ def _shared_trace(*static_argnums):
     )
 
 
-@_shared_trace(3, 4, 5, 6, 7, 8)
+@_shared_trace(3, 4, 5, 6, 7, 8, 9, 10)
 def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
-                  interpret):
+                  interpret, mask=None, plan=None):
     """The forward ``pallas_call``. ``rows`` is the plan of ``_walk``:
     given and the sequence one tile, the strips kernel; given over a
     grid of tiles, the grid kernel that walks the diagonal tiles; None,
-    the grid of whole tiles."""
+    the grid of whole tiles. Under a ``mask``, ``plan`` is its
+    ``_BlockDiffusionPlan`` and the grid kernel that mask's (``causal``
+    and ``rows`` are then unread)."""
     bh, s_len, d = q.shape
     dv = v.shape[2]
     if rows is not None and _one_tile(s_len, s_len, block_q, block_k):
@@ -543,7 +841,11 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
             cost_estimate=cost,
             interpret=interpret,
         )(q, k, v)
-    if rows is not None:
+    if plan is not None:
+        kernel = functools.partial(
+            _fwd_diffusion_kernel, plan=plan, scale=scale)
+        kv_tile = plan.kv_tile
+    elif rows is not None:
         kernel = functools.partial(
             _fwd_grid_kernel, rows=rows, sub=block_q // len(rows),
             scale=scale,
@@ -555,10 +857,10 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
         )
         kv_tile = _streamed
     return _grid_forward(kernel, kv_tile, q, k, v, block_q, block_k, causal,
-                         interpret)
+                         interpret, mask)
 
 
-def _forward_outputs(q, k, v, causal):
+def _forward_outputs(q, k, v, causal, mask=None):
     """(``out_shape``, ``cost_estimate``) of a forward ``pallas_call``:
     o and the logsumexp, carried as (BH, S, 1)."""
     bh, s_len, d = q.shape
@@ -572,6 +874,7 @@ def _forward_outputs(q, k, v, causal):
         bh, s_len, s_len, d, dv, causal=causal,
         byte_tensors=[(1 + kv, s_len, d, q.dtype.itemsize),
                       (1 + kv, s_len, dv, q.dtype.itemsize)],
+        mask=mask,
     )
     return out_shape, cost
 
@@ -589,13 +892,13 @@ def _streamed(resident, streamed):
 
 
 def _grid_forward(kernel, kv_tile, q, k, v, block_q, block_k, causal,
-                  interpret):
+                  interpret, mask=None):
     """The forward ``pallas_call`` over the grid of tiles: ``kernel`` is
     a grid step's body, ``kv_tile(i, j)`` the K/V tile that step j of q
     block i names."""
     bh, s_len, d = q.shape
     dv = v.shape[2]
-    out_shape, cost = _forward_outputs(q, k, v, causal)
+    out_shape, cost = _forward_outputs(q, k, v, causal, mask)
     kv_row = _kv_row(q, k)
     return pl.pallas_call(
         kernel,
@@ -628,11 +931,11 @@ def _grid_forward(kernel, kv_tile, q, k, v, block_q, block_k, causal,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, mask):
     o, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                          interpret)
+                          interpret, mask)
     return o
 
 
@@ -667,9 +970,9 @@ def describe_kept(v) -> str:
     )
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, mask):
     o, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            interpret)
+                            interpret, mask)
     # The names have to sit here, inside the forward rule: one on
     # ``flash_attention``'s result alone would keep o and still run the
     # kernel again for the logsumexp.
@@ -678,7 +981,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, mask, res, g):
     """Backward via the tiled Pallas kernels (flash_chunk_grads with the
     whole sequence as one chunk). Profiled on v5e: the previous XLA
     blockwise-scan backward was ~22% of transformer step device time at
@@ -693,7 +996,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     dq, dk, dv = flash_chunk_grads(
         q, k, v, g, lse[..., None], delta, 0, 0, causal=causal,
         scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, mask=mask,
     )
     group = q.shape[0] // k.shape[0]
     if group > 1:
@@ -827,7 +1130,7 @@ def _bwd_tile_math(q, k_blk, v_blk, do, lse, delta, q_start, k_start,
     ) * scale
     p = jnp.exp(s - lse)
     if causal:
-        p = jnp.where(_causal_mask(p.shape, q_start, k_start), p, 0.0)
+        p = jnp.where(_visible(p.shape, q_start, k_start, causal), p, 0.0)
     dp = jax.lax.dot_general(
         do, v_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -943,71 +1246,94 @@ def _strip_of(ref, strip):
 
 def _dq_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, *, rows, sub, q_offset, k_offset, scale,
-                      dq_acc=None):
+                      dq_acc=None, starts=None, rule=True, final=True):
     """dq over one grid tile walked by ``_walk``: q strip ``i`` against
     the keys its row of the plan reaches; a strip wholly above the
     diagonal gets zeros. Each strip is written once, so no scratch of
     its own; ``dq_acc`` is what the tiles to the left have summed
     (``_dq_grid_kernel``: the diagonal tile is the last that
-    contributes, so the strip is flushed with its own part added)."""
+    contributes, so the strip is flushed with its own part added).
+    ``starts``, ``rule``, ``final``: as ``_fwd_strips_kernel``'s, for a
+    boundary tile of a block-diffusion call; not ``final``, the strip's
+    part is added to ``dq_acc`` and nothing is flushed."""
     for i, n_k in enumerate(rows):
-        strip, keys = pl.ds(i * sub, sub), pl.ds(0, n_k * sub)
-        if not n_k:
-            dq_ref[0, strip, :] = (
-                jnp.zeros((sub, dq_ref.shape[2]), dq_ref.dtype)
-                if dq_acc is None else dq_acc[strip, :])
+        first = starts[i] if starts else 0
+        strip = pl.ds(i * sub, sub)
+        keys = pl.ds(first * sub, (n_k - first) * sub)
+        if n_k == first:
+            if final:
+                dq_ref[0, strip, :] = (
+                    jnp.zeros((sub, dq_ref.shape[2]), dq_ref.dtype)
+                    if dq_acc is None else dq_acc[strip, :])
             continue
         k_blk = k_ref[0, keys, :]
-        q_start = q_offset + i * sub
+        q_start, k_start = q_offset + i * sub, k_offset + first * sub
         ds, _ = _bwd_tile_math(
             q_ref[0, strip, :], k_blk, v_ref[0, keys, :],
             do_ref[0, strip, :], lse_ref[0, strip, :],
-            delta_ref[0, strip, :], q_start, k_offset,
-            _diagonal_crosses(q_start, k_offset, n_k * sub), scale,
+            delta_ref[0, strip, :], q_start, k_start,
+            _holds_masked(rule, q_start, sub, k_start, (n_k - first) * sub),
+            scale,
         )
         dq = jax.lax.dot_general(
             ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
+        if not final:
+            dq_acc[strip, :] += dq
+            continue
         dq_ref[0, strip, :] = dq if dq_acc is None else dq_acc[strip, :] + dq
 
 
 def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, *, rows, sub, q_offset, k_offset,
-                       scale):
+                       scale, starts=None, rule=True, add=False):
     """dk/dv over one grid tile walked by ``_walk``: k strip ``j``
     against the queries from the first row whose plan reaches it to the
     tile's end; a strip no query sees gets zeros. ``dk_ref, dv_ref``:
     the output blocks, or the scratch accumulators of
     ``_dkv_grid_kernel`` (the diagonal tile is the first that
-    contributes to its keys, so it sets them)."""
-    block_q = q_ref.shape[1]
+    contributes to its keys, so it sets them). ``starts``, ``rule``: as
+    ``_fwd_strips_kernel``'s, for a boundary tile of a block-diffusion
+    call (the rows that reach a strip are consecutive under every
+    rule); ``add``: the strips' parts are added to the accumulators (a
+    clean tile's keys were seen by noised tiles before it)."""
     for j in range(k_ref.shape[1] // sub):
         strip = pl.ds(j * sub, sub)
         dk_strip, dv_strip = _strip_of(dk_ref, strip), _strip_of(dv_ref, strip)
-        first = next((i for i, n_k in enumerate(rows) if n_k > j), None)
-        if first is None:
-            dk_ref[dk_strip] = jnp.zeros((sub, dk_ref.shape[-1]),
-                                         dk_ref.dtype)
-            dv_ref[dv_strip] = jnp.zeros((sub, dv_ref.shape[-1]),
-                                         dv_ref.dtype)
+        reach = [i for i, n_k in enumerate(rows)
+                 if (starts[i] if starts else 0) <= j < n_k]
+        if not reach:
+            if not add:
+                dk_ref[dk_strip] = jnp.zeros((sub, dk_ref.shape[-1]),
+                                             dk_ref.dtype)
+                dv_ref[dv_strip] = jnp.zeros((sub, dv_ref.shape[-1]),
+                                             dv_ref.dtype)
             continue
-        queries = pl.ds(first * sub, block_q - first * sub)
+        first, q_len = reach[0], (reach[-1] + 1 - reach[0]) * sub
+        queries = pl.ds(first * sub, q_len)
         q, do = q_ref[0, queries, :], do_ref[0, queries, :]
         q_start, k_start = q_offset + first * sub, k_offset + j * sub
         ds, p = _bwd_tile_math(
             q, k_ref[0, strip, :], v_ref[0, strip, :], do,
             lse_ref[0, queries, :], delta_ref[0, queries, :], q_start,
-            k_start, _diagonal_crosses(q_start, k_start, sub), scale,
+            k_start, _holds_masked(rule, q_start, q_len, k_start, sub),
+            scale,
         )
-        dk_ref[dk_strip] = jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        dv_ref[dv_strip] = jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        for ref, at, part in (
+            (dk_ref, dk_strip, lambda: jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale),
+            (dv_ref, dv_strip, lambda: jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )),
+        ):
+            if add:
+                ref[at] += part()
+            else:
+                ref[at] = part()
 
 
 def _dq_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
@@ -1074,7 +1400,85 @@ def _dkv_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_acc[:]
 
 
-def _grads_costs(q, v_chunk, causal):
+def _dq_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                         lse_ref, delta_ref, dq_ref, dq_acc, *, plan,
+                         scale):
+    """``_dq_kernel`` under a :class:`BlockDiffusion` mask: the steps of
+    ``_fwd_diffusion_kernel``, each adding its part of dq; the clean
+    tile of the row's place is the last that contributes and flushes."""
+    del qoff_ref, koff_ref
+    qi = pl.program_id(1)
+    kt = pl.program_id(2)
+    noised, place, on_noised, on_clean = _diffusion_steps(plan, qi, kt)
+
+    def strips(walk, final):
+        _dq_strips_kernel(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+            rows=walk.rows, sub=plan.sub, q_offset=0, k_offset=0,
+            scale=scale, dq_acc=dq_acc, starts=walk.starts, rule=walk.rule,
+            final=final,
+        )
+
+    @pl.when(kt == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    pl.when(noised & on_noised)(lambda: strips(plan.own, False))
+
+    @pl.when((kt >= plan.n) & (kt - plan.n < place))
+    def _whole():
+        _dq_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, 0, 0,
+            False, scale,
+        )
+
+    pl.when(noised & on_clean)(lambda: strips(plan.before, True))
+    pl.when(jnp.logical_not(noised) & on_clean)(
+        lambda: strips(plan.clean, True))
+
+
+def _dkv_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
+                          dv_acc, *, plan, scale):
+    """``_dkv_kernel`` under a :class:`BlockDiffusion` mask. A noised
+    key tile is seen by the noised q tile of its place alone (``own``
+    sets the accumulators; every other step is dead). Clean key tile c
+    is seen by noised q tile c under ``before`` (the first: it sets the
+    accumulators), the noised tiles after it whole, clean q tile c under
+    ``clean`` (added) and the clean tiles after it whole."""
+    del qoff_ref, koff_ref
+    ki = pl.program_id(1)
+    qt = pl.program_id(2)
+    n = plan.n
+    noised, place, on_noised, on_clean = _diffusion_steps(plan, ki, qt)
+    clean = jnp.logical_not(noised)
+
+    def strips(walk, add):
+        _dkv_strips_kernel(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
+            dv_acc, rows=walk.rows, sub=plan.sub, q_offset=0, k_offset=0,
+            scale=scale, starts=walk.starts, rule=walk.rule, add=add,
+        )
+
+    pl.when(noised & on_noised)(lambda: strips(plan.own, False))
+    pl.when(clean & on_noised)(lambda: strips(plan.before, False))
+
+    @pl.when(clean & (((qt > place) & (qt < n)) | (qt > ki)))
+    def _whole():
+        _dkv_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
+            dv_acc, 0, 0, False, scale,
+        )
+
+    pl.when(clean & on_clean)(lambda: strips(plan.clean, True))
+
+    @pl.when(qt == pl.num_programs(2) - 1)
+    def _flush():
+        dk_ref[0] = dk_acc[:]
+        dv_ref[0] = dv_acc[:]
+
+
+def _grads_costs(q, v_chunk, causal, mask=None):
     """(dq kernel's, dk/dv kernel's) ``pl.CostEstimate``: both read q,
     k (width d) and v, do (width dv)."""
     bh, sq, d = q.shape
@@ -1085,7 +1489,7 @@ def _grads_costs(q, v_chunk, causal):
              (kv, sk, d, size), (kv, sk, dv, size)]
     return tuple(
         _cost(bh, sq, sk, d, dv, causal=causal,
-              byte_tensors=reads + written)
+              byte_tensors=reads + written, mask=mask)
         for written in ([(1, sq, d, 4)], [(1, sk, d, 4), (1, sk, dv, 4)])
     )
 
@@ -1136,15 +1540,22 @@ def _strips_grads(q, k_chunk, v_chunk, do, lse, delta, rows, q_offset,
     return dq, dk, dv
 
 
-@_shared_trace(8, 9, 10, 11, 12, 13)
+@_shared_trace(8, 9, 10, 11, 12, 13, 14, 15)
 def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
-                 causal, scale, block_q, block_k, rows, interpret):
+                 causal, scale, block_q, block_k, rows, interpret,
+                 mask=None, plan=None):
     """``flash_chunk_grads`` over a grid of tiles. ``rows`` None: whole
     tiles, the offsets traced (scalar-prefetched), a tile strictly above
     the diagonal predicated out. ``rows`` the plan of ``_walk`` over
     several tiles: the diagonal tiles walked, dead steps naming the
-    diagonal's tiles."""
-    if rows is None:
+    diagonal's tiles. Under a ``mask``, ``plan`` is its
+    ``_BlockDiffusionPlan``: that mask's kernels and index maps."""
+    if plan is not None:
+        common = dict(plan=plan, scale=scale)
+        dq_kernel = functools.partial(_dq_diffusion_kernel, **common)
+        dkv_kernel = functools.partial(_dkv_diffusion_kernel, **common)
+        kv_tile, q_tile = plan.kv_tile, plan.q_tile
+    elif rows is None:
         common = dict(causal=causal, scale=scale)
         dq_kernel = functools.partial(_dq_kernel, block_k=block_k, **common)
         dkv_kernel = functools.partial(_dkv_kernel, block_q=block_q,
@@ -1158,20 +1569,20 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
     return _grid_grads(
         dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk, v_chunk, do,
         lse, delta, q_offset, k_offset, block_q, block_k, causal,
-        interpret,
+        interpret, mask,
     )
 
 
 def _grid_grads(dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk,
                 v_chunk, do, lse, delta, q_offset, k_offset, block_q,
-                block_k, causal, interpret):
+                block_k, causal, interpret, mask=None):
     """The two backward ``pallas_call``s over the grid of tiles:
     ``kv_tile(i, j)`` is the K/V tile that step j of q block i names in
     the dq kernel, ``q_tile(i, j)`` the q/do/lse/delta tile that step j
     of k block i names in the dk/dv kernel."""
     bh, sq, d = q.shape
     sk, dv = v_chunk.shape[1:]
-    dq_cost, dkv_cost = _grads_costs(q, v_chunk, causal)
+    dq_cost, dkv_cost = _grads_costs(q, v_chunk, causal, mask)
     kv_row = _kv_row(q, k_chunk)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape((1,))
     koff = jnp.asarray(k_offset, jnp.int32).reshape((1,))
@@ -1257,6 +1668,7 @@ def flash_chunk_grads(
     causal: bool = True, scale: Optional[float] = None,
     block_q: int = 0, block_k: int = 0,
     interpret: bool = False,
+    mask: Optional[BlockDiffusion] = None,
 ):
     """Backward of one attention chunk pairing, fully tiled.
 
@@ -1279,7 +1691,9 @@ def flash_chunk_grads(
             f"flash_chunk_grads: shapes (Sq={sq}, Sk={sk}) must tile by "
             f"blocks ({block_q}, {block_k})"
         )
-    rows = _walk(causal, sq, sk, block_q, block_k, q_offset, k_offset)
+    # Under a mask: the whole sequence against itself (``_flash_bwd``).
+    rows = None if mask is not None else _walk(
+        causal, sq, sk, block_q, block_k, q_offset, k_offset)
     if rows is not None and _one_tile(sq, sk, block_q, block_k):
         return _strips_grads(
             q, k_chunk, v_chunk, do, lse, delta, rows, q_offset, k_offset,
@@ -1287,7 +1701,8 @@ def flash_chunk_grads(
         )
     return _tiles_grads(
         q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset, causal,
-        float(scale), block_q, block_k, rows, interpret,
+        float(scale), block_q, block_k, rows, interpret, mask,
+        _mask_plan(mask, sq, block_q, block_k),
     )
 
 
@@ -1330,14 +1745,25 @@ def _blocks(sq: int, sk: int, block_q: int, block_k: int):
     return block_q, block_k
 
 
-def supports(q_shape, block_q: int = 0, block_k: int = 0) -> bool:
+def supports(q_shape, block_q: int = 0, block_k: int = 0,
+             mask: Optional[BlockDiffusion] = None) -> bool:
     """Static shape gate — callers fall back to dense otherwise. With
     default blocks (0), S must admit a lane-aligned tiling block
     (``_auto_block``); explicit blocks keep the raw divisibility rule
-    (tests drive small interpret-mode tiles)."""
+    (tests drive small interpret-mode tiles). Under a ``mask`` the
+    blocks tile a half, and the kernels need a plan for it
+    (``_block_diffusion_plan``)."""
     s_len = q_shape[1]
     if s_len % 8:
         return False
+    if mask is not None:
+        if not (block_q or block_k) and not (
+                _auto_block(mask.half, DEFAULT_BLOCK_Q)
+                and _auto_block(mask.half, DEFAULT_BLOCK_K)):
+            return False
+        return _block_diffusion_plan(
+            mask, s_len, *_blocks(mask.half, mask.half, block_q, block_k)
+        ) is not None
     if not block_q and not block_k:
         return (
             _auto_block(s_len, DEFAULT_BLOCK_Q) > 0
@@ -1357,12 +1783,19 @@ def flash_attention(
     block_q: int = 0,
     block_k: int = 0,
     interpret: bool = False,
+    mask: Optional[BlockDiffusion] = None,
 ):
     """Fused attention. q: (B, S, H, D); k: (B, S, Hkv, D); v: (B, S,
     Hkv, Dv), whose head size may differ from q's and k's (latent
     attention: 192 against 128); returns (B, S, H, Dv). Hkv divides H:
     query head h reads key/value head ``h // (H / Hkv)``, in place
     (``_kv_row``).
+
+    ``mask``: a :class:`BlockDiffusion` over S = 2 x half in place of
+    ``causal`` (which is then not read): the same custom VJP, the same
+    three kernels' tile mathematics, one call over the S x S grid whose
+    dead tiles cost a predicated-out step each and fetch nothing
+    (``_BlockDiffusionPlan``). The blocks tile a half.
 
     ``block_q/block_k`` 0 = auto: the largest lane-aligned default-or-
     smaller block that tiles S (``_auto_block`` — gate callers check
@@ -1377,7 +1810,11 @@ def flash_attention(
         raise ValueError(
             f"flash_attention: {h} query heads over {k.shape[2]} key and "
             f"{v.shape[2]} value heads")
-    block_q, block_k = _blocks(s_len, s_len, block_q, block_k)
+    if mask is not None:
+        causal = False
+        block_q, block_k = _blocks(mask.half, mask.half, block_q, block_k)
+    else:
+        block_q, block_k = _blocks(s_len, s_len, block_q, block_k)
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(
@@ -1385,6 +1822,6 @@ def flash_attention(
 
     o = _flash(
         to_bh(q), to_bh(k), to_bh(v), causal, float(scale), block_q,
-        block_k, interpret,
+        block_k, interpret, mask,
     )
     return o.reshape(b, h, s_len, v.shape[3]).transpose(0, 2, 1, 3)

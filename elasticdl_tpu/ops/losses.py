@@ -124,3 +124,23 @@ def masked_next_token_cross_entropy(labels, logits, mask):
         mask[:, None], ll.shape
     )
     return -jnp.sum(ll * weights) / jnp.maximum(jnp.sum(weights), 1.0)
+
+
+def weighted_in_place_cross_entropy(targets, logits, weights, mask):
+    """The loss of masked (absorbing-state) diffusion over a row:
+    position i predicts ``targets[:, i]`` itself, no shift; ``weights``
+    (B, S) are the model's own (zero where the token was not masked,
+    1 / p of its block where it was: ``models/sdar_moe.py``); ``mask``
+    is the (B,) padded-row mask. Targets (B, S) int, logits (B, S, V).
+    The sum over rows and positions of weight x CE over the positions of
+    the real rows: (1 / (B S)) sum m / p CE. The ``logsumexp(x) -
+    x[target]`` form of :func:`masked_next_token_cross_entropy`, for its
+    reasons."""
+    logits32 = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits32, axis=-1)            # (B, S)
+    target_logit = jnp.take_along_axis(
+        logits32, targets[..., None].astype(jnp.int32), axis=-1
+    )[..., 0]
+    rows = mask.astype(jnp.float32)
+    total = jnp.sum((lse - target_logit) * weights * rows[:, None])
+    return total / jnp.maximum(jnp.sum(rows) * targets.shape[1], 1.0)

@@ -38,7 +38,7 @@ def _from_bh(x, b, h):
 
 
 def dense_attention(q, k, v, causal: bool = True,
-                    scale: Optional[float] = None, q_offset=0):
+                    scale: Optional[float] = None, q_offset=0, mask=None):
     """Plain softmax attention. Shapes: q = (B, Sq, H, D), k/v =
     (B, Sk, H, D) with Sk >= Sq allowed (KV-cache decoding: ``q_offset``
     is q[:,0]'s global position, so causality masks the right keys —
@@ -47,12 +47,19 @@ def dense_attention(q, k, v, causal: bool = True,
     Reference semantics for ``ring_attention`` (used when the mesh has no
     sequence axis, and by tests). f32 softmax accumulation regardless of
     input dtype — bf16 inputs stay bf16 through the matmuls (MXU) but the
-    normalization happens in f32.
+    normalization happens in f32. ``mask``: a
+    ``flash_attention.BlockDiffusion`` over the whole sequence, in place
+    of ``causal``.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    if causal:
+    if mask is not None:
+        positions = jnp.arange(q.shape[1])
+        s = jnp.where(
+            mask.visible(positions[:, None], positions[None, :]), s,
+            _NEG_INF)
+    elif causal:
         q_len, k_len = q.shape[1], k.shape[1]
         qpos = q_offset + jnp.arange(q_len)[:, None]
         kpos = jnp.arange(k_len)[None, :]

@@ -257,16 +257,6 @@ class Worker:
         # rows: {name: device array} per program call, read back with
         # the losses.
         self._task_counters = []
-        self._m_moe_rows = self._metrics.counter(
-            "worker_moe_rows_total",
-            "Token-choices routed to experts this worker holds, summed "
-            "over expert layers and steps",
-        )
-        self._m_moe_rows_max = self._metrics.gauge(
-            "worker_moe_expert_rows_max",
-            "Largest per-step sum over expert layers of the fullest "
-            "held expert's rows, in the last trained task",
-        )
         # The first call of the training program (load or compile, and
         # the first run) is a start-up phase around the first
         # ``device_step`` (_first_step).
@@ -903,12 +893,22 @@ class Worker:
                 for name, values in sorted(steps.items())
             ),
         )
-        if "moe_rows" in steps:
-            self._m_moe_rows.inc(int(steps["moe_rows"].sum()))
-        if "moe_expert_rows_max" in steps:
-            self._m_moe_rows_max.set(
-                int(steps["moe_expert_rows_max"].max())
-            )
+        # Every counter a model brings has a series on the page, by its
+        # name alone: ``<name>_max`` a gauge, the largest step of the
+        # last trained task; any other a counter ``<name>_total``, the
+        # sum over steps (docs/observability.md says what each counts).
+        for name, values in steps.items():
+            if name.endswith("_max"):
+                self._metrics.gauge(
+                    f"worker_{name}",
+                    f"Model counter {name}: its largest step in the "
+                    "last trained task",
+                ).set(int(values.max()))
+            else:
+                self._metrics.counter(
+                    f"worker_{name}_total",
+                    f"Model counter {name}, summed over steps",
+                ).inc(int(values.sum()))
 
     def _end_cycle(self, task, trained_ok: bool):
         """A task's cycle has ended (its report is in): say why, if it

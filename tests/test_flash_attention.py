@@ -764,3 +764,244 @@ def test_cost_and_plan_say_the_two_head_counts():
         "28 skipped; one key/value head read in place by 16 query heads, "
         "dk/dv summed over them")
     assert "key/value" not in flash.describe_tiles(4096)
+
+
+# ----------------------------------------------- the block-diffusion mask
+
+def _diffusion_inputs(half, heads, group, d, seed=0, rows=2):
+    rng = np.random.RandomState(seed)
+    mk = lambda h: jnp.asarray(  # noqa: E731
+        rng.randn(rows, 2 * half, h, d), jnp.float32) * 0.5
+    return mk(heads), mk(heads // group), mk(heads // group)
+
+
+def _dense_under(mask, q, k, v):
+    group = q.shape[2] // k.shape[2]
+    return dense_attention(
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+        mask=mask)
+
+
+def test_block_diffusion_rule_is_the_four_regions():
+    """``BlockDiffusion.visible`` against the four rules written out,
+    and the count of visible pairs."""
+    half, block = 24, 4
+    mask = flash.BlockDiffusion(half, block)
+    seen = np.asarray(mask.visible(
+        jnp.arange(2 * half)[:, None], jnp.arange(2 * half)[None, :]))
+    for i in range(2 * half):
+        for j in range(2 * half):
+            n_i, n_j = i % half // block, j % half // block
+            if i < half and j < half:
+                want = n_i == n_j
+            elif i < half:
+                want = n_j < n_i
+            elif j < half:
+                want = False
+            else:
+                want = n_j <= n_i
+            assert seen[i, j] == want, (i, j)
+    assert seen.sum() == mask.pairs == half * (half + block)
+    assert flash.BlockDiffusion(4096, 4).pairs == 16793600
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize(
+    "half,tile,sub,block",
+    [(64, 64, 16, 4), (64, 64, 32, 32), (128, 32, 8, 4), (128, 64, 32, 32),
+     (96, 32, 16, 4)],
+    ids=["one-tile-b4", "one-tile-b32", "grid-4-b4", "grid-2-b32",
+         "grid-3-b4"])
+def test_block_diffusion_kernels_match_dense(monkeypatch, half, tile, sub,
+                                             block, group, d):
+    """Forward and dq, dk, dv under the mask against dense attention
+    under the same mask: halves of one and of several grid tiles, blocks
+    of 4 and of 32 (a sub-tile's width: the strict rule then drops the
+    diagonal sub-tile whole), one and eight query heads to a key/value
+    head, head sizes 64 and 128."""
+    monkeypatch.setattr(flash, "SUB_TILE", sub)
+    mask = flash.BlockDiffusion(half, block)
+    q, k, v = _diffusion_inputs(half, 8, group, d, rows=1)
+    assert supports(q.shape, tile, tile, mask=mask)
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, block_q=tile, block_k=tile,
+                               interpret=True, mask=mask)
+
+    got = _attend_and_grads(q, k, v, kernels)
+    want = _attend_and_grads(
+        q, k, v, lambda q, k, v: _dense_under(mask, q, k, v))
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-4)
+
+
+def test_block_diffusion_plan_counts_and_covers_every_visible_pair():
+    """At the cell's shape: 12 tiles whole, 12 boundary, 40 dead; and at
+    a small one every visible pair lies in a tile the plan multiplies (a
+    whole tile, or a live sub-tile of a boundary tile) and no whole tile
+    holds a masked pair."""
+    plan = flash.tile_plan(8192, 8192, mask=flash.BlockDiffusion(4096, 4))
+    assert plan.grid == (8, 8) and plan.tiles == (12, 12, 40)
+    assert (plan.diffusion.own.computed, plan.diffusion.before.computed,
+            plan.diffusion.clean.computed, plan.total) == (4, 10, 10, 16)
+    text = plan.describe()
+    assert ("12 tiles whole and unmasked, 12 boundary tiles walked (4 "
+            "noised on their own blocks 4, 4 noised on the clean blocks "
+            "before 10, 4 clean 10 of 16 sub-tiles 256x256), 40 skipped"
+            ) in text
+    # 64 grid tiles under a compare against what the mask requires.
+    assert round(64 * 16 / (12 * 16 + 4 * 4 + 8 * 10), 1) == 3.6
+    half, block, tile, sub = 96, 4, 32, 8
+    mask = flash.BlockDiffusion(half, block)
+    small = flash.tile_plan(2 * half, 2 * half, block_q=tile, block_k=tile,
+                            sub=sub, mask=mask).diffusion
+    n, per = small.n, tile // sub
+    covered = np.zeros((2 * half, 2 * half), bool)
+    whole = np.zeros_like(covered)
+    for qi in range(2 * n):
+        place = qi % n
+        for kt in range(2 * n):
+            rows = slice(qi * tile, (qi + 1) * tile)
+            cols = slice(kt * tile, (kt + 1) * tile)
+            if kt >= n and kt - n < place:
+                covered[rows, cols] = whole[rows, cols] = True
+                continue
+            walk = (small.own if qi < n and kt == qi else
+                    small.before if qi < n and kt == n + place else
+                    small.clean if qi >= n and kt == qi else None)
+            if walk is None:
+                continue
+            for i in range(per):
+                for j in range(walk.starts[i], walk.rows[i]):
+                    covered[qi * tile + i * sub:qi * tile + (i + 1) * sub,
+                            kt * tile + j * sub:kt * tile + (j + 1) * sub
+                            ] = True
+    seen = np.asarray(mask.visible(
+        jnp.arange(2 * half)[:, None], jnp.arange(2 * half)[None, :]))
+    assert not (seen & ~covered).any()
+    assert not (whole & ~seen).any()
+    assert small.n * (small.n - 1) == 6 == whole.sum() // tile ** 2
+
+
+def test_block_diffusion_fetches_nothing_of_the_dead_quadrant(monkeypatch):
+    """Noised keys and values poisoned with NaN, where no clean query
+    may look: the clean half's rows and their dq are what they were (dk
+    and dv of the clean keys are not: the noised queries see them and
+    are NaN themselves); and the index maps name, at a dead step, the
+    tile a live step of the row names."""
+    monkeypatch.setattr(flash, "SUB_TILE", 8)
+    half, tile = 64, 32
+    mask = flash.BlockDiffusion(half, 4)
+    q, k, v = _diffusion_inputs(half, 2, 1, 16, seed=3, rows=1)
+
+    def clean_rows(q, k, v):
+        return flash_attention(q, k, v, block_q=tile, block_k=tile,
+                               interpret=True, mask=mask)[:, half:]
+
+    def loss(q, k, v):
+        return jnp.sum(clean_rows(q, k, v) ** 2)
+
+    want = clean_rows(q, k, v)
+    want_dq = jax.grad(loss)(q, k, v)
+    k_bad = k.at[:, :half].set(jnp.nan)
+    v_bad = v.at[:, :half].set(jnp.nan)
+    np.testing.assert_array_equal(clean_rows(q, k_bad, v_bad), want)
+    np.testing.assert_array_equal(
+        jax.grad(loss)(q, k_bad, v_bad)[:, half:], want_dq[:, half:])
+    plan = flash.tile_plan(2 * half, 2 * half, block_q=tile, block_k=tile,
+                           mask=mask).diffusion
+    n = plan.n
+    for i in range(2 * n):
+        live = ({i} | set(range(n, n + i + 1))) if i < n else set(
+            range(n, i + 1))
+        named = [int(plan.kv_tile(jnp.int32(i), jnp.int32(j)))
+                 for j in range(2 * n)]
+        assert set(named) == live, (i, named)
+        assert all(named[j] == j for j in live)
+        # One fetch a live tile: a dead step names what is resident or
+        # wanted next, never a tile of its own.
+        assert sum(a != b for a, b in zip(named, named[1:])) == len(live) - 1
+    for i in range(2 * n):
+        live = {i} if i < n else (set(range(i - n, n)) | set(range(i, 2 * n)))
+        named = [int(plan.q_tile(jnp.int32(i), jnp.int32(j)))
+                 for j in range(2 * n)]
+        assert set(named) == live, (i, named)
+        assert all(named[j] == j for j in live)
+
+
+def test_block_diffusion_shapes_without_a_plan_are_refused(monkeypatch):
+    monkeypatch.setattr(flash, "SUB_TILE", 8)
+    mask = flash.BlockDiffusion(64, 4)
+    q, k, v = _diffusion_inputs(64, 2, 1, 16)
+    assert supports(q.shape, 32, 32, mask=mask)
+    for why, bad, blocks in (
+            ("the row is not the two halves", flash.BlockDiffusion(32, 4),
+             (32, 32)),
+            ("a block that is no power of two", flash.BlockDiffusion(60, 6),
+             (32, 32)),
+            ("a block wider than a sub-tile", flash.BlockDiffusion(64, 16),
+             (32, 32)),
+            ("a tile under two sub-tiles", mask, (8, 8)),
+            ("tiles that are not square", mask, (32, 64))):
+        assert not supports(q.shape, *blocks, mask=bad), why
+        with pytest.raises(ValueError):
+            flash_attention(q, k, v, block_q=blocks[0], block_k=blocks[1],
+                            interpret=True, mask=bad)
+    # The default blocks tile a half: 1,024 at the cell's shape, 512
+    # for halves of 512.
+    assert supports((2, 8192, 32, 128), mask=flash.BlockDiffusion(4096, 4))
+    monkeypatch.setattr(flash, "SUB_TILE", 256)
+    assert supports((2, 1024, 4, 64), mask=flash.BlockDiffusion(512, 4))
+    assert flash.tile_plan(1024, 1024, mask=flash.BlockDiffusion(512, 4)
+                           ).grid == (2, 2)
+    assert not supports((2, 200, 4, 64), mask=flash.BlockDiffusion(100, 4))
+
+
+def test_block_diffusion_cost_counts_the_visible_pairs():
+    mask = flash.BlockDiffusion(4096, 4)
+    cost = flash._cost(64, 8192, 8192, 128, 128, False,
+                       [(2, 8192, 128, 2)], mask=mask)
+    assert cost.flops == 2 * 64 * (128 + 128) * mask.pairs
+    assert cost.transcendentals == 64 * mask.pairs
+    causal = flash._cost(64, 8192, 8192, 128, 128, True, [(2, 8192, 128, 2)])
+    assert causal.flops == 2 * 64 * 8192 * 8192 * 256 // 2
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("s,block", [(64, 64), (128, 32)],
+                         ids=["one-tile", "grid"])
+def test_causal_and_unmasked_calls_trace_the_kernels_they_traced(
+        monkeypatch, causal, s, block):
+    """A call without ``mask`` traces the kernels it traced before the
+    second mask kind: none of the diffusion kernels, and the only mask
+    any tile builds is the causal one (``_visible`` asked with True, or
+    not at all). (That the operations in them are the parent's was held
+    once against the parent's jaxprs with locations blanked: PERF.md,
+    PR 34.)"""
+    monkeypatch.setattr(flash, "SUB_TILE", 16)
+    expected = (("_fwd_strips_kernel", "_dq_strips_kernel",
+                 "_dkv_strips_kernel") if causal and s == block else
+                GRID_KERNELS if causal else
+                ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"))
+    counts = _count_traces(
+        monkeypatch, "_fwd_diffusion_kernel", "_dq_diffusion_kernel",
+        "_dkv_diffusion_kernel", *expected)
+    asked = []
+    real = flash._visible
+    monkeypatch.setattr(
+        flash, "_visible",
+        lambda shape, q0, k0, kind: asked.append(kind) or real(
+            shape, q0, k0, kind))
+    # Shapes no other test of this file traces: a shared trace is cached.
+    q, k, v, _ = _grouped_inputs(s, 6, 3)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=causal, block_q=block, block_k=block,
+            interpret=True))
+
+    jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    assert {name for name, n in counts.items() if n} == set(expected)
+    assert all(kind is True for kind in asked) and bool(asked) == causal

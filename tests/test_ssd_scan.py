@@ -175,3 +175,44 @@ def test_published_shapes_compile_for_a_described_v5e(one_chip):
     # L for the whole sequence would be 537 MB in float32; the states
     # residual is 268 MB and x, y, dx, dy 67 MB each.
     assert compiled.memory_analysis().temp_size_in_bytes < 900e6
+
+
+def test_block_diffusion_attention_compiles_for_a_described_v5e(one_chip):
+    """``sdar_ep8_steady``'s attention through the TPU's compiler, no
+    chip attached (a compile that passes is not a chip run): 32 query
+    heads over 4 key/value heads of 128, the row twice over, 8,192
+    positions, under the block-diffusion mask. Three kernels, and no
+    (2L)^2 score tensor among the program's buffers. (In this file, not
+    beside the kernels' other tests: one file may describe the topology,
+    a second goes to another worker where the library's lock skips it.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from elasticdl_tpu.ops.flash_attention import (
+        BlockDiffusion,
+        flash_attention,
+    )
+
+    rows, half, h, hkv, d = 2, 4096, 32, 4, 128
+    shaped = lambda heads: jax.ShapeDtypeStruct(  # noqa: E731
+        (rows, 2 * half, heads, d), jnp.bfloat16, sharding=one_chip)
+    mask = BlockDiffusion(half, 4)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, mask=mask).astype(
+            jnp.float32))
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2))).lower(
+                shaped(h), shaped(hkv), shaped(hkv)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    # One head's scores over the doubled row would be 268 MB in float32,
+    # a row's 8.6 GB; dk and dv leave the kernel a query head each (2 x
+    # 268 MB, float32) beside dq and the operands' copies.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

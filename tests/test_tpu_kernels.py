@@ -239,6 +239,74 @@ class TestFlashAttentionOnChip:
                 err_msg=f"{name}: strips against the whole tile",
             )
 
+    @pytest.mark.parametrize("half,block", [(4096, 4), (1024, 32)])
+    def test_block_diffusion_mask_matches_dense_at_the_cells_geometry(
+            self, tpu, half, block):
+        """``sdar_ep8_steady``'s attention, compiled: 32 query heads
+        over 4 key/value heads of 128 over the row twice (8,192
+        positions; and halves of one grid tile), bfloat16, forward and
+        dq, dk, dv against dense attention under the same mask in
+        float32 (a query head at a time: the dense scores of one head
+        are 268 MB)."""
+        import jax
+        import jax.numpy as jnp
+
+        from elasticdl_tpu.ops.flash_attention import (
+            BlockDiffusion,
+            flash_attention,
+        )
+        from elasticdl_tpu.ops.ring_attention import dense_attention
+
+        h, hkv, d = 32, 4, 128
+        rng = np.random.RandomState(half)
+        mk = lambda heads: jnp.asarray(  # noqa: E731
+            rng.randn(1, 2 * half, heads, d).astype(np.float32) * 0.5,
+            jnp.bfloat16)
+        q, k, v = mk(h), mk(hkv), mk(hkv)
+        mask = BlockDiffusion(half, block)
+
+        def total(attend):
+            def f(q, k, v):
+                out = attend(q, k, v).astype(jnp.float32)
+                return jnp.sum(out * jnp.cos(out)), out
+            return jax.jit(jax.grad(f, argnums=(0, 1, 2), has_aux=True))
+
+        (dq, dk, dv), out = total(
+            lambda q, k, v: flash_attention(q, k, v, mask=mask))(q, k, v)
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+
+        def close(got, want, what):
+            # The operands are the same bfloat16 numbers on both sides;
+            # the kernels round p and ds to bfloat16 for the MXU.
+            err = float(np.max(np.abs(np.asarray(got) - np.asarray(want))))
+            assert err <= 2e-2 * float(np.max(np.abs(want))), (what, err)
+
+        dense = total(lambda q, k, v: dense_attention(
+            q, k, v, mask=mask))
+        want_dk = np.zeros(k.shape, np.float32)
+        want_dv = np.zeros(v.shape, np.float32)
+        with jax.default_matmul_precision("highest"):
+            for head in range(0, h, 8):        # one head of each group
+                kv = head // (h // hkv)
+                (wq, wk, wv), want = dense(
+                    f32(q[:, :, head:head + 1]), f32(k[:, :, kv:kv + 1]),
+                    f32(v[:, :, kv:kv + 1]))
+                close(f32(out[:, :, head]), want[:, :, 0], ("o", head))
+                close(f32(dq[:, :, head]), wq[:, :, 0], ("dq", head))
+        # dk and dv sum over a group's eight query heads: one whole
+        # group against its dense sum.
+        with jax.default_matmul_precision("highest"):
+            for head in range(8):
+                (_, wk, wv), _ = dense(
+                    f32(q[:, :, head:head + 1]), f32(k[:, :, :1]),
+                    f32(v[:, :, :1]))
+                want_dk[:, :, :1] += np.asarray(wk)
+                want_dv[:, :, :1] += np.asarray(wv)
+        close(f32(dk[:, :, 0]), want_dk[:, :, 0], "dk")
+        close(f32(dv[:, :, 0]), want_dv[:, :, 0], "dv")
+        assert np.isfinite(np.asarray(f32(dq))).all()
+        assert np.isfinite(np.asarray(f32(dk))).all()
+
     def test_chunk_update_streams_to_full_answer(self, tpu):
         """The ring building block compiled on chip: folding K/V chunks
         through flash_chunk_update must equal one-shot attention."""
