@@ -10,7 +10,7 @@ so scores only ever exist as (block_q, block_k) tiles on-chip.
 What the causal mask saves, and where: all of it rests on knowing the
 diagonal's place at trace time (``_walk``: a causal call, Python-int
 offsets). Inside ONE grid tile, which is the whole sequence at S <=
-1,024 with the default blocks, the three kernels walk the tile in
+1,024 with the default blocks, the kernels walk the tile in
 ``SUB_TILE``-wide strips that stop at the diagonal: 10 of 16 sub-tiles
 multiplied at S = 1,024, 3 of 4 at S = 512. Over a square GRID of tiles
 (S over one block: 2,048, 4,096; equal offsets) grid step (qi, kt) is a
@@ -29,12 +29,17 @@ mathematics (``_tile_scores``, ``_tile_probs``, ``_bwd_tile_math``) and
 give the same bits: the rows dropped are exact zeros of the mask.
 
 Backward is a custom VJP: the forward saves only o and the logsumexp
-L = m + log(l) (the flash-attention residual trick); the backward runs
-the same tiled Pallas kernels as the ring path (``flash_chunk_grads``:
-dq k-sequential, dk/dv q-sequential) with probability tiles recomputed
-from the residuals in VMEM. An earlier pure-XLA blockwise-scan backward
-measured ~3.2x the forward's device time on v5e (~22% of the whole
-transformer train step) and was replaced by these kernels.
+L = m + log(l) (the flash-attention residual trick); the backward is
+ONE tiled Pallas kernel a call, the ring path's too
+(``flash_chunk_grads``): the probability tile is recomputed from the
+residuals in VMEM once a live tile, and S, P, dP and dS feed dq, dk and
+dv together, 5 products a tile (until PR 35 a dq kernel and a dk/dv
+kernel each recomputed them: 7 products, and q, k, v, do, lse and delta
+read twice). dk and dv sum over the q tiles of a k block in scratch; dq
+sums over the k blocks in its output block, a (batch*head)'s whole row
+resident in VMEM. An earlier pure-XLA blockwise-scan backward measured
+~3.2x the forward's device time on v5e (~22% of the whole transformer
+train step) and was replaced by kernels.
 
 Numerics: QK^T and PV matmuls run in the input dtype on the MXU with
 float32 accumulation (``preferred_element_type``); softmax state is
@@ -42,6 +47,7 @@ float32 throughout.
 """
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -94,27 +100,79 @@ DEFAULT_BLOCK_K = 1024
 # bits. The dead steps cost what they did because a dk/dv step fetched
 # q, do and two (block, 1) f32 columns (lse, delta), whose rows are 4
 # bytes each: 2.9 us a dead step, 768 of them a call.
+# Since PR 35 the backward is ONE kernel (S, P, dP, dS once a tile: 5
+# products where the dq and dk/dv kernels ran 7; same tool and chip, ms
+# a layer fwd / bwd / sum, beside the rows above, each shape's last
+# and its whole tiles', as fwd / dq + dk/dv / sum):
+#   S 4,096, q/k 192, v 128   7.234 / 20.730 / 27.964 -> 7.237 / 14.029 / 21.267
+#   S 4,096, D 64             (sum 19.846)            -> 6.015 /  8.967 / 14.982
+#     whole tiles             6.491 / 17.772 / 24.262 -> 6.492 / 11.907 / 18.399
+#   S 2,048, D 64             (sum  5.386)            -> 1.779 /  2.456 /  4.235
+#     whole tiles             1.888 /  5.100 /  6.988 -> 1.998 /  3.393 /  5.392
+#   S 1,024, D 64             0.332 /  0.904 /  1.237 -> 0.332 /  0.612 /  0.944
+# and at B*H = 64, S 8,192, D 128: 32 query heads over 2, causal, 9.817
+# / 24.340 / 34.157 -> 9.821 / 16.417 / 26.238; over 4 under the
+# block-diffusion mask 6.979 / 15.631 / 22.610 -> 6.977 / 10.643 / 17.620.
 SUB_TILE = 256
 # A head wider than ``WIDE_HEAD`` (latent attention's q and k are 192)
-# takes the dk/dv kernel's operands, outputs and accumulators 0.5 MiB
-# past Mosaic's default 16 MiB of scoped VMEM at the default blocks (the
-# compiler's refusal, v5e): such calls raise the limit. v5e sweep, B*H =
+# took the former dk/dv kernel's operands, outputs and accumulators 0.5
+# MiB past Mosaic's default 16 MiB of scoped VMEM at the default blocks
+# (the compiler's refusal, v5e): such calls raise the limit. v5e sweep, B*H =
 # 128, S = 4,096, q/k 192, v 128, bf16, forward + backward, ms a layer:
 # 1024 x 1024 under this limit 42.8; under the default limit 512 x 1024:
 # 45.4, 1024 x 512: 49.8, 512 x 512: 50.8
 # (tools/bench_mla_moe_parts.py). Calls with heads up to 128 pass no
-# limit, as before.
+# limit, but for a backward whose blocks need more (below).
 WIDE_HEAD = 128
 WIDE_HEAD_VMEM_BYTES = 64 * 2 ** 20
 
 
-def _compiler_params(semantics, *head_sizes):
+# Mosaic's default limit of scoped VMEM (v5e: 16 of 128 MiB). The
+# backward over a grid of tiles keeps a b's whole dq row, (Sq, d)
+# float32, in VMEM as an output block (twice: the pipeline's second
+# buffer), so what it needs follows the sequence: the compiler's own
+# figures (the smallest limit it accepts, v5e, bf16, 1024 x 1024 blocks,
+# MiB): S 2,048 D 64 15.8; S 4,096 D 64 18.2; S 8,192 D 128 21.4 (under
+# the block-diffusion mask the same); S 4,096 q/k 192 v 128 25.7; S
+# 16,384 D 128 29.9. ``_grads_vmem_bytes`` counts the blocks as VMEM
+# lays them out and three float32 score tiles for the kernel's
+# temporaries (the compiler takes 6.4-8.2 MiB for them), and a call
+# whose count passes the default raises the limit as a wide head does.
+DEFAULT_VMEM_BYTES = 16 * 2 ** 20
+# The longest dq row the backward keeps resident; a longer call's
+# queries are cut into runs that fit (``flash_chunk_grads``).
+RESIDENT_DQ_BYTES = 8 * 2 ** 20
+
+
+def _compiler_params(semantics, *head_sizes, vmem_bytes=0):
+    raised = (max(head_sizes) > WIDE_HEAD
+              or vmem_bytes > DEFAULT_VMEM_BYTES)
     return pltpu.CompilerParams(
         dimension_semantics=semantics,
-        vmem_limit_bytes=(
-            WIDE_HEAD_VMEM_BYTES if max(head_sizes) > WIDE_HEAD else None
-        ),
+        vmem_limit_bytes=WIDE_HEAD_VMEM_BYTES if raised else None,
     )
+
+
+def _grads_vmem_bytes(sq, block_q, block_k, d, dv, itemsize):
+    """What the backward over a grid of tiles holds in VMEM: the
+    streamed blocks (q, do, k, v and the lse and delta columns, a lane
+    tile wide each) and the outputs (dq's whole row; dk, dv) twice, the
+    dk/dv accumulators, three float32 score tiles."""
+    def lanes(width):
+        return -(-width // 128) * 128
+
+    heads = lanes(d) + lanes(dv)
+    streamed = (block_q + block_k) * heads * itemsize + 2 * block_q * 128 * 4
+    written = (sq * lanes(d) + block_k * heads) * 4
+    return (2 * (streamed + written) + block_k * heads * 4
+            + 3 * block_q * block_k * 4)
+
+
+def _resident_rows(d, block_q):
+    """How many of a call's queries, in whole blocks, the backward
+    kernel takes at once: those whose float32 dq rows fit
+    ``RESIDENT_DQ_BYTES``."""
+    return max(1, RESIDENT_DQ_BYTES // (4 * d * block_q)) * block_q
 
 
 class BlockDiffusion(NamedTuple):
@@ -229,7 +287,7 @@ class _BlockDiffusionPlan(NamedTuple):
     clean: _Strips
 
     def kv_tile(self, i, j):
-        """The K/V tile step j of query row i names (forward, dq): a
+        """The K/V tile step j of query row i names (forward): a
         live step its own, a dead one the next live one's, or the last
         live one's past it: nothing is fetched for a dead step."""
         n = self.n
@@ -238,7 +296,7 @@ class _BlockDiffusionPlan(NamedTuple):
         return jnp.where(i < n, noised, jnp.clip(j, n, i))
 
     def q_tile(self, i, j):
-        """The q/do/lse/delta tile step j of key column i names (dk/dv):
+        """The q/do/lse/delta tile step j of key column i names (backward):
         a noised column is seen by its own tile alone; clean column c by
         the noised tiles from c on and the clean tiles from c on."""
         n = self.n
@@ -268,6 +326,12 @@ def _block_diffusion_plan(mask: BlockDiffusion, s_len, block_q, block_k,
         half // block_q, sub,
         *(_strips(_Rule(kind, b), block_q, sub)
           for kind in ("block_diagonal", "block_strict", "block_causal")))
+
+
+# Which backward a trace took is static, so its counter is this clause
+# of the line ``log_traced`` prints (every ``TilePlan.describe`` ends
+# with it): S, dP, dQ, dK, dV once a live tile, in one ``pallas_call``.
+BACKWARD_FORM = "; backward: one kernel, 5 products a tile"
 
 
 class TilePlan(NamedTuple):
@@ -321,7 +385,7 @@ class TilePlan(NamedTuple):
         shared = (
             f"; one key/value head read in place by {self.group} query "
             "heads, dk/dv summed over them" if self.group > 1 else ""
-        )
+        ) + BACKWARD_FORM
         if self.diffusion is not None:
             plan, n = self.diffusion, self.diffusion.n
             whole, boundary, skipped = self.tiles
@@ -428,13 +492,13 @@ def _diagonal_crosses(q_start, k_start, k_len):
     return q_start < k_start + k_len - 1
 
 
-def _cost(bh, sq, sk, d, dv, causal, byte_tensors, mask=None):
+def _cost(bh, sq, sk, d, dv, causal, byte_tensors, mask=None, matmuls=2):
     """pl.CostEstimate for one attention kernel, MODEL-FLOPs convention:
-    count the two algorithmically required matmuls of each kernel, one
-    that contracts or produces the q/k head size ``d`` and one the v
-    head size ``dv`` (fwd: QK over d, PV over dv; dq kernel: dP over
-    dv, dQ over d; dkv kernel: dK over d, dV over dv), and NOT the
-    in-kernel score recomputes (those are rematerialization — the same
+    count the algorithmically required ``matmuls`` of the kernel, half
+    of which contract or produce the q/k head size ``d`` and half the v
+    head size ``dv`` (fwd, 2: QK over d, PV over dv; the backward, 4:
+    dQ and dK over d, dP and dV over dv), and NOT the
+    in-kernel score recompute (that is rematerialization — the same
     convention under which benchlib.program_flops excludes
     jax.checkpoint recompute). Causal discounts by 1/2 (the exact useful fraction is
     (S+1)/2S; 1/2 is the conservative side, and ring chunks fully below
@@ -452,9 +516,9 @@ def _cost(bh, sq, sk, d, dv, causal, byte_tensors, mask=None):
         frac = mask.pairs / (sq * sk)
     else:
         frac = 0.5 if causal else 1.0
-    flops = int(2 * bh * sq * sk * (d + dv) * frac)
-    # One exp per score element per kernel (fwd online-softmax; each
-    # bwd kernel recomputes P once).
+    flops = int(matmuls * bh * sq * sk * (d + dv) * frac)
+    # One exp per score element per kernel (fwd online-softmax; the
+    # backward recomputes P once).
     transcendentals = int(bh * sq * sk * frac)
     nbytes = int(sum(
         count * bh * s * width * size
@@ -703,7 +767,7 @@ def _fwd_grid_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc,
 
 def _diffusion_steps(plan, row, step):
     """Which of a block-diffusion call's tiles grid step ``step`` of
-    tile row (forward, dq) or key column (dk/dv) ``row`` is, as traced
+    tile row (forward) or key column (backward) ``row`` is, as traced
     booleans: (the row lies in the noised half, its place in its half,
     whether the step is the noised tile of the same place, whether it
     is the clean one)."""
@@ -982,12 +1046,12 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, mask):
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, mask, res, g):
-    """Backward via the tiled Pallas kernels (flash_chunk_grads with the
+    """Backward via the tiled Pallas kernel (flash_chunk_grads with the
     whole sequence as one chunk). Profiled on v5e: the previous XLA
     blockwise-scan backward was ~22% of transformer step device time at
-    ~3.2x the Pallas forward's cost per call; the kernels (shared with
-    the ring path, gradient-verified there) keep score tiles in VMEM
-    and run both passes on the MXU."""
+    ~3.2x the Pallas forward's cost per call; the kernel (shared with
+    the ring path, gradient-verified there) keeps score tiles in VMEM
+    and runs its five products a tile on the MXU."""
     q, k, v, o, lse = res
     dof = g.astype(jnp.float32)
     delta = (dof * o.astype(jnp.float32)).sum(
@@ -1000,7 +1064,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, mask, res, g):
     )
     group = q.shape[0] // k.shape[0]
     if group > 1:
-        # The dk/dv kernel gives every query head's part (float32);
+        # The kernel gives every query head's part of dk, dv (float32);
         # a key/value head's gradient is the sum over its group.
         dk, dv = (
             x.reshape((k.shape[0], group) + x.shape[1:]).sum(axis=1)
@@ -1139,79 +1203,70 @@ def _bwd_tile_math(q, k_blk, v_blk, do, lse, delta, q_start, k_start,
     return ds, p
 
 
-def _dq_tile_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dq_acc, q_start, k_start, causal, scale):
-    """One whole K/V tile's part of dq, added to the scratch
-    accumulator."""
-    ds, _ = _bwd_tile_math(
-        q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-        delta_ref[0], q_start, k_start, causal, scale,
-    )
-    dq_acc[:] += jax.lax.dot_general(
-        ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+def _tile_rows(tile, block, first=0, rows=None):
+    """``rows`` rows (the tile's all, by default) from row ``first`` of
+    grid tile ``tile`` (a grid index, traced) in a (1, S, width) block
+    that holds the whole sequence in tiles of ``block`` rows."""
+    start = pl.multiple_of(tile * block + first, math.gcd(block, first))
+    return pl.ds(start, rows or block)
+
+
+def _bwd_products(ds, p, q, k_blk, do, scale):
+    """(dq, dk, dv) parts of one rectangle from its ``_bwd_tile_math``:
+    dS K, dS^T Q and P^T dO, in the input type with float32 sums."""
+    ds = ds.astype(q.dtype)
+    dq = jax.lax.dot_general(
+        ds, k_blk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
+    dk = jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    dv = jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return dq, dk, dv
 
 
-def _dkv_tile_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dk_acc, dv_acc, q_start, k_start, causal, scale):
-    """One whole q/do/lse/delta tile's part of dk and dv, added to the
-    scratch accumulators."""
+def _bwd_tile_update(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_acc, dv_acc, q_tile, q_start, k_start,
+                     causal, scale):
+    """One whole q/do/lse/delta tile against the resident K/V tile: S,
+    P, dP and dS once, their parts of dk and dv added to the scratch
+    accumulators and their part of dq to rows ``q_tile`` of the
+    resident dq row."""
     ds, p = _bwd_tile_math(
         q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
         delta_ref[0], q_start, k_start, causal, scale,
     )
-    dk_acc[:] += jax.lax.dot_general(
-        ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    dv_acc[:] += jax.lax.dot_general(
-        p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    dq, dk, dv = _bwd_products(ds, p, q_ref[0], k_ref[0], do_ref[0], scale)
+    dq_ref[0, _tile_rows(q_tile, q_ref.shape[1]), :] += dq
+    dk_acc[:] += dk
+    dv_acc[:] += dv
 
 
-def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-               delta_ref, dq_ref, dq_acc, *, block_k: int, causal: bool,
-               scale: float):
-    """Grid (bh, q-block, k-tile), k sequential: dq accumulates in
-    scratch while K/V tiles stream; flushed at the last tile."""
-    qi = pl.program_id(1)
-    kt = pl.program_id(2)
-    num_kt = pl.num_programs(2)
-    block_q = q_ref.shape[1]
-    q_start = qoff_ref[0] + qi * block_q
-    k_start = koff_ref[0] + kt * block_k
-
-    @pl.when(kt == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    def _compute():
-        _dq_tile_update(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc,
-            q_start, k_start, causal, scale,
-        )
-
-    if causal:
-        pl.when(q_start + block_q - 1 >= k_start)(_compute)
-    else:
-        _compute()
-
-    @pl.when(kt == num_kt - 1)
-    def _flush():
-        dq_ref[0] = dq_acc[:]
+def _zero_dq_tile(dq_ref, k_tile, q_tile, block_q):
+    """A b's dq row stays in VMEM across its (k tile, q tile) steps,
+    every live step adding its part; the first k tile's steps, live or
+    dead, zero the q tile they name first."""
+    @pl.when(k_tile == 0)
+    def _zero():
+        dq_ref[0, _tile_rows(q_tile, block_q), :] = jnp.zeros(
+            (block_q, dq_ref.shape[2]), dq_ref.dtype)
 
 
-def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                block_q: int, causal: bool, scale: float):
-    """Grid (bh, k-block, q-tile), q sequential: dK/dV accumulate in
-    scratch while Q/dO/lse/Δ tiles stream; flushed at the last tile."""
+def _bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                causal: bool, scale: float):
+    """Grid (bh, k-block, q-tile), both sequential: dK/dV accumulate in
+    scratch while Q/dO/lse/delta tiles stream, flushed at the last
+    tile; dq accumulates in its output block, the whole (1, Sq, d) row
+    of the b, written back when b moves on."""
     ki = pl.program_id(1)
     qt = pl.program_id(2)
-    num_qt = pl.num_programs(2)
-    block_k = k_ref.shape[1]
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     q_start = qoff_ref[0] + qt * block_q
     k_start = koff_ref[0] + ki * block_k
 
@@ -1220,10 +1275,12 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    _zero_dq_tile(dq_ref, ki, qt, block_q)
+
     def _compute():
-        _dkv_tile_update(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
-            dv_acc, q_start, k_start, causal, scale,
+        _bwd_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+            dk_acc, dv_acc, qt, q_start, k_start, causal, scale,
         )
 
     if causal:
@@ -1231,7 +1288,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     else:
         _compute()
 
-    @pl.when(qt == num_qt - 1)
+    @pl.when(qt == pl.num_programs(2) - 1)
     def _flush():
         dk_ref[0] = dk_acc[:]
         dv_ref[0] = dv_acc[:]
@@ -1244,60 +1301,27 @@ def _strip_of(ref, strip):
         strip, slice(None))
 
 
-def _dq_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, *, rows, sub, q_offset, k_offset, scale,
-                      dq_acc=None, starts=None, rule=True, final=True):
-    """dq over one grid tile walked by ``_walk``: q strip ``i`` against
-    the keys its row of the plan reaches; a strip wholly above the
-    diagonal gets zeros. Each strip is written once, so no scratch of
-    its own; ``dq_acc`` is what the tiles to the left have summed
-    (``_dq_grid_kernel``: the diagonal tile is the last that
-    contributes, so the strip is flushed with its own part added).
-    ``starts``, ``rule``, ``final``: as ``_fwd_strips_kernel``'s, for a
-    boundary tile of a block-diffusion call; not ``final``, the strip's
-    part is added to ``dq_acc`` and nothing is flushed."""
-    for i, n_k in enumerate(rows):
-        first = starts[i] if starts else 0
-        strip = pl.ds(i * sub, sub)
-        keys = pl.ds(first * sub, (n_k - first) * sub)
-        if n_k == first:
-            if final:
-                dq_ref[0, strip, :] = (
-                    jnp.zeros((sub, dq_ref.shape[2]), dq_ref.dtype)
-                    if dq_acc is None else dq_acc[strip, :])
-            continue
-        k_blk = k_ref[0, keys, :]
-        q_start, k_start = q_offset + i * sub, k_offset + first * sub
-        ds, _ = _bwd_tile_math(
-            q_ref[0, strip, :], k_blk, v_ref[0, keys, :],
-            do_ref[0, strip, :], lse_ref[0, strip, :],
-            delta_ref[0, strip, :], q_start, k_start,
-            _holds_masked(rule, q_start, sub, k_start, (n_k - first) * sub),
-            scale,
-        )
-        dq = jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if not final:
-            dq_acc[strip, :] += dq
-            continue
-        dq_ref[0, strip, :] = dq if dq_acc is None else dq_acc[strip, :] + dq
-
-
-def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, *, rows, sub, q_offset, k_offset,
-                       scale, starts=None, rule=True, add=False):
-    """dk/dv over one grid tile walked by ``_walk``: k strip ``j``
-    against the queries from the first row whose plan reaches it to the
-    tile's end; a strip no query sees gets zeros. ``dk_ref, dv_ref``:
-    the output blocks, or the scratch accumulators of
-    ``_dkv_grid_kernel`` (the diagonal tile is the first that
-    contributes to its keys, so it sets them). ``starts``, ``rule``: as
-    ``_fwd_strips_kernel``'s, for a boundary tile of a block-diffusion
-    call (the rows that reach a strip are consecutive under every
-    rule); ``add``: the strips' parts are added to the accumulators (a
-    clean tile's keys were seen by noised tiles before it)."""
+def _bwd_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, *, rows, sub, q_offset,
+                       k_offset, scale, starts=None, rule=True, add=False,
+                       q_tile=None):
+    """The backward over one grid tile walked by ``_walk``: k strip
+    ``j`` against the queries from the first row whose plan reaches it
+    to the tile's end, S, P, dP and dS once a strip; its dk and dv are
+    the strip's own, its dq part is added to those queries' rows (a q
+    strip's dq is summed k strip by k strip). A k strip no query sees
+    gets zeros. Without ``q_tile`` the tile is the whole call
+    (``_strips_grads``): dq's rows are the tile's, zeroed here first.
+    With it, the tile is grid tile ``q_tile`` of the resident dq row and
+    ``dk_ref, dv_ref`` the scratch accumulators of ``_bwd_grid_kernel``
+    (the diagonal tile is the first that contributes to its keys, so it
+    sets them). ``starts``, ``rule``: as ``_fwd_strips_kernel``'s, for
+    a boundary tile of a block-diffusion call (the rows that reach a
+    strip are consecutive under every rule); ``add``: the strips' parts
+    are added to the accumulators (a clean tile's keys were seen by
+    noised tiles before it)."""
+    if q_tile is None:
+        dq_ref[0] = jnp.zeros(dq_ref.shape[1:], dq_ref.dtype)
     for j in range(k_ref.shape[1] // sub):
         strip = pl.ds(j * sub, sub)
         dk_strip, dv_strip = _strip_of(dk_ref, strip), _strip_of(dv_ref, strip)
@@ -1313,85 +1337,51 @@ def _dkv_strips_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         first, q_len = reach[0], (reach[-1] + 1 - reach[0]) * sub
         queries = pl.ds(first * sub, q_len)
         q, do = q_ref[0, queries, :], do_ref[0, queries, :]
+        k_blk = k_ref[0, strip, :]
         q_start, k_start = q_offset + first * sub, k_offset + j * sub
         ds, p = _bwd_tile_math(
-            q, k_ref[0, strip, :], v_ref[0, strip, :], do,
-            lse_ref[0, queries, :], delta_ref[0, queries, :], q_start,
-            k_start, _holds_masked(rule, q_start, q_len, k_start, sub),
-            scale,
+            q, k_blk, v_ref[0, strip, :], do, lse_ref[0, queries, :],
+            delta_ref[0, queries, :], q_start, k_start,
+            _holds_masked(rule, q_start, q_len, k_start, sub), scale,
         )
-        for ref, at, part in (
-            (dk_ref, dk_strip, lambda: jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale),
-            (dv_ref, dv_strip, lambda: jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )),
-        ):
-            if add:
-                ref[at] += part()
-            else:
-                ref[at] = part()
+        dq, dk, dv = _bwd_products(ds, p, q, k_blk, do, scale)
+        dq_rows = queries if q_tile is None else _tile_rows(
+            q_tile, q_ref.shape[1], first * sub, q_len)
+        dq_ref[0, dq_rows, :] += dq
+        if add:
+            dk_ref[dk_strip] += dk
+            dv_ref[dv_strip] += dv
+        else:
+            dk_ref[dk_strip] = dk
+            dv_ref[dv_strip] = dv
 
 
-def _dq_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, dq_ref, dq_acc, *, rows, sub,
-                    scale):
-    """``_dq_kernel`` where the diagonal tiles are walked (``_walk``
-    over several tiles; the offsets are equal and do not enter): a k
-    tile below the diagonal adds its part with no mask built, the
-    diagonal tile's strips add theirs and flush, the tiles above are
-    dead steps."""
-    del qoff_ref, koff_ref
-    qi = pl.program_id(1)
-    kt = pl.program_id(2)
-
-    @pl.when(kt == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    @pl.when(kt < qi)
-    def _below():
-        _dq_tile_update(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, 0, 0,
-            False, scale,
-        )
-
-    @pl.when(kt == qi)
-    def _diagonal():
-        _dq_strips_kernel(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-            rows=rows, sub=sub, q_offset=0, k_offset=0, scale=scale,
-            dq_acc=dq_acc,
-        )
-
-
-def _dkv_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                     *, rows, sub, scale):
-    """``_dkv_kernel`` where the diagonal tiles are walked: the q tiles
-    before the diagonal are dead steps, the diagonal tile's strips set
-    the accumulators, a q tile below the diagonal adds its part with no
-    mask built."""
+def _bwd_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                     lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_acc,
+                     dv_acc, *, rows, sub, scale):
+    """``_bwd_kernel`` where the diagonal tiles are walked (``_walk``
+    over several tiles; the offsets are equal and do not enter): the q
+    tiles before the diagonal are dead steps, the diagonal tile's strips
+    set the dk/dv accumulators, a q tile below the diagonal adds its
+    part with no mask built; every live step adds its part of dq."""
     del qoff_ref, koff_ref
     ki = pl.program_id(1)
     qt = pl.program_id(2)
+    _zero_dq_tile(dq_ref, ki, qt, q_ref.shape[1])
 
     @pl.when(qt == ki)
     def _diagonal():
-        _dkv_strips_kernel(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
-            dv_acc, rows=rows, sub=sub, q_offset=0, k_offset=0,
-            scale=scale,
+        _bwd_strips_kernel(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+            dk_acc, dv_acc, rows=rows, sub=sub, q_offset=0, k_offset=0,
+            scale=scale, q_tile=qt,
         )
 
     @pl.when(qt > ki)
     def _below():
-        _dkv_tile_update(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
-            dv_acc, 0, 0, False, scale,
+        _bwd_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+            dk_acc, dv_acc, qt, 0, 0, False, scale,
         )
 
     @pl.when(qt == pl.num_programs(2) - 1)
@@ -1400,64 +1390,31 @@ def _dkv_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_acc[:]
 
 
-def _dq_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                         lse_ref, delta_ref, dq_ref, dq_acc, *, plan,
-                         scale):
-    """``_dq_kernel`` under a :class:`BlockDiffusion` mask: the steps of
-    ``_fwd_diffusion_kernel``, each adding its part of dq; the clean
-    tile of the row's place is the last that contributes and flushes."""
-    del qoff_ref, koff_ref
-    qi = pl.program_id(1)
-    kt = pl.program_id(2)
-    noised, place, on_noised, on_clean = _diffusion_steps(plan, qi, kt)
-
-    def strips(walk, final):
-        _dq_strips_kernel(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-            rows=walk.rows, sub=plan.sub, q_offset=0, k_offset=0,
-            scale=scale, dq_acc=dq_acc, starts=walk.starts, rule=walk.rule,
-            final=final,
-        )
-
-    @pl.when(kt == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    pl.when(noised & on_noised)(lambda: strips(plan.own, False))
-
-    @pl.when((kt >= plan.n) & (kt - plan.n < place))
-    def _whole():
-        _dq_tile_update(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, 0, 0,
-            False, scale,
-        )
-
-    pl.when(noised & on_clean)(lambda: strips(plan.before, True))
-    pl.when(jnp.logical_not(noised) & on_clean)(
-        lambda: strips(plan.clean, True))
-
-
-def _dkv_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                          lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
-                          dv_acc, *, plan, scale):
-    """``_dkv_kernel`` under a :class:`BlockDiffusion` mask. A noised
+def _bwd_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                          dk_acc, dv_acc, *, plan, scale):
+    """``_bwd_kernel`` under a :class:`BlockDiffusion` mask. A noised
     key tile is seen by the noised q tile of its place alone (``own``
     sets the accumulators; every other step is dead). Clean key tile c
     is seen by noised q tile c under ``before`` (the first: it sets the
     accumulators), the noised tiles after it whole, clean q tile c under
-    ``clean`` (added) and the clean tiles after it whole."""
+    ``clean`` (added) and the clean tiles after it whole. At a live
+    step the q tile is the step's own (``plan.q_tile``), and its part of
+    dq goes to that tile's rows."""
     del qoff_ref, koff_ref
     ki = pl.program_id(1)
     qt = pl.program_id(2)
     n = plan.n
     noised, place, on_noised, on_clean = _diffusion_steps(plan, ki, qt)
     clean = jnp.logical_not(noised)
+    _zero_dq_tile(dq_ref, ki, qt, q_ref.shape[1])
 
     def strips(walk, add):
-        _dkv_strips_kernel(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
-            dv_acc, rows=walk.rows, sub=plan.sub, q_offset=0, k_offset=0,
-            scale=scale, starts=walk.starts, rule=walk.rule, add=add,
+        _bwd_strips_kernel(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+            dk_acc, dv_acc, rows=walk.rows, sub=plan.sub, q_offset=0,
+            k_offset=0, scale=scale, starts=walk.starts, rule=walk.rule,
+            add=add, q_tile=qt,
         )
 
     pl.when(noised & on_noised)(lambda: strips(plan.own, False))
@@ -1465,9 +1422,9 @@ def _dkv_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(clean & (((qt > place) & (qt < n)) | (qt > ki)))
     def _whole():
-        _dkv_tile_update(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
-            dv_acc, 0, 0, False, scale,
+        _bwd_tile_update(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+            dk_acc, dv_acc, qt, 0, 0, False, scale,
         )
 
     pl.when(clean & on_clean)(lambda: strips(plan.clean, True))
@@ -1478,66 +1435,62 @@ def _dkv_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_acc[:]
 
 
-def _grads_costs(q, v_chunk, causal, mask=None):
-    """(dq kernel's, dk/dv kernel's) ``pl.CostEstimate``: both read q,
-    k (width d) and v, do (width dv)."""
+def _grads_outputs(q, v_chunk, causal, mask=None):
+    """(``out_shape``, ``cost_estimate``) of the backward
+    ``pallas_call``: dq, dk, dv in float32, dk and dv with q's rows; the
+    four required matmuls (dP and dV over dv, dQ and dK over d); q, k, v
+    and do read once."""
     bh, sq, d = q.shape
     sk, dv = v_chunk.shape[1:]
     size = q.dtype.itemsize
     kv = _kv_share(q, v_chunk)
-    reads = [(1, sq, d, size), (1, sq, dv, size),
-             (kv, sk, d, size), (kv, sk, dv, size)]
-    return tuple(
-        _cost(bh, sq, sk, d, dv, causal=causal,
-              byte_tensors=reads + written, mask=mask)
-        for written in ([(1, sq, d, 4)], [(1, sk, d, 4), (1, sk, dv, 4)])
+    out_shape = [
+        jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
+        jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
+        jax.ShapeDtypeStruct((bh, sk, dv), jnp.float32),
+    ]
+    cost = _cost(
+        bh, sq, sk, d, dv, causal=causal, mask=mask, matmuls=4,
+        byte_tensors=[(1, sq, d, size), (1, sq, dv, size),
+                      (kv, sk, d, size), (kv, sk, dv, size),
+                      (1, sq, d, 4), (1, sk, d, 4), (1, sk, dv, 4)],
     )
+    return out_shape, cost
 
 
 @_shared_trace(6, 7, 8, 9, 10)
 def _strips_grads(q, k_chunk, v_chunk, do, lse, delta, rows, q_offset,
                   k_offset, scale, interpret):
-    """``flash_chunk_grads`` where ``_walk`` has a plan: both kernels
-    take one (batch*head) a grid step and the whole tile as one block."""
+    """``flash_chunk_grads`` where ``_walk`` has a plan of one tile: one
+    (batch*head) a grid step and the whole tile as one block."""
     bh, sq, d = q.shape
     sk, dv = v_chunk.shape[1:]
-    dq_cost, dkv_cost = _grads_costs(q, v_chunk, True)
+    out_shape, cost = _grads_outputs(q, v_chunk, True)
     kv_row = _kv_row(q, k_chunk)
     q_rows = pl.BlockSpec((1, sq, d), lambda b: (b, 0, 0))
-    k_rows = pl.BlockSpec((1, sk, d), lambda b: (b, 0, 0))
     do_rows = pl.BlockSpec((1, sq, dv), lambda b: (b, 0, 0))
-    v_rows = pl.BlockSpec((1, sk, dv), lambda b: (b, 0, 0))
     q_col = pl.BlockSpec((1, sq, 1), lambda b: (b, 0, 0))
-    in_specs = [
-        q_rows,
-        pl.BlockSpec((1, sk, d), lambda b: (kv_row(b), 0, 0)),
-        pl.BlockSpec((1, sk, dv), lambda b: (kv_row(b), 0, 0)),
-        do_rows, q_col, q_col,
-    ]
-    common = dict(
-        rows=rows, sub=sq // len(rows), q_offset=q_offset,
-        k_offset=k_offset, scale=scale,
-    )
-    params = _compiler_params(("parallel",), d, dv)
-    operands = (q, k_chunk, v_chunk, do, lse, delta)
-    dq = pl.pallas_call(
-        functools.partial(_dq_strips_kernel, **common),
-        grid=(bh,), in_specs=in_specs, out_specs=q_rows,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-        compiler_params=params, cost_estimate=dq_cost,
-        interpret=interpret,
-    )(*operands)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_strips_kernel, **common),
-        grid=(bh,), in_specs=in_specs, out_specs=[k_rows, v_rows],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sk, dv), jnp.float32),
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_strips_kernel, rows=rows, sub=sq // len(rows),
+            q_offset=q_offset, k_offset=k_offset, scale=scale),
+        grid=(bh,),
+        in_specs=[
+            q_rows,
+            pl.BlockSpec((1, sk, d), lambda b: (kv_row(b), 0, 0)),
+            pl.BlockSpec((1, sk, dv), lambda b: (kv_row(b), 0, 0)),
+            do_rows, q_col, q_col,
         ],
-        compiler_params=params, cost_estimate=dkv_cost,
+        out_specs=[
+            q_rows,
+            pl.BlockSpec((1, sk, d), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, sk, dv), lambda b: (b, 0, 0)),
+        ],
+        out_shape=out_shape,
+        compiler_params=_compiler_params(("parallel",), d, dv),
+        cost_estimate=cost,
         interpret=interpret,
-    )(*operands)
-    return dq, dk, dv
+    )(q, k_chunk, v_chunk, do, lse, delta)
 
 
 @_shared_trace(8, 9, 10, 11, 12, 13, 14, 15)
@@ -1549,79 +1502,41 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
     the diagonal predicated out. ``rows`` the plan of ``_walk`` over
     several tiles: the diagonal tiles walked, dead steps naming the
     diagonal's tiles. Under a ``mask``, ``plan`` is its
-    ``_BlockDiffusionPlan``: that mask's kernels and index maps."""
+    ``_BlockDiffusionPlan``: that mask's kernel and index map."""
     if plan is not None:
-        common = dict(plan=plan, scale=scale)
-        dq_kernel = functools.partial(_dq_diffusion_kernel, **common)
-        dkv_kernel = functools.partial(_dkv_diffusion_kernel, **common)
-        kv_tile, q_tile = plan.kv_tile, plan.q_tile
+        kernel = functools.partial(
+            _bwd_diffusion_kernel, plan=plan, scale=scale)
+        q_tile = plan.q_tile
     elif rows is None:
-        common = dict(causal=causal, scale=scale)
-        dq_kernel = functools.partial(_dq_kernel, block_k=block_k, **common)
-        dkv_kernel = functools.partial(_dkv_kernel, block_q=block_q,
-                                       **common)
-        kv_tile = q_tile = _streamed
+        kernel = functools.partial(_bwd_kernel, causal=causal, scale=scale)
+        q_tile = _streamed
     else:
-        common = dict(rows=rows, sub=block_q // len(rows), scale=scale)
-        dq_kernel = functools.partial(_dq_grid_kernel, **common)
-        dkv_kernel = functools.partial(_dkv_grid_kernel, **common)
-        kv_tile, q_tile = jnp.minimum, jnp.maximum
+        kernel = functools.partial(
+            _bwd_grid_kernel, rows=rows, sub=block_q // len(rows),
+            scale=scale)
+        q_tile = jnp.maximum
     return _grid_grads(
-        dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk, v_chunk, do,
-        lse, delta, q_offset, k_offset, block_q, block_k, causal,
-        interpret, mask,
+        kernel, q_tile, q, k_chunk, v_chunk, do, lse, delta, q_offset,
+        k_offset, block_q, block_k, causal, interpret, mask,
     )
 
 
-def _grid_grads(dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk,
-                v_chunk, do, lse, delta, q_offset, k_offset, block_q,
-                block_k, causal, interpret, mask=None):
-    """The two backward ``pallas_call``s over the grid of tiles:
-    ``kv_tile(i, j)`` is the K/V tile that step j of q block i names in
-    the dq kernel, ``q_tile(i, j)`` the q/do/lse/delta tile that step j
-    of k block i names in the dk/dv kernel."""
+def _grid_grads(kernel, q_tile, q, k_chunk, v_chunk, do, lse, delta,
+                q_offset, k_offset, block_q, block_k, causal, interpret,
+                mask=None):
+    """The backward ``pallas_call`` over the grid of tiles (bh, k block,
+    q tile): ``q_tile(i, j)`` is the q/do/lse/delta tile that step j of
+    k block i names. dk and dv leave a block at its last step; dq's
+    block is the whole row of a b, resident across that b's steps, so
+    the k-block axis is sequential too."""
     bh, sq, d = q.shape
     sk, dv = v_chunk.shape[1:]
-    dq_cost, dkv_cost = _grads_costs(q, v_chunk, causal, mask)
+    out_shape, cost = _grads_outputs(q, v_chunk, causal, mask)
     kv_row = _kv_row(q, k_chunk)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape((1,))
     koff = jnp.asarray(k_offset, jnp.int32).reshape((1,))
-
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(bh, sq // block_q, sk // block_k),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d),
-                             lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec(
-                    (1, block_k, d),
-                    lambda b, i, j, *_: (kv_row(b), kv_tile(i, j), 0)),
-                pl.BlockSpec(
-                    (1, block_k, dv),
-                    lambda b, i, j, *_: (kv_row(b), kv_tile(i, j), 0)),
-                pl.BlockSpec((1, block_q, dv),
-                             lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1),
-                             lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1),
-                             lambda b, i, j, *_: (b, i, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, d),
-                                   lambda b, i, j, *_: (b, i, 0)),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary"), d, dv
-        ),
-        cost_estimate=dq_cost,
-        interpret=interpret,
-    )(qoff, koff, q, k_chunk, v_chunk, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    return pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, sk // block_k, sq // block_q),
@@ -1640,6 +1555,7 @@ def _grid_grads(dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk,
                              lambda b, i, j, *_: (b, q_tile(i, j), 0)),
             ],
             out_specs=[
+                pl.BlockSpec((1, sq, d), lambda b, i, j, *_: (b, 0, 0)),
                 pl.BlockSpec((1, block_k, d),
                              lambda b, i, j, *_: (b, i, 0)),
                 pl.BlockSpec((1, block_k, dv),
@@ -1650,17 +1566,15 @@ def _grid_grads(dq_kernel, dkv_kernel, kv_tile, q_tile, q, k_chunk,
                 pltpu.VMEM((block_k, dv), jnp.float32),
             ],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sk, dv), jnp.float32),
-        ],
+        out_shape=out_shape,
         compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary"), d, dv
+            ("parallel", "arbitrary", "arbitrary"), d, dv,
+            vmem_bytes=_grads_vmem_bytes(
+                sq, block_q, block_k, d, dv, q.dtype.itemsize),
         ),
-        cost_estimate=dkv_cost,
+        cost_estimate=cost,
         interpret=interpret,
     )(qoff, koff, q, k_chunk, v_chunk, do, lse, delta)
-    return dq, dk, dv
 
 
 def flash_chunk_grads(
@@ -1677,10 +1591,15 @@ def flash_chunk_grads(
     the ring accumulates dq over chunks and rotates dk/dv home. k and v
     may have fewer rows than q, B*Hkv (``_kv_row``): dk and dv still
     come back with q's BH rows, every query head's part, for the caller
-    to sum over a group. Two
-    kernels (dq: k-sequential; dk/dv: q-sequential) so each output has
-    exactly one sequential accumulation dim; score tiles never leave
-    VMEM.
+    to sum over a group. ONE
+    kernel: S, P, dP and dS are computed once a live tile and feed dq,
+    dk and dv together (5 products a tile); dk/dv accumulate over the
+    q tiles of a k block in scratch, dq over the k blocks in its output
+    block, a b's whole (Sq, D) float32 row resident in VMEM; score
+    tiles never leave VMEM. Where that row would pass
+    ``RESIDENT_DQ_BYTES`` (no model here runs such a length) the
+    queries are cut into runs of whole blocks that fit, one kernel a
+    run over whole tiles, dk and dv summed over the runs.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -1691,6 +1610,22 @@ def flash_chunk_grads(
             f"flash_chunk_grads: shapes (Sq={sq}, Sk={sk}) must tile by "
             f"blocks ({block_q}, {block_k})"
         )
+    run = _resident_rows(q.shape[2], block_q)
+    if sq > run:
+        if mask is not None:
+            raise ValueError(
+                f"flash_chunk_grads: {mask} over {sq} positions has no "
+                "kernel plan (dq's row is not resident); gate callers "
+                "with supports()")
+        parts = [
+            flash_chunk_grads(
+                q[:, at:at + run], k_chunk, v_chunk, do[:, at:at + run],
+                lse[:, at:at + run], delta[:, at:at + run], q_offset + at,
+                k_offset, causal, scale, block_q, block_k, interpret)
+            for at in range(0, sq, run)
+        ]
+        dq, dk, dv = zip(*parts)
+        return jnp.concatenate(dq, axis=1), sum(dk), sum(dv)
     # Under a mask: the whole sequence against itself (``_flash_bwd``).
     rows = None if mask is not None else _walk(
         causal, sq, sk, block_q, block_k, q_offset, k_offset)
@@ -1752,7 +1687,8 @@ def supports(q_shape, block_q: int = 0, block_k: int = 0,
     (``_auto_block``); explicit blocks keep the raw divisibility rule
     (tests drive small interpret-mode tiles). Under a ``mask`` the
     blocks tile a half, and the kernels need a plan for it
-    (``_block_diffusion_plan``)."""
+    (``_block_diffusion_plan``) and the row's dq resident in the
+    backward."""
     s_len = q_shape[1]
     if s_len % 8:
         return False
@@ -1761,9 +1697,11 @@ def supports(q_shape, block_q: int = 0, block_k: int = 0,
                 _auto_block(mask.half, DEFAULT_BLOCK_Q)
                 and _auto_block(mask.half, DEFAULT_BLOCK_K)):
             return False
-        return _block_diffusion_plan(
-            mask, s_len, *_blocks(mask.half, mask.half, block_q, block_k)
-        ) is not None
+        block_q, block_k = _blocks(mask.half, mask.half, block_q, block_k)
+        # The backward keeps the whole row's dq resident, and a masked
+        # call cannot be cut into runs (``flash_chunk_grads``).
+        return s_len <= _resident_rows(q_shape[3], block_q) and (
+            _block_diffusion_plan(mask, s_len, block_q, block_k) is not None)
     if not block_q and not block_k:
         return (
             _auto_block(s_len, DEFAULT_BLOCK_Q) > 0
@@ -1793,7 +1731,7 @@ def flash_attention(
 
     ``mask``: a :class:`BlockDiffusion` over S = 2 x half in place of
     ``causal`` (which is then not read): the same custom VJP, the same
-    three kernels' tile mathematics, one call over the S x S grid whose
+    kernels' tile mathematics, one call over the S x S grid whose
     dead tiles cost a predicated-out step each and fetch nothing
     (``_BlockDiffusionPlan``). The blocks tile a half.
 

@@ -200,9 +200,10 @@ def _ring_local_pallas_fwd(q, k, v, axis_name: str, causal: bool,
 
 def _ring_local_bwd_pallas(q, k, v, o, lse, do, axis_name: str,
                            causal: bool, scale, interpret: bool):
-    """Fused ring backward with the per-chunk Pallas kernels
-    (flash_chunk_grads): score tiles never leave VMEM. Same rotation
-    schedule as the jnp version."""
+    """Fused ring backward with the per-chunk Pallas kernel
+    (flash_chunk_grads: ONE kernel a chunk pairing gives dq, dk and dv;
+    dq's parts are summed over the chunks here): score tiles never
+    leave VMEM. Same rotation schedule as the jnp version."""
     from elasticdl_tpu.ops.flash_attention import flash_chunk_grads
 
     n = jax.lax.axis_size(axis_name)

@@ -148,7 +148,7 @@ def test_plan_counts(block, sub, computed, total):
     assert (plan.sub_q, plan.sub_k) == (sub, sub)
     assert plan.describe() == (
         f"blocks {block}x{block}, sub-tiles {sub}x{sub}, "
-        f"{computed} of {total} computed"
+        f"{computed} of {total} computed" + flash.BACKWARD_FORM
     )
 
 
@@ -181,7 +181,8 @@ def test_plan_keeps_the_whole_tile(why, kwargs):
     assert plan.tiles == (plan.grid[0] * plan.grid[1], 0, 0), why
     assert plan.describe() == (
         f"blocks {plan.block_q}x{plan.block_k}, sub-tiles "
-        f"{plan.block_q}x{plan.block_k}, 1 of 1 computed")
+        f"{plan.block_q}x{plan.block_k}, 1 of 1 computed"
+        + flash.BACKWARD_FORM)
 
 
 @pytest.mark.parametrize(
@@ -217,7 +218,8 @@ def test_plan_walks_the_diagonal_tiles_of_a_grid(s, kwargs, grid, block,
     assert plan.rows == flash.tile_plan(block, block, sub=sub).rows
     assert (plan.computed, plan.total) == (computed, total)
     assert plan.tiles == tiles and sum(tiles) == grid * grid
-    assert plan.describe() == line
+    assert plan.describe() == line + flash.BACKWARD_FORM
+    assert flash.BACKWARD_FORM == "; backward: one kernel, 5 products a tile"
 
 
 # (q_offset, k_offset) of a 64-long q block against a 64-long chunk,
@@ -291,11 +293,11 @@ def test_strips_match_dense_and_whole_tile_at_real_blocks(monkeypatch, s, d):
     """The default blocks at S = 512 and 1,024, heads of 64 and 128:
     forward, dq, dk, dv against the dense reference, and against the
     whole-tile kernels (the walk switched off by a sub-tile as large as
-    the block). o and dq bit for bit. dk and dv contract over the
-    queries, fewer of them in a strip than in the tile, and the CPU
-    backend's dot blocks that sum by its length: the last bits move at
-    D = 64 here (on the chip they do not: tests/test_tpu_kernels.py
-    holds all four to the bit)."""
+    the block). o bit for bit. A q strip's dq is summed k strip by k
+    strip since the backward is one kernel, so its last bits move; dk
+    and dv contract over the queries, fewer of them in a strip than in
+    the tile, and the CPU backend's dot blocks that sum by its length:
+    the last bits move at D = 64 here."""
     rng = np.random.RandomState(s + d)
     q, k, v = (jnp.asarray(rng.randn(1, s, 2, d), jnp.float32) * 0.3
                for _ in range(3))
@@ -316,7 +318,7 @@ def test_strips_match_dense_and_whole_tile_at_real_blocks(monkeypatch, s, d):
         got, same, want = (np.asarray(x) for x in (got, same, want))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5,
                                    err_msg=f"{name} against dense")
-        if name in ("o", "dq"):
+        if name == "o":
             np.testing.assert_array_equal(got, same, err_msg=name)
         else:
             np.testing.assert_allclose(
@@ -427,7 +429,7 @@ def _count_traces(monkeypatch, *names):
     return counts
 
 
-GRID_KERNELS = ("_fwd_grid_kernel", "_dq_grid_kernel", "_dkv_grid_kernel")
+GRID_KERNELS = ("_fwd_grid_kernel", "_bwd_grid_kernel")
 
 
 @pytest.mark.parametrize(
@@ -475,8 +477,7 @@ def test_chunk_grads_walk_a_grid_only_on_equal_int_offsets(monkeypatch, why,
     tiles, and those are walked; traced or unequal offsets keep whole
     tiles under the traced compare. One answer either way."""
     monkeypatch.setattr(flash, "SUB_TILE", 12)
-    counts = _count_traces(monkeypatch, "_dq_grid_kernel",
-                           "_dkv_grid_kernel")
+    counts = _count_traces(monkeypatch, "_bwd_grid_kernel")
     ops = _chunk_operands(bh=2, n=96, d=20, seed=23)
     plan = flash.tile_plan(96, 96, block_q=48, block_k=48,
                            q_offset=offsets[0], k_offset=offsets[1])
@@ -484,9 +485,7 @@ def test_chunk_grads_walk_a_grid_only_on_equal_int_offsets(monkeypatch, why,
     got = flash.flash_chunk_grads(
         *ops, *offsets, causal=True, block_q=48, block_k=48,
         interpret=True)
-    assert counts == (
-        {"_dq_grid_kernel": 1, "_dkv_grid_kernel": 1} if walked else {}
-    ), why
+    assert counts == ({"_bwd_grid_kernel": 1} if walked else {}), why
     # The same pairing with the walk switched off.
     monkeypatch.setattr(flash, "SUB_TILE", 48)
     want = flash.flash_chunk_grads(
@@ -548,13 +547,13 @@ def test_log_line_states_the_sub_tiles(q_shape, kwargs, tail):
         flash.log_traced.cache_clear()
     assert records == [
         f"attention: traced pallas flash kernel for q{q_shape}: why; {tail}"
+        "; backward: one kernel, 5 products a tile"
     ]
 
 
 @pytest.mark.parametrize(
     "s,block,kernels",
-    [(80, 80, ("_fwd_strips_kernel", "_dq_strips_kernel",
-               "_dkv_strips_kernel")),
+    [(80, 80, ("_fwd_strips_kernel", "_bwd_strips_kernel")),
      (96, 32, GRID_KERNELS)],
     ids=["one_tile", "grid_of_tiles"],
 )
@@ -587,6 +586,184 @@ def test_layers_share_one_trace_of_each_kernel(monkeypatch, s, block,
     assert counts == {name: 1 for name in kernels}
 
 
+# --- the backward as one kernel (PR 35) -----------------------------------
+
+ONE_KERNEL_CASES = {
+    # id: (s, block, sub, heads, key/value heads, d, dv, causal, mask)
+    "one_walked_tile_d64": (64, 64, 16, 2, 2, 64, 64, True, None),
+    "grid_2x2_qk192_v128": (64, 32, 8, 2, 2, 192, 128, True, None),
+    "group_of_8": (64, 32, 8, 8, 1, 16, 16, True, None),
+    "block_diffusion_2x2": (64, 16, 8, 2, 2, 16, 16, False,
+                            flash.BlockDiffusion(32, 4)),
+    "non_causal": (64, 32, 8, 2, 2, 16, 16, False, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_KERNEL_CASES))
+def test_one_backward_kernel_matches_dense(monkeypatch, case):
+    """S, P, dP and dS once a tile, feeding dq, dk and dv together: the
+    three gradients of every form of the one backward kernel against
+    dense attention's in float32: a single walked tile, a causal grid
+    with two head sizes, a group of query heads on one key/value head, a
+    block-diffusion grid (halves of 32 in tiles of 16: 4 x 4 tiles, each
+    half 2 x 2), whole tiles without a mask."""
+    s, block, sub, h, hkv, d, dv, causal, mask = ONE_KERNEL_CASES[case]
+    monkeypatch.setattr(flash, "SUB_TILE", sub)
+    rng = np.random.RandomState(len(case))
+    mk = lambda heads, width: jnp.asarray(  # noqa: E731
+        rng.randn(2, s, heads, width), jnp.float32) * 0.4
+    q, k, v, weight = mk(h, d), mk(hkv, d), mk(hkv, dv), mk(h, dv)
+
+    def kernels(q, k, v):
+        return jnp.sum(weight * flash_attention(
+            q, k, v, causal=causal, block_q=block, block_k=block,
+            interpret=True, mask=mask))
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
+        out = (dense_attention(q, k, v, mask=mask) if mask is not None
+               else dense_attention(q, k, v, causal=causal))
+        return jnp.sum(weight * out)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(kernels, (0, 1, 2))(q, k, v)
+        want = jax.grad(dense, (0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        assert a.shape == b.shape and a.dtype == jnp.float32
+        np.testing.assert_allclose(
+            a, b, rtol=0, atol=2e-5 * float(np.abs(b).max()) + 1e-6,
+            err_msg=name)
+
+
+def test_one_backward_kernel_on_a_chunk_pair_as_the_ring_sends_it():
+    """The ring's backward step: the local queries (the second half of
+    the sequence) against each K/V chunk under traced offsets, dq
+    summed over the chunks by the caller, dk and dv a chunk each: one
+    ``pallas_call`` a pairing, and the sums are dense attention's
+    gradients."""
+    n, d = 32, 16
+    rng = np.random.RandomState(35)
+    mk = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.randn(*shape), jnp.float32) * 0.4
+    q, k, v, do = (mk(1, 2 * n, 3, d) for _ in range(4))
+    do = do.at[:, :n].set(0.0)    # the first half's queries are remote
+    scale = d ** -0.5
+    to_bh = lambda x: x.transpose(0, 2, 1, 3).reshape(3, -1, d)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(
+            lambda q, k, v: dense_attention(q, k, v, causal=True), q, k, v)
+        want = [to_bh(x) for x in vjp(do)]
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        scores = jnp.where(jnp.tril(jnp.ones((2 * n, 2 * n), bool)),
+                           scores, -jnp.inf)
+        lse = jax.nn.logsumexp(scores, axis=-1).reshape(3, 2 * n, 1)
+        delta = to_bh(do * out).sum(axis=-1, keepdims=True)
+        local = lambda x: to_bh(x)[:, n:]   # noqa: E731
+
+        def pair(chunk):
+            rows = slice(chunk * n, (chunk + 1) * n)
+            grads = lambda q_off, k_off: flash.flash_chunk_grads(  # noqa: E731
+                local(q), to_bh(k)[:, rows], to_bh(v)[:, rows], local(do),
+                lse[:, n:], delta[:, n:], q_off, k_off, causal=True,
+                scale=scale, block_q=16, block_k=16, interpret=True)
+            assert count_calls(jax.make_jaxpr(grads)(
+                jnp.int32(n), jnp.int32(chunk * n)).jaxpr) == 1
+            return jax.jit(grads)(jnp.int32(n), jnp.int32(chunk * n))
+
+        (dq0, dk0, dv0), (dq1, dk1, dv1) = pair(0), pair(1)
+    got = (dq0 + dq1, jnp.concatenate([dk0, dk1], axis=1),
+           jnp.concatenate([dv0, dv1], axis=1))
+    np.testing.assert_allclose(got[0], want[0][:, n:], atol=2e-6)
+    np.testing.assert_allclose(got[1], want[1], atol=2e-6)
+    np.testing.assert_allclose(got[2], want[2], atol=2e-6)
+
+
+@pytest.mark.parametrize("form", ["one_tile", "grid", "whole_tiles",
+                                  "block_diffusion"])
+def test_a_backward_traces_one_kernel_body_and_two_calls_a_gradient(
+        monkeypatch, form):
+    """``flash_chunk_grads`` issues one ``pallas_call`` whose body is one
+    of the four backward bodies, no other of them is traced, and the
+    jaxpr of a gradient holds two calls a layer: forward, backward."""
+    bodies = {"one_tile": "_bwd_strips_kernel", "grid": "_bwd_grid_kernel",
+              "whole_tiles": "_bwd_kernel",
+              "block_diffusion": "_bwd_diffusion_kernel"}
+    assert sorted(bodies.values()) == sorted(
+        name for name in vars(flash)
+        if name.startswith(("_bwd_", "_dq_", "_dkv_"))
+        and name.endswith("_kernel"))
+    monkeypatch.setattr(flash, "SUB_TILE", 8)
+    counts = _count_traces(monkeypatch, *bodies.values())
+    s = 160   # shapes no other test traces: the shared traces are cold
+    block = {"one_tile": s, "block_diffusion": 40}.get(form, 80)
+    kwargs = dict(causal=form != "whole_tiles", block_q=block,
+                  block_k=block, interpret=True)
+    if form == "block_diffusion":
+        kwargs["mask"] = flash.BlockDiffusion(80, 4)
+    q, k, v = _qkv(seed=35, s=s)
+
+    def layer(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, **kwargs) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(layer, argnums=(0, 1, 2)))(q, k, v)
+    assert count_calls(jaxpr.jaxpr) == 2
+    # The strips body is also what the grid bodies walk their diagonal
+    # and boundary tiles with.
+    counts.pop("_bwd_strips_kernel" if form != "one_tile" else "", None)
+    assert counts == {bodies[form]: 1}
+
+
+def test_a_dq_row_too_long_to_stay_resident_is_cut_into_runs(monkeypatch):
+    """No model here runs such a length: past ``RESIDENT_DQ_BYTES`` the
+    queries are cut into runs of whole blocks, one kernel a run over
+    whole tiles against all the keys, dq's rows side by side and dk, dv
+    summed; one answer. A masked call has no such form and says so."""
+    monkeypatch.setattr(flash, "SUB_TILE", 8)
+    ops = _chunk_operands(bh=2, n=96, d=16, seed=5)
+    blocks = dict(block_q=16, block_k=16, interpret=True)
+    assert flash._resident_rows(16, 16) >= 96
+    want = flash.flash_chunk_grads(*ops, 0, 0, causal=True, **blocks)
+    monkeypatch.setattr(flash, "RESIDENT_DQ_BYTES", 40 * 16 * 4)
+    assert flash._resident_rows(16, 16) == 32
+    grads = lambda *a: flash.flash_chunk_grads(  # noqa: E731
+        *a, 0, 0, causal=True, **blocks)
+    assert count_calls(jax.make_jaxpr(grads)(*ops).jaxpr) == 3
+    for a, b, name in zip(grads(*ops), want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6, err_msg=name)
+    mask = flash.BlockDiffusion(48, 4)
+    assert not supports((1, 96, 2, 16), 16, 16, mask=mask)
+    with pytest.raises(ValueError, match="not resident"):
+        flash.flash_chunk_grads(*ops, 0, 0, mask=mask, **blocks)
+    # A block is the least a run can be.
+    monkeypatch.setattr(flash, "RESIDENT_DQ_BYTES", 1)
+    assert flash._resident_rows(192, 1024) == 1024
+
+
+@pytest.mark.parametrize(
+    "cell,s,block,d,dv,raised",
+    [("whole tiles of 512", 512, 512, 64, 64, False),
+     ("s2048_d64", 2048, 1024, 64, 64, True),
+     ("joyai_ep16_steady", 4096, 1024, 192, 128, True),
+     ("nemotron3n_ep16_steady, sdar_ep8_steady", 8192, 1024, 128, 128,
+      True)],
+)
+def test_the_backwards_vmem_limit_follows_its_blocks(cell, s, block, d, dv,
+                                                     raised):
+    """The backward over a grid of tiles keeps a b's whole dq row in
+    VMEM, so the limit follows the bytes of the blocks, not the head
+    size alone: heads of 128 at S 8,192 pass Mosaic's default and raise
+    it; small tiles do not."""
+    need = flash._grads_vmem_bytes(s, block, block, d, dv, 2)
+    params = flash._compiler_params(("parallel",), d, dv, vmem_bytes=need)
+    assert (need > flash.DEFAULT_VMEM_BYTES) == raised, cell
+    assert params.vmem_limit_bytes == (
+        flash.WIDE_HEAD_VMEM_BYTES if raised else None)
+    # The longest resident row still fits the raised limit.
+    rows = flash._resident_rows(d, 1024)
+    assert flash._grads_vmem_bytes(
+        rows, 1024, 1024, d, dv, 2) < flash.WIDE_HEAD_VMEM_BYTES
+
+
 def count_calls(jaxpr, primitive="pallas_call"):
     """Equations of ``primitive`` in a jaxpr and every jaxpr under it."""
     return sum(
@@ -604,7 +781,7 @@ def count_calls(jaxpr, primitive="pallas_call"):
 def test_a_recomputed_block_runs_the_forward_kernel_once(monkeypatch, s,
                                                          block, heads):
     """A block under ``jax.checkpoint`` with ``remat_policy()`` keeps the
-    forward kernel's o and logsumexp: its gradient holds the three
+    forward kernel's o and logsumexp: its gradient holds the two
     kernel calls of a block that is not recomputed, where a plain
     checkpoint runs the forward kernel again, and dq, dk, dv are the
     same bits in all three."""
@@ -631,7 +808,7 @@ def test_a_recomputed_block_runs_the_forward_kernel_once(monkeypatch, s,
         grad = jax.grad(fn, argnums=(0, 1, 2))
         calls[name] = count_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)
         grads[name] = jax.jit(grad)(q, k, v)
-    assert calls == {"no_checkpoint": 3, "plain": 4, "policy": 3}
+    assert calls == {"no_checkpoint": 2, "plain": 3, "policy": 2}
     for name in ("plain", "policy"):
         for got, want, leaf in zip(grads[name], grads["no_checkpoint"],
                                    ("dq", "dk", "dv")):
@@ -730,7 +907,7 @@ def test_equal_head_counts_trace_the_kernels_they_traced(s, block):
 
     q, k, v, _ = _grouped_inputs(s, 4, 1)
     equal = _index_map_primitives(grads, q, k, v)
-    assert len(equal) == 3
+    assert len(equal) == 2
     assert not any("div" in ops for call in equal for ops in call)
     q, k, v, _ = _grouped_inputs(s, 4, 4)
     grouped = _index_map_primitives(grads, q, k, v)
@@ -738,7 +915,7 @@ def test_equal_head_counts_trace_the_kernels_they_traced(s, block):
         changed = [i for i, (a, b) in enumerate(zip(call_equal, call_grouped))
                    if a != b]
         # k and v: operands 1 and 2 after the scalar-prefetched offsets
-        # (two of them, in the backward kernels' grids of tiles).
+        # (two of them, in the backward kernel's grid of tiles).
         assert len(changed) == 2 and changed[1] == changed[0] + 1
         for i in changed:
             assert call_grouped[i] == ["div"] + call_equal[i]
@@ -762,7 +939,7 @@ def test_cost_and_plan_say_the_two_head_counts():
     assert equal.bytes_accessed == 2 * 32 * 64 * (16 + 8) * 2
     assert flash.describe_tiles(8192, group=16).endswith(
         "28 skipped; one key/value head read in place by 16 query heads, "
-        "dk/dv summed over them")
+        "dk/dv summed over them; backward: one kernel, 5 products a tile")
     assert "key/value" not in flash.describe_tiles(4096)
 
 
@@ -981,13 +1158,12 @@ def test_causal_and_unmasked_calls_trace_the_kernels_they_traced(
     once against the parent's jaxprs with locations blanked: PERF.md,
     PR 34.)"""
     monkeypatch.setattr(flash, "SUB_TILE", 16)
-    expected = (("_fwd_strips_kernel", "_dq_strips_kernel",
-                 "_dkv_strips_kernel") if causal and s == block else
-                GRID_KERNELS if causal else
-                ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"))
+    expected = (("_fwd_strips_kernel", "_bwd_strips_kernel")
+                if causal and s == block else
+                GRID_KERNELS if causal else ("_fwd_kernel", "_bwd_kernel"))
     counts = _count_traces(
-        monkeypatch, "_fwd_diffusion_kernel", "_dq_diffusion_kernel",
-        "_dkv_diffusion_kernel", *expected)
+        monkeypatch, "_fwd_diffusion_kernel", "_bwd_diffusion_kernel",
+        *expected)
     asked = []
     real = flash._visible
     monkeypatch.setattr(

@@ -521,7 +521,7 @@ def test_lines_say_the_noise_the_mask_and_the_experts_scores():
 def test_the_tpu_branch_traces_the_kernels_under_the_mask(
         kernels_traced, monkeypatch):
     """At a size the kernels plan, the model's TPU branch calls them
-    under the mask (three custom calls a layer: forward, dq, dk/dv; a
+    under the mask (two custom calls a layer: forward, backward; a
     recomputed layer keeps o and the logsumexp) and the line says what
     the plan skips."""
     import functools
@@ -547,7 +547,7 @@ def test_the_tpu_branch_traces_the_kernels_under_the_mask(
 
     with _Lines(flash.logger) as log:
         jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
-    assert count_calls(jaxpr.jaxpr) == 3
+    assert count_calls(jaxpr.jaxpr) == 2
     line, = [m for m in log.lines if "pallas flash kernel" in m]
     assert ("block-diffusion mask, blocks of 4 over halves of 512; grid 8x8 "
             "of blocks 128x128: 12 tiles whole and unmasked, 12 boundary "

@@ -181,7 +181,8 @@ def test_block_diffusion_attention_compiles_for_a_described_v5e(one_chip):
     """``sdar_ep8_steady``'s attention through the TPU's compiler, no
     chip attached (a compile that passes is not a chip run): 32 query
     heads over 4 key/value heads of 128, the row twice over, 8,192
-    positions, under the block-diffusion mask. Three kernels, and no
+    positions, under the block-diffusion mask. Two kernels (forward;
+    the backward is one since PR 35), and no
     (2L)^2 score tensor among the program's buffers. (In this file, not
     beside the kernels' other tests: one file may describe the topology,
     a second goes to another worker where the library's lock skips it.)"""
@@ -211,8 +212,55 @@ def test_block_diffusion_attention_compiles_for_a_described_v5e(one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert compiled.as_text().count("tpu_custom_call") == 2
     # One head's scores over the doubled row would be 268 MB in float32,
     # a row's 8.6 GB; dk and dv leave the kernel a query head each (2 x
     # 268 MB, float32) beside dq and the operands' copies.
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize(
+    "bh,bkv,s,d,dv,half",
+    [(128, 128, 1024, 64, 64, None), (128, 128, 4096, 192, 128, None),
+     (64, 4, 8192, 128, 128, None), (64, 8, 8192, 128, 128, 4096),
+     (128, 128, 4096, 64, 64, None), (128, 128, 2048, 64, 64, None),
+     (16, 16, 16384, 128, 128, None)],
+    ids=["gpt2m_steady", "joyai_ep16_steady", "nemotron3n_ep16_steady",
+         "sdar_ep8_steady", "s4096_d64", "s2048_d64",
+         "the_longest_resident_row"],
+)
+def test_the_one_backward_kernel_compiles_for_a_described_v5e(
+        one_chip, bh, bkv, s, d, dv, half):
+    """``flash_chunk_grads`` through the TPU's compiler, no chip
+    attached (a compile that passes is not a chip run), at the four
+    cells' attention shapes, at the shapes of the sweep in
+    ``ops/flash_attention.py``'s header, and at the longest row whose dq
+    stays resident: one kernel, under the scoped-VMEM limit the call
+    sets from its blocks (Mosaic refuses S 4,096 at D 64 under its
+    default: 18.2 MiB of 16)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from elasticdl_tpu.ops import flash_attention as flash
+
+    shaped = lambda *shape, dtype=jnp.bfloat16: (  # noqa: E731
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip))
+    mask = half and flash.BlockDiffusion(half, 4)
+    assert s == flash._resident_rows(d, 1024) or s < 16384
+
+    def backward(*operands):
+        return flash.flash_chunk_grads(
+            *operands, 0, 0, causal=mask is None, mask=mask)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(backward).lower(
+            shaped(bh, s, d), shaped(bkv, s, d), shaped(bkv, s, dv),
+            shaped(bh, s, dv), shaped(bh, s, 1, dtype=jnp.float32),
+            shaped(bh, s, 1, dtype=jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+

@@ -18,14 +18,16 @@ One JSON line per row:
   has the block, the sub-tile, how many sub-tiles of a grid tile are
   computed (``tile_plan``), and device ms per kernel from the profiler's
   ``XLA Ops`` lane. The kernels are the program's ``tpu_custom_call``
-  instructions, named from the compiled HLO and labelled forward, dq,
-  dk/dv in the order their names number them (the order the trace made
-  them). ``kernel_model_flops_frac_of_peak`` is the required work
-  (``ops/flash_attention._cost``'s convention: 2 matmuls a kernel,
-  causal half) over the three kernels' time, over the bf16 peak.
+  instructions, named from the compiled HLO and labelled forward,
+  backward in the order their names number them (the order the trace
+  made them; the backward is one kernel since PR 35).
+  ``kernel_model_flops_frac_of_peak`` is the required work
+  (``ops/flash_attention._cost``'s convention: 2 matmuls forward, 4
+  backward, causal half) over the two kernels' time, over the bf16
+  peak.
 - **grid rows** (``"row": "grid"``), where the call has several grid
   tiles whose diagonal tiles are walked (S over one block: 2,048,
-  4,096): what each part of that walk buys, the three kernels built
+  4,096): what each part of that walk buys, the two kernels built
   from ``ops.flash_attention``'s own kernel functions and called on
   (B*H, S, D) operands: ``today`` the grid of whole tiles under the
   traced compare (what ran before PR 28); ``1`` strips in the diagonal
@@ -133,7 +135,7 @@ GRID_PARTS = ("today", "1", "1+2", "1+2+3", "shipped")
 
 def grid_kernels(flash, part, rows, scale, block):
     """fn(q, k, v, do) -> (o, lse, dq, dk, dv) on (B*H, S, D) operands:
-    the forward, dq and dk/dv kernels of a causal call over a square
+    the forward and backward kernels of a causal call over a square
     grid of ``block`` tiles, as ``part`` of GRID_PARTS builds them from
     the module's kernel functions. Part 1 alone keeps today's tile
     below the diagonal: masked by a compare of iotas that every score of
@@ -166,41 +168,23 @@ def grid_kernels(flash, part, rows, scale, block):
                 q_ref, k_ref, v_ref, o_ref, l_ref, rows=rows, sub=sub,
                 scale=scale, carried=(m_acc, l_acc, o_acc))
 
-    def dq_1(qoff, koff, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-             dq_ref, dq_acc):
-        qi, kt = pl.program_id(1), pl.program_id(2)
-        refs = (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref)
-
-        @pl.when(kt == 0)
-        def _init():
-            dq_acc[:] = jnp.zeros_like(dq_acc)
-
-        @pl.when(kt < qi)
-        def _below():
-            flash._dq_tile_update(*refs, dq_acc, qi * block, kt * block,
-                                  True, scale)
-
-        @pl.when(kt == qi)
-        def _diagonal():
-            flash._dq_strips_kernel(
-                *refs, dq_ref, rows=rows, sub=sub, q_offset=0, k_offset=0,
-                scale=scale, dq_acc=dq_acc)
-
-    def dkv_1(qoff, koff, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-              dk_ref, dv_ref, dk_acc, dv_acc):
+    def bwd_1(qoff, koff, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+              dq_ref, dk_ref, dv_ref, dk_acc, dv_acc):
         ki, qt = pl.program_id(1), pl.program_id(2)
-        refs = (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref)
+        refs = (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                dk_acc, dv_acc)
+        flash._zero_dq_tile(dq_ref, ki, qt, block)
 
         @pl.when(qt == ki)
         def _diagonal():
-            flash._dkv_strips_kernel(
-                *refs, dk_acc, dv_acc, rows=rows, sub=sub, q_offset=0,
-                k_offset=0, scale=scale)
+            flash._bwd_strips_kernel(
+                *refs, rows=rows, sub=sub, q_offset=0, k_offset=0,
+                scale=scale, q_tile=qt)
 
         @pl.when(qt > ki)
         def _below():
-            flash._dkv_tile_update(*refs, dk_acc, dv_acc, qt * block,
-                                   ki * block, True, scale)
+            flash._bwd_tile_update(*refs, qt, qt * block, ki * block, True,
+                                   scale)
 
         @pl.when(qt == pl.num_programs(2) - 1)
         def _flush():
@@ -208,12 +192,11 @@ def grid_kernels(flash, part, rows, scale, block):
             dv_ref[0] = dv_acc[:]
 
     walked = dict(rows=rows, sub=sub, scale=scale)
-    fwd_kernel, dq_kernel, dkv_kernel = {
-        "1": (fwd_1, dq_1, dkv_1),
+    fwd_kernel, bwd_kernel = {
+        "1": (fwd_1, bwd_1),
     }.get(part, tuple(
         functools.partial(kernel, **walked) for kernel in (
-            flash._fwd_grid_kernel, flash._dq_grid_kernel,
-            flash._dkv_grid_kernel)))
+            flash._fwd_grid_kernel, flash._bwd_grid_kernel)))
     clamped = part == "1+2+3"
     kv_tile = jnp.minimum if clamped else flash._streamed
     q_tile = jnp.maximum if clamped else flash._streamed
@@ -235,8 +218,8 @@ def grid_kernels(flash, part, rows, scale, block):
                                        scale, block, block, plan, False)
         else:
             grads = flash._grid_grads(
-                dq_kernel, dkv_kernel, kv_tile, q_tile, q, k, v, do, lse,
-                delta, 0, 0, block, block, True, False)
+                bwd_kernel, q_tile, q, k, v, do, lse, delta, 0, 0, block,
+                block, True, False)
         return (o, lse, *grads)
 
     return kernels
@@ -261,8 +244,8 @@ def grid_rows(flash, shape_name, bh, s, d, dv):
             flash, part, plan.rows, d ** -0.5, plan.block_q,
         )).lower(q, k, v, do).compile()
         names = kernel_names(compiled)
-        if len(names) != 3:
-            raise RuntimeError(f"expected three kernels, found {names}")
+        if len(names) != 2:
+            raise RuntimeError(f"expected two kernels, found {names}")
         outputs = compiled(q, k, v, do)
         today = today or outputs
         gaps = {
@@ -277,8 +260,8 @@ def grid_rows(flash, shape_name, bh, s, d, dv):
             "row": "grid", "shape": shape_name, "head_sizes": [d, dv],
             "part": part, "plan": plan.describe(),
             "device_ms": {
-                "fwd": round(per_kernel[0], 4), "dq": round(per_kernel[1], 4),
-                "dkv": round(per_kernel[2], 4),
+                "fwd": round(per_kernel[0], 4),
+                "bwd": round(per_kernel[1], 4),
                 "kernels": round(sum(per_kernel), 4),
                 "program": program and round(program, 4),
             },
@@ -322,7 +305,7 @@ def main(argv=None):
     q, k, v = (jnp.asarray(rng.randn(b, s, h, d), jnp.bfloat16)
                for _ in range(3))
     # 2*BHSSD a matmul, 6 required matmuls (fwd QK, PV; bwd dP, dQ, dK,
-    # dV), causal half: the three kernels' ``_cost`` together.
+    # dV), causal half: the two kernels' ``_cost`` together.
     model_flops = 12 * b * h * s * s * d * 0.5
     peak = peaks.peak(device.device_kind, "bf16_flops_per_s")
 
@@ -347,8 +330,8 @@ def main(argv=None):
             plan = flash.tile_plan(s, s, True, block_q, block_k)
             compiled = grads(block_q, block_k).lower(q, k, v).compile()
         names = kernel_names(compiled)
-        if len(names) != 3:
-            raise RuntimeError(f"expected three kernels, found {names}")
+        if len(names) != 2:
+            raise RuntimeError(f"expected two kernels, found {names}")
         per_kernel, program = device_ms(compiled, (q, k, v), names)
         total = sum(per_kernel)
         print(json.dumps({
@@ -357,8 +340,8 @@ def main(argv=None):
             "sub_tile": [plan.sub_q, plan.sub_k],
             "computed": plan.computed, "total": plan.total,
             "device_ms": {
-                "fwd": round(per_kernel[0], 4), "dq": round(per_kernel[1], 4),
-                "dkv": round(per_kernel[2], 4), "kernels": round(total, 4),
+                "fwd": round(per_kernel[0], 4),
+                "bwd": round(per_kernel[1], 4), "kernels": round(total, 4),
                 "program": program and round(program, 4),
             },
             "kernel_model_flops_frac_of_peak": round(
