@@ -116,8 +116,8 @@ def load_config_harness(name, seed=0, spec_parts=None):
     """(spec, task, batch, steps, measure_tasks) for a bench_suite
     config: ``load_config_spec`` plus a device-resident stacked task of
     ``steps`` deterministic batches — the prologue every measurement
-    tool shares (profile_config, measure_config, duel_fused_head,
-    dump_config_hlo). ``spec_parts`` reuses an
+    tool shares (profile_config, measure_config, duel_fused_head).
+    ``spec_parts`` reuses an
     existing ``load_config_spec(name)`` result instead of rebuilding
     the zoo spec (tools that sweep model variants)."""
     import jax

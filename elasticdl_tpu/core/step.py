@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.core.train_state import init_train_state
+from elasticdl_tpu.observability.tracing import LOSS_SCOPE, OPTIMIZER_SCOPE
 
 
 def _call_loss(loss_fn, labels, predictions, mask):
@@ -60,7 +61,11 @@ def _model_metrics(preds) -> dict:
 def _train_step_body(loss_fn: Callable) -> Callable:
     """The dense step body ``(state, batch) -> (state, metrics)``: one
     forward+backward+apply. Every dense program, per batch or per task,
-    on one device or on a mesh, is built from it."""
+    on one device or on a mesh, is built from it. The loss and the
+    optimizer run under a named scope each: metadata of the compiled
+    program's instructions and nothing else, by which a profiler window's
+    operation table (utils/hlo_ops.py) tells them from the scan's own
+    operations; the model's are told by Flax's module paths."""
 
     def train_step(state, batch):
         state, rng = state.next_rng()
@@ -69,9 +74,10 @@ def _train_step_body(loss_fn: Callable) -> Callable:
             preds, new_batch_stats = _apply_model(
                 state, params, batch, training=True, rng=rng
             )
-            loss = _call_loss(
-                loss_fn, batch["labels"], preds, batch["mask"]
-            )
+            with jax.named_scope(LOSS_SCOPE):
+                loss = _call_loss(
+                    loss_fn, batch["labels"], preds, batch["mask"]
+                )
             return loss, (preds, new_batch_stats)
 
         grad_fn = jax.value_and_grad(compute_loss, has_aux=True)
@@ -85,9 +91,10 @@ def _train_step_body(loss_fn: Callable) -> Callable:
                 lambda new, old: jnp.where(is_full, new, old),
                 new_batch_stats, state.batch_stats,
             )
-        new_state = state.apply_gradients(
-            grads=grads, batch_stats=new_batch_stats
-        )
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            new_state = state.apply_gradients(
+                grads=grads, batch_stats=new_batch_stats
+            )
         return new_state, {"loss": loss, **_model_metrics(preds)}
 
     return train_step
@@ -132,7 +139,9 @@ def jit_task(body: Callable, state_shardings=None,
     unrolled by 4, 1,389.5 ms against 1,313.3 (PERF.md, PR 27).
     """
 
-    # The benchmark finds the task programs on the trace by this name.
+    # The benchmark finds the task programs on the trace by this name,
+    # and the operation table a profiler window writes for them
+    # (``<profile_dir>/programs/jit_multi_step.ops.json``).
     def multi_step(state, batches):
         return jax.lax.scan(body, state, batches)
 

@@ -51,6 +51,12 @@ _local = threading.local()  # .stack: [(trace_id, span_id, role, instance)]
 # process, so this is too).
 _ANNOTATE = None
 ANNOTATION_PREFIX = "edl:"
+# The two named scopes a training step's body carries (core/step.py):
+# they reach the compiled program as ``op_name`` metadata and nothing
+# else, and utils/hlo_ops.py reads them back when the profiler's window
+# writes the program's operation table. Nothing else spells them.
+OPTIMIZER_SCOPE = "edl_optimizer"
+LOSS_SCOPE = "edl_loss"
 
 
 def enabled() -> bool:

@@ -108,7 +108,9 @@ def masked_next_token_cross_entropy(labels, logits, mask):
     but only (B, S) tensors materialize — the log_softmax form wrote
     full (B, S, V) f32 log-probs, which at the d512 bench shape
     (8, 1024, 32768) was four ~1 GB loop fusions ≈ 2.5 ms/step of pure
-    HBM traffic (round-4 raw profile + dump_config_hlo attribution).
+    HBM traffic (round-4 raw profile, attributed from the compiled
+    program's text by hand; ``tools/step_breakdown.py`` does that join
+    for a ``--profile_dir`` window now).
     The backward is ``(softmax - onehot) * w`` either way; here XLA
     fuses it straight into the lm_head gradient matmul's input."""
     import jax

@@ -10,11 +10,25 @@ in TensorBoard/Perfetto. While the window is open the worker's phases
 ``TraceAnnotation("edl:<name>")``, so the host's side of a task cycle
 lies in the same file, on the same clock, as the device's lanes.
 
+The trace names a device operation and nothing else (``fusion.6760``),
+so the window also leaves what each name belongs to: the worker tells
+the profiler which training program it runs (``note_program``), and
+``stop()`` writes ``<profile_dir>/programs/<module>.ops.json``
+(``utils/hlo_ops.py``: forward, recomputed forward, backward, optimizer
+and the Flax module of every instruction of the compiled program),
+``<module>`` being the program's name on the trace's ``XLA Modules``
+lane without its id (``jit_multi_step``, ``jit_train_step``: the name
+``core/step.py::jit_task`` gives, by which the benchmark finds the
+programs too). ``tools/step_breakdown.py <profile_dir>`` joins the two.
+
 Wired via ``--profile_dir`` (+ ``--profile_start_step/--profile_steps``):
 the worker starts the trace when the step window opens and stops it when
 it closes, so steady-state steps are captured rather than compile time.
 """
 
+import json
+import os
+import time
 from typing import Optional
 
 from elasticdl_tpu.common.log_utils import get_logger
@@ -47,6 +61,7 @@ class Profiler:
         self._active = False
         self._done = False
         self._window_end = None
+        self._program = None  # (jitted callable, its arguments' shapes)
 
     @property
     def enabled(self) -> bool:
@@ -80,12 +95,64 @@ class Profiler:
         # restored state can rewind the counter): keep tracing; stop()
         # on loop exit closes the window regardless.
 
+    def note_program(self, program, *args):
+        """The training program the worker runs, with the arguments of
+        this call: kept as shapes, the first time, for ``stop()``'s
+        operation table. A step that is no one compiled program (the
+        host tier's runner pulls rows around its own) has no table."""
+        if (self._program is None and not self._done
+                and hasattr(program, "lower")):
+            import jax
+
+            def shape_of(x):
+                # Committed to its devices where the array is.
+                committed = getattr(x, "committed", False)
+                return jax.ShapeDtypeStruct(
+                    x.shape, x.dtype,
+                    sharding=x.sharding if committed else None,
+                    weak_type=getattr(x, "weak_type", False),
+                )
+
+            self._program = (program, jax.tree.map(shape_of, args))
+
+    def _write_operation_table(self):
+        """``<profile_dir>/programs/<module>.ops.json`` for the program
+        told to ``note_program``: its compiled text, lowered again at
+        the same shapes (the executable that runs: JAX's caches answer),
+        parsed by ``utils/hlo_ops.py``. The second executable is dropped
+        at once."""
+        from elasticdl_tpu.utils import hlo_ops
+
+        program, shapes = self._program
+        started = time.monotonic()
+        text = program.lower(*shapes).compile().as_text()
+        table = hlo_ops.table_of(text)
+        directory = os.path.join(self.profile_dir, "programs")
+        os.makedirs(directory, exist_ok=True)
+        path = os.path.join(directory, table["module"] + ".ops.json")
+        with open(path, "w") as f:
+            json.dump(table, f)
+        logger.info(
+            "profiler: operation table of %s (%d operations) written to "
+            "%s in %.2fs", table["module"], len(table["ops"]), path,
+            time.monotonic() - started,
+        )
+
     def stop(self):
         if self._active:
             tracing.close_trace_window()
             self._get_backend().stop_trace()
             self._active = False
             self._done = True
+            if self._program is not None:
+                try:
+                    self._write_operation_table()
+                except Exception as exc:  # the trace itself is written
+                    logger.warning(
+                        "profiler: no operation table: %s: %s",
+                        type(exc).__name__, exc,
+                    )
+                self._program = None
             logger.info("profiler: trace written to %s", self.profile_dir)
 
 

@@ -749,6 +749,9 @@ class Worker:
                     # Pre-step so the window [start, start+num) captures
                     # the steps it names.
                     self._profiler.observe_step(int(self.state.step))
+                    self._profiler.note_program(
+                        self._train_step, self.state, batch
+                    )
                 with self._first_step(), self._phases.phase(
                     "device_step", kind="train"
                 ) as step:
@@ -808,6 +811,10 @@ class Worker:
             self._profiler.observe_step(int(self.state.step))
         with self._phases.phase("stack"):
             stacked = stack_batches(batch_list)
+        if self._profiler is not None:
+            self._profiler.note_program(
+                self._multi_step, self.state, stacked
+            )
         with self._first_step(), self._phases.phase(
             "device_step", kind="train_fused", batches=len(batch_list)
         ) as step:

@@ -492,6 +492,33 @@ def test_phases_tile_the_fused_task_cycle(tmp_path):
     ), dispatched
 
 
+def test_worker_without_a_profiler_keeps_no_shapes(tmp_path, monkeypatch):
+    """No ``--profile_dir``: no ``Profiler`` is built, so the loop tells
+    nobody its program, keeps no shapes and imports no parser of
+    compiled text."""
+    import sys
+
+    from elasticdl_tpu.utils import profiler as step_profiler
+
+    class Args:
+        profile_dir = ""
+
+    assert step_profiler.from_args(Args()) is None
+
+    def never(*args, **kwargs):
+        raise AssertionError("the profiler's seam was entered")
+
+    monkeypatch.setattr(step_profiler.Profiler, "note_program", never)
+    monkeypatch.setattr(step_profiler.Profiler, "observe_step", never)
+    monkeypatch.delitem(
+        sys.modules, "elasticdl_tpu.utils.hlo_ops", raising=False)
+    cluster = _fused_mnist_cluster(tmp_path, records=128)
+    assert cluster.workers[0]._profiler is None
+    cluster.run()
+    assert cluster.finished
+    assert "elasticdl_tpu.utils.hlo_ops" not in sys.modules
+
+
 def test_slow_task_line_names_the_phase(tmp_path):
     """A task made slow by a sleeping reader gets one WARNING line that
     says so: its cycle, the running median, and ``fetch`` holding the

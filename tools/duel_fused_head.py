@@ -1,8 +1,10 @@
 """Paired duel: transformer bench config with fused_head off vs on.
 
 The materialized-logits path carries four (B,S,32768) f32 log-softmax
-loop fusions (~2.5 ms/step at d512 — tools/dump_config_hlo.py mapping of
-the round-4 raw profile); fused_next_token_cross_entropy avoids forming
+loop fusions (~2.5 ms/step at d512: the round-4 raw profile mapped back
+by hand to the compiled program's fusions, a tool that went in PR 36;
+``tools/step_breakdown.py <profile_dir>`` reads a ``--profile_dir``
+window the same way now); fused_next_token_cross_entropy avoids forming
 logits at all. An earlier-round duel measured the fused path ~4% slower;
 runtime updates since (the flash custom-calls alone dropped ~21%) make
 this worth re-measuring whenever the stack changes.
