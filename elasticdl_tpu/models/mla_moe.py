@@ -19,8 +19,14 @@ the losses, and nothing else. Pre-RMSNorm blocks, no biases:
   computes its own experts' part of the result, drop-free: token-choices
   sorted by expert, grouped matrix products over the held groups, the
   weighted rows gathered back. What absent experts would add is left
-  out. Under a mesh with an ``ep`` axis each member holds
-  ``n_held / ep`` of them and the parts are summed over ``ep``. The
+  out. The held experts own ``n_held / router_width`` of the router, so
+  the work runs over a static bound of that share of the ``T*k``
+  token-choices times ``SLACK`` (:func:`rows_bound`), not over all of
+  them; a step's layer whose held rows pass the bound runs over every
+  token-choice instead, chosen on the device by the traced count
+  (``moe_overflow_layers`` counts those), so no row is ever dropped.
+  Under a mesh with an ``ep`` axis each member holds ``n_held / ep`` of
+  them, bounds its own share, and the parts are summed over ``ep``. The
   selection bias only selects; what the backward pass returns for it is
   its load's direction (:func:`_load_tap`), for an optimizer that moves
   it by plain descent (auxiliary-loss-free balancing; the zoo's does).
@@ -278,12 +284,52 @@ SCORINGS = {
 }
 
 
+# How far over its expected rows a member's static row bound reaches
+# (:func:`rows_bound`). The held experts own ``n / router_width`` of the
+# router and get that share of the ``T*k`` token-choices when the
+# routing is balanced; a step's layer whose held rows pass the bound
+# runs over all ``T*k`` instead (exact, and as slow as before the
+# bound), so the factor has to clear what the cells' routing reaches
+# and no more. Readings (a v5e; PERF.md, PR 37, call 1), layers a step
+# that overflowed at 2: ``sdar_ep8_steady`` (98-114k rows a step over 6
+# layers, bound 32,768 a layer) and ``nemotron3n_ep16_steady`` (21.6-
+# 29.6k over 4, bound 12,288) none in 3 seeds each, 400 and 384 steps;
+# ``joyai_ep16_steady`` (bound 16,384 a layer) 1 or 2 of its 5 layers
+# in EVERY step of seed 3000000011 (44.8-70.0k rows a step, the
+# fullest expert of each layer 31-38k together: tokens without context
+# crowd one or two held experts of a layer, and one expert can hold all
+# 16,384 tokens) and in 18 of 120 steps of seed 3700000031. 4 is the
+# next the issue named and clears a layer of two experts with every
+# token each; what it costs beside 2 where 2 held (a recomputed layer,
+# ms): 29.8 / 20.7 ``sdar``, 25.6 / 21.1 ``nemotron3n``, 18.4 / 14.2
+# ``joyai``, against 41.3, 50.2 and 39.0 over every token-choice. At 4
+# (call 2): none in 888 steps, ``joyai``'s 360 on 3 seeds with the heavy
+# one (44.7-69.8k rows a step) among them.
+SLACK = 4
+
+# The bound is whole blocks of this many rows (a grouped product's row
+# tile, and a sub-multiple of every cell's ``T*k``).
+_ROW_BLOCK = 512
+
+
+def rows_bound(choices: int, n: int, router_width: int) -> int:
+    """The static bound of the rows an expert layer's work runs over:
+    ``SLACK`` times the share of ``choices`` (``T*k``) that ``n`` held
+    experts of a router ``router_width`` wide expect, in whole blocks
+    of 512 rows, and never more than ``choices``: where every expert is
+    held, that is the bound and one path is traced."""
+    expected = SLACK * choices * n
+    blocks = -(-expected // (router_width * _ROW_BLOCK))
+    return min(choices, blocks * _ROW_BLOCK)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _spread(rows, order, inverse, live, k):
-    """rows (T, d) -> (T*k, d): token-choice ``order[j]`` (token
-    ``order[j] // k``) at sorted place j. Its transpose is
-    :func:`_gather_back`, so both directions are plain gathers.
-    ``live`` (T*k,) marks the sorted places that belong to a group: a
+    """rows (T, d) -> (bound, d): token-choice ``order[j]`` (token
+    ``order[j] // k``) at sorted place j, for the ``bound`` first
+    sorted places (``order`` is that long; ``inverse`` is always all
+    ``T*k``): a plain gather. Its transpose is :func:`_gather_back`.
+    ``live`` (bound,) marks the sorted places that belong to a group: a
     grouped product's cotangent is unspecified at the others (on a TPU:
     whatever the memory held), and is cut off before it is summed into
     the tokens'."""
@@ -302,10 +348,25 @@ def _spread_bwd(k, res, g):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _gather_back(sorted_rows, order, inverse, live, k):
-    """sorted rows (T*k, d) -> (T, d): every token's k rows, summed."""
-    t = sorted_rows.shape[0] // k
-    picked = sorted_rows[inverse].reshape(t, k, sorted_rows.shape[1])
-    return picked.astype(jnp.float32).sum(axis=1).astype(sorted_rows.dtype)
+    """sorted rows (bound, d) -> (T, d): every token's k rows, summed in
+    float32. Over all ``T*k`` places a plain gather by ``inverse`` (so
+    that both directions of the path over every token-choice are
+    gathers). Under a bound the ``bound`` rows are added into their
+    tokens instead (rows of no group are zero by then): ``T*k`` gathers
+    from the shorter source cost what they cost from the full one, 4.5
+    ms a pass at the cells' shapes, and the scatter-add reads the
+    bound's rows alone (``tools/bench_ssd_scan.py bound``; PERF.md, PR
+    37: a recomputed layer 21.1 against 27.1 ms on ``nemotron3n``, 20.7
+    against 25.9 on ``sdar``, 14.2 against 13.6 on ``joyai`` at an
+    eighth or a quarter of the places, 18.4 against 23.1 and 25.6
+    against 36.0 at twice that)."""
+    t, d = inverse.shape[0] // k, sorted_rows.shape[1]
+    if sorted_rows.shape[0] == inverse.shape[0]:
+        picked = sorted_rows[inverse].reshape(t, k, d).astype(jnp.float32)
+        return picked.sum(axis=1).astype(sorted_rows.dtype)
+    summed = jnp.zeros((t, d), jnp.float32).at[order // k].add(
+        sorted_rows.astype(jnp.float32))
+    return summed.astype(sorted_rows.dtype)
 
 
 def _gather_back_fwd(sorted_rows, order, inverse, live, k):
@@ -322,22 +383,12 @@ _spread.defvjp(_spread_fwd, _spread_bwd)
 _gather_back.defvjp(_gather_back_fwd, _gather_back_bwd)
 
 
-def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
-                      first_held):
-    """The held experts' part of an expert layer's result, drop-free.
-
-    rows (T, d); chosen (T, k) int32 expert ids over the router's whole
-    width; weights (T, k); w_gate/w_up (n, d, f), w_down (n, f, d): the
-    experts ``first_held .. first_held + n`` (``first_held`` may be
-    traced: a member's place on ``ep``). ``w_gate`` None: the experts
-    have no gate and are ``w_down(relu(w_up x)^2)``, else
-    ``w_down(silu(w_gate x) * (w_up x))``. Token-choices are sorted by
-    expert with those of absent experts last; the grouped products run
-    over the n held groups; no capacity, so no choice of a held expert
-    is ever dropped. Returns (part (T, d), rows of every held expert
-    (n,) int32)."""
+def _sorted_choices(chosen, first_held, n):
+    """Token-choices sorted by held expert, those of absent experts
+    last: (``order`` (T*k,) the choice at every sorted place, ``inverse``
+    its inverse, ``sizes`` (n,) the rows of every held expert, ``held``
+    (T, k) whether a choice's expert is held)."""
     t, k = chosen.shape
-    n = w_up.shape[0]
     local = chosen - first_held
     held = (local >= 0) & (local < n)
     group = jnp.where(held, local, n).reshape(t * k)
@@ -349,9 +400,110 @@ def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
         group[:, None] == jnp.arange(n, dtype=group.dtype)[None, :],
         axis=0, dtype=jnp.int32,
     )
+    return order, inverse, sizes, held
+
+
+def _part_over(gated, rows, weights, w_up, w_down, order, inverse, sizes,
+               held):
+    """The held experts' part computed over the first ``len(order)``
+    sorted places (the bound: all ``T*k``, or fewer where the held rows
+    are known to fit): the spread, both products, the activation and
+    the row weights run over that many rows; the gather back reaches
+    every token. ``w_up`` (n, d, wide) and ``w_down`` (n, wide, d) are
+    the compute-type copies the products run on; ``gated``: ``w_up`` is
+    the gate's columns and then the up projection's, (n, d, 2 wide)."""
+    t, k = held.shape
+    bound = order.shape[0]
     dt = rows.dtype
-    live = jnp.arange(t * k, dtype=jnp.int32) < jnp.sum(sizes)
+    live = jnp.arange(bound, dtype=jnp.int32) < jnp.sum(sizes)
     sorted_rows = _spread(rows, order, inverse, live, k)
+    if gated:
+        wide = w_down.shape[1]
+        gate_up = grouped_matmul(sorted_rows, w_up, sizes)
+        hidden = nn.silu(gate_up[:, :wide]) * gate_up[:, wide:]
+    else:
+        hidden = relu2(grouped_matmul(sorted_rows, w_up, sizes))
+    out = grouped_matmul(hidden, w_down, sizes)
+    # Past the held rows a grouped product leaves what it likes, in its
+    # result and in its cotangent: those rows are cut off (selected
+    # away, here and in ``_spread``'s backward; a zero weight would
+    # carry a NaN on) before anything multiplies them.
+    row_weight = jnp.where(held, weights, 0.0).reshape(t * k)[order]
+    out = jnp.where(live[:, None], out, 0) * row_weight[:, None].astype(dt)
+    return _gather_back(out.astype(dt), order, inverse, live, k)
+
+
+def _either_path(bound, run, sizes, *operands):
+    """``run(places, *operands)`` over the ``bound`` first sorted places
+    where the held rows fit under them, else over every place (``None``);
+    chosen on the device by the traced count."""
+    return jax.lax.cond(
+        jnp.sum(sizes) <= bound,
+        functools.partial(run, bound), functools.partial(run, None),
+        *operands,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _part_under(bound, gated, rows, weights, w_up, w_down, order, inverse,
+                sizes, held):
+    """:func:`_part_over` at ``bound`` rows where the held rows fit, at
+    ``T*k`` where they do not: no row is ever dropped. One custom rule
+    around the conditional, so that JAX neither differentiates through
+    it (each branch would be handed the other's residuals as ``T*k``
+    arrays of zeros) nor keeps anything of its forward: the rule's
+    residuals are its own arguments, and its backward is one conditional
+    whose branches run their path again and pull the cotangent through
+    it. Under a block's ``nn.remat`` the recomputed forward conditional
+    is dead code, so a step still runs a layer's products forward twice
+    and backward once."""
+    def forward(places, rows, weights, w_up, w_down, order, *rest):
+        return _part_over(gated, rows, weights, w_up, w_down, order[:places],
+                          *rest)
+
+    return _either_path(bound, forward, sizes, rows, weights, w_up, w_down,
+                        order, inverse, sizes, held)
+
+
+def _part_under_fwd(bound, gated, *operands):
+    return _part_under(bound, gated, *operands), operands
+
+
+def _part_under_bwd(bound, gated, operands, g):
+    def backward(places, g, rows, weights, w_up, w_down, order, *rest):
+        _, pull = jax.vjp(
+            lambda *moving: _part_over(gated, *moving, order[:places], *rest),
+            rows, weights, w_up, w_down,
+        )
+        return pull(g)
+
+    *_, sizes, _ = operands
+    moved = _either_path(bound, backward, sizes, g, *operands)
+    return (*moved, None, None, None, None)
+
+
+_part_under.defvjp(_part_under_fwd, _part_under_bwd)
+
+
+def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
+                      first_held, router_width):
+    """The held experts' part of an expert layer's result, drop-free.
+
+    rows (T, d); chosen (T, k) int32 expert ids over the router's whole
+    width ``router_width``; weights (T, k); w_gate/w_up (n, d, f),
+    w_down (n, f, d): the experts ``first_held .. first_held + n``
+    (``first_held`` may be traced: a member's place on ``ep``).
+    ``w_gate`` None: the experts have no gate and are
+    ``w_down(relu(w_up x)^2)``, else ``w_down(silu(w_gate x) * (w_up
+    x))``. Token-choices are sorted by expert with those of absent
+    experts last; the spread, the grouped products over the n held
+    groups and what lies between them run over :func:`rows_bound` rows,
+    and over all ``T*k`` in a step whose held rows pass that bound; no
+    capacity, so no choice of a held expert is ever dropped. Returns
+    (part (T, d), rows of every held expert (n,) int32)."""
+    t, k = chosen.shape
+    n = w_up.shape[0]
+    order, inverse, sizes, held = _sorted_choices(chosen, first_held, n)
     # The two products run at ``product_width`` of the experts' width:
     # the compute-type copies of the weights are zero-padded to it (the
     # parameters and their gradients keep their shapes: a pad's
@@ -361,43 +513,39 @@ def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
     wide = product_width(w_up.shape[2])
 
     def widened(w, axis):
-        return zero_padded(w.astype(dt), axis, wide)
+        return zero_padded(w.astype(rows.dtype), axis, wide)
 
-    if w_gate is None:
-        hidden = relu2(grouped_matmul(sorted_rows, widened(w_up, 2), sizes))
-    else:
-        gate_up = grouped_matmul(
-            sorted_rows,
-            jnp.concatenate([widened(w_gate, 2), widened(w_up, 2)], axis=2),
-            sizes,
-        )
-        hidden = nn.silu(gate_up[:, :wide]) * gate_up[:, wide:]
-    out = grouped_matmul(hidden, widened(w_down, 1), sizes)
-    # Past the held rows a grouped product leaves what it likes, in its
-    # result and in its cotangent: those rows are cut off (selected
-    # away, here and in ``_spread``'s backward; a zero weight would
-    # carry a NaN on) before anything multiplies them.
-    row_weight = jnp.where(held, weights, 0.0).reshape(t * k)[order]
-    out = jnp.where(live[:, None], out, 0) * row_weight[:, None].astype(dt)
-    return _gather_back(out.astype(dt), order, inverse, live, k), sizes
+    gated = w_gate is not None
+    up = widened(w_up, 2)
+    if gated:
+        up = jnp.concatenate([widened(w_gate, 2), up], axis=2)
+    operands = (rows, weights, up, widened(w_down, 1), order, inverse, sizes,
+                held)
+    bound = rows_bound(t * k, n, router_width)
+    if bound == t * k:
+        return _part_over(gated, *operands), sizes
+    return _part_under(bound, gated, *operands), sizes
 
 
 @functools.lru_cache(maxsize=None)
-def log_traced_experts(cfg: MlaMoeConfig, rows_bound: int, ep: int):
+def log_traced_experts(cfg: MlaMoeConfig, bound: int, choices: int,
+                       ep: int):
     """One static line per traced expert layer shape (every layer of
     every trace asks again), like the attention's: what is held, what
-    the router scores, the static bound of the grouped products' rows,
-    which grouped product runs, and the width it runs at where that is
-    not the experts' own (:func:`product_width`)."""
+    the router scores, the static ``bound`` of the rows a member's work
+    runs over (:func:`rows_bound`) beside the ``choices`` (``T*k``) it
+    falls back to, which grouped product runs, and the width it runs at
+    where that is not the experts' own (:func:`product_width`)."""
     f = cfg.moe_intermediate_size
     shared = cfg.shared_intermediate_size or f
     wide = product_width(f)
     products = f", products at {wide} (zero columns)" if wide != f else ""
     logger.info(
         "experts: traced drop-free layer holding experts [%d, %d) of "
-        "router width %d, top-%d, rows bound %d, grouped product %s%s%s%s",
+        "router width %d, top-%d, rows bound %d of %d, grouped product "
+        "%s%s%s%s",
         cfg.first_held, cfg.first_held + cfg.n_held, cfg.router_width,
-        cfg.top_k, rows_bound, GROUPED_PRODUCT,
+        cfg.top_k, bound, choices, GROUPED_PRODUCT,
         f", {cfg.n_held // ep} a member over ep={ep}" if ep > 1 else "",
         products if cfg.expert_form == "silu_gated" else (
             f", experts {cfg.expert_form} of width {f}{products}, "
@@ -513,14 +661,16 @@ class ExpertLayer(nn.Module):
         ) * cfg.routed_scaling_factor
 
         ep = 1 if self.mesh is None else self.mesh.shape.get("ep", 1)
-        log_traced_experts(cfg, b * s * k, ep)
+        bound = rows_bound(b * s * k, n // ep, cfg.router_width)
+        log_traced_experts(cfg, bound, b * s * k, ep)
         if ep > 1:
             part, sizes = self._over_ep(
                 rows, chosen, weights, w_gate, w_up, w_down, ep
             )
         else:
             part, sizes = held_experts_part(
-                rows, chosen, weights, w_gate, w_up, w_down, cfg.first_held
+                rows, chosen, weights, w_gate, w_up, w_down, cfg.first_held,
+                cfg.router_width,
             )
         load = jnp.sum(
             chosen[..., None] == jnp.arange(cfg.router_width), axis=(0, 1),
@@ -531,8 +681,14 @@ class ExpertLayer(nn.Module):
         shared = shared_mlp(
             cfg.shared_intermediate_size or f, cfg, self.mesh, name="shared"
         )(x) if cfg.shared_expert else None
+        # A member whose held rows pass its bound runs the layer over
+        # every token-choice (``held_experts_part``): counted, so that a
+        # routing the bound was not written for shows.
         counters = {
-            "moe_rows": jnp.sum(sizes), "moe_expert_rows_max": jnp.max(sizes)
+            "moe_rows": jnp.sum(sizes), "moe_expert_rows_max": jnp.max(sizes),
+            "moe_overflow_layers": jnp.any(
+                jnp.sum(sizes.reshape(ep, n // ep), axis=1) > bound
+            ).astype(jnp.int32),
         }
         part = part.reshape(b, s, d)
         out = wsc(part if shared is None else shared + part,
@@ -553,7 +709,8 @@ class ExpertLayer(nn.Module):
         def member(rows, chosen, weights, w_gate, w_up, w_down):
             first = cfg.first_held + jax.lax.axis_index("ep") * per
             part, sizes = held_experts_part(
-                rows, chosen, weights, w_gate, w_up, w_down, first
+                rows, chosen, weights, w_gate, w_up, w_down, first,
+                cfg.router_width,
             )
             return jax.lax.psum(part, "ep"), sizes
 

@@ -2,7 +2,8 @@
 
 ``grouped_matmul(lhs (m, k), rhs (g, k, n), group_sizes (g,))``: rows
 ``sum(sizes[:i]) .. sum(sizes[:i+1])`` of ``lhs`` times ``rhs[i]``. The
-sizes are ragged and traced; ``m`` is the static bound. Rows past
+sizes are ragged and traced; ``m`` is the static bound, the caller's to
+choose at or over ``sum(group_sizes)``. Rows past
 ``sum(group_sizes)`` belong to no group: what comes back there is
 unspecified, callers cut it off.
 
@@ -24,7 +25,10 @@ well and anything else badly (1,856 columns cost 1.6 times what 2,048
 do). :func:`product_width` has the readings and the rule the expert
 layer pads its weights by; the static bound costs too (at 6,144 live
 rows a layer's 5.95 + 13.66 ms under a bound of 98,304 are 4.25 + 10.31
-under 24,576: PERF.md section 7, row 28).
+under 24,576: PERF.md section 7, row 28), so the expert layer gives its
+products ``m`` = ``models/mla_moe.py::rows_bound`` rows, the held
+experts' share of the token-choices times a slack, and all the
+token-choices only in a step whose groups pass that (PR 37).
 """
 
 import jax

@@ -48,6 +48,7 @@ _WRAPPERS = frozenset((
     "while", "body", "cond", "closed_call", "checkpoint",
     "rematted_computation"))
 _LAYER_INDEX = re.compile(r"_\d+$")
+_BRANCH = re.compile(r"branch_\d+_fun$")
 
 
 def module_name(text: str) -> str:
@@ -139,8 +140,12 @@ def module_of(op_name: str) -> str:
     """The Flax path of an ``op_name``'s first origin: what follows the
     last differentiation wrapper (each ``jvp(...)`` / ``transpose(...)``
     restates the stack it wrapped, so a module's path would otherwise
-    differ between the passes), with the other transformations, control
-    flow and the trailing primitive dropped and layer indices folded
+    differ between the passes; one that wraps a function inside a
+    module, as a custom rule's own ``jax.vjp`` of a conditional's branch
+    does, restates nothing, and the path before it speaks), with the
+    other transformations, control flow (a conditional's
+    ``branch_<i>_fun`` too) and the trailing primitive dropped and layer
+    indices folded
     (``blocks_3/attn/q_proj`` -> ``blocks_*/attn/q_proj``); ``loss`` and
     ``optimizer`` under their scopes; '' where nothing is left (the
     scan's own slices)."""
@@ -150,10 +155,14 @@ def module_of(op_name: str) -> str:
     parts = origin.split("/")
     if OPTIMIZER_SCOPE in parts:
         return "optimizer"
-    wrapped = [i for i, p in enumerate(parts)
-               if p.startswith(("jvp(", "transpose("))]
-    parts = parts[wrapped[-1] + 1:] if wrapped else parts
-    named = [p for p in parts[:-1] if "(" not in p and p not in _WRAPPERS]
+    starts = [0] + [i + 1 for i, p in enumerate(parts)
+                    if p.startswith(("jvp(", "transpose("))]
+    for start in reversed(starts):
+        named = [p for p in parts[start:-1]
+                 if "(" not in p and p not in _WRAPPERS
+                 and not _BRANCH.match(p)]
+        if named:
+            break
     return "/".join(_LAYER_INDEX.sub("_*", p) for p in named)
 
 

@@ -90,8 +90,8 @@ def test_padded_products_are_the_plain_loops(gated):
 
     with jax.default_matmul_precision("highest"):
         (_, got), grads = total(
-            lambda **kw: held_experts_part(**kw)[0])
-        _, sizes = held_experts_part(**given, first_held=1)
+            lambda **kw: held_experts_part(**kw, router_width=4)[0])
+        _, sizes = held_experts_part(**given, first_held=1, router_width=4)
     (_, want), want_grads = total(_by_a_loop)
     held = (given["chosen"] >= 1) & (given["chosen"] < 3)
     assert int(sizes.sum()) == int(held.sum()) > 0
@@ -123,7 +123,8 @@ def _traced(gated, t, k, d, f, n, grad):
         given = dict(zip(names, args))
         given.setdefault("w_gate", None)
         given["rows"] = given["rows"].astype(jnp.bfloat16)
-        part, _ = held_experts_part(chosen=chosen, first_held=0, **given)
+        part, _ = held_experts_part(
+            chosen=chosen, first_held=0, router_width=n, **given)
         return jnp.sum(part.astype(jnp.float32))
 
     fn = jax.grad(loss, tuple(range(1, 1 + len(names)))) if grad else loss

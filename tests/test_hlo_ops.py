@@ -166,6 +166,17 @@ ORIGINS = [
     ("jit(multi_step)/while/body/closed_call/transpose(jvp(W.apply))/W/"
      "jvp(W.apply)/W/checkpoint/block_0/moe/gather", "backward",
      "W/block_*/moe"),
+    # a conditional under a custom rule (the expert layer's two paths):
+    # the branches are their layer's, and the rule's own ``jax.vjp`` of
+    # a branch wraps a function inside the module and restates nothing
+    ("jit(multi_step)/while/body/closed_call/jvp(W.apply)/W/block_1/moe/"
+     "cond/branch_1_fun/ragged_dot_general", "forward", "W/block_*/moe"),
+    ("jit(multi_step)/while/body/closed_call/transpose(jvp(W.apply))/W/"
+     "jvp(W.apply)/W/checkpoint/block_0/moe/cond/branch_0_fun/"
+     "transpose(jvp())/mul", "backward", "W/block_*/moe"),
+    ("jit(multi_step)/while/body/closed_call/transpose(jvp(W.apply))/W/"
+     "jvp(W.apply)/W/checkpoint/block_0/moe/cond/branch_0_fun/jvp()/"
+     "reduce_sum", "backward", "W/block_*/moe"),
     ("jit(multi_step)/while/body/closed_call/jvp(edl_loss)/integer_pow",
      "forward", "loss"),
     ("jit(multi_step)/while/body/closed_call/transpose(jvp(edl_loss))/mul",

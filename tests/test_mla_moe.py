@@ -332,20 +332,26 @@ def test_held_part_under_any_split_of_the_rows(highest):
     wg, wu = (jnp.asarray(rng.normal(size=(n, d, f)), jnp.float32)
               for _ in range(2))
     wd = jnp.asarray(rng.normal(size=(n, f, d)), jnp.float32)
-    whole, sizes = held_experts_part(rows, chosen, weights, wg, wu, wd, 0)
+    whole, sizes = held_experts_part(rows, chosen, weights, wg, wu, wd, 0, n)
     assert int(sizes.sum()) == t * k
     parts = [held_experts_part(rows, chosen, weights, wg[a:b], wu[a:b],
-                               wd[a:b], a)[0] for a, b in ((0, 3), (3, 8))]
+                               wd[a:b], a, n)[0]
+             for a, b in ((0, 3), (3, 8))]
     np.testing.assert_allclose(parts[0] + parts[1], whole, atol=1e-4)
 
 
-@pytest.mark.parametrize("f", [16, 1856])
-def test_rows_of_no_group_may_hold_anything(monkeypatch, highest, f):
+@pytest.mark.parametrize("f,width,tokens", [
+    (16, 8, 24), (1856, 8, 24), (16, 64, 512)],
+    ids=["16", "1856", "under_the_bound"])
+def test_rows_of_no_group_may_hold_anything(monkeypatch, highest, f, width,
+                                            tokens):
     """A grouped product says nothing of the rows past its groups, in its
     result or in its cotangent (a TPU leaves what the memory held; the
     CPU writes zeros, which hid it). With both poisoned the layer and its
     gradients are what they were; so too at a width whose products run
-    zero-padded (1,856 at 2,048: gate and up each padded, cut at 2,048)."""
+    zero-padded (1,856 at 2,048: gate and up each padded, cut at 2,048),
+    and where the work runs over a bound's rows (3 experts of a router
+    64 wide: 512 of 1,536 sorted places, most of them of no group)."""
     from elasticdl_tpu.ops.grouped_matmul import grouped_matmul
 
     def in_a_group(x, sizes):
@@ -366,10 +372,11 @@ def test_rows_of_no_group_may_hold_anything(monkeypatch, highest, f):
         return jnp.where(in_a_group(lhs, sizes), d_lhs, jnp.nan), d_rhs, None
 
     poisoned.defvjp(forward, backward)
-    given = _layer_inputs(width=8, held=3, seed=7, f=f)
+    given = _layer_inputs(width=width, held=3, seed=7, f=f, tokens=tokens)
     x, params = given["x"], given["params"]
-    cfg = program_config(router_width=8, first_held=1, n_held=3, top_k=3,
+    cfg = program_config(router_width=width, first_held=1, n_held=3, top_k=3,
                          moe_intermediate_size=f)
+    assert mla_moe.rows_bound(tokens * 3, 3, width) == min(tokens * 3, 512)
 
     def loss(params, x):
         out, _ = ExpertLayer(cfg).apply({"params": params}, x)
@@ -382,6 +389,235 @@ def test_rows_of_no_group_may_hold_anything(monkeypatch, highest, f):
         assert bool(jnp.isfinite(a).all())
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5 * f / 16)
+
+
+FORMS = pytest.mark.parametrize(
+    "gated", [True, False], ids=["silu_gated", "relu2"])
+
+
+def _bounded_inputs(gated, routing="free", dtype=jnp.float32, seed=11):
+    """256 tokens x top 4 over a router 16 wide, experts 2 and 3 held:
+    1,024 token-choices under a bound of 512 rows. ``free``: a random
+    routing (a quarter of the bound is held); ``at_the_bound``: every
+    token chooses both held experts, 512 rows exactly; ``all_held``:
+    every choice is a held expert's, 1,024 rows."""
+    t, k, d, f, n, width, first = 256, 4, 32, 16, 2, 16, 2
+    rng = np.random.default_rng(seed)
+    mk = lambda scale, *shape: jnp.asarray(
+        rng.normal(0, scale, shape), jnp.float32)
+    absent = np.stack([rng.permutation(np.arange(4, width))[:k]
+                       for _ in range(t)])
+    chosen = {
+        "free": np.stack([rng.permutation(width)[:k] for _ in range(t)]),
+        "at_the_bound": np.concatenate(
+            [np.tile([2, 3], (t, 1)), absent[:, :2]], axis=1),
+        "all_held": np.tile([2, 3, 3, 2], (t, 1)),
+    }[routing]
+    assert mla_moe.rows_bound(t * k, n, width) == 512
+    return dict(
+        rows=mk(1.0, t, d).astype(dtype), chosen=jnp.asarray(chosen, jnp.int32),
+        weights=jnp.asarray(rng.uniform(0.1, 1, (t, k)), jnp.float32),
+        w_gate=mk(d ** -0.5, n, d, f) if gated else None,
+        w_up=mk(d ** -0.5, n, d, f), w_down=mk(f ** -0.5, n, f, d),
+        first_held=first)
+
+
+def _part_and_gradients(given, router_width, part_of=held_experts_part):
+    """(part, held rows, the gradient of rows, weights and every weight
+    stack) under a fixed random cotangent, compiled as one program."""
+    names = [name for name in ("rows", "weights", "w_gate", "w_up", "w_down")
+             if given[name] is not None]
+    pull = jnp.asarray(np.random.default_rng(1).normal(
+        size=given["rows"].shape), jnp.float32)
+
+    def loss(*moving):
+        part, sizes = part_of(**dict(given, **dict(zip(names, moving))),
+                              router_width=router_width)
+        return jnp.sum(part.astype(jnp.float32) * pull), (part, sizes)
+
+    (_, (part, sizes)), grads = jax.jit(jax.value_and_grad(
+        loss, tuple(range(len(names))), has_aux=True))(
+            *(given[name] for name in names))
+    return part, sizes, dict(zip(names, grads))
+
+
+def _one_choice_after_another(rows, chosen, weights, w_gate, w_up, w_down,
+                              first_held, router_width):
+    """The plain layer: every choice of a held expert by that expert's
+    own matrices, float32."""
+    n = w_up.shape[0]
+    dot = lambda a, b: jnp.einsum(
+        "td,tdf->tf", a, b, precision=jax.lax.Precision.HIGHEST)
+    rows32 = rows.astype(jnp.float32)
+    out = jnp.zeros_like(rows32)
+    for j in range(chosen.shape[1]):
+        local = chosen[:, j] - first_held
+        held = (local >= 0) & (local < n)
+        e = jnp.clip(local, 0, n - 1)
+        hidden = (mla_moe.relu2(dot(rows32, w_up[e])) if w_gate is None else
+                  jax.nn.silu(dot(rows32, w_gate[e])) * dot(rows32, w_up[e]))
+        out = out + jnp.where(held, weights[:, j], 0.0)[:, None] * dot(
+            hidden, w_down[e])
+    held_rows = jnp.sum((chosen >= first_held) & (chosen < first_held + n))
+    return out.astype(rows.dtype), held_rows
+
+
+@pytest.fixture
+def places_run(monkeypatch):
+    """The sorted places every executed ``_part_over`` ran over, in
+    order (a conditional traces both branches and runs one)."""
+    seen = []
+    plain = mla_moe._part_over
+
+    def noting(gated, rows, weights, w_up, w_down, order, *rest):
+        jax.debug.callback(lambda: seen.append(order.shape[0]))
+        return plain(gated, rows, weights, w_up, w_down, order, *rest)
+
+    monkeypatch.setattr(mla_moe, "_part_over", noting)
+    yield seen
+    jax.effects_barrier()
+
+
+@FORMS
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_under_the_bound_the_layer_is_the_one_over_every_choice(
+        highest, places_run, gated, dtype, tol):
+    """A held share of 1/8: the work over 512 of 1,024 sorted places
+    gives the part, the held rows and every gradient of the work over
+    all of them (the layer told its experts are the whole router: one
+    path, no conditional)."""
+    given = _bounded_inputs(gated, dtype=dtype)
+    got = _part_and_gradients(given, router_width=16)
+    jax.effects_barrier()
+    assert places_run == [512, 512]         # forward, recomputed to pull
+    want = _part_and_gradients(given, router_width=2)
+    jax.effects_barrier()
+    assert places_run[2:] == [1024]
+    assert 0 < int(want[1].sum()) < 512
+    np.testing.assert_array_equal(got[1], want[1])
+    close = lambda a, b, name: np.testing.assert_allclose(
+        np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=tol,
+        atol=tol, err_msg=name)
+    close(got[0], want[0], "part")
+    for name in want[2]:
+        assert got[2][name].shape == given[name].shape
+        close(got[2][name], want[2][name], name)
+
+
+@FORMS
+@pytest.mark.parametrize("routing,places", [("all_held", 1024),
+                                            ("at_the_bound", 512)])
+def test_rows_past_the_bound_take_the_path_over_every_choice(
+        highest, places_run, gated, routing, places):
+    """Every choice a held expert's: 1,024 rows where the bound is 512,
+    so the step runs over every token-choice, forward and backward, and
+    is the plain layer; 512 rows exactly still fit under the bound."""
+    given = _bounded_inputs(gated, routing)
+    part, sizes, grads = _part_and_gradients(given, router_width=16)
+    jax.effects_barrier()
+    assert places_run == [places, places]
+    want, held_rows, want_grads = _part_and_gradients(
+        given, 16, _one_choice_after_another)
+    assert int(sizes.sum()) == int(held_rows) == (
+        1024 if routing == "all_held" else 512)
+    np.testing.assert_allclose(part, want, rtol=1e-5, atol=1e-5)
+    for name, grad in grads.items():
+        scale = max(1.0, float(jnp.max(jnp.abs(want_grads[name]))))
+        np.testing.assert_allclose(grad, want_grads[name], rtol=1e-5,
+                                   atol=1e-5 * scale, err_msg=name)
+
+
+def _primitives(jaxpr, name):
+    from tests.test_flash_attention import count_calls
+    return count_calls(jaxpr.jaxpr, name)
+
+
+@FORMS
+def test_an_overflowing_layer_is_counted_and_a_fitting_one_is_not(
+        highest, monkeypatch, gated):
+    """The layer's own counter: a selection bias that sends every token
+    to the two held experts overflows the bound (and loses nothing); the
+    router left alone does not."""
+    width, k = 16, 2
+    given = _layer_inputs(width=width, held=2, tokens=1024, seed=3)
+    x, params = given["x"], given["params"]
+    cfg = program_config(
+        router_width=width, first_held=2, n_held=2, top_k=k,
+        moe_intermediate_size=16,
+        expert_form="silu_gated" if gated else "relu2")
+    if not gated:
+        del params["w_gate"], params["shared"]["gate"]
+    bound = mla_moe.rows_bound(1024 * k, 2, width)
+    assert bound < 1024 * k
+    _, counters = ExpertLayer(cfg).apply({"params": params}, x)
+    assert int(counters["moe_rows"]) < bound
+    assert int(counters["moe_overflow_layers"]) == 0
+    pulled = dict(params, router_bias=jnp.where(
+        (jnp.arange(width) >= 2) & (jnp.arange(width) < 4), 50.0, 0.0))
+    out, counters = ExpertLayer(cfg).apply({"params": pulled}, x)
+    assert int(counters["moe_rows"]) == 1024 * k
+    assert int(counters["moe_overflow_layers"]) == 1
+    monkeypatch.setattr(mla_moe, "SLACK", 10 ** 6)     # one path: no bound
+    want, _ = ExpertLayer(cfg).apply({"params": pulled}, x)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=2e-6)
+
+
+@FORMS
+def test_a_recomputed_block_runs_no_product_a_second_time(gated):
+    """A block under ``nn.remat`` with the kernels' policy, its expert
+    layer under a bound: the gradients are the plain block's, and the
+    rematted program holds the grouped products of the plain one (the
+    rule's own residuals are the layer's arguments, so the recomputed
+    forward conditional is dead code), where a recomputed layer without
+    the rule would run its forward products again."""
+    import flax.linen as nn
+
+    from elasticdl_tpu.ops.flash_attention import remat_policy
+
+    cfg = program_config(
+        router_width=16, first_held=2, n_held=2, top_k=4,
+        expert_form="silu_gated" if gated else "relu2")
+    x = jnp.asarray(np.random.default_rng(2).normal(
+        size=(2, 128, cfg.hidden_size)), jnp.float32)
+    assert mla_moe.rows_bound(256 * 4, 2, 16) == 512
+    params = MlaBlock(cfg).init(jax.random.PRNGKey(0), x)["params"]
+
+    def gradient(block):
+        def loss(params, x):
+            return jnp.sum(block.apply({"params": params}, x)[0] ** 2)
+        return jax.grad(loss, (0, 1))
+
+    plain = gradient(MlaBlock(cfg))
+    rematted = gradient(nn.remat(MlaBlock, policy=remat_policy())(cfg))
+    for got, want in zip(jax.tree.leaves(jax.jit(rematted)(params, x)),
+                         jax.tree.leaves(jax.jit(plain)(params, x))):
+        scale = max(1.0, float(jnp.max(jnp.abs(want))))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * scale)
+    conds, products = (
+        [_primitives(jax.make_jaxpr(fn)(params, x), name)
+         for fn in (plain, rematted)]
+        for name in ("cond", "ragged_dot_general"))
+    assert conds == [2, 2]          # forward, backward
+    # Forward: 2 products a branch. Backward: a branch runs its 2 again
+    # and pulls through each twice (rows, weights).
+    assert products == [2 * (2 + 2 + 4)] * 2
+
+
+def test_a_layer_that_holds_the_whole_router_traces_no_conditional():
+    given = _bounded_inputs(True)
+    traced = jax.make_jaxpr(
+        lambda rows, weights: held_experts_part(
+            **dict(given, rows=rows, weights=weights), router_width=2)[0])(
+                given["rows"], given["weights"])
+    assert _primitives(traced, "cond") == 0
+    assert _primitives(traced, "ragged_dot_general") == 2
+    bounded = jax.make_jaxpr(
+        lambda rows, weights: held_experts_part(
+            **dict(given, rows=rows, weights=weights), router_width=16)[0])(
+                given["rows"], given["weights"])
+    assert _primitives(bounded, "cond") == 1
 
 
 def test_interleaved_rope_turns_the_pairs_by_hand():
@@ -602,7 +838,8 @@ def test_step_metrics_carry_the_models_counters(seeded):
              "mask": np.ones((ROWS,), np.float32)}
     state = init_train_state(model, ZOO.optimizer(), batch)
     state, metrics = build_train_step(ZOO.loss)(state, batch)
-    assert set(metrics) == {"loss", "moe_rows", "moe_expert_rows_max"}
+    assert set(metrics) == {"loss", "moe_rows", "moe_expert_rows_max",
+                            "moe_overflow_layers"}
     # All eight experts held: every choice of every expert layer (and
     # the MTP block's) is a held one.
     layers = CFG["num_hidden_layers"] - CFG["first_k_dense_replace"] + 1
@@ -622,31 +859,43 @@ def test_expert_layer_line_is_logged_once():
     mla_moe.log_traced_experts.cache_clear()
     try:
         for _ in range(2):
-            mla_moe.log_traced_experts(program_config(), 96, 1)
+            mla_moe.log_traced_experts(program_config(), 96, 96, 1)
     finally:
         mla_moe.logger.removeHandler(handler)
         mla_moe.log_traced_experts.cache_clear()
     assert records == [
         "experts: traced drop-free layer holding experts [2, 6) of router "
-        "width 8, top-3, rows bound 96, grouped product ragged_dot"]
+        "width 8, top-3, rows bound 96 of 96, grouped product ragged_dot"]
 
 
-def test_over_an_ep_mesh_the_members_parts_add_up(highest):
-    """ep = 4 on the virtual CPU devices: each member holds two of the
-    eight experts; the layer's result is the single-chip layer's."""
+@pytest.mark.parametrize("ep,width,held,k,tokens", [
+    (4, 8, 8, 3, 24), (2, 16, 4, 4, 256)],
+    ids=["whole_router_over_4", "a_quarter_over_2_under_the_bound"])
+def test_over_an_ep_mesh_the_members_parts_add_up(highest, ep, width, held,
+                                                  k, tokens):
+    """On the virtual CPU devices: each member of ``ep`` holds its share
+    of the held experts; the layer's result is the single-chip layer's.
+    Four members of a router wholly held (every member's bound is all
+    24 x 3 choices: one path); two members holding two experts each of
+    a router 16 wide, each under its own bound of 512 of 1,024 rows,
+    the conditional inside ``shard_map``."""
     from jax.sharding import Mesh
 
-    given = _layer_inputs(width=8, held=8, seed=6)
+    given = _layer_inputs(width=width, held=held, seed=6, tokens=tokens)
     x, params = given["x"], given["params"]
-    cfg = program_config(router_width=8, first_held=0, n_held=8, top_k=3,
-                         moe_intermediate_size=16)
+    cfg = program_config(router_width=width, first_held=0, n_held=held,
+                         top_k=k, moe_intermediate_size=16)
+    per = mla_moe.rows_bound(tokens * k, held // ep, width)
+    assert per == (tokens * k if held == width else 512)
     want, want_counters = ExpertLayer(cfg).apply({"params": params}, x)
-    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(1, 4), ("dp", "ep"))
-    got, counters = jax.jit(
-        lambda p, x: ExpertLayer(cfg, mesh).apply({"params": p}, x)
-    )(params, x)
+    mesh = Mesh(np.asarray(jax.devices()[:ep]).reshape(1, ep), ("dp", "ep"))
+    layer = lambda p, x: ExpertLayer(cfg, mesh).apply({"params": p}, x)
+    got, counters = jax.jit(layer)(params, x)
     np.testing.assert_allclose(got, want, atol=2e-5)
-    assert int(counters["moe_rows"]) == int(want_counters["moe_rows"])
+    for name in ("moe_rows", "moe_expert_rows_max", "moe_overflow_layers"):
+        assert int(counters[name]) == int(want_counters[name]), name
+    assert _primitives(jax.make_jaxpr(layer)(params, x), "cond") == (
+        per < tokens * k)
     rules = dict(mla_moe.mla_moe_sharding_rules())
     assert rules[r"moe/w_(gate|up|down)"][0] == "ep"
 

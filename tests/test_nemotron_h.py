@@ -327,7 +327,8 @@ def test_fused_task_is_the_steps_one_by_one(seeded):
     stacked = jax.tree.map(lambda *xs: np.stack(xs), *batches)
     body = _train_step_body(ZOO.loss)
     fused_state, fused = jit_task(body, donate=False)(state, stacked)
-    assert set(fused) == {"loss", "moe_rows", "moe_expert_rows_max"}
+    assert set(fused) == {"loss", "moe_rows", "moe_expert_rows_max",
+                          "moe_overflow_layers"}
     step = jit_step(body, donate=False)
     losses = []
     for batch in batches:
@@ -411,11 +412,13 @@ def test_lines_say_the_scan_the_head_counts_and_the_experts_form():
     with _Lines(mla_moe.logger) as log:
         mla_moe.log_traced_experts(program_config(
             moe_intermediate_size=1856, shared_intermediate_size=3712,
-            first_held=0, n_held=8, router_width=128, top_k=6), 98304, 1)
+            first_held=0, n_held=8, router_width=128, top_k=6),
+            mla_moe.rows_bound(98304, 8, 128), 98304, 1)
     mla_moe.log_traced_experts.cache_clear()
     assert log.lines == [
         "experts: traced drop-free layer holding experts [0, 8) of router "
-        "width 128, top-6, rows bound 98304, grouped product ragged_dot, "
+        f"width 128, top-6, rows bound {mla_moe.rows_bound(98304, 8, 128)} "
+        "of 98304, grouped product ragged_dot, "
         "experts relu2 of width 1856, products at 2048 (zero columns), "
         "shared expert 3712"]
 

@@ -450,7 +450,7 @@ def test_fused_task_is_the_steps_one_by_one(seeded):
     body = _train_step_body(ZOO.loss)
     fused_state, fused = jit_task(body, donate=False)(state, stacked)
     assert set(fused) == {"loss", "moe_rows", "moe_expert_rows_max",
-                          "diffusion_masked_tokens"}
+                          "moe_overflow_layers", "diffusion_masked_tokens"}
     step = jit_step(body, donate=False)
     losses = []
     for i, batch in enumerate(batches):

@@ -5,8 +5,11 @@ around ``block_until_ready``, the median of ``REPEATS`` calls after one
 to compile). Run through the chip tool; prints one JSON line a reading.
 
     python tools/bench_ssd_scan.py [check] [scan] [attention] [experts]
+
+(``bound`` alone is the first part of ``experts``.)
 """
 
+import functools
 import json
 import os
 import statistics
@@ -18,6 +21,7 @@ import jax.numpy as jnp
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from elasticdl_tpu.models import mla_moe  # noqa: E402
 from elasticdl_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from elasticdl_tpu.ops.ring_attention import dense_attention  # noqa: E402
 from elasticdl_tpu.ops.ssd_scan import ssd_reference, ssd_scan  # noqa: E402
@@ -165,51 +169,103 @@ def _expert_layer(bound, live, groups, d, f, wide, gated, params):
     return jax.jit(loss), jax.jit(jax.grad(loss, every)), (rows, *weights)
 
 
-def _hidden_size(bound, live, groups, d, d_wide, f, wide, k=6):
-    """The same layer from the tokens' side, for the hidden size: tokens
-    (bound / k, d) zero-padded to ``d_wide``, spread to the bound's rows
-    and gathered back by the program's own ``_spread`` and
-    ``_gather_back``, ``w_up``'s rows and ``w_down``'s columns
-    zero-padded, the result cut at ``d``."""
-    from elasticdl_tpu.models.mla_moe import _gather_back, _spread
-    from elasticdl_tpu.ops.grouped_matmul import zero_padded
+# The expert cells' layers from the tokens' side: tokens a step, top k,
+# the router's width, held experts, hidden size, experts' width, gate.
+CELL_LAYERS = {
+    "joyai_ep16_steady": (16384, 8, 256, 16, 2048, 768, True),
+    "nemotron3n_ep16_steady": (16384, 6, 128, 8, 2688, 1856, False),
+    "sdar_ep8_steady": (16384, 8, 128, 16, 2048, 768, True),
+}
 
-    sizes = jnp.full((groups,), live // groups, jnp.int32)
-    tokens = jax.random.normal(
-        jax.random.PRNGKey(3), (bound // k, d), jnp.bfloat16)
-    order = jnp.arange(bound, dtype=jnp.int32)
-    in_a_group = order < live
 
-    def loss(tokens, up, down):
-        rows = _spread(zero_padded(tokens, 1, d_wide), order, order,
-                       in_a_group, k)
-        out = _products(
-            rows, sizes, wide,
-            zero_padded(up.astype(jnp.bfloat16), 1, d_wide),
-            zero_padded(down.astype(jnp.bfloat16), 2, d_wide))
-        out = jnp.where(in_a_group[:, None], out, 0)
-        out = _gather_back(out, order, order, in_a_group, k)[:, :d]
-        return jnp.sum(out.astype(jnp.float32))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gathered_back(sorted_rows, order, inverse, live, k):
+    """``mla_moe._gather_back`` under a bound as ``T*k`` gathers from the
+    bound's rows (a choice past them reads zero), for the comparison:
+    the layer's other way to combine, which it ran before the readings."""
+    t = inverse.shape[0] // k
+    picked = sorted_rows.at[inverse].get(mode="fill", fill_value=0)
+    picked = picked.reshape(t, k, sorted_rows.shape[1])
+    return picked.astype(jnp.float32).sum(axis=1).astype(sorted_rows.dtype)
 
-    return (jax.jit(loss), jax.jit(jax.grad(loss, (0, 1, 2))),
-            (tokens, *_expert_weights(groups, d, f, False, jnp.float32)))
+
+_gathered_back.defvjp(
+    lambda *a: (_gathered_back(*a), a[1:4]), mla_moe._gather_back_bwd)
+
+
+def _recomputed_layer(tokens, k, router_width, n, d, f, gated, told_width):
+    """``held_experts_part`` itself on a balanced routing (every expert
+    of the router gets ``tokens * k / router_width`` choices), told the
+    router is ``told_width`` wide: ``n`` gives the bound ``tokens * k``
+    and the one path the program ran before the bound. Returns
+    (forward, a recomputed layer whole: forward, then under
+    ``jax.checkpoint`` what its backward pass runs) and their
+    arguments."""
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    rows = jax.random.normal(keys[0], (tokens, d), jnp.bfloat16)
+    weigh = jax.random.uniform(keys[1], (tokens, k), jnp.float32)
+    pull = jax.random.normal(keys[2], (tokens, d), jnp.float32)
+    chosen = (jnp.arange(tokens, dtype=jnp.int32)[:, None]
+              + jnp.arange(k, dtype=jnp.int32)[None, :] * (router_width // k)
+              ) % router_width
+    up, down, *gate = _expert_weights(n, d, f, gated, jnp.float32)
+
+    def part(rows, weigh, up, down, *gate):
+        return mla_moe.held_experts_part(rows, chosen, weigh, gate[0] if gate
+                                 else None, up, down, 0, told_width)[0]
+
+    def loss(*moving):
+        return jnp.sum(jax.checkpoint(part)(*moving).astype(jnp.float32)
+                       * pull)
+
+    args = (rows, weigh, up, down, *gate)
+    return (jax.jit(part),
+            jax.jit(jax.value_and_grad(loss, tuple(range(len(args))))), args)
+
+
+def bound():
+    """What the static row bound buys (PERF.md section 7, row 28): the
+    cells' three expert layers from the tokens' side, by the code the
+    cells run. Over every token-choice (the path a step falls back to,
+    all the program had before PR 37) beside over ``rows_bound`` rows at
+    ``SLACK`` 2 and 4, the bound's rows scatter-added into their tokens
+    (what the layer does) and gathered back by every token-choice."""
+    kept = mla_moe.SLACK, mla_moe._gather_back
+    for cell, (tokens, k, width, n, d, f, gated) in CELL_LAYERS.items():
+        ways = [("every token-choice", n, kept[0], kept[1])] + [
+            (f"rows bound at SLACK {slack}, {how}", width, slack, back)
+            for slack in (2, 4)
+            for how, back in (("scatter-added back", kept[1]),
+                              ("gathered back", _gathered_back))]
+        for what, told, slack, back in ways:
+            mla_moe.SLACK, mla_moe._gather_back = slack, back
+            try:
+                forward, whole, args = _recomputed_layer(
+                    tokens, k, width, n, d, f, gated, told)
+                say(what=f"{cell}'s expert layer from the tokens' side, "
+                    f"{tokens * k * n // width} live rows, {what}",
+                    rows=mla_moe.rows_bound(tokens * k, n, told),
+                    forward_ms=timed(forward, *args),
+                    recomputed_layer_ms=timed(whole, *args))
+            finally:
+                mla_moe.SLACK, mla_moe._gather_back = kept
+            del forward, whole, args
 
 
 def experts():
     """The sweep ``ops/grouped_matmul.py::product_width`` is written
-    from, and the sizing of what it leaves (PERF.md section 7, row 28):
-    an expert layer's two grouped products, forward and forward with
-    backward; a recomputed layer pays the one and then the other.
+    from: an expert layer's two grouped products over rows given as
+    they are, forward and forward with backward; a recomputed layer
+    pays the one and then the other. (What surrounds the products, and
+    the rows they run over, is :func:`bound`'s.)
 
-    The third family's cell (bound 98,304 rows, 6,144 live, 8 groups,
-    2,688 -> f -> 2,688, relu^2): bfloat16 weights of width f itself,
-    then what the program does, float32 parameters of the published
-    1,856 cast and zero-padded. The second family's cell (bound 131,072,
-    8,192 live, 16 groups, 2,048 -> 2 x 768 -> 2,048, silu-gated) at 768
-    and padded to 1,024. The static bound at 6,144 live rows. The
-    hidden size 2,688 (21 lane tiles) against 3,072: the products alone
-    with weights and rows 3,072 wide, then from the tokens' side with
-    what padding to it would add."""
+    The third family's cell (98,304 rows, 6,144 live, 8 groups, 2,688
+    -> f -> 2,688, relu^2): bfloat16 weights of width f itself, then
+    what the program does, float32 parameters of the published 1,856
+    cast and zero-padded. The second family's cell (131,072 rows, 8,192
+    live, 16 groups, 2,048 -> 2 x 768 -> 2,048, silu-gated) at 768 and
+    padded to 1,024."""
+    bound()
     bf16, f32 = jnp.bfloat16, jnp.float32
     cell = dict(bound=98304, live=6144, groups=8, d=2688)
     joyai = dict(bound=131072, live=8192, groups=16, d=2048)
@@ -225,13 +281,6 @@ def experts():
         (f"float32 parameters of width 768, products at {wide}",
          dict(joyai, f=768, wide=wide, gated=True, params=f32))
         for wide in (768, 1024)
-    ] + [
-        (f"float32 parameters of width 1856, products at 2048, bound {bound}",
-         dict(cell, bound=bound, f=1856, wide=2048, gated=False, params=f32))
-        for bound in (24576, 12288)
-    ] + [
-        ("float32 parameters of width 1856, products at 2048",
-         dict(cell, d=3072, f=1856, wide=2048, gated=False, params=f32))
     ]
     for what, shape in readings:
         forward, both, args = _expert_layer(**shape)
@@ -242,18 +291,9 @@ def experts():
             forward_ms=timed(forward, *args),
             forward_and_backward_ms=timed(both, *args))
         del forward, both, args
-    for d_wide in (2688, 3072):
-        forward, both, args = _hidden_size(**cell, d_wide=d_wide, f=1856,
-                                           wide=2048)
-        say(what="relu2 experts from the tokens' side (the program's spread, "
-            "products at 2048, its gather back, cut), 6144 live rows of "
-            f"98304, hidden size 2688 run at {d_wide}, a layer",
-            forward_ms=timed(forward, *args),
-            forward_and_backward_ms=timed(both, *args))
-        del forward, both, args
 
 
 if __name__ == "__main__":
     for part in sys.argv[1:] or ["check", "scan", "attention", "experts"]:
         {"check": check, "scan": scan, "attention": attention,
-         "experts": experts}[part]()
+         "experts": experts, "bound": bound}[part]()
