@@ -43,7 +43,7 @@ evaluation it returns the main logits alone.
 
 import functools
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -234,10 +234,11 @@ class LatentAttention(nn.Module):
 
 
 class GatedMlp(nn.Module):
-    """W_down(silu(W_gate x) * (W_up x))."""
+    """W_down(act(W_gate x) * (W_up x)), ``act`` SiLU unless told."""
     width: int
     cfg: MlaMoeConfig
     mesh: Optional[Mesh] = None
+    act: Callable = nn.silu
 
     @nn.compact
     def __call__(self, x):
@@ -245,7 +246,7 @@ class GatedMlp(nn.Module):
         wsc = _Constrain(self.mesh)
         gate = _dense(self.width, dt, "gate")(x)
         up = _dense(self.width, dt, "up")(x)
-        hidden = wsc(nn.silu(gate) * up, "dp", None, "tp")
+        hidden = wsc(self.act(gate) * up, "dp", None, "tp")
         return _dense(self.cfg.hidden_size, dt, "down")(hidden)
 
 
@@ -269,9 +270,15 @@ class Relu2Mlp(nn.Module):
 
 
 # An expert's form: (the shared expert's module, whether the routed
-# experts have a gate matrix). ``silu_gated``: DeepSeek-V3's, three
-# matrices; ``relu2``: Nemotron-H's, two.
-EXPERT_FORMS = {"silu_gated": (GatedMlp, True), "relu2": (Relu2Mlp, False)}
+# experts have a gate matrix, the activation: of the gate's product
+# where there is one, else of the up projection's). ``silu_gated``:
+# DeepSeek-V3's and Qwen3-MoE's, three matrices; ``relu2``:
+# Nemotron-H's, two; ``relu_gated``: SmallThinker's ReGLU, three.
+EXPERT_FORMS = {
+    "silu_gated": (GatedMlp, True, nn.silu),
+    "relu2": (Relu2Mlp, False, relu2),
+    "relu_gated": (functools.partial(GatedMlp, act=nn.relu), True, nn.relu),
+}
 
 # A router's scores of ALL its experts from their float32 logits (T,
 # width): ``sigmoid``, each expert on its own (DeepSeek-V3's,
@@ -403,15 +410,17 @@ def _sorted_choices(chosen, first_held, n):
     return order, inverse, sizes, held
 
 
-def _part_over(gated, rows, weights, w_up, w_down, order, inverse, sizes,
+def _part_over(form, rows, weights, w_up, w_down, order, inverse, sizes,
                held):
     """The held experts' part computed over the first ``len(order)``
     sorted places (the bound: all ``T*k``, or fewer where the held rows
     are known to fit): the spread, both products, the activation and
     the row weights run over that many rows; the gather back reaches
     every token. ``w_up`` (n, d, wide) and ``w_down`` (n, wide, d) are
-    the compute-type copies the products run on; ``gated``: ``w_up`` is
-    the gate's columns and then the up projection's, (n, d, 2 wide)."""
+    the compute-type copies the products run on; ``form`` is the
+    experts' (``EXPERT_FORMS``): where it has a gate, ``w_up`` is the
+    gate's columns and then the up projection's, (n, d, 2 wide)."""
+    _, gated, act = EXPERT_FORMS[form]
     t, k = held.shape
     bound = order.shape[0]
     dt = rows.dtype
@@ -420,9 +429,9 @@ def _part_over(gated, rows, weights, w_up, w_down, order, inverse, sizes,
     if gated:
         wide = w_down.shape[1]
         gate_up = grouped_matmul(sorted_rows, w_up, sizes)
-        hidden = nn.silu(gate_up[:, :wide]) * gate_up[:, wide:]
+        hidden = act(gate_up[:, :wide]) * gate_up[:, wide:]
     else:
-        hidden = relu2(grouped_matmul(sorted_rows, w_up, sizes))
+        hidden = act(grouped_matmul(sorted_rows, w_up, sizes))
     out = grouped_matmul(hidden, w_down, sizes)
     # Past the held rows a grouped product leaves what it likes, in its
     # result and in its cotangent: those rows are cut off (selected
@@ -445,7 +454,7 @@ def _either_path(bound, run, sizes, *operands):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _part_under(bound, gated, rows, weights, w_up, w_down, order, inverse,
+def _part_under(bound, form, rows, weights, w_up, w_down, order, inverse,
                 sizes, held):
     """:func:`_part_over` at ``bound`` rows where the held rows fit, at
     ``T*k`` where they do not: no row is ever dropped. One custom rule
@@ -458,21 +467,21 @@ def _part_under(bound, gated, rows, weights, w_up, w_down, order, inverse,
     is dead code, so a step still runs a layer's products forward twice
     and backward once."""
     def forward(places, rows, weights, w_up, w_down, order, *rest):
-        return _part_over(gated, rows, weights, w_up, w_down, order[:places],
+        return _part_over(form, rows, weights, w_up, w_down, order[:places],
                           *rest)
 
     return _either_path(bound, forward, sizes, rows, weights, w_up, w_down,
                         order, inverse, sizes, held)
 
 
-def _part_under_fwd(bound, gated, *operands):
-    return _part_under(bound, gated, *operands), operands
+def _part_under_fwd(bound, form, *operands):
+    return _part_under(bound, form, *operands), operands
 
 
-def _part_under_bwd(bound, gated, operands, g):
+def _part_under_bwd(bound, form, operands, g):
     def backward(places, g, rows, weights, w_up, w_down, order, *rest):
         _, pull = jax.vjp(
-            lambda *moving: _part_over(gated, *moving, order[:places], *rest),
+            lambda *moving: _part_over(form, *moving, order[:places], *rest),
             rows, weights, w_up, w_down,
         )
         return pull(g)
@@ -486,16 +495,18 @@ _part_under.defvjp(_part_under_fwd, _part_under_bwd)
 
 
 def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
-                      first_held, router_width):
+                      first_held, router_width, form=None):
     """The held experts' part of an expert layer's result, drop-free.
 
     rows (T, d); chosen (T, k) int32 expert ids over the router's whole
     width ``router_width``; weights (T, k); w_gate/w_up (n, d, f),
     w_down (n, f, d): the experts ``first_held .. first_held + n``
     (``first_held`` may be traced: a member's place on ``ep``).
-    ``w_gate`` None: the experts have no gate and are
-    ``w_down(relu(w_up x)^2)``, else ``w_down(silu(w_gate x) * (w_up
-    x))``. Token-choices are sorted by expert with those of absent
+    ``form`` names what an expert computes (``EXPERT_FORMS``):
+    ``relu2`` ``w_down(relu(w_up x)^2)`` (``w_gate`` None),
+    ``silu_gated`` and ``relu_gated`` ``w_down(act(w_gate x) * (w_up
+    x))``; not given, it is ``relu2`` without a gate and ``silu_gated``
+    with one. Token-choices are sorted by expert with those of absent
     experts last; the spread, the grouped products over the n held
     groups and what lies between them run over :func:`rows_bound` rows,
     and over all ``T*k`` in a step whose held rows pass that bound; no
@@ -515,16 +526,20 @@ def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
     def widened(w, axis):
         return zero_padded(w.astype(rows.dtype), axis, wide)
 
-    gated = w_gate is not None
+    form = form or ("relu2" if w_gate is None else "silu_gated")
+    if EXPERT_FORMS[form][1] != (w_gate is not None):
+        raise ValueError(
+            f"experts of form {form} "
+            + ("have no gate" if w_gate is not None else "need w_gate"))
     up = widened(w_up, 2)
-    if gated:
+    if w_gate is not None:
         up = jnp.concatenate([widened(w_gate, 2), up], axis=2)
     operands = (rows, weights, up, widened(w_down, 1), order, inverse, sizes,
                 held)
     bound = rows_bound(t * k, n, router_width)
     if bound == t * k:
-        return _part_over(gated, *operands), sizes
-    return _part_under(bound, gated, *operands), sizes
+        return _part_over(form, *operands), sizes
+    return _part_under(bound, form, *operands), sizes
 
 
 @functools.lru_cache(maxsize=None)
@@ -548,8 +563,9 @@ def log_traced_experts(cfg: MlaMoeConfig, bound: int, choices: int,
         cfg.top_k, bound, choices, GROUPED_PRODUCT,
         f", {cfg.n_held // ep} a member over ep={ep}" if ep > 1 else "",
         products if cfg.expert_form == "silu_gated" else (
-            f", experts {cfg.expert_form} of width {f}{products}, "
-            f"shared expert {shared}"),
+            f", experts {cfg.expert_form.replace('_', '-')} of width "
+            f"{f}{products}"
+            + (f", shared expert {shared}" if cfg.shared_expert else "")),
         "" if (cfg.scoring, cfg.selection_bias, cfg.shared_expert) == (
             "sigmoid", True, True) else (
             f", {cfg.scoring} scores"
@@ -608,19 +624,24 @@ class ExpertLayer(nn.Module):
     ``routing`` (B, S, k) int32, where given, are the experts every
     token goes to in place of the layer's own top k (routing replay:
     the weights are still this layer's scores of them).
+    ``router_input`` (B, S, d), where given, is what the router reads
+    in place of ``x`` (a router placed before attention reads the
+    layer's input while the experts take the stream after it); the
+    experts' rows are ``x`` either way.
 
     ``cfg`` is any configuration with the expert layer's fields
     (``n_held``, ``first_held``, ``router_width``, ``top_k``,
     ``routed_scaling_factor``, ``moe_intermediate_size``,
     ``shared_intermediate_size``, ``expert_form``, ``scoring``,
-    ``selection_bias``, ``shared_expert``, ``compute_dtype``,
-    ``hidden_size``): this family's, ``models/nemotron_h.py``'s or
-    ``models/sdar_moe.py``'s."""
+    ``selection_bias``, ``shared_expert``, ``compute_dtype``): this
+    family's, ``models/nemotron_h.py``'s, ``models/sdar_moe.py``'s or
+    ``models/smallthinker.py``'s (a shared expert's module reads
+    ``hidden_size`` too)."""
     cfg: MlaMoeConfig
     mesh: Optional[Mesh] = None
 
     @nn.compact
-    def __call__(self, x, routing=None):
+    def __call__(self, x, routing=None, router_input=None):
         cfg = self.cfg
         dt = cfg.compute_dtype
         b, s, d = x.shape
@@ -637,15 +658,17 @@ class ExpertLayer(nn.Module):
             (cfg.router_width,), jnp.float32,
         ) if cfg.selection_bias else None
         init = nn.initializers.normal(0.02)
-        shared_mlp, gated = EXPERT_FORMS[cfg.expert_form]
+        shared_mlp, gated, _ = EXPERT_FORMS[cfg.expert_form]
         w_gate = (self.param("w_gate", init, (n, d, f), jnp.float32)
                   if gated else None)
         w_up = self.param("w_up", init, (n, d, f), jnp.float32)
         w_down = self.param("w_down", init, (n, f, d), jnp.float32)
 
         rows = x.reshape(b * s, d)
+        read = rows if router_input is None else router_input.reshape(
+            b * s, d)
         scores = SCORINGS[cfg.scoring](jnp.matmul(
-            rows.astype(jnp.float32), router, precision=HIGHEST
+            read.astype(jnp.float32), router, precision=HIGHEST
         ))                                              # (T, width) f32
         if routing is None:
             _, chosen = jax.lax.top_k(
@@ -670,7 +693,7 @@ class ExpertLayer(nn.Module):
         else:
             part, sizes = held_experts_part(
                 rows, chosen, weights, w_gate, w_up, w_down, cfg.first_held,
-                cfg.router_width,
+                cfg.router_width, cfg.expert_form,
             )
         load = jnp.sum(
             chosen[..., None] == jnp.arange(cfg.router_width), axis=(0, 1),
@@ -710,7 +733,7 @@ class ExpertLayer(nn.Module):
             first = cfg.first_held + jax.lax.axis_index("ep") * per
             part, sizes = held_experts_part(
                 rows, chosen, weights, w_gate, w_up, w_down, first,
-                cfg.router_width,
+                cfg.router_width, cfg.expert_form,
             )
             return jax.lax.psum(part, "ep"), sizes
 

@@ -28,6 +28,19 @@ compare, as before. All forms share one definition of the tile
 mathematics (``_tile_scores``, ``_tile_probs``, ``_bwd_tile_math``) and
 give the same bits: the rows dropped are exact zeros of the mask.
 
+Two more mask kinds take ``causal``'s place (``mask=``), each ONE call
+over the S x S grid by a STATIC PLAN that says, for every row of query
+tiles, which k steps are whole tiles (no mask built), which are
+boundary tiles walked in sub-tile strips under a rule, and which are
+dead (predicated out, their index maps clamped to a live step's tile):
+:class:`BlockDiffusion` (a row twice over, ``_BlockDiffusionPlan``) and
+:class:`SlidingWindow` (a causal band, ``_BandPlan``: the diagonal
+tile, the whole tiles inside the band, one more boundary tile at the
+band's far edge, dead steps on both sides). One pair of kernels runs
+any plan (``_fwd_plan_kernel``, ``_bwd_plan_kernel``): a new mask kind
+is a plan class and, where its boundary needs one, a ``_Rule`` kind,
+not a kernel body.
+
 Backward is a custom VJP: the forward saves only o and the logsumexp
 L = m + log(l) (the flash-attention residual trick); the backward is
 ONE tiled Pallas kernel a call, the ring path's too
@@ -48,7 +61,7 @@ float32 throughout.
 
 import functools
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -208,18 +221,60 @@ class BlockDiffusion(NamedTuple):
         clean, half x block noised on noised."""
         return self.half * (self.half + self.block)
 
+    @property
+    def span(self) -> int:
+        """The length the kernels' blocks tile: a half."""
+        return self.half
+
+
+class SlidingWindow(NamedTuple):
+    """A causal band over a sequence of ``length`` positions: query i
+    sees keys i - window + 1 .. i, itself among them (a window of
+    ``length`` or more is plain causal attention). ``flash_attention(...,
+    mask=SlidingWindow(length, window))`` runs it through the kernels
+    that run a :class:`BlockDiffusion` call, by a static plan
+    (``_BandPlan``); ``visible`` is the same rule on positions, for
+    ``dense_attention`` and the tests."""
+    length: int
+    window: int
+
+    def visible(self, qpos, kpos):
+        return (kpos <= qpos) & (qpos - kpos < self.window)
+
+    @property
+    def pairs(self) -> int:
+        """Visible (query, key) pairs of one row and head: the first
+        ``window`` queries see 1, 2, .. window keys, every later one
+        ``window``."""
+        w = min(self.window, self.length)
+        return w * (w + 1) // 2 + (self.length - w) * w
+
+    @property
+    def span(self) -> int:
+        """The length the kernels' blocks tile: the sequence."""
+        return self.length
+
+
+# The mask kinds ``flash_attention(..., mask=)`` takes in place of
+# ``causal``. Each has ``visible(qpos, kpos)``, ``pairs`` and ``span``.
+Mask = Union[BlockDiffusion, SlidingWindow]
+
 
 class _Rule(NamedTuple):
-    """What a boundary tile of a block-diffusion call masks, on
-    positions counted from the tile's corner (a block never straddles a
-    tile: ``block`` divides the sub-tile): ``block_causal`` n(k) <=
-    n(q), ``block_strict`` n(k) < n(q), ``block_diagonal`` n(k) ==
-    n(q). ``block`` is a power of two, so the block of a position is
-    its high bits."""
+    """What a boundary tile of a masked call masks, on positions counted
+    from the tile's corner. Of a block-diffusion call (a block never
+    straddles a tile: ``block`` divides the sub-tile): ``block_causal``
+    n(k) <= n(q), ``block_strict`` n(k) < n(q), ``block_diagonal`` n(k)
+    == n(q); ``block`` is a power of two, so the block of a position is
+    its high bits. Of a sliding window's far edge, a whole number of
+    tiles behind the diagonal: ``after``, the key after the query
+    (``block`` 1, unread)."""
     kind: str
     block: int
 
     def visible(self, qpos, kpos):
+        if self.kind == "after":
+            return kpos > qpos
         low = self.block - 1
         if self.kind == "block_causal":
             return (qpos | low) >= kpos
@@ -233,6 +288,8 @@ class _Rule(NamedTuple):
 
     def any_visible(self, q0, q_len, k0, k_len) -> bool:
         q_lo, q_hi, k_lo, k_hi = self._blocks(q0, q_len, k0, k_len)
+        if self.kind == "after":
+            return k_hi > q_lo
         if self.kind == "block_causal":
             return k_lo <= q_hi
         if self.kind == "block_strict":
@@ -241,6 +298,8 @@ class _Rule(NamedTuple):
 
     def any_masked(self, q0, q_len, k0, k_len) -> bool:
         q_lo, q_hi, k_lo, k_hi = self._blocks(q0, q_len, k0, k_len)
+        if self.kind == "after":
+            return k_lo <= q_hi
         if self.kind == "block_causal":
             return k_hi > q_lo
         if self.kind == "block_strict":
@@ -251,8 +310,9 @@ class _Rule(NamedTuple):
 class _Strips(NamedTuple):
     """How a boundary tile is walked: sub-tile row ``i`` multiplies the
     sub-tiles ``starts[i] .. rows[i]`` (none where they are equal) under
-    ``rule``."""
-    rule: _Rule
+    ``rule``: a :class:`_Rule`, or True for the causal diagonal through
+    the tile's corner."""
+    rule: Union[_Rule, bool]
     starts: Tuple[int, ...]
     rows: Tuple[int, ...]
 
@@ -271,6 +331,37 @@ def _strips(rule: _Rule, block: int, sub: int) -> _Strips:
     return _Strips(rule, tuple(starts), tuple(rows))
 
 
+def _diffusion_steps(plan, row, step):
+    """Which of a block-diffusion call's tiles grid step ``step`` of
+    tile row (forward) or key column (backward) ``row`` is, as traced
+    booleans: (the row lies in the noised half, its place in its half,
+    whether the step is the noised tile of the same place, whether it
+    is the clean one)."""
+    n = plan.n
+    noised = row < n
+    place = jnp.where(noised, row, row - n)
+    return noised, place, step == place, step == n + place
+
+
+# A static plan is what the plan kernels (``_fwd_plan_kernel``,
+# ``_bwd_plan_kernel``) run a masked call by, one class a mask kind,
+# each with:
+#   forward_steps(row, step) / backward_steps(column, step): the kinds
+#     of live tile of a q tile row's k steps (a k column's q steps), as
+#     (live, walk, flag): ``live()`` the traced boolean "this step is
+#     one" (a thunk: its operations are emitted where its step is, so a
+#     plan's program is its steps' in order), ``walk`` the boundary
+#     tile's :class:`_Strips` or None for a whole tile with no mask
+#     built, ``flag`` the strips kernels' ``final`` (forward: the row's
+#     last live tile) or ``add`` (backward: the accumulators hold
+#     earlier tiles' parts). A step that is none of them is dead;
+#   kv_tile(i, j) / q_tile(i, j): the index maps, clamped so that a dead
+#     step names a live one's tile and fetches nothing;
+#   tiles: (whole, boundary, skipped); ``walked``: the boundary tiles'
+#     clause of the attention line; ``rows``: the strips ``TilePlan``
+#     shows.
+
+
 class _BlockDiffusionPlan(NamedTuple):
     """The static plan of a :class:`BlockDiffusion` call over a square
     grid of 2n x 2n tiles, n to a half. Query tile row r of a half sees:
@@ -285,6 +376,54 @@ class _BlockDiffusionPlan(NamedTuple):
     own: _Strips
     before: _Strips
     clean: _Strips
+
+    @property
+    def rows(self):
+        return self.clean.rows
+
+    @property
+    def tiles(self) -> Tuple[int, int, int]:
+        n = self.n
+        return n * (n - 1), 3 * n, 4 * n * n - n * (n - 1) - 3 * n
+
+    def walked(self, total, sub_tiles) -> str:
+        n = self.n
+        return (
+            f"{n} noised on their own blocks {self.own.computed}, {n} "
+            f"noised on the clean blocks before {self.before.computed}, "
+            f"{n} clean {self.clean.computed} of {total} {sub_tiles}")
+
+    def forward_steps(self, qi, kb):
+        """A noised q tile steps its state over its own noised tile,
+        the clean tiles before its place whole, and the clean tile of
+        its place under ``before``, where it is finalised; a clean q
+        tile over the clean tiles before it whole and its own under
+        ``clean``."""
+        n = self.n
+        noised, place, on_noised, on_clean = _diffusion_steps(self, qi, kb)
+        return (
+            (lambda: noised & on_noised, self.own, False),
+            (lambda: (kb >= n) & (kb - n < place), None, False),
+            (lambda: noised & on_clean, self.before, True),
+            (lambda: jnp.logical_not(noised) & on_clean, self.clean, True),
+        )
+
+    def backward_steps(self, ki, qt):
+        """A noised key tile is seen by the noised q tile of its place
+        alone (``own`` sets the accumulators). Clean key tile c is seen
+        by noised q tile c under ``before`` (the first: it sets the
+        accumulators), the noised tiles after it whole, clean q tile c
+        under ``clean`` (added) and the clean tiles after it whole."""
+        n = self.n
+        noised, place, on_noised, on_clean = _diffusion_steps(self, ki, qt)
+        clean = jnp.logical_not(noised)
+        return (
+            (lambda: noised & on_noised, self.own, False),
+            (lambda: clean & on_noised, self.before, False),
+            (lambda: clean & (((qt > place) & (qt < n)) | (qt > ki)),
+             None, True),
+            (lambda: clean & on_clean, self.clean, True),
+        )
 
     def kv_tile(self, i, j):
         """The K/V tile step j of query row i names (forward): a
@@ -328,6 +467,89 @@ def _block_diffusion_plan(mask: BlockDiffusion, s_len, block_q, block_k,
           for kind in ("block_diagonal", "block_strict", "block_causal")))
 
 
+class _BandPlan(NamedTuple):
+    """The static plan of a :class:`SlidingWindow` call over a square
+    grid of n x n tiles, the window ``w`` whole tiles wide. Query tile
+    row i sees key tile i - w (where there is one) under ``edge``, the
+    rule "key after query" from the tile's corner; the w - 1 tiles
+    between (or the i before i) whole with no mask built; and tile i
+    under ``diagonal``, the causal walk, where it is finalised. The
+    tiles before the edge and after the diagonal are dead: dead steps
+    on BOTH sides, where a causal grid has them on one."""
+    n: int
+    sub: int
+    w: int
+    diagonal: _Strips
+    edge: _Strips
+
+    @property
+    def rows(self):
+        return self.diagonal.rows
+
+    @property
+    def tiles(self) -> Tuple[int, int, int]:
+        n, w = self.n, self.w
+        whole = sum(min(i, w - 1) for i in range(n))
+        boundary = n + max(0, n - w)
+        return whole, boundary, n * n - whole - boundary
+
+    def walked(self, total, sub_tiles) -> str:
+        return (
+            f"{self.n} diagonal, {max(0, self.n - self.w)} at the "
+            f"window's edge, {self.diagonal.computed} of {total} "
+            f"{sub_tiles}")
+
+    def kv_tile(self, i, j):
+        """The K/V tile step j of query row i names (forward)."""
+        return jnp.clip(j, jnp.maximum(i - self.w, 0), i)
+
+    def q_tile(self, i, j):
+        """The q/do/lse/delta tile step j of key column i names
+        (backward): the diagonal's, the w - 1 after it, the edge's."""
+        return jnp.clip(j, i, jnp.minimum(i + self.w, self.n - 1))
+
+    def forward_steps(self, qi, kb):
+        w = self.w
+        return (
+            (lambda: kb == qi - w, self.edge, False),
+            (lambda: (kb > qi - w) & (kb < qi), None, False),
+            (lambda: kb == qi, self.diagonal, True),
+        )
+
+    def backward_steps(self, ki, qt):
+        """The diagonal tile is the first that sees a key tile: it sets
+        the accumulators, the others add."""
+        w = self.w
+        return (
+            (lambda: qt == ki, self.diagonal, False),
+            (lambda: (qt > ki) & (qt < ki + w), None, True),
+            (lambda: qt == ki + w, self.edge, True),
+        )
+
+
+def _band_plan(mask: SlidingWindow, s_len, block_q, block_k,
+               sub=None) -> Optional[_BandPlan]:
+    """The plan of a sliding-window call, or None where the kernels do
+    not tile it: the sequence is the mask's, the tiles are square, of at
+    least two sub-tiles each way, and the window is a whole number of
+    them (then every boundary is known at trace time: the far edge runs
+    corner to corner through tile i - w as the diagonal does through
+    tile i)."""
+    sub = sub or SUB_TILE
+    if not (
+        s_len == mask.length and block_q == block_k
+        and s_len % block_q == 0 and block_q % sub == 0
+        and block_q >= 2 * sub and mask.window >= block_q
+        and mask.window % block_q == 0
+    ):
+        return None
+    n_sub = block_q // sub
+    return _BandPlan(
+        s_len // block_q, sub, mask.window // block_q,
+        _Strips(True, (0,) * n_sub, tuple(range(1, n_sub + 1))),
+        _strips(_Rule("after", 1), block_q, sub))
+
+
 # Which backward a trace took is static, so its counter is this clause
 # of the line ``log_traced`` prints (every ``TilePlan.describe`` ends
 # with it): S, dP, dQ, dK, dV once a live tile, in one ``pallas_call``.
@@ -341,8 +563,9 @@ class TilePlan(NamedTuple):
     sub-tile: ``rows == (1,)`` and ``sub_q, sub_k`` the block itself.
     Over a ``grid`` of several tiles ``rows`` is the plan of the tiles
     on the diagonal (``tiles`` counts them and the others). Under a
-    block-diffusion mask ``diffusion`` is the call's plan and ``rows``
-    its clean boundary tiles'."""
+    ``mask``, ``plan`` is the call's static plan and ``rows`` its
+    causal boundary tiles' (a block-diffusion call's clean ones, a
+    sliding window's diagonal ones)."""
     block_q: int
     block_k: int
     sub_q: int
@@ -351,7 +574,7 @@ class TilePlan(NamedTuple):
     grid: Tuple[int, int] = (1, 1)
     # Query heads that read one key/value head (``_kv_row``).
     group: int = 1
-    diffusion: Optional[_BlockDiffusionPlan] = None
+    plan: Union[_BlockDiffusionPlan, _BandPlan, None] = None
 
     @property
     def computed(self) -> int:
@@ -370,9 +593,8 @@ class TilePlan(NamedTuple):
         grid that is not walked visits every tile whole (what its
         traced compare predicates out is not known here)."""
         n_q, n_k = self.grid
-        if self.diffusion is not None:
-            n = self.diffusion.n
-            return n * (n - 1), 3 * n, n_q * n_k - n * (n - 1) - 3 * n
+        if self.plan is not None:
+            return self.plan.tiles
         if self.rows == (1,):
             return n_q * n_k, 0, 0
         off_diagonal = n_q * (n_k - 1) // 2
@@ -386,16 +608,13 @@ class TilePlan(NamedTuple):
             f"; one key/value head read in place by {self.group} query "
             "heads, dk/dv summed over them" if self.group > 1 else ""
         ) + BACKWARD_FORM
-        if self.diffusion is not None:
-            plan, n = self.diffusion, self.diffusion.n
+        if self.plan is not None:
             whole, boundary, skipped = self.tiles
             return (
                 f"grid {self.grid[0]}x{self.grid[1]} of {blocks}: {whole} "
                 f"tile{'s' if whole != 1 else ''} whole and unmasked, "
-                f"{boundary} boundary tiles walked ({n} noised on their "
-                f"own blocks {plan.own.computed}, {n} noised on the clean "
-                f"blocks before {plan.before.computed}, {n} clean "
-                f"{plan.clean.computed} of {self.total} {sub_tiles}), "
+                f"{boundary} boundary tiles walked "
+                f"({self.plan.walked(self.total, sub_tiles)}), "
                 f"{skipped} skipped{shared}"
             )
         if self.grid == (1, 1) or self.rows == (1,):
@@ -454,14 +673,14 @@ def _one_tile(sq, sk, block_q, block_k):
 def tile_plan(sq, sk, causal=True, block_q=0, block_k=0, q_offset=0,
               k_offset=0, sub=None, group=1, mask=None) -> TilePlan:
     """What a kernel call with these arguments multiplies (the kernels
-    ask ``_walk`` the same question, or ``_block_diffusion_plan`` under
-    a ``mask``): for the line ``log_traced`` prints and for the tests.
+    ask ``_walk`` the same question, or ``_mask_plan`` under a
+    ``mask``): for the line ``log_traced`` prints and for the tests.
     Traced offsets are anything that is not an int."""
     sub = sub or SUB_TILE
     if mask is not None:
-        block_q, block_k = _blocks(mask.half, mask.half, block_q, block_k)
+        block_q, block_k = _blocks(mask.span, mask.span, block_q, block_k)
         plan = _mask_plan(mask, sq, block_q, block_k, sub)
-        return TilePlan(block_q, block_k, sub, sub, plan.clean.rows,
+        return TilePlan(block_q, block_k, sub, sub, plan.rows,
                         (sq // block_q, sk // block_k), group, plan)
     block_q, block_k = _blocks(sq, sk, block_q, block_k)
     grid = (sq // block_q, sk // block_k)
@@ -686,8 +905,8 @@ def _fwd_strips_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, *, rows, sub,
     recurrence from it. Either way the diagonal tile is the last that
     contributes to its queries, so the strip is finalised here.
 
-    A boundary tile of a block-diffusion call (``_fwd_diffusion_kernel``)
-    is walked likewise, under its ``rule`` and from sub-tile
+    A boundary tile of a masked call (``_fwd_plan_kernel``) is walked
+    likewise, under its ``rule`` and from sub-tile
     ``starts[i]`` on; ``final`` False (a noised tile on its own blocks:
     clean tiles follow) leaves the stepped state in ``carried``."""
     for i, n_k in enumerate(rows):
@@ -765,31 +984,18 @@ def _fwd_grid_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc,
         )
 
 
-def _diffusion_steps(plan, row, step):
-    """Which of a block-diffusion call's tiles grid step ``step`` of
-    tile row (forward) or key column (backward) ``row`` is, as traced
-    booleans: (the row lies in the noised half, its place in its half,
-    whether the step is the noised tile of the same place, whether it
-    is the clean one)."""
-    n = plan.n
-    noised = row < n
-    place = jnp.where(noised, row, row - n)
-    return noised, place, step == place, step == n + place
-
-
-def _fwd_diffusion_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc,
-                          o_acc, *, plan, scale):
+def _fwd_plan_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc,
+                     o_acc, *, plan, scale):
     """One (batch*head, q-block, k-block) grid step of a call under a
-    :class:`BlockDiffusion` mask (``_BlockDiffusionPlan``). A noised q
-    tile steps its state over its own noised tile (the blocks on the
-    diagonal), the clean tiles before its place whole with no mask
-    built, and the clean tile of its place under ``before``, where it is
-    finalised; a clean q tile over the clean tiles before it whole and
-    its own under ``clean``. Every other step is dead: predicated out,
-    and ``plan.kv_tile`` names no new block for it."""
+    mask with a static plan (``_BlockDiffusionPlan``, ``_BandPlan``):
+    the q tile steps its softmax state over the step's tile if
+    ``plan.forward_steps`` says it is live, a boundary tile walked in
+    strips under its rule (the row's last is finalised there), a whole
+    one multiplied with no mask built. Every other step is dead:
+    predicated out, and ``plan.kv_tile`` names no new block for it."""
     qi = pl.program_id(1)
     kb = pl.program_id(2)
-    noised, place, on_noised, on_clean = _diffusion_steps(plan, qi, kb)
+    steps = plan.forward_steps(qi, kb)
     carried = (m_acc, l_acc, o_acc)
 
     def strips(walk, final):
@@ -799,24 +1005,30 @@ def _fwd_diffusion_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_acc, l_acc,
             starts=walk.starts, rule=walk.rule, final=final,
         )
 
+    def whole():
+        _scratch_tile_update(
+            q_ref, k_ref, v_ref, m_acc, l_acc, o_acc, 0, 0,
+            block_k=k_ref.shape[1], causal=False, scale=scale,
+        )
+
     @pl.when(kb == 0)
     def _init():
         m_acc[:] = jnp.full_like(m_acc, _NEG_INF)
         l_acc[:] = jnp.zeros_like(l_acc)
         o_acc[:] = jnp.zeros_like(o_acc)
 
-    pl.when(noised & on_noised)(lambda: strips(plan.own, False))
+    for live, walk, final in steps:
+        pl.when(live())(
+            whole if walk is None
+            else functools.partial(strips, walk, final))
 
-    @pl.when((kb >= plan.n) & (kb - plan.n < place))
-    def _whole():
-        _scratch_tile_update(
-            q_ref, k_ref, v_ref, m_acc, l_acc, o_acc, 0, 0,
-            block_k=k_ref.shape[1], causal=False, scale=scale,
-        )
 
-    pl.when(noised & on_clean)(lambda: strips(plan.before, True))
-    pl.when(jnp.logical_not(noised) & on_clean)(
-        lambda: strips(plan.clean, True))
+def _plan_of(mask, s_len, block_q, block_k, sub=None):
+    """``mask``'s static plan for these blocks, or None where the
+    kernels have none."""
+    planner = (_band_plan if isinstance(mask, SlidingWindow)
+               else _block_diffusion_plan)
+    return planner(mask, s_len, block_q, block_k, sub)
 
 
 def _mask_plan(mask, s_len, block_q, block_k, sub=None):
@@ -824,7 +1036,7 @@ def _mask_plan(mask, s_len, block_q, block_k, sub=None):
     whole sequence against itself); None without a mask."""
     if mask is None:
         return None
-    plan = _block_diffusion_plan(mask, s_len, block_q, block_k, sub)
+    plan = _plan_of(mask, s_len, block_q, block_k, sub)
     if plan is None:
         raise ValueError(
             f"flash_attention: {mask} over {s_len} positions in blocks "
@@ -835,7 +1047,7 @@ def _mask_plan(mask, s_len, block_q, block_k, sub=None):
 
 def _flash_forward(q, k, v, causal: bool, scale: float, block_q: int,
                    block_k: int, interpret: bool,
-                   mask: Optional[BlockDiffusion] = None):
+                   mask: Optional[Mask] = None):
     """q,k: (BH, S, D), v: (BH, S, Dv) -> (o (BH,S,Dv), L (BH,S,1))."""
     s_len = q.shape[1]
     if s_len % block_q or s_len % block_k:
@@ -876,9 +1088,9 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
     """The forward ``pallas_call``. ``rows`` is the plan of ``_walk``:
     given and the sequence one tile, the strips kernel; given over a
     grid of tiles, the grid kernel that walks the diagonal tiles; None,
-    the grid of whole tiles. Under a ``mask``, ``plan`` is its
-    ``_BlockDiffusionPlan`` and the grid kernel that mask's (``causal``
-    and ``rows`` are then unread)."""
+    the grid of whole tiles. Under a ``mask``, ``plan`` is its static
+    plan and the grid kernel the one that runs plans (``causal`` and
+    ``rows`` are then unread)."""
     bh, s_len, d = q.shape
     dv = v.shape[2]
     if rows is not None and _one_tile(s_len, s_len, block_q, block_k):
@@ -907,7 +1119,7 @@ def _forward_call(q, k, v, causal, scale, block_q, block_k, rows,
         )(q, k, v)
     if plan is not None:
         kernel = functools.partial(
-            _fwd_diffusion_kernel, plan=plan, scale=scale)
+            _fwd_plan_kernel, plan=plan, scale=scale)
         kv_tile = plan.kv_tile
     elif rows is not None:
         kernel = functools.partial(
@@ -1390,23 +1602,19 @@ def _bwd_grid_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_acc[:]
 
 
-def _bwd_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                          lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                          dk_acc, dv_acc, *, plan, scale):
-    """``_bwd_kernel`` under a :class:`BlockDiffusion` mask. A noised
-    key tile is seen by the noised q tile of its place alone (``own``
-    sets the accumulators; every other step is dead). Clean key tile c
-    is seen by noised q tile c under ``before`` (the first: it sets the
-    accumulators), the noised tiles after it whole, clean q tile c under
-    ``clean`` (added) and the clean tiles after it whole. At a live
-    step the q tile is the step's own (``plan.q_tile``), and its part of
-    dq goes to that tile's rows."""
+def _bwd_plan_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
+                     lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_acc,
+                     dv_acc, *, plan, scale):
+    """``_bwd_kernel`` under a mask with a static plan: the key tile's
+    accumulators take the part of every q tile ``plan.backward_steps``
+    says is live, a boundary tile's by its strips (the first live one
+    sets them, a later one adds), a whole one's with no mask built. At
+    a live step the q tile is the step's own (``plan.q_tile``), and its
+    part of dq goes to that tile's rows."""
     del qoff_ref, koff_ref
     ki = pl.program_id(1)
     qt = pl.program_id(2)
-    n = plan.n
-    noised, place, on_noised, on_clean = _diffusion_steps(plan, ki, qt)
-    clean = jnp.logical_not(noised)
+    steps = plan.backward_steps(ki, qt)
     _zero_dq_tile(dq_ref, ki, qt, q_ref.shape[1])
 
     def strips(walk, add):
@@ -1417,17 +1625,15 @@ def _bwd_diffusion_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             add=add, q_tile=qt,
         )
 
-    pl.when(noised & on_noised)(lambda: strips(plan.own, False))
-    pl.when(clean & on_noised)(lambda: strips(plan.before, False))
-
-    @pl.when(clean & (((qt > place) & (qt < n)) | (qt > ki)))
-    def _whole():
+    def whole():
         _bwd_tile_update(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             dk_acc, dv_acc, qt, 0, 0, False, scale,
         )
 
-    pl.when(clean & on_clean)(lambda: strips(plan.clean, True))
+    for live, walk, add in steps:
+        pl.when(live())(
+            whole if walk is None else functools.partial(strips, walk, add))
 
     @pl.when(qt == pl.num_programs(2) - 1)
     def _flush():
@@ -1501,11 +1707,11 @@ def _tiles_grads(q, k_chunk, v_chunk, do, lse, delta, q_offset, k_offset,
     tiles, the offsets traced (scalar-prefetched), a tile strictly above
     the diagonal predicated out. ``rows`` the plan of ``_walk`` over
     several tiles: the diagonal tiles walked, dead steps naming the
-    diagonal's tiles. Under a ``mask``, ``plan`` is its
-    ``_BlockDiffusionPlan``: that mask's kernel and index map."""
+    diagonal's tiles. Under a ``mask``, ``plan`` is its static plan:
+    the kernel that runs plans and the plan's index map."""
     if plan is not None:
         kernel = functools.partial(
-            _bwd_diffusion_kernel, plan=plan, scale=scale)
+            _bwd_plan_kernel, plan=plan, scale=scale)
         q_tile = plan.q_tile
     elif rows is None:
         kernel = functools.partial(_bwd_kernel, causal=causal, scale=scale)
@@ -1582,7 +1788,7 @@ def flash_chunk_grads(
     causal: bool = True, scale: Optional[float] = None,
     block_q: int = 0, block_k: int = 0,
     interpret: bool = False,
-    mask: Optional[BlockDiffusion] = None,
+    mask: Optional[Mask] = None,
 ):
     """Backward of one attention chunk pairing, fully tiled.
 
@@ -1681,27 +1887,28 @@ def _blocks(sq: int, sk: int, block_q: int, block_k: int):
 
 
 def supports(q_shape, block_q: int = 0, block_k: int = 0,
-             mask: Optional[BlockDiffusion] = None) -> bool:
+             mask: Optional[Mask] = None) -> bool:
     """Static shape gate — callers fall back to dense otherwise. With
     default blocks (0), S must admit a lane-aligned tiling block
     (``_auto_block``); explicit blocks keep the raw divisibility rule
-    (tests drive small interpret-mode tiles). Under a ``mask`` the
-    blocks tile a half, and the kernels need a plan for it
-    (``_block_diffusion_plan``) and the row's dq resident in the
-    backward."""
+    (tests drive small interpret-mode tiles). Under a ``mask`` (a
+    :class:`BlockDiffusion` or a :class:`SlidingWindow`) the blocks tile
+    its ``span`` (a half; the sequence), and the kernels need a static
+    plan for it (``_block_diffusion_plan``; ``_band_plan``: the window a
+    whole number of blocks) and the row's dq resident in the backward."""
     s_len = q_shape[1]
     if s_len % 8:
         return False
     if mask is not None:
         if not (block_q or block_k) and not (
-                _auto_block(mask.half, DEFAULT_BLOCK_Q)
-                and _auto_block(mask.half, DEFAULT_BLOCK_K)):
+                _auto_block(mask.span, DEFAULT_BLOCK_Q)
+                and _auto_block(mask.span, DEFAULT_BLOCK_K)):
             return False
-        block_q, block_k = _blocks(mask.half, mask.half, block_q, block_k)
+        block_q, block_k = _blocks(mask.span, mask.span, block_q, block_k)
         # The backward keeps the whole row's dq resident, and a masked
         # call cannot be cut into runs (``flash_chunk_grads``).
         return s_len <= _resident_rows(q_shape[3], block_q) and (
-            _block_diffusion_plan(mask, s_len, block_q, block_k) is not None)
+            _plan_of(mask, s_len, block_q, block_k) is not None)
     if not block_q and not block_k:
         return (
             _auto_block(s_len, DEFAULT_BLOCK_Q) > 0
@@ -1721,7 +1928,7 @@ def flash_attention(
     block_q: int = 0,
     block_k: int = 0,
     interpret: bool = False,
-    mask: Optional[BlockDiffusion] = None,
+    mask: Optional[Mask] = None,
 ):
     """Fused attention. q: (B, S, H, D); k: (B, S, Hkv, D); v: (B, S,
     Hkv, Dv), whose head size may differ from q's and k's (latent
@@ -1729,11 +1936,13 @@ def flash_attention(
     query head h reads key/value head ``h // (H / Hkv)``, in place
     (``_kv_row``).
 
-    ``mask``: a :class:`BlockDiffusion` over S = 2 x half in place of
-    ``causal`` (which is then not read): the same custom VJP, the same
-    kernels' tile mathematics, one call over the S x S grid whose
-    dead tiles cost a predicated-out step each and fetch nothing
-    (``_BlockDiffusionPlan``). The blocks tile a half.
+    ``mask``, in place of ``causal`` (which is then not read): a
+    :class:`BlockDiffusion` over S = 2 x half (the blocks tile a half)
+    or a :class:`SlidingWindow` over S = length (a causal band; the
+    window a whole number of blocks). Either way the same custom VJP,
+    the same kernels' tile mathematics, one call over the S x S grid by
+    a static plan (``_BlockDiffusionPlan``, ``_BandPlan``) whose dead
+    tiles cost a predicated-out step each and fetch nothing.
 
     ``block_q/block_k`` 0 = auto: the largest lane-aligned default-or-
     smaller block that tiles S (``_auto_block`` — gate callers check
@@ -1750,7 +1959,7 @@ def flash_attention(
             f"{v.shape[2]} value heads")
     if mask is not None:
         causal = False
-        block_q, block_k = _blocks(mask.half, mask.half, block_q, block_k)
+        block_q, block_k = _blocks(mask.span, mask.span, block_q, block_k)
     else:
         block_q, block_k = _blocks(s_len, s_len, block_q, block_k)
 

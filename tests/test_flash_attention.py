@@ -687,7 +687,7 @@ def test_a_backward_traces_one_kernel_body_and_two_calls_a_gradient(
     jaxpr of a gradient holds two calls a layer: forward, backward."""
     bodies = {"one_tile": "_bwd_strips_kernel", "grid": "_bwd_grid_kernel",
               "whole_tiles": "_bwd_kernel",
-              "block_diffusion": "_bwd_diffusion_kernel"}
+              "block_diffusion": "_bwd_plan_kernel"}
     assert sorted(bodies.values()) == sorted(
         name for name in vars(flash)
         if name.startswith(("_bwd_", "_dq_", "_dkv_"))
@@ -1021,8 +1021,8 @@ def test_block_diffusion_plan_counts_and_covers_every_visible_pair():
     holds a masked pair."""
     plan = flash.tile_plan(8192, 8192, mask=flash.BlockDiffusion(4096, 4))
     assert plan.grid == (8, 8) and plan.tiles == (12, 12, 40)
-    assert (plan.diffusion.own.computed, plan.diffusion.before.computed,
-            plan.diffusion.clean.computed, plan.total) == (4, 10, 10, 16)
+    assert (plan.plan.own.computed, plan.plan.before.computed,
+            plan.plan.clean.computed, plan.total) == (4, 10, 10, 16)
     text = plan.describe()
     assert ("12 tiles whole and unmasked, 12 boundary tiles walked (4 "
             "noised on their own blocks 4, 4 noised on the clean blocks "
@@ -1033,7 +1033,7 @@ def test_block_diffusion_plan_counts_and_covers_every_visible_pair():
     half, block, tile, sub = 96, 4, 32, 8
     mask = flash.BlockDiffusion(half, block)
     small = flash.tile_plan(2 * half, 2 * half, block_q=tile, block_k=tile,
-                            sub=sub, mask=mask).diffusion
+                            sub=sub, mask=mask).plan
     n, per = small.n, tile // sub
     covered = np.zeros((2 * half, 2 * half), bool)
     whole = np.zeros_like(covered)
@@ -1088,7 +1088,7 @@ def test_block_diffusion_fetches_nothing_of_the_dead_quadrant(monkeypatch):
     np.testing.assert_array_equal(
         jax.grad(loss)(q, k_bad, v_bad)[:, half:], want_dq[:, half:])
     plan = flash.tile_plan(2 * half, 2 * half, block_q=tile, block_k=tile,
-                           mask=mask).diffusion
+                           mask=mask).plan
     n = plan.n
     for i in range(2 * n):
         live = ({i} | set(range(n, n + i + 1))) if i < n else set(
@@ -1162,7 +1162,7 @@ def test_causal_and_unmasked_calls_trace_the_kernels_they_traced(
                 if causal and s == block else
                 GRID_KERNELS if causal else ("_fwd_kernel", "_bwd_kernel"))
     counts = _count_traces(
-        monkeypatch, "_fwd_diffusion_kernel", "_bwd_diffusion_kernel",
+        monkeypatch, "_fwd_plan_kernel", "_bwd_plan_kernel",
         *expected)
     asked = []
     real = flash._visible
@@ -1181,3 +1181,196 @@ def test_causal_and_unmasked_calls_trace_the_kernels_they_traced(
     jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
     assert {name for name, n in counts.items() if n} == set(expected)
     assert all(kind is True for kind in asked) and bool(asked) == causal
+
+
+# -------------------------------------------------- the sliding window
+
+def _window_inputs(s, heads, group, d, dv=None, seed=0):
+    rng = np.random.RandomState(seed)
+    mk = lambda h, width: jnp.asarray(  # noqa: E731
+        rng.randn(1, s, h, width), jnp.float32) * 0.5
+    return mk(heads, d), mk(heads // group, d), mk(heads // group, dv or d)
+
+
+def test_sliding_window_rule_and_pairs():
+    """Query i sees keys i - window + 1 .. i; the pairs of the cell's
+    two kinds of layer by hand."""
+    mask = flash.SlidingWindow(24, 8)
+    seen = np.asarray(mask.visible(
+        jnp.arange(24)[:, None], jnp.arange(24)[None, :]))
+    for i in range(24):
+        for j in range(24):
+            assert seen[i, j] == (i - 7 <= j <= i), (i, j)
+    assert seen.sum() == mask.pairs == 8 * 9 // 2 + 16 * 8
+    assert flash.SlidingWindow(16384, 4096).pairs == 58722304
+    assert flash.SlidingWindow(16384, 16384).pairs == 134225920
+    assert flash.SlidingWindow(64, 4096).pairs == 64 * 65 // 2
+
+
+@pytest.mark.parametrize("heads,group", [(7, 7), (4, 1)],
+                         ids=["group-7", "group-1"])
+@pytest.mark.parametrize(
+    "s,tile,sub,window",
+    [(128, 32, 8, 32), (128, 32, 8, 64), (128, 32, 8, 128),
+     (96, 32, 16, 64), (64, 32, 16, 32)],
+    ids=["grid-4-w1", "grid-4-w2", "grid-4-all", "grid-3-w2", "grid-2-w1"])
+def test_sliding_window_kernels_match_dense(monkeypatch, s, tile, sub,
+                                            window, heads, group):
+    """Forward and dq, dk, dv under the band against dense attention
+    under the same ``visible``: a grid of several tiles with a window
+    of 1, 2 and all tiles, a group of 7 query heads to a key/value head
+    and of 1, v's head size unequal to q's and k's."""
+    monkeypatch.setattr(flash, "SUB_TILE", sub)
+    mask = flash.SlidingWindow(s, window)
+    q, k, v = _window_inputs(s, heads, group, 16, dv=8)
+    assert supports(q.shape, tile, tile, mask=mask)
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, block_q=tile, block_k=tile,
+                               interpret=True, mask=mask)
+
+    got = _attend_and_grads(q, k, v, kernels)
+    want = _attend_and_grads(
+        q, k, v, lambda q, k, v: _dense_under(mask, q, k, v))
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-4)
+
+
+def test_a_window_of_all_tiles_is_the_causal_grid_to_the_bit(monkeypatch):
+    """The band's diagonal tiles are walked as the causal grid walks
+    them and its whole tiles multiplied as the grid's: with no far edge
+    inside the sequence the two give the same bits, o and gradients."""
+    monkeypatch.setattr(flash, "SUB_TILE", 8)
+    q, k, v = _window_inputs(96, 4, 2, 16, seed=5)
+    band = _attend_and_grads(q, k, v, lambda q, k, v: flash_attention(
+        q, k, v, block_q=32, block_k=32, interpret=True,
+        mask=flash.SlidingWindow(96, 96)))
+    grid = _attend_and_grads(q, k, v, lambda q, k, v: flash_attention(
+        q, k, v, block_q=32, block_k=32, interpret=True))
+    for a, b in zip(band, grid):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_sliding_window_plan_counts_and_covers_every_visible_pair():
+    """At the cell's shape, from shapes alone: 42 tiles whole, 28
+    boundary (16 diagonal, 12 at the window's edge, 10 of 16 sub-tiles
+    each), 186 dead; and at a small one every visible pair lies in a
+    tile the plan multiplies, no whole tile holds a masked pair, and a
+    dead step names a live step's tile."""
+    plan = flash.tile_plan(16384, 16384, group=7,
+                           mask=flash.SlidingWindow(16384, 4096))
+    assert plan.grid == (16, 16) and plan.tiles == (42, 28, 186)
+    assert (plan.plan.diagonal.computed, plan.plan.edge.computed,
+            plan.total) == (10, 10, 16)
+    assert plan.describe() == (
+        "grid 16x16 of blocks 1024x1024: 42 tiles whole and unmasked, 28 "
+        "boundary tiles walked (16 diagonal, 12 at the window's edge, 10 "
+        "of 16 sub-tiles 256x256), 186 skipped; one key/value head read "
+        "in place by 7 query heads, dk/dv summed over them; backward: one "
+        "kernel, 5 products a tile")
+    # A global layer of the same cell: the causal grid.
+    assert flash.tile_plan(16384, 16384).tiles == (120, 16, 120)
+    assert supports((1, 16384, 28, 128),
+                    mask=flash.SlidingWindow(16384, 4096))
+    s, tile, sub, window = 160, 32, 8, 64
+    mask = flash.SlidingWindow(s, window)
+    small = flash.tile_plan(s, s, block_q=tile, block_k=tile, sub=sub,
+                            mask=mask).plan
+    n, w, per = small.n, small.w, tile // sub
+    assert (n, w) == (5, 2)
+    covered = np.zeros((s, s), bool)
+    whole = np.zeros_like(covered)
+    for qi in range(n):
+        live = []
+        for kt in range(n):
+            rows = slice(qi * tile, (qi + 1) * tile)
+            cols = slice(kt * tile, (kt + 1) * tile)
+            if qi - w < kt < qi:
+                covered[rows, cols] = whole[rows, cols] = True
+                live.append(kt)
+                continue
+            walk = (small.diagonal if kt == qi else
+                    small.edge if kt == qi - w else None)
+            if walk is None:
+                continue
+            live.append(kt)
+            for i in range(per):
+                for j in range(walk.starts[i], walk.rows[i]):
+                    covered[qi * tile + i * sub:qi * tile + (i + 1) * sub,
+                            kt * tile + j * sub:kt * tile + (j + 1) * sub
+                            ] = True
+        named = [int(small.kv_tile(jnp.int32(qi), jnp.int32(j)))
+                 for j in range(n)]
+        assert set(named) == set(live) and all(named[j] == j for j in live)
+        assert sum(a != b for a, b in zip(named, named[1:])) == len(live) - 1
+        # The backward: key column qi is seen by q tiles qi .. qi + w.
+        seen_by = [t for t in range(qi, qi + w + 1) if t < n]
+        named = [int(small.q_tile(jnp.int32(qi), jnp.int32(j)))
+                 for j in range(n)]
+        assert set(named) == set(seen_by)
+        assert all(named[j] == j for j in seen_by)
+    seen = np.asarray(mask.visible(
+        jnp.arange(s)[:, None], jnp.arange(s)[None, :]))
+    assert not (seen & ~covered).any()
+    assert not (whole & ~seen).any()
+    assert small.tiles == (whole.sum() // tile ** 2, n + n - w,
+                           n * n - whole.sum() // tile ** 2 - 2 * n + w)
+
+
+def test_sliding_window_shapes_without_a_plan_are_refused(monkeypatch):
+    monkeypatch.setattr(flash, "SUB_TILE", 8)
+    q, k, v = _window_inputs(128, 2, 1, 16)
+    assert supports(q.shape, 32, 32, mask=flash.SlidingWindow(128, 64))
+    for why, bad, blocks in (
+            ("a window that is no multiple of the block",
+             flash.SlidingWindow(128, 48), (32, 32)),
+            ("a window under a block", flash.SlidingWindow(128, 16),
+             (32, 32)),
+            ("another sequence's mask", flash.SlidingWindow(64, 32),
+             (32, 32)),
+            ("a tile under two sub-tiles", flash.SlidingWindow(128, 64),
+             (8, 8)),
+            ("tiles that are not square", flash.SlidingWindow(128, 64),
+             (32, 64))):
+        assert not supports(q.shape, *blocks, mask=bad), why
+        with pytest.raises(ValueError):
+            flash_attention(q, k, v, block_q=blocks[0], block_k=blocks[1],
+                            interpret=True, mask=bad)
+    # The dq row of a masked call is resident: S 16,384 at D 128 sits
+    # on the limit, twice that is past it.
+    assert supports((1, 16384, 28, 128),
+                    mask=flash.SlidingWindow(16384, 4096))
+    assert not supports((1, 32768, 28, 128),
+                        mask=flash.SlidingWindow(32768, 4096))
+
+
+def test_sliding_window_cost_counts_the_visible_pairs():
+    mask = flash.SlidingWindow(16384, 4096)
+    cost = flash._cost(28, 16384, 16384, 128, 128, False,
+                       [(2, 16384, 128, 2)], mask=mask)
+    assert cost.flops == 2 * 28 * (128 + 128) * mask.pairs
+    assert cost.transcendentals == 28 * mask.pairs
+
+
+def test_both_masks_trace_the_one_pair_of_plan_kernels(monkeypatch):
+    """No kernel body of the band's own: a block-diffusion call and a
+    sliding-window call trace ``_fwd_plan_kernel`` and
+    ``_bwd_plan_kernel`` (and the strips bodies they walk a boundary
+    tile by) and nothing else; the file has nine bodies."""
+    monkeypatch.setattr(flash, "SUB_TILE", 8)
+    bodies = [name for name in vars(flash)
+              if name.endswith("_kernel") and callable(getattr(flash, name))]
+    assert len(bodies) == 9, bodies
+    counts = _count_traces(monkeypatch, *bodies)
+    # A head size no other test of this file traces: a shared trace is
+    # cached.
+    for mask in (flash.SlidingWindow(96, 48), flash.BlockDiffusion(48, 4)):
+        q, k, v = _window_inputs(96, 3, 3, 24, seed=9)
+        jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, block_q=48, block_k=48, interpret=True, mask=mask)),
+            argnums=(0, 1, 2)))(q, k, v)
+    # The strips bodies walk the plan kernels' boundary tiles.
+    assert {name for name, n in counts.items() if n} == {
+        "_fwd_plan_kernel", "_bwd_plan_kernel", "_fwd_strips_kernel",
+        "_bwd_strips_kernel"}

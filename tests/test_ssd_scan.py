@@ -219,6 +219,50 @@ def test_block_diffusion_attention_compiles_for_a_described_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "global"])
+def test_window_and_global_attention_compile_for_a_described_v5e(
+        one_chip, window):
+    """``smallthinker_ep8_steady``'s two kinds of attention layer
+    through the TPU's compiler, no chip attached (a compile that passes
+    is not a chip run): 28 query heads over 4 key/value heads of 128 (a
+    group of 7), one row of 16,384 positions, under the band of 4,096
+    (the plan kernels; the dq row of 8 MiB sits on the resident limit)
+    and plain causal (the grid kernels). Two kernels a layer, and no S^2
+    score tensor among the program's buffers."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from elasticdl_tpu.ops.flash_attention import (
+        SlidingWindow,
+        flash_attention,
+        supports,
+    )
+
+    s, h, hkv, d = 16384, 28, 4, 128
+    shaped = lambda heads: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, s, heads, d), jnp.bfloat16, sharding=one_chip)
+    mask = window and SlidingWindow(s, window)
+    assert supports((1, s, h, d), mask=mask)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, mask=mask).astype(
+            jnp.float32))
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2))).lower(
+                shaped(h), shaped(hkv), shaped(hkv)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    # One head's scores would be 1.07 GB in float32; dk and dv leave the
+    # kernel a query head each (2 x 235 MB, float32) beside dq.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 @pytest.mark.parametrize(
     "bh,bkv,s,d,dv,half",
     [(128, 128, 1024, 64, 64, None), (128, 128, 4096, 192, 128, None),
