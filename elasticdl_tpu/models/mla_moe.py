@@ -20,11 +20,13 @@ the losses, and nothing else. Pre-RMSNorm blocks, no biases:
   sorted by expert, grouped matrix products over the held groups, the
   weighted rows gathered back. What absent experts would add is left
   out. The held experts own ``n_held / router_width`` of the router, so
-  the work runs over a static bound of that share of the ``T*k``
-  token-choices times ``SLACK`` (:func:`rows_bound`), not over all of
-  them; a step's layer whose held rows pass the bound runs over every
-  token-choice instead, chosen on the device by the traced count
-  (``moe_overflow_layers`` counts those), so no row is ever dropped.
+  the work runs over a static bound of rows, not over all ``T*k``
+  token-choices: a ladder of static sizes (:func:`rows_ladder`: that
+  share times ``RUNG_FACTORS``, then ``T*k`` itself), the smallest
+  rung that holds a step's held rows chosen on the device by the traced
+  count (``moe_bound_rows`` counts the rung's rows; a layer whose held
+  rows pass every rung runs over every token-choice and
+  ``moe_overflow_layers`` counts it), so no row is ever dropped.
   Under a mesh with an ``ep`` axis each member holds ``n_held / ep`` of
   them, bounds its own share, and the parts are summed over ``ep``. The
   selection bias only selects; what the backward pass returns for it is
@@ -291,43 +293,53 @@ SCORINGS = {
 }
 
 
-# How far over its expected rows a member's static row bound reaches
-# (:func:`rows_bound`). The held experts own ``n / router_width`` of the
-# router and get that share of the ``T*k`` token-choices when the
-# routing is balanced; a step's layer whose held rows pass the bound
-# runs over all ``T*k`` instead (exact, and as slow as before the
-# bound), so the factor has to clear what the cells' routing reaches
-# and no more. Readings (a v5e; PERF.md, PR 37, call 1), layers a step
-# that overflowed at 2: ``sdar_ep8_steady`` (98-114k rows a step over 6
-# layers, bound 32,768 a layer) and ``nemotron3n_ep16_steady`` (21.6-
-# 29.6k over 4, bound 12,288) none in 3 seeds each, 400 and 384 steps;
-# ``joyai_ep16_steady`` (bound 16,384 a layer) 1 or 2 of its 5 layers
-# in EVERY step of seed 3000000011 (44.8-70.0k rows a step, the
-# fullest expert of each layer 31-38k together: tokens without context
-# crowd one or two held experts of a layer, and one expert can hold all
-# 16,384 tokens) and in 18 of 120 steps of seed 3700000031. 4 is the
-# next the issue named and clears a layer of two experts with every
-# token each; what it costs beside 2 where 2 held (a recomputed layer,
-# ms): 29.8 / 20.7 ``sdar``, 25.6 / 21.1 ``nemotron3n``, 18.4 / 14.2
-# ``joyai``, against 41.3, 50.2 and 39.0 over every token-choice. At 4
-# (call 2): none in 888 steps, ``joyai``'s 360 on 3 seeds with the heavy
-# one (44.7-69.8k rows a step) among them.
-SLACK = 4
+# How far over their expected rows the rungs of a member's static row
+# bounds reach (:func:`rows_ladder`). The held experts own ``n /
+# router_width`` of the router and get that share of the ``T*k``
+# token-choices when the routing is balanced; a step's layer runs over
+# the smallest rung that holds its held rows, and over all ``T*k``
+# (exact, and as slow as before any bound) where they pass the last, so
+# the first factor is what most layers of most steps need and the last
+# what the heaviest need. Readings (a v5e; PERF.md, PR 37, calls 1 and
+# 2), layers a step that passed a bound of 2: ``sdar_ep8_steady``
+# (98-114k rows a step over 6 layers, 32,768 a layer) and
+# ``nemotron3n_ep16_steady`` (21.6-29.6k over 4, 12,288 a layer) none in
+# 784 steps of 6 seeds; ``joyai_ep16_steady`` (16,384 a layer) 1 or 2 of
+# its 5 layers in EVERY step of seed 3000000011 (44.8-70.0k rows a
+# step, the fullest expert of each layer 31-38k together: tokens without
+# context crowd one or two held experts of a layer, and one expert can
+# hold all 16,384 tokens) and in 18 of 120 steps of seed 3700000031. Of
+# 4: none in 888 steps, ``joyai``'s 360 on 3 seeds with the heavy one
+# among them; it clears a layer of two experts with every token each.
+# What 4 costs beside 2 where 2 holds (a recomputed layer, ms): 29.8 /
+# 20.7 ``sdar``, 25.6 / 21.1 ``nemotron3n``, 18.4 / 14.2 ``joyai``,
+# against 41.3, 50.2 and 39.0 over every token-choice: as one constant
+# 4 was ``joyai``'s need and the other cells' cost, so both are rungs.
+RUNG_FACTORS = (2, 4)
 
-# The bound is whole blocks of this many rows (a grouped product's row
+# A rung is whole blocks of this many rows (a grouped product's row
 # tile, and a sub-multiple of every cell's ``T*k``).
 _ROW_BLOCK = 512
 
 
-def rows_bound(choices: int, n: int, router_width: int) -> int:
-    """The static bound of the rows an expert layer's work runs over:
-    ``SLACK`` times the share of ``choices`` (``T*k``) that ``n`` held
-    experts of a router ``router_width`` wide expect, in whole blocks
-    of 512 rows, and never more than ``choices``: where every expert is
-    held, that is the bound and one path is traced."""
-    expected = SLACK * choices * n
-    blocks = -(-expected // (router_width * _ROW_BLOCK))
-    return min(choices, blocks * _ROW_BLOCK)
+def rows_ladder(choices: int, n: int, router_width: int) -> Tuple[int, ...]:
+    """The static sizes an expert layer's work may run over, strictly
+    increasing: the share of ``choices`` (``T*k``) that ``n`` held
+    experts of a router ``router_width`` wide expect, times each of
+    ``RUNG_FACTORS``, in whole blocks of 512 rows, for as long as that
+    stays under ``choices``; then ``choices`` itself, the path over every
+    token-choice. Where every expert is held (or the first rung already
+    reaches ``choices``) that is the whole ladder and one path is
+    traced."""
+    rungs = []
+    for factor in RUNG_FACTORS:
+        blocks = -(-factor * choices * n // (router_width * _ROW_BLOCK))
+        rung = blocks * _ROW_BLOCK
+        if rung >= choices:
+            break
+        if not rungs or rung > rungs[-1]:
+            rungs.append(rung)
+    return (*rungs, choices)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -442,43 +454,52 @@ def _part_over(form, rows, weights, w_up, w_down, order, inverse, sizes,
     return _gather_back(out.astype(dt), order, inverse, live, k)
 
 
-def _either_path(bound, run, sizes, *operands):
-    """``run(places, *operands)`` over the ``bound`` first sorted places
-    where the held rows fit under them, else over every place (``None``);
-    chosen on the device by the traced count."""
-    return jax.lax.cond(
-        jnp.sum(sizes) <= bound,
-        functools.partial(run, bound), functools.partial(run, None),
+def _rungs_passed(ladder, held):
+    """How many rungs of ``ladder`` below its last ``held`` rows pass:
+    the place in the ladder of the smallest that holds them (0 where
+    the ladder is one rung)."""
+    return sum((held > rung).astype(jnp.int32) for rung in ladder[:-1])
+
+
+def _on_the_rung(ladder, run, sizes, *operands):
+    """``run(places, *operands)`` over the first sorted places of the
+    smallest rung of ``ladder`` that holds the held rows, over every
+    place (``None``) where they pass every rung below the last; chosen
+    on the device by the traced count (:func:`_rungs_passed`)."""
+    return jax.lax.switch(
+        _rungs_passed(ladder, jnp.sum(sizes)),
+        [functools.partial(run, places) for places in (*ladder[:-1], None)],
         *operands,
     )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _part_under(bound, form, rows, weights, w_up, w_down, order, inverse,
+def _part_under(ladder, form, rows, weights, w_up, w_down, order, inverse,
                 sizes, held):
-    """:func:`_part_over` at ``bound`` rows where the held rows fit, at
-    ``T*k`` where they do not: no row is ever dropped. One custom rule
-    around the conditional, so that JAX neither differentiates through
-    it (each branch would be handed the other's residuals as ``T*k``
-    arrays of zeros) nor keeps anything of its forward: the rule's
-    residuals are its own arguments, and its backward is one conditional
-    whose branches run their path again and pull the cotangent through
-    it. Under a block's ``nn.remat`` the recomputed forward conditional
-    is dead code, so a step still runs a layer's products forward twice
-    and backward once."""
+    """:func:`_part_over` at the smallest rung of ``ladder`` the held
+    rows fit under, at ``T*k`` where they fit under none: no row is ever
+    dropped. One custom rule around the conditional, so that JAX neither
+    differentiates through it (each branch would be handed the others'
+    residuals as arrays of zeros) nor keeps anything of its forward: the
+    rule's residuals are its own arguments, and its backward is one
+    conditional whose branches run their path again and pull the
+    cotangent through it, on the rung the same ``sizes`` choose. Under a
+    block's ``nn.remat`` the recomputed forward conditional is dead
+    code, so a step still runs a layer's products forward twice and
+    backward once."""
     def forward(places, rows, weights, w_up, w_down, order, *rest):
         return _part_over(form, rows, weights, w_up, w_down, order[:places],
                           *rest)
 
-    return _either_path(bound, forward, sizes, rows, weights, w_up, w_down,
+    return _on_the_rung(ladder, forward, sizes, rows, weights, w_up, w_down,
                         order, inverse, sizes, held)
 
 
-def _part_under_fwd(bound, form, *operands):
-    return _part_under(bound, form, *operands), operands
+def _part_under_fwd(ladder, form, *operands):
+    return _part_under(ladder, form, *operands), operands
 
 
-def _part_under_bwd(bound, form, operands, g):
+def _part_under_bwd(ladder, form, operands, g):
     def backward(places, g, rows, weights, w_up, w_down, order, *rest):
         _, pull = jax.vjp(
             lambda *moving: _part_over(form, *moving, order[:places], *rest),
@@ -487,7 +508,7 @@ def _part_under_bwd(bound, form, operands, g):
         return pull(g)
 
     *_, sizes, _ = operands
-    moved = _either_path(bound, backward, sizes, g, *operands)
+    moved = _on_the_rung(ladder, backward, sizes, g, *operands)
     return (*moved, None, None, None, None)
 
 
@@ -508,10 +529,11 @@ def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
     x))``; not given, it is ``relu2`` without a gate and ``silu_gated``
     with one. Token-choices are sorted by expert with those of absent
     experts last; the spread, the grouped products over the n held
-    groups and what lies between them run over :func:`rows_bound` rows,
-    and over all ``T*k`` in a step whose held rows pass that bound; no
-    capacity, so no choice of a held expert is ever dropped. Returns
-    (part (T, d), rows of every held expert (n,) int32)."""
+    groups and what lies between them run over the smallest rung of
+    :func:`rows_ladder` that holds the step's held rows, all ``T*k``
+    where none below does; no capacity, so no choice of a held expert
+    is ever dropped. Returns (part (T, d), rows of every held expert
+    (n,) int32)."""
     t, k = chosen.shape
     n = w_up.shape[0]
     order, inverse, sizes, held = _sorted_choices(chosen, first_held, n)
@@ -536,31 +558,33 @@ def held_experts_part(rows, chosen, weights, w_gate, w_up, w_down,
         up = jnp.concatenate([widened(w_gate, 2), up], axis=2)
     operands = (rows, weights, up, widened(w_down, 1), order, inverse, sizes,
                 held)
-    bound = rows_bound(t * k, n, router_width)
-    if bound == t * k:
+    ladder = rows_ladder(t * k, n, router_width)
+    if len(ladder) == 1:
         return _part_over(form, *operands), sizes
-    return _part_under(bound, form, *operands), sizes
+    return _part_under(ladder, form, *operands), sizes
 
 
 @functools.lru_cache(maxsize=None)
-def log_traced_experts(cfg: MlaMoeConfig, bound: int, choices: int,
-                       ep: int):
+def log_traced_experts(cfg: MlaMoeConfig, ladder: Tuple[int, ...], ep: int):
     """One static line per traced expert layer shape (every layer of
     every trace asks again), like the attention's: what is held, what
-    the router scores, the static ``bound`` of the rows a member's work
-    runs over (:func:`rows_bound`) beside the ``choices`` (``T*k``) it
-    falls back to, which grouped product runs, and the width it runs at
-    where that is not the experts' own (:func:`product_width`)."""
+    the router scores, the static sizes of the rows a member's work may
+    run over (:func:`rows_ladder`: the rungs, then the ``T*k``
+    token-choices it falls back to), which grouped product runs, and the
+    width it runs at where that is not the experts' own
+    (:func:`product_width`)."""
+    *rungs, choices = ladder
     f = cfg.moe_intermediate_size
     shared = cfg.shared_intermediate_size or f
     wide = product_width(f)
     products = f", products at {wide} (zero columns)" if wide != f else ""
     logger.info(
         "experts: traced drop-free layer holding experts [%d, %d) of "
-        "router width %d, top-%d, rows bound %d of %d, grouped product "
+        "router width %d, top-%d, rows bound %s of %d, grouped product "
         "%s%s%s%s",
         cfg.first_held, cfg.first_held + cfg.n_held, cfg.router_width,
-        cfg.top_k, bound, choices, GROUPED_PRODUCT,
+        cfg.top_k, " / ".join(map(str, rungs or ladder)), choices,
+        GROUPED_PRODUCT,
         f", {cfg.n_held // ep} a member over ep={ep}" if ep > 1 else "",
         products if cfg.expert_form == "silu_gated" else (
             f", experts {cfg.expert_form.replace('_', '-')} of width "
@@ -684,8 +708,8 @@ class ExpertLayer(nn.Module):
         ) * cfg.routed_scaling_factor
 
         ep = 1 if self.mesh is None else self.mesh.shape.get("ep", 1)
-        bound = rows_bound(b * s * k, n // ep, cfg.router_width)
-        log_traced_experts(cfg, bound, b * s * k, ep)
+        ladder = rows_ladder(b * s * k, n // ep, cfg.router_width)
+        log_traced_experts(cfg, ladder, ep)
         if ep > 1:
             part, sizes = self._over_ep(
                 rows, chosen, weights, w_gate, w_up, w_down, ep
@@ -704,15 +728,23 @@ class ExpertLayer(nn.Module):
         shared = shared_mlp(
             cfg.shared_intermediate_size or f, cfg, self.mesh, name="shared"
         )(x) if cfg.shared_expert else None
-        # A member whose held rows pass its bound runs the layer over
-        # every token-choice (``held_experts_part``): counted, so that a
-        # routing the bound was not written for shows.
         counters = {
             "moe_rows": jnp.sum(sizes), "moe_expert_rows_max": jnp.max(sizes),
-            "moe_overflow_layers": jnp.any(
-                jnp.sum(sizes.reshape(ep, n // ep), axis=1) > bound
-            ).astype(jnp.int32),
         }
+        # What the ladder did with this step's routing. Whether a
+        # member's held rows passed the last rung below the path over
+        # every token-choice (never, where that path is the whole
+        # ladder): counted, so that a routing the ladder was not written
+        # for shows. And the rows of the rung each member's work ran
+        # over, by the index ``held_experts_part`` switches on, summed
+        # over the members.
+        held = jnp.sum(sizes.reshape(ep, n // ep), axis=1)
+        last = ladder[-2] if len(ladder) > 1 else ladder[-1]
+        counters["moe_overflow_layers"] = jnp.any(held > last).astype(
+            jnp.int32)
+        counters["moe_bound_rows"] = jnp.sum(jnp.asarray(ladder, jnp.int32)[
+            _rungs_passed(ladder, held)]) if len(ladder) > 1 else jnp.int32(
+                ep * ladder[0])
         part = part.reshape(b, s, d)
         out = wsc(part if shared is None else shared + part,
                   "dp", None, None)

@@ -26,9 +26,10 @@ do). :func:`product_width` has the readings and the rule the expert
 layer pads its weights by; the static bound costs too (at 6,144 live
 rows a layer's 5.95 + 13.66 ms under a bound of 98,304 are 4.25 + 10.31
 under 24,576: PERF.md section 7, row 28), so the expert layer gives its
-products ``m`` = ``models/mla_moe.py::rows_bound`` rows, the held
-experts' share of the token-choices times a slack, and all the
-token-choices only in a step whose groups pass that (PR 37).
+products ``m`` = a rung of ``models/mla_moe.py::rows_ladder`` rows, the
+held experts' share of the token-choices times 2 or 4 as the step's
+groups need, and all the token-choices only in a step whose groups pass
+both (PR 37, PR 40).
 """
 
 import jax

@@ -20,6 +20,7 @@ from elasticdl_tpu.core.train_state import init_train_state
 from elasticdl_tpu.models import mla_moe, nemotron_h
 from elasticdl_tpu.models.mla_moe import ExpertLayer
 from elasticdl_tpu.models.nemotron_h import NemotronHConfig, NemotronHLM
+from tests.test_mla_moe import RUNGS_LANDED_ON, layer_on_a_rung
 
 ZOO = load_module("model_zoo/nemotron_h/nemotron_h_lm.py")
 
@@ -207,6 +208,17 @@ def test_shares_add_up_to_the_uncut_layer(highest):
     np.testing.assert_allclose(total, whole, atol=2e-5)
 
 
+@pytest.mark.parametrize("lands", RUNGS_LANDED_ON)
+def test_relu2_experts_run_on_the_rung_their_rows_need(highest, monkeypatch,
+                                                      lands):
+    """The family's layer (relu^2 experts, a wider shared expert) under a
+    ladder of three sizes, on each of them."""
+    given = _layer_inputs(width=16, held=2, tokens=1024)
+    cfg = program_config(router_width=16, first_held=2, n_held=2, top_k=2)
+    layer_on_a_rung(monkeypatch, ExpertLayer(cfg), given["params"],
+                    given["x"], lands)
+
+
 def test_relu2_experts_over_an_ep_mesh_add_up(highest):
     """ep = 4 on the virtual CPU devices: experts without a gate matrix
     go through ``ExpertLayer._over_ep`` (two stacked operands, not
@@ -328,7 +340,7 @@ def test_fused_task_is_the_steps_one_by_one(seeded):
     body = _train_step_body(ZOO.loss)
     fused_state, fused = jit_task(body, donate=False)(state, stacked)
     assert set(fused) == {"loss", "moe_rows", "moe_expert_rows_max",
-                          "moe_overflow_layers"}
+                          "moe_bound_rows", "moe_overflow_layers"}
     step = jit_step(body, donate=False)
     losses = []
     for batch in batches:
@@ -413,12 +425,12 @@ def test_lines_say_the_scan_the_head_counts_and_the_experts_form():
         mla_moe.log_traced_experts(program_config(
             moe_intermediate_size=1856, shared_intermediate_size=3712,
             first_held=0, n_held=8, router_width=128, top_k=6),
-            mla_moe.rows_bound(98304, 8, 128), 98304, 1)
+            mla_moe.rows_ladder(98304, 8, 128), 1)
     mla_moe.log_traced_experts.cache_clear()
     assert log.lines == [
         "experts: traced drop-free layer holding experts [0, 8) of router "
-        f"width 128, top-6, rows bound {mla_moe.rows_bound(98304, 8, 128)} "
-        "of 98304, grouped product ragged_dot, "
+        "width 128, top-6, rows bound 12288 / 24576 of 98304, grouped product "
+        "ragged_dot, "
         "experts relu2 of width 1856, products at 2048 (zero columns), "
         "shared expert 3712"]
 

@@ -136,8 +136,9 @@ def test_restore_from_dir_detects_orbax_backend(tmp_path):
                      devices=jax.devices()[:8])
     _, state = _mesh_state(mesh)
     state = state.replace(step=state.step + 5)
-    save_state(OrbaxSaver(str(tmp_path)), state)
-    OrbaxSaver(str(tmp_path)).wait()
+    saver = OrbaxSaver(str(tmp_path))
+    save_state(saver, state)
+    saver.wait()        # the saver that wrote: another's waits on nothing
 
     _, fresh = _mesh_state(mesh)
     restored = restore_from_dir(fresh, str(tmp_path))

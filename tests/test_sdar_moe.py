@@ -19,6 +19,7 @@ from elasticdl_tpu.models import mla_moe, sdar_moe
 from elasticdl_tpu.models.mla_moe import ExpertLayer
 from elasticdl_tpu.models.sdar_moe import SdarMoeConfig, SdarMoeLM
 from tests.test_nemotron_h import _Lines
+from tests.test_mla_moe import RUNGS_LANDED_ON, layer_on_a_rung
 
 ZOO = load_module("model_zoo/sdar_moe/sdar_moe_lm.py")
 
@@ -329,6 +330,18 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(highest):
     np.testing.assert_allclose(total, whole, atol=2e-5)
 
 
+@pytest.mark.parametrize("lands", RUNGS_LANDED_ON)
+def test_the_layer_runs_on_the_rung_its_rows_need(highest, monkeypatch,
+                                                  lands):
+    """The family's layer (softmax scores, no selection bias, no shared
+    expert) under a ladder of three sizes, on each of them."""
+    given = _layer_inputs(width=16, tokens=1024)
+    cfg = program_config(router_width=16, first_held=2, n_held=2, top_k=2)
+    share = dict(given["params"], **{name: given["params"][name][2:4]
+                                     for name in ("w_gate", "w_up", "w_down")})
+    layer_on_a_rung(monkeypatch, ExpertLayer(cfg), share, given["x"], lands)
+
+
 # 3 members of 2 experts each, this one the second; a token chooses 3.
 ALIKE = dict(CFG, num_experts=2, router_width=6, first_held=2,
              num_experts_per_tok=3, router_init="members_alike")
@@ -450,7 +463,8 @@ def test_fused_task_is_the_steps_one_by_one(seeded):
     body = _train_step_body(ZOO.loss)
     fused_state, fused = jit_task(body, donate=False)(state, stacked)
     assert set(fused) == {"loss", "moe_rows", "moe_expert_rows_max",
-                          "moe_overflow_layers", "diffusion_masked_tokens"}
+                          "moe_bound_rows", "moe_overflow_layers",
+                          "diffusion_masked_tokens"}
     step = jit_step(body, donate=False)
     losses = []
     for i, batch in enumerate(batches):

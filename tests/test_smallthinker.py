@@ -24,6 +24,7 @@ from elasticdl_tpu.models.smallthinker import (
     SmallThinkerLM,
 )
 from tests.test_nemotron_h import _Lines
+from tests.test_mla_moe import RUNGS_LANDED_ON, layer_on_a_rung
 
 ZOO = load_module("model_zoo/smallthinker/smallthinker_lm.py")
 
@@ -337,6 +338,20 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(highest):
     assert float(jnp.max(jnp.abs(silu.reshape(whole.shape) - whole))) > 1e-3
 
 
+@pytest.mark.parametrize("lands", RUNGS_LANDED_ON)
+def test_the_layer_runs_on_the_rung_its_rows_need(highest, monkeypatch,
+                                                  lands):
+    """The family's layer (ReLU-gated experts, the router reading
+    another tensor) under a ladder of three sizes, on each of them."""
+    given = _layer_inputs(width=16, tokens=1024)
+    cfg = program_config(router_width=16, first_held=2, n_held=2, top_k=2,
+                         moe_intermediate_size=8, hidden_size=16)
+    share = dict(given["params"], **{name: given["params"][name][2:4]
+                                     for name in ("w_gate", "w_up", "w_down")})
+    layer_on_a_rung(monkeypatch, ExpertLayer(cfg), share, given["x"], lands,
+                    router_input=given["read"])
+
+
 def test_a_form_and_its_gate_have_to_agree():
     given = _layer_inputs(width=4)
     p = given["params"]
@@ -453,7 +468,8 @@ def test_fused_task_is_the_steps_one_by_one(seeded):
     body = _train_step_body(ZOO.loss)
     fused_state, fused = jit_task(body, donate=False)(state, stacked)
     assert set(fused) == {"loss", "moe_rows", "moe_expert_rows_max",
-                          "moe_overflow_layers", "attn_visible_pairs"}
+                          "moe_bound_rows", "moe_overflow_layers",
+                          "attn_visible_pairs"}
     step = jit_step(body, donate=False)
     losses = []
     for i, batch in enumerate(batches):

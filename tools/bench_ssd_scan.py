@@ -6,10 +6,11 @@ to compile). Run through the chip tool; prints one JSON line a reading.
 
     python tools/bench_ssd_scan.py [check] [scan] [attention] [experts]
 
-(``bound`` alone is the first part of ``experts``.)
+(``bound`` alone is the first part of ``experts``; ``bound <cell> ...``
+keeps it to those cells' layers; ``combine [<cell> ...]`` times the
+combine of a rung's rows alone.)
 """
 
-import functools
 import json
 import os
 import statistics
@@ -170,49 +171,40 @@ def _expert_layer(bound, live, groups, d, f, wide, gated, params):
 
 
 # The expert cells' layers from the tokens' side: tokens a step, top k,
-# the router's width, held experts, hidden size, experts' width, gate.
+# the router's width, held experts, hidden size, experts' width, form,
+# and whether the cell's seeded router sends every token to one held
+# expert and k - 1 absent ones (``members_alike`` and its kin) or to k
+# of the router's experts at random.
 CELL_LAYERS = {
-    "joyai_ep16_steady": (16384, 8, 256, 16, 2048, 768, True),
-    "nemotron3n_ep16_steady": (16384, 6, 128, 8, 2688, 1856, False),
-    "sdar_ep8_steady": (16384, 8, 128, 16, 2048, 768, True),
+    "joyai_ep16_steady": (16384, 8, 256, 16, 2048, 768, "silu_gated", False),
+    "nemotron3n_ep16_steady": (16384, 6, 128, 8, 2688, 1856, "relu2", False),
+    "sdar_ep8_steady": (16384, 8, 128, 16, 2048, 768, "silu_gated", True),
+    "smallthinker_ep8_steady": (16384, 6, 64, 8, 2560, 768, "relu_gated",
+                                True),
 }
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _gathered_back(sorted_rows, order, inverse, live, k):
-    """``mla_moe._gather_back`` under a bound as ``T*k`` gathers from the
-    bound's rows (a choice past them reads zero), for the comparison:
-    the layer's other way to combine, which it ran before the readings."""
-    t = inverse.shape[0] // k
-    picked = sorted_rows.at[inverse].get(mode="fill", fill_value=0)
-    picked = picked.reshape(t, k, sorted_rows.shape[1])
-    return picked.astype(jnp.float32).sum(axis=1).astype(sorted_rows.dtype)
-
-
-_gathered_back.defvjp(
-    lambda *a: (_gathered_back(*a), a[1:4]), mla_moe._gather_back_bwd)
-
-
-def _recomputed_layer(tokens, k, router_width, n, d, f, gated, told_width):
-    """``held_experts_part`` itself on a balanced routing (every expert
-    of the router gets ``tokens * k / router_width`` choices), told the
-    router is ``told_width`` wide: ``n`` gives the bound ``tokens * k``
-    and the one path the program ran before the bound. Returns
+def _recomputed_layer(tokens, k, router_width, n, d, f, form, crowd):
+    """``held_experts_part`` itself on a routing spread evenly over the
+    first ``router_width // crowd`` experts of the router (so the held
+    ones get ``crowd`` times a balanced router's share). Returns
     (forward, a recomputed layer whole: forward, then under
-    ``jax.checkpoint`` what its backward pass runs) and their
-    arguments."""
+    ``jax.checkpoint`` what its backward pass runs), their arguments
+    and the held rows."""
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
     rows = jax.random.normal(keys[0], (tokens, d), jnp.bfloat16)
     weigh = jax.random.uniform(keys[1], (tokens, k), jnp.float32)
     pull = jax.random.normal(keys[2], (tokens, d), jnp.float32)
+    routed = router_width // crowd
     chosen = (jnp.arange(tokens, dtype=jnp.int32)[:, None]
-              + jnp.arange(k, dtype=jnp.int32)[None, :] * (router_width // k)
-              ) % router_width
-    up, down, *gate = _expert_weights(n, d, f, gated, jnp.float32)
+              + jnp.arange(k, dtype=jnp.int32)[None, :] * (routed // k)
+              ) % routed
+    up, down, *gate = _expert_weights(n, d, f, form != "relu2", jnp.float32)
 
     def part(rows, weigh, up, down, *gate):
-        return mla_moe.held_experts_part(rows, chosen, weigh, gate[0] if gate
-                                 else None, up, down, 0, told_width)[0]
+        return mla_moe.held_experts_part(
+            rows, chosen, weigh, gate[0] if gate else None, up, down, 0,
+            router_width, form)[0]
 
     def loss(*moving):
         return jnp.sum(jax.checkpoint(part)(*moving).astype(jnp.float32)
@@ -220,36 +212,100 @@ def _recomputed_layer(tokens, k, router_width, n, d, f, gated, told_width):
 
     args = (rows, weigh, up, down, *gate)
     return (jax.jit(part),
-            jax.jit(jax.value_and_grad(loss, tuple(range(len(args))))), args)
+            jax.jit(jax.value_and_grad(loss, tuple(range(len(args))))), args,
+            int(jnp.sum(chosen < n)))
 
 
-def bound():
-    """What the static row bound buys (PERF.md section 7, row 28): the
-    cells' three expert layers from the tokens' side, by the code the
-    cells run. Over every token-choice (the path a step falls back to,
-    all the program had before PR 37) beside over ``rows_bound`` rows at
-    ``SLACK`` 2 and 4, the bound's rows scatter-added into their tokens
-    (what the layer does) and gathered back by every token-choice."""
-    kept = mla_moe.SLACK, mla_moe._gather_back
-    for cell, (tokens, k, width, n, d, f, gated) in CELL_LAYERS.items():
-        ways = [("every token-choice", n, kept[0], kept[1])] + [
-            (f"rows bound at SLACK {slack}, {how}", width, slack, back)
-            for slack in (2, 4)
-            for how, back in (("scatter-added back", kept[1]),
-                              ("gathered back", _gathered_back))]
-        for what, told, slack, back in ways:
-            mla_moe.SLACK, mla_moe._gather_back = slack, back
+def bound(cells=()):
+    """What the ladder of row bounds buys (PERF.md section 7, row 28):
+    the cells' expert layers (``cells``, or all four) from the tokens'
+    side, by the code the cells run. Over every token-choice on one
+    path (all the program had before PR 37); then under a two-way
+    conditional at 2 and at 4 times the held share (the second PR 37's
+    program) and under the ladder's three-way one, on a balanced
+    routing, on one that sends the held experts 3 times their share
+    (the rung at 4) and on one that sends them 6 times (past every
+    rung: the path over every token-choice inside the conditional)."""
+    kept = mla_moe.RUNG_FACTORS
+    ways = [((), 1), ((2,), 1), ((4,), 1), ((2, 4), 1), ((2,), 3), ((4,), 3),
+            ((2, 4), 3), ((4,), 6), ((2, 4), 6)]
+    for cell in cells or CELL_LAYERS:
+        tokens, k, width, n, d, f, form, _ = CELL_LAYERS[cell]
+        for factors, crowd in ways:
+            mla_moe.RUNG_FACTORS = factors
             try:
-                forward, whole, args = _recomputed_layer(
-                    tokens, k, width, n, d, f, gated, told)
+                forward, whole, args, live = _recomputed_layer(
+                    tokens, k, width, n, d, f, form, crowd)
+                ladder = mla_moe.rows_ladder(tokens * k, n, width)
                 say(what=f"{cell}'s expert layer from the tokens' side, "
-                    f"{tokens * k * n // width} live rows, {what}",
-                    rows=mla_moe.rows_bound(tokens * k, n, told),
+                    f"{live} live rows, rungs at {factors or 'none'}",
+                    ladder=ladder, branches=len(ladder),
+                    rows=min(rung for rung in ladder
+                             if rung >= live or rung == ladder[-1]),
                     forward_ms=timed(forward, *args),
                     recomputed_layer_ms=timed(whole, *args))
             finally:
-                mla_moe.SLACK, mla_moe._gather_back = kept
+                mla_moe.RUNG_FACTORS = kept
             del forward, whole, args
+
+
+def _combine_case(cell, sizes_of_rows):
+    """One routing of ``cell``'s layer, as its seeded router sends it,
+    sorted as the layer sorts it, and the combine alone over the first
+    ``places`` sorted places for every ``places`` of ``sizes_of_rows``."""
+    import numpy as np
+
+    tokens, k, width, n, d, _, _, alike = CELL_LAYERS[cell]
+    rng = np.random.default_rng(0)
+    if not alike:
+        chosen = np.argsort(rng.random((tokens, width)), axis=1)[:, :k]
+    else:
+        chosen = np.concatenate([
+            rng.integers(0, n, (tokens, 1)),
+            np.stack([rng.permutation(np.arange(n, width))[:k - 1]
+                      for _ in range(tokens)])], axis=1)
+    order, inverse, sizes, _ = mla_moe._sorted_choices(
+        jnp.asarray(chosen, jnp.int32), 0, n)
+    held = int(sizes.sum())
+
+    def added(rows, to):
+        return jnp.zeros((tokens, d), jnp.float32).at[to].add(
+            rows.astype(jnp.float32)).astype(rows.dtype)
+
+    def gathered(rows, inverse):
+        picked = rows.at[inverse].get(mode="fill", fill_value=0)
+        return picked.reshape(tokens, k, d).astype(jnp.float32).sum(
+            axis=1).astype(rows.dtype)
+
+    for places in sizes_of_rows:
+        live = jnp.arange(places) < held
+        rows = jnp.where(live[:, None], jax.random.normal(
+            jax.random.PRNGKey(1), (places, d), jnp.bfloat16), 0)
+        to = order[:places] // k
+        say(what=f"{cell}'s combine alone, {held} live rows of width {d}, "
+            + ("one held choice a token" if alike
+               else "a balanced routing"), rows=places,
+            scatter_added_ms=timed(jax.jit(added), rows, to),
+            gathered_ms=timed(jax.jit(gathered), rows, inverse))
+
+
+def combine(cells=()):
+    """The combine of a rung's rows into their tokens alone
+    (``mla_moe._gather_back`` under a rung, and ``_spread``'s transpose:
+    two a layer), which PR 40 found slower over 24,576 rows than over
+    49,152 in ``smallthinker_ep8_steady``: the float32 scatter-add as
+    the layer runs it and the ``T*k`` gathers by ``inverse`` it
+    replaced, over 2 and 4 times the held share and the sizes between
+    them. (With the rows of no group sent past the last token and
+    dropped the scatter-add gave the same bits in the same time at every
+    size: PERF.md section 6, PR 40, call B.)"""
+    for cell in cells or CELL_LAYERS:
+        tokens, k, width, n, *_ = CELL_LAYERS[cell]
+        first, second = (-(-factor * tokens * k * n // (width * 512)) * 512
+                         for factor in (2, 4))
+        _combine_case(cell, sorted({
+            tokens, (tokens + first) // 2, first, (first + second) // 2,
+            second}))
 
 
 def experts():
@@ -294,6 +350,11 @@ def experts():
 
 
 if __name__ == "__main__":
-    for part in sys.argv[1:] or ["check", "scan", "attention", "experts"]:
-        {"check": check, "scan": scan, "attention": attention,
-         "experts": experts, "bound": bound}[part]()
+    named = [arg for arg in sys.argv[1:] if arg not in CELL_LAYERS]
+    for part in named or ["check", "scan", "attention", "experts"]:
+        if part in ("bound", "combine"):
+            {"bound": bound, "combine": combine}[part](
+                [arg for arg in sys.argv[1:] if arg in CELL_LAYERS])
+        else:
+            {"check": check, "scan": scan, "attention": attention,
+             "experts": experts}[part]()
